@@ -40,9 +40,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 
 from sda_tpu.ops import find_packed_parameters
-from sda_tpu.ops.jaxcfg import ensure_x64, sync_platform_to_env
+from sda_tpu.ops.jaxcfg import ensure_x64
 
-sync_platform_to_env()
 ensure_x64()
 
 import jax

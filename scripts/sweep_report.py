@@ -1,8 +1,9 @@
-"""Summarize healthy-window experiment artifacts into a defaults table.
+"""Summarize banked experiment artifacts into a defaults table.
 
-``scripts/tpu-experiments.sh`` banks budget-capped north-star variants as
-``bench-artifacts/exp-<tag>-<stamp>.json``. This reads them all, groups by
-configuration (rng x chunk x check), and prints per-config best rates plus
+Budget-capped north-star variants (``python bench.py --rng/--chunk/--check
+...`` run on the chip, each metric line saved as
+``bench-artifacts/exp-<tag>-<stamp>.json``) are read here, grouped by
+configuration (rng x chunk x check), and printed as per-config best rates plus
 a recommendation line — the evidence trail for changing bench defaults
 (e.g. ``--chunk``) between rounds. Partial runs are rate-bearing (the
 bench verifies what it measured before stopping), so they count, flagged.

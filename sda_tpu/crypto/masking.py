@@ -8,8 +8,6 @@ all participants' masks and subtracts. Vectors are numpy int64 throughout
 
 from __future__ import annotations
 
-import logging
-
 import numpy as np
 
 from ..native import chacha_combine, chacha_expand as expand_seed
@@ -141,32 +139,18 @@ class ChaChaMasker(SecretMasker, MaskCombiner, SecretUnmasker):
 
     #: below this many expanded elements the host loop beats device dispatch
     DEVICE_COMBINE_THRESHOLD = 1 << 22
-    #: distinct failures already warned about — a jax-less deployment warns
-    #: once, while a *new* failure mode (e.g. device OOM) still surfaces
-    _device_combine_warned: set = set()
 
     def combine(self, seeds):
         seed_rows = [np.asarray(s, dtype=np.int64).astype(np.uint32) for s in seeds]
         if len(seed_rows) * self.dimension >= self.DEVICE_COMBINE_THRESHOLD:
-            # reveal hot loop (receive.rs:102-118): expand + sum on device,
-            # Pallas ChaCha kernel when available (ops/chacha_pallas.py)
-            try:
-                from ..ops.chacha_pallas import combine_masks_device
+            # reveal hot loop (receive.rs:102-118): expand + sum on device
+            # (ops/chacha_pallas.py). Above the threshold this path runs or
+            # raises — a device failure must not hide behind the host loop.
+            from ..ops.chacha_pallas import combine_masks_device
 
-                return np.asarray(
-                    combine_masks_device(np.stack(seed_rows), self.dimension, self.modulus)
-                )
-            except Exception as e:
-                # any failure falls back to the host loop (results stay
-                # correct); each *distinct* failure mode is warned once —
-                # no per-reveal spam on jax-less hosts, but a new problem
-                # (e.g. device OOM) can't hide behind an old warning
-                failure = f"{type(e).__name__}: {e}"
-                if failure not in ChaChaMasker._device_combine_warned:
-                    ChaChaMasker._device_combine_warned.add(failure)
-                    logging.getLogger(__name__).warning(
-                        "device mask combine unavailable (%s); using host loop", failure
-                    )
+            return np.asarray(
+                combine_masks_device(np.stack(seed_rows), self.dimension, self.modulus)
+            )
         if not seed_rows:
             return np.zeros(self.dimension, dtype=np.int64)
         # one C call expands + folds the whole cohort (19x the numpy loop;

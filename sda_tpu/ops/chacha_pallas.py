@@ -21,15 +21,15 @@ the same lane axis — one kernel launch expands every participant's stream.
 Bit parity: every path (numpy host, jnp, Pallas) runs the same djb quarter
 round over states from the one state builder (``chacha_state_jnp``), so
 outputs are bit-identical — asserted in tests/test_ops_field.py on the
-interpreter and (when available) on real TPU. ``ChaChaMasker.combine``
-(crypto/masking.py) dispatches here for large reveal batches and falls back
-to the host loop when no accelerator path is usable.
+interpreter and by ``chip_smoke.py`` on the TPU. ``ChaChaMasker.combine``
+(crypto/masking.py) dispatches here for large reveal batches.
 """
 
 from __future__ import annotations
 
 import logging
 
+from .. import telemetry
 from .chacha import apply_rounds_jnp, chacha_rounds_jnp, chacha_state_jnp, rand03_zone
 
 # lane-axis tile: 512 blocks x 16 words x 4 B x 2 (in+out) = 64 KiB of VMEM
@@ -74,57 +74,25 @@ def chacha_blocks_pallas(
     return _rounds_pallas(state, interpret=interpret)
 
 
-#: probe cache: None = not yet probed; True/False = cached for the process
-_PALLAS_OK: bool | None = None
+def default_backend() -> str:
+    """Which rounds implementation ``"auto"`` means on this process's JAX
+    backend: the compiled kernel on a TPU, the jnp twin anywhere else
+    (there Pallas only offers its interpreter, slower than jnp). A kernel
+    that fails to compile on the TPU raises at its call site."""
+    import jax
 
-
-def pallas_available() -> bool:
-    """Can this backend run the compiled kernel? (CPU meshes and the
-    interpreter don't count — they'd be slower than the jnp twin.)
-
-    Probed lazily on first use (the jax backend is already initialized by
-    then — ``ensure_x64`` ran) and cached for the process either way, with
-    exactly one log line on failure: re-probing would re-trace a failed
-    pallas_call per chunk (~1000 redundant compile attempts per large
-    reveal on a backend without Pallas). A kernel that *runs but produces
-    wrong bits* is logged as an error — it would otherwise corrupt masks
-    silently.
-    """
-    global _PALLAS_OK
-    if _PALLAS_OK is not None:
-        return _PALLAS_OK
-    import numpy as np
-
-    log = logging.getLogger(__name__)
-    try:
-        import jax.numpy as jnp
-
-        got = np.asarray(chacha_blocks_pallas(jnp.arange(8, dtype=jnp.uint32), 0, 1))
-        from .chacha import chacha_blocks
-
-        ok = bool(np.array_equal(got, chacha_blocks(np.arange(8), 0, 1)))
-        if not ok:
-            log.error("Pallas ChaCha kernel produced wrong bits; disabled for process")
-    except Exception as e:
-        log.warning(
-            "Pallas ChaCha unavailable (%s: %s); using jnp rounds for process",
-            type(e).__name__,
-            e,
-        )
-        ok = False
-    _PALLAS_OK = ok
-    return ok
+    return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
 def _rounds(states, backend: str):
     """Dispatch ``(N, 16) -> (N, 16)`` rounds by backend name.
 
-    ``auto`` = compiled Pallas kernel when the backend supports it, else the
-    jnp twin; ``pallas`` / ``interpret`` / ``jnp`` force a specific path
-    (interpret = Pallas interpreter, for CPU tests of the kernel source).
+    ``auto`` = :func:`default_backend`; ``pallas`` / ``interpret`` / ``jnp``
+    force a specific path (interpret = Pallas interpreter, for CPU tests of
+    the kernel source).
     """
     if backend == "auto":
-        backend = "pallas" if pallas_available() else "jnp"
+        backend = default_backend()
     if backend == "pallas":
         return _rounds_pallas(states)
     if backend == "interpret":
@@ -174,9 +142,7 @@ def expand_seeds_counts(seed_words, dim: int, modulus: int, backend: str = "jnp"
     trailing mask values; callers MUST check ``counts`` (host-side, in
     their epilogue) before using the masks. :func:`expand_seeds_batch` is
     the eager wrapper that does exactly that and raises ``SlackExhausted``.
-    ``backend`` must be resolved ("jnp"/"pallas"/"interpret") when called
-    under jit — "auto" probes the backend eagerly at trace time, which is
-    fine on first trace but pins the choice into the compiled computation.
+    ``backend`` as in ``_rounds``.
     """
     from .jaxcfg import ensure_x64
 
@@ -274,7 +240,9 @@ def _fold_chunk_jit(batch, dim: int, modulus: int, backend: str):
 _COMBINE_BYTES_BUDGET = 2 << 30
 
 
-def combine_masks_device(seed_words, dim: int, modulus: int, *, chunk: int | None = None):
+def combine_masks_device(
+    seed_words, dim: int, modulus: int, *, chunk: int | None = None, backend: str = "auto"
+):
     """Recipient reveal hot loop on device: Σ_p expand(seed_p) mod m.
 
     (P, w) uint32 seeds -> (dim,) int64 combined mask — the ChaCha
@@ -283,6 +251,7 @@ def combine_masks_device(seed_words, dim: int, modulus: int, *, chunk: int | Non
     sized so one fold's ~5 transient chunk x dim x 8 B tensors fit in
     ``_COMBINE_BYTES_BUDGET`` (e.g. dim=100K -> chunk ~ 1K folds of ~2 GB),
     so the headline 1M x 100K reveal streams instead of OOMing.
+    ``backend`` as in ``_rounds``.
     """
     from .jaxcfg import ensure_x64
 
@@ -294,7 +263,16 @@ def combine_masks_device(seed_words, dim: int, modulus: int, *, chunk: int | Non
 
     if chunk is None:
         chunk = max(16, _COMBINE_BYTES_BUDGET // (5 * 8 * dim))
-    backend = "pallas" if pallas_available() else "jnp"
+    if backend == "auto":  # resolved here: it is a static jit argument
+        backend = default_backend()
+    seed_words = np.asarray(seed_words, dtype=np.uint32)
+    # same series the host paths count into (native/__init__.py), so a
+    # scrape shows which implementation expanded a reveal's seeds
+    telemetry.counter(
+        "sda_crypto_chacha_expands_total",
+        "ChaCha mask seeds expanded/combined by path",
+        path=backend,
+    ).inc(int(seed_words.shape[0]))
 
     def fold_chunk(batch):
         return _fold_chunk_jit(batch, dim, modulus, backend)
@@ -309,7 +287,6 @@ def combine_masks_device(seed_words, dim: int, modulus: int, *, chunk: int | Non
             return jnp.sum(masks, axis=0) % jnp.int64(modulus)
         return mod_sum_wide_jnp(masks, modulus, axis=0)
 
-    seed_words = np.asarray(seed_words, dtype=np.uint32)
     total = jnp.zeros((dim,), dtype=jnp.int64)
     for start in range(0, seed_words.shape[0], chunk):
         batch = seed_words[start : start + chunk]

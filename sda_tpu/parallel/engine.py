@@ -43,7 +43,6 @@ from dataclasses import dataclass
 import numpy as np
 
 from .. import telemetry
-from . import compat
 from ..ops import shamir
 from ..ops.jaxcfg import ensure_x64
 from ..protocol import AdditiveSharing, BasicShamirSharing, PackedShamirSharing
@@ -380,7 +379,7 @@ class TpuAggregator:
             # all participants sum locally — wide-safe reduction
             return clerk_combine_mod(resharded, modulus)  # (n/p, B)
 
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             local_step,
             mesh=self.mesh,
             in_specs=(P("p", None), P()),
@@ -435,7 +434,7 @@ class TpuAggregator:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             self._limb_accumulator_local_step(("p",)),
             mesh=self.mesh,
             # in_specs requires a "d" axis, so no d-less fallback here
@@ -474,7 +473,7 @@ class TpuAggregator:
             total = lax.psum(partial, axis_name="p")
             return lax.rem(total, jnp.int64(modulus))
 
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             local_step,
             mesh=self.mesh,
             in_specs=(P("p", "d"), P()),

@@ -157,10 +157,8 @@ def hierarchical_clerk_sums(scheme, dim: int, mesh):
         total = lax.psum(partial, axis_name="h")
         return lax.rem(total, jnp.int64(plan.modulus))
 
-    from . import compat
-
     d_spec = "d" if "d" in mesh.axis_names else None
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(("h", "p"), d_spec), P()),
@@ -186,14 +184,13 @@ def hierarchical_limb_accumulators(scheme, dim: int, mesh):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from . import compat
     from .engine import TpuAggregator
 
     agg = TpuAggregator(scheme, dim, mesh=mesh)
     agg.validate_d_sharding(dim)
 
     d_spec = "d" if "d" in mesh.axis_names else None
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         # ICI ("p") before DCN ("h"): only the tiny accumulator crosses hosts
         agg._limb_accumulator_local_step(("p", "h")),
         mesh=mesh,

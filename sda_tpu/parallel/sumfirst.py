@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import numpy as np
 
-from . import compat
 from ..ops import shamir
 from ..ops.jaxcfg import ensure_x64
 from ..ops.modular import modmatmul_np
@@ -237,7 +236,7 @@ def sharded_value_limb_sums(plan: AggregationPlan, mesh):
         return lax.psum(acc, axis_name="p")
 
     d_spec = "d" if "d" in mesh.axis_names else None
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P("p", d_spec), P()),

@@ -16,10 +16,7 @@ def main() -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=2")
 
-    from sda_tpu.ops.jaxcfg import ensure_x64, sync_platform_to_env
-
-    sync_platform_to_env()
-
+    from sda_tpu.ops.jaxcfg import ensure_x64
     from sda_tpu.parallel.multihost import initialize_distributed
 
     initialize_distributed(

@@ -119,9 +119,7 @@ def test_fold_mesh_axes_distinct_per_device():
     def per_device(key):
         return jax.random.key_data(fold_mesh_axes(key, mesh))[None]
 
-    from sda_tpu.parallel import compat
-
-    keys = compat.shard_map(
+    keys = jax.shard_map(
         per_device, mesh=mesh, in_specs=P(), out_specs=P(("p", "d")),
         check_vma=False,
     )(jax.random.key(0))
@@ -209,17 +207,16 @@ def test_two_process_distributed_round():
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]
 
-    dep_paths = [p for p in sys.path if p and not p.startswith(str(repo))]
     env = dict(
         os.environ,
         JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=2",
-        PYTHONPATH=os.pathsep.join(dep_paths + [str(repo)]),
+        PYTHONPATH=str(repo),
     )
     worker = str(repo / "tests" / "multihost_worker.py")
     procs = [
         subprocess.Popen(
-            [sys.executable, "-S", worker, str(i), "2", str(port)],
+            [sys.executable, worker, str(i), "2", str(port)],
             stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
             env=env, cwd=repo,
         )

@@ -200,7 +200,8 @@ def test_chacha_expand_deterministic_and_in_range():
 def test_chacha_pallas_kernel_bit_identical():
     """The Pallas TPU kernel (ops/chacha_pallas.py) must produce the same
     keystream bits as the numpy host path — run here on the interpreter
-    (CPU test mesh); the same assertion runs on real TPU when available."""
+    (CPU test mesh); chip_smoke.py holds the compiled kernel to the same
+    bits on the TPU."""
     import jax.numpy as jnp
 
     from sda_tpu.ops import chacha_pallas

@@ -332,9 +332,11 @@ def test_basic_shamir_engine_end_to_end():
 
 
 def test_pallas_participant_path_bit_identical(jax_mods):
-    """The fused Pallas participant kernel (interpret mode on CPU) produces
-    bit-identical limb accumulators to the jnp share_combine_limb for the
-    same key, across block-aligned and odd participant counts."""
+    """The fused Pallas participant kernel (interpret mode, passed
+    explicitly) produces bit-identical limb accumulators to the jnp
+    share_combine_limb for the same key — across the pad path, several
+    participant blocks, an odd participant count, and more than one
+    batch tile."""
     import jax.numpy as jnp
     from jax import random
 
@@ -346,12 +348,15 @@ def test_pallas_participant_path_bit_identical(jax_mods):
     from sda_tpu.protocol import PackedShamirSharing
 
     scheme = PackedShamirSharing(5, 8, 2, p, w2, w3)
-    dim = 23  # pad path
-    plan = make_plan(scheme, dim)
     rng = np.random.default_rng(17)
-    for P in (500, 37):  # block-aligned (250x2) and odd (single-step fallback)
+    # (dim, participants): nb=5 pad path with 600 > 512 rows = 2 blocks;
+    # an odd count; nb=2100 > 2048 lanes = 2 batch tiles x 3 blocks of 32
+    for dim, P in ((23, 600), (23, 37), (10_500, 70)):
+        plan = make_plan(scheme, dim)
         secrets = rng.integers(0, p, size=(P, dim)).astype(np.int64)
         key = random.key(P)
         want = np.asarray(share_combine_limb(jnp.asarray(secrets), key, plan))
-        got = np.asarray(share_combine_limb_pallas(jnp.asarray(secrets), key, plan))
+        got = np.asarray(
+            share_combine_limb_pallas(jnp.asarray(secrets), key, plan, interpret=True)
+        )
         np.testing.assert_array_equal(got, want)

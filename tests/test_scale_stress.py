@@ -25,14 +25,10 @@ N = int(os.environ.get("SDA_STRESS_N", 100_000))
 
 def _run(backend: str, tmp_path) -> dict:
     repo = pathlib.Path(__file__).resolve().parent.parent
-    dep_paths = [p for p in sys.path if p and not p.startswith(str(repo))]
-    env = dict(
-        os.environ,
-        PYTHONPATH=os.pathsep.join(dep_paths + [str(repo)]),
-    )
+    env = dict(os.environ, PYTHONPATH=str(repo))
     out = subprocess.run(
         [
-            sys.executable, "-S",
+            sys.executable,
             str(repo / "tests" / "scale_stress_worker.py"),
             backend, str(N), "8", str(tmp_path),
         ],
