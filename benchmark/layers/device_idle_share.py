@@ -1,0 +1,14 @@
+"""Share of the traced window in which no operation ran, on the chip that
+idles most."""
+
+name = "device.idle_share"
+unit = "%"
+layer = "device"
+moves = "round_s"
+cells = None
+
+
+def reduce(spans, trace, cell):
+    if trace is None:
+        return None
+    return 100.0 * trace.idle_share()
