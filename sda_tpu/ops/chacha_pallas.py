@@ -62,6 +62,7 @@ def _rounds_pallas(states, *, interpret: bool = False):
         out_specs=pl.BlockSpec((16, _TILE), lambda i: (zero, i)),
         out_shape=jax.ShapeDtypeStruct((16, padded), jnp.uint32),
         interpret=interpret,
+        name="chacha_rounds",
     )(st)
     return out[:, :n].T
 

@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import telemetry
 from .lagrange import lagrange_matrix
 from .modular import modmatmul_np, positive
 from .ntt import dft_matrix, inverse_dft_matrix
@@ -162,10 +163,12 @@ def reconstruct_clerk_sums_host(clerk_sums, indices, scheme, dim: int) -> np.nda
     surviving 0-based clerk ``indices`` -> ``(dim,)`` aggregate (pad
     truncated). Exact for any modulus width; shared by the wide branch of
     ``parallel.engine.reconstruct`` and the sum-first bench epilogue."""
-    L = reconstruction_matrix(scheme, list(indices))  # (k, R)
+    p = scheme.prime_modulus
     rows = np.asarray(clerk_sums)[list(indices)]  # (R, B)
-    secrets = reconstruct_batches(rows.T, L, scheme.prime_modulus)  # (B, k)
-    return np.asarray(secrets).reshape(-1)[:dim]
+    with telemetry.span("fabric.reconstruct", modulus_bits=p.bit_length(), shape=rows.shape):
+        L = reconstruction_matrix(scheme, list(indices))  # (k, R)
+        secrets = reconstruct_batches(rows.T, L, p)  # (B, k)
+        return np.asarray(secrets).reshape(-1)[:dim]
 
 
 def reshare_coefficients(scheme, survivors, position) -> np.ndarray:

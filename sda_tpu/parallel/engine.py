@@ -51,39 +51,9 @@ from ..protocol import AdditiveSharing, BasicShamirSharing, PackedShamirSharing
 def _step_hist(step: str):
     return telemetry.histogram(
         "sda_engine_step_seconds",
-        "secure_sum stage / sharded-fabric invocation timing (host "
-        "dispatch unless JAX blocks)",
+        "secure_sum stage timing (host dispatch unless JAX blocks)",
         step=step,
     )
-
-
-def _instrument_fabric(fn, fabric: str, axis_size: int):
-    """Wrap a jitted sharded fabric fn(secrets, key): invocation timing
-    plus nominal psum traffic (result size x participant-axis size).
-
-    Transparent under tracing — ``verified_step`` re-jits over fabric
-    fns, and trace-time side effects would count compilations as
-    invocations — and under disabled telemetry.
-    """
-
-    def instrumented(secrets, key):
-        if not telemetry.enabled():
-            return fn(secrets, key)
-        import jax.core
-
-        if isinstance(secrets, jax.core.Tracer):
-            return fn(secrets, key)
-        t0 = time.perf_counter()
-        out = fn(secrets, key)
-        _step_hist(fabric).observe(time.perf_counter() - t0)
-        telemetry.counter(
-            "sda_engine_psum_bytes_total",
-            "nominal bytes moved per psum/all_to_all by sharded fabrics",
-            fabric=fabric,
-        ).inc(int(out.size) * out.dtype.itemsize * axis_size)
-        return out
-
-    return instrumented
 
 
 @dataclass(frozen=True)
@@ -145,19 +115,25 @@ def _batch_secrets(secrets, plan: AggregationPlan):
     happens at the true global tail.
     """
     jnp = _jnp()
+    import jax
+
     P, d = secrets.shape
     nb = -(-d // plan.input_size)
     pad = nb * plan.input_size - d
-    padded = jnp.pad(secrets, ((0, 0), (0, pad)))
-    return padded.reshape(P, nb, plan.input_size)
+    with jax.named_scope("fabric.input/batch"):
+        padded = jnp.pad(secrets, ((0, 0), (0, pad)))
+        return padded.reshape(P, nb, plan.input_size)
 
 
 def _device_randomness(key, shape, modulus):
     """Counter-based uniform draws in [0, modulus) (simulation-grade RNG —
     real participants draw on their own hosts; see ops/rng.py)."""
+    import jax
+
     from ..ops.rng import uniform_mod_device
 
-    return uniform_mod_device(key, shape, modulus)
+    with jax.named_scope("fabric.rand/draw"):
+        return uniform_mod_device(key, shape, modulus)
 
 
 def share_participants(
@@ -228,6 +204,8 @@ def share_combine_limb(secrets, key, plan: AggregationPlan, draw=None):
     multiply/divide never touches the (participants x dim) tensor.
     """
     jnp = _jnp()
+    import jax
+
     from .limbmatmul import fold_const_limbs, limb_partials_const
 
     if draw is None:
@@ -236,20 +214,21 @@ def share_combine_limb(secrets, key, plan: AggregationPlan, draw=None):
     batches = _batch_secrets(secrets, plan)  # (C, b, k)
     C, nb = batches.shape[0], batches.shape[1]
     randomness = draw(key, (C, nb, plan.rand_size), p)
-    # keep the big tensor in native int32 lanes when the field fits
-    dt = jnp.int32 if p <= (1 << 31) else jnp.int64
-    values = jnp.concatenate([batches.astype(dt), randomness.astype(dt)], axis=-1)
+    with jax.named_scope("fabric.values"):
+        # keep the big tensor in native int32 lanes when the field fits
+        dt = jnp.int32 if p <= (1 << 31) else jnp.int64
+        values = jnp.concatenate([batches.astype(dt), randomness.astype(dt)], axis=-1)
+        values = values.reshape(C * nb, -1)
     stacks = fold_const_limbs(plan.share_matrix.T, p)  # (L, L*(k+t), n)
-    partials = limb_partials_const(
-        values.reshape(C * nb, -1), stacks, p
-    )  # (W=L, C*nb, n)
+    partials = limb_partials_const(values, stacks, p)  # (W=L, C*nb, n)
     W, LK = stacks.shape[0], stacks.shape[1]
-    per_part = partials.reshape(W, C, nb, -1)
-    # participant-axis reduction: stay in int32 when the bound allows
-    # (partial elements <= L*K * 127^2), halving the reduction cost
-    if C * LK * 127 * 127 < 2**31:
-        return jnp.sum(per_part, axis=1).astype(jnp.int64)  # (W, b, n)
-    return jnp.sum(per_part.astype(jnp.int64), axis=1)  # (W, b, n)
+    with jax.named_scope("fabric.combine"):
+        per_part = partials.reshape(W, C, nb, -1)
+        # participant-axis reduction: stay in int32 when the bound allows
+        # (partial elements <= L*K * 127^2), halving the reduction cost
+        if C * LK * 127 * 127 < 2**31:
+            return jnp.sum(per_part, axis=1).astype(jnp.int64)  # (W, b, n)
+        return jnp.sum(per_part.astype(jnp.int64), axis=1)  # (W, b, n)
 
 
 def clerk_combine(shares):
@@ -386,7 +365,7 @@ class TpuAggregator:
             out_specs=P("p", None),
             check_vma=False,
         )
-        return _instrument_fabric(jax.jit(mapped), "all_to_all", p_size)
+        return jax.jit(mapped)
 
     def _limb_accumulator_local_step(self, psum_axes):
         """Shared per-device body of the wide-modulus fabric: fused limb
@@ -394,6 +373,7 @@ class TpuAggregator:
         order (single-slice: ('p',); hybrid: ('p', 'h') — ICI before
         DCN). One definition so overflow-bound or chunking fixes apply to
         every fabric at once."""
+        import jax
         from jax import lax
 
         plan = self.plan
@@ -402,8 +382,9 @@ class TpuAggregator:
         def local_step(secrets, key):
             key = fold_mesh_axes(key, mesh)
             acc = share_combine_limb(secrets, key, plan)  # (W, b_local, n)
-            for ax in psum_axes:
-                acc = lax.psum(acc, axis_name=ax)
+            with jax.named_scope("fabric.psum"):
+                for ax in psum_axes:
+                    acc = lax.psum(acc, axis_name=ax)
             return acc
 
         return local_step
@@ -442,9 +423,7 @@ class TpuAggregator:
             out_specs=P(None, "d", None),
             check_vma=False,
         )
-        return _instrument_fabric(
-            jax.jit(mapped), "sharded_limb_accumulators", self.mesh.shape["p"]
-        )
+        return jax.jit(mapped)
 
     def sharded_clerk_sums(self):
         """Build the jitted sharded share+combine step over the mesh.
@@ -480,9 +459,7 @@ class TpuAggregator:
             out_specs=P(None, "d") if "d" in self.mesh.axis_names else P(),
             check_vma=False,
         )
-        return _instrument_fabric(
-            jax.jit(mapped), "sharded_clerk_sums", self.mesh.shape["p"]
-        )
+        return jax.jit(mapped)
 
 
 

@@ -132,6 +132,7 @@ def participant_limb_sums_pallas(values, stacks, *, interpret: bool = False):
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="limb_share_combine",
     )(values, jnp.asarray(rows))
     return out[:, :, :nb]
 

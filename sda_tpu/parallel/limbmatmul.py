@@ -160,31 +160,33 @@ def limb_partials_const(A, stacks, p: int):
     if LK * 127 * 127 >= (1 << 31):
         raise ValueError(f"contraction {LK} overflows int32 accumulator")
 
-    x = A.astype(jnp.int32) if p <= (1 << 31) else A.astype(jnp.int64)
-    seven = x.dtype.type(0x7F)
-    a_limbs = jnp.concatenate(
-        [((x >> x.dtype.type(7 * i)) & seven).astype(jnp.int8) for i in range(L)],
-        axis=-1,
-    )  # (M, L*K)
     import jax
 
-    if jax.default_backend() == "cpu":
-        # XLA's CPU emitter mis-fuses the int64->int8 limb extraction into
-        # the int8 dot for some degenerate shapes (k=1 wide), producing
-        # invalid IR ("add i32, i8"). A barrier cuts that fusion; the TPU
-        # path (where a_limbs materializes for the L dots anyway) is left
-        # untouched.
-        a_limbs = lax.optimization_barrier(a_limbs)
-    partials = [
-        lax.dot_general(
-            a_limbs,
-            jnp.asarray(stacks[m]),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
-        for m in range(L)
-    ]
-    return jnp.stack(partials)  # (L, M, N) int32
+    with jax.named_scope("fabric.share_matmul/limbs"):
+        x = A.astype(jnp.int32) if p <= (1 << 31) else A.astype(jnp.int64)
+        seven = x.dtype.type(0x7F)
+        a_limbs = jnp.concatenate(
+            [((x >> x.dtype.type(7 * i)) & seven).astype(jnp.int8) for i in range(L)],
+            axis=-1,
+        )  # (M, L*K)
+        if jax.default_backend() == "cpu":
+            # XLA's CPU emitter mis-fuses the int64->int8 limb extraction into
+            # the int8 dot for some degenerate shapes (k=1 wide), producing
+            # invalid IR ("add i32, i8"). A barrier cuts that fusion; the TPU
+            # path (where a_limbs materializes for the L dots anyway) is left
+            # untouched.
+            a_limbs = lax.optimization_barrier(a_limbs)
+    with jax.named_scope("fabric.share_matmul/dot"):
+        partials = [
+            lax.dot_general(
+                a_limbs,
+                jnp.asarray(stacks[m]),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32,
+            )
+            for m in range(L)
+        ]
+        return jnp.stack(partials)  # (L, M, N) int32
 
 
 def limb_modmatmul_const(A, B_host, p: int):
@@ -224,8 +226,13 @@ def limb_recombine_host(partials, p: int):
     arithmetic is fine. Returns canonical int64 values."""
     import numpy as np
 
-    arr = np.asarray(partials, dtype=object)
-    out = np.zeros(arr.shape[1:], dtype=object)
-    for w in range(arr.shape[0]):
-        out = (out + arr[w] * pow(128, w, p)) % p
-    return out.astype(np.int64)
+    from .. import telemetry
+
+    with telemetry.span(
+        "fabric.epilogue.recombine", modulus_bits=int(p).bit_length(), shape=np.shape(partials)
+    ):
+        arr = np.asarray(partials, dtype=object)
+        out = np.zeros(arr.shape[1:], dtype=object)
+        for w in range(arr.shape[0]):
+            out = (out + arr[w] * pow(128, w, p)) % p
+        return out.astype(np.int64)

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import telemetry
 from ..ops import shamir
 from ..ops.jaxcfg import ensure_x64
 from ..ops.modular import modmatmul_np
@@ -87,12 +88,14 @@ def value_limb_sums_chunk_pair(hi, lo, key, plan: AggregationPlan, draw_pair):
     (parity-tested bit-exact against :func:`value_limb_sums_chunk`).
     """
     ensure_x64()
+    import jax
     import jax.numpy as jnp
 
     C = hi.shape[0]
     batches_hi = _batch_secrets(hi, plan)  # (C, b, k) — pad/reshape, dtype-agnostic
     batches_lo = _batch_secrets(lo, plan)
-    rand_hi, rand_lo = draw_pair(key, (C, batches_hi.shape[1], plan.rand_size))
+    with jax.named_scope("fabric.rand/draw"):
+        rand_hi, rand_lo = draw_pair(key, (C, batches_hi.shape[1], plan.rand_size))
     cols_hi = jnp.concatenate([batches_hi, rand_hi], axis=-1)  # (C, b, K)
     cols_lo = jnp.concatenate([batches_lo, rand_lo], axis=-1)
     return jnp.stack([exact_sum_narrow_u32(cols_lo), exact_sum_narrow_u32(cols_hi)])
@@ -117,6 +120,7 @@ def value_limb_sums_chunk(secrets, key, plan: AggregationPlan, draw=None):
     ``share_participants`` for the same key).
     """
     ensure_x64()
+    import jax
     import jax.numpy as jnp
 
     p = plan.modulus
@@ -142,7 +146,11 @@ def value_limb_sums_chunk(secrets, key, plan: AggregationPlan, draw=None):
             [jnp.sum(x & mask, axis=0), jnp.sum(x >> jnp.int64(32), axis=0)]
         )
 
-    return jnp.concatenate([limb_sums(batches), limb_sums(randomness)], axis=-1)
+    with jax.named_scope("fabric.input/limb_sum"):
+        input_sums = limb_sums(batches)
+    with jax.named_scope("fabric.rand/limb_sum"):
+        rand_sums = limb_sums(randomness)
+    return jnp.concatenate([input_sums, rand_sums], axis=-1)
 
 
 def exact_value_sums(limb_acc):
@@ -167,14 +175,17 @@ def clerk_sums_from_limb_acc(limb_acc, plan: AggregationPlan, exact=None):
     precomputed ``exact_value_sums(limb_acc)`` as ``exact`` to reuse it.
     """
     p = plan.modulus
-    if exact is None:
-        exact = exact_value_sums(limb_acc)
-    vsum = exact % p  # exact sums >= 0: % == canonical rem
     if plan.share_matrix is None:
         raise ValueError("sum-first epilogue requires a packed share matrix")
-    S_T = plan.share_matrix.T.astype(np.int64)  # (K, n)
-    clerk = modmatmul_np(vsum, S_T, p)  # (B, n) in (-p, p)
-    clerk = np.where(clerk < 0, clerk + p, clerk).astype(np.int64)
+    bits = p.bit_length()
+    with telemetry.span("fabric.epilogue.recombine", modulus_bits=bits, shape=np.shape(limb_acc)):
+        if exact is None:
+            exact = exact_value_sums(limb_acc)
+        vsum = exact % p  # exact sums >= 0: % == canonical rem
+    with telemetry.span("fabric.epilogue.share_matmul", modulus_bits=bits, shape=vsum.shape):
+        S_T = plan.share_matrix.T.astype(np.int64)  # (K, n)
+        clerk = modmatmul_np(vsum, S_T, p)  # (B, n) in (-p, p)
+        clerk = np.where(clerk < 0, clerk + p, clerk).astype(np.int64)
     return clerk.T.copy(), vsum.astype(np.int64)
 
 
@@ -233,7 +244,8 @@ def sharded_value_limb_sums(plan: AggregationPlan, mesh):
             )
         key = fold_mesh_axes(key, mesh)
         acc = value_limb_sums_chunk(secrets, key, plan)
-        return lax.psum(acc, axis_name="p")
+        with jax.named_scope("fabric.psum"):
+            return lax.psum(acc, axis_name="p")
 
     d_spec = "d" if "d" in mesh.axis_names else None
     mapped = jax.shard_map(

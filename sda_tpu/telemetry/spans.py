@@ -10,7 +10,10 @@ thread *and* per async task without any locking.
 Spans are deliberately cheap records (name, trace_id, wall start,
 duration, attrs), kept in a bounded ring buffer for inspection
 (``recent()`` / the ``/v1/metrics.json`` view) and optionally mirrored as
-structured JSON log lines keyed by trace-id (see :mod:`.logsink`).
+structured JSON log lines keyed by trace-id (see :mod:`.logsink`). In a
+process that has imported JAX an open span is also a
+``jax.profiler.TraceAnnotation``: an event of the profiler's trace, on its
+clock, beside the device's operations.
 """
 
 from __future__ import annotations
@@ -18,6 +21,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import re
+import sys
 import threading
 import time
 import uuid
@@ -33,6 +37,16 @@ _TRACE_RE = re.compile(r"[A-Za-z0-9_.:-]{1,64}")
 _trace_var: contextvars.ContextVar = contextvars.ContextVar(
     "sda_trace_id", default=None
 )
+
+
+def _profiler_annotation(name: str):
+    """The open span as an event of the JAX profiler's trace, where JAX is
+    already imported; a process that never imports it (the REST planes)
+    still does not."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return contextlib.nullcontext()
+    return profiler.TraceAnnotation(name)
 
 
 def new_trace_id() -> str:
@@ -82,7 +96,7 @@ class SpanLog:
         """Time a block; record {name, trace_id, start, duration_s, attrs}.
 
         Disabled telemetry short-circuits to a bare yield — no clock
-        reads, no record, no log line."""
+        reads, no record, no log line, no profiler annotation."""
         if not self._registry.enabled:
             yield None
             return
@@ -92,16 +106,17 @@ class SpanLog:
             "start": time.time(),
             "attrs": attrs or None,
         }
-        t0 = time.perf_counter()
-        try:
-            yield record
-        finally:
-            record["duration_s"] = time.perf_counter() - t0
-            with self._lock:
-                self._spans.append(record)
-            from .logsink import emit as _log_emit
+        with _profiler_annotation(name):
+            t0 = time.perf_counter()
+            try:
+                yield record
+            finally:
+                record["duration_s"] = time.perf_counter() - t0
+                with self._lock:
+                    self._spans.append(record)
+                from .logsink import emit as _log_emit
 
-            _log_emit("span", record)
+                _log_emit("span", record)
 
     def recent(self, name: str | None = None, trace_id: str | None = None) -> list:
         """Finished spans, oldest first, optionally filtered by name
