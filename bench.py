@@ -3421,9 +3421,9 @@ def run_fabric(args: argparse.Namespace, watchdog=None) -> dict:
         # const-folded limb partials: one weight group per limb of p
         W = limb_count(p)
         acc_shape = (W, B, n) if use_limbs else (n, B)
-        # same division-free synthetic draws as the sumfirst branch: masked
-        # bits over a power-of-two sub-range (zero modulo bias; the emulated
-        # 64-bit `%` in uniform_mod_device would dominate the pipeline)
+        # same synthetic draws as the sumfirst branch: masked bits over a
+        # power-of-two sub-range (zero modulo bias; one threefry draw where
+        # uniform_mod_device takes two and a reduction mod p)
         nbits = p.bit_length() - 1
         narrow = use_limbs and p <= (1 << 31)
 

@@ -251,3 +251,99 @@ def modmatmul_jnp(A, B, m):
     mm = jnp.asarray(m, dtype=jnp.int64)
     prods = lax.rem(A[..., :, None] * B[None, ...], mm)
     return lax.rem(jnp.sum(prods, axis=-2), mm)
+
+
+# ---------------------------------------------------------------------------
+# u64 mod a modulus known at trace time, division-free
+# ---------------------------------------------------------------------------
+
+
+def _mulhi32_const(x, c: int):
+    """High uint32 word of ``x * c``: ``x`` a uint32 array, ``c`` a python
+    int below 2**32. XLA has no multiply-high; schoolbook on 16-bit halves,
+    every partial product and sum below 2**32."""
+    import jax.numpy as jnp
+
+    u32 = jnp.uint32
+    x1, x0 = x >> u32(16), x & u32(0xFFFF)
+    c1, c0 = c >> 16, c & 0xFFFF
+    w = x1 * u32(c0) + ((x0 * u32(c0)) >> u32(16))
+    if c1 == 0:
+        return w >> u32(16)
+    w2 = x0 * u32(c1) + (w & u32(0xFFFF))
+    return x1 * u32(c1) + (w >> u32(16)) + (w2 >> u32(16))
+
+
+def mod_u64_const(hi, lo, m: int):
+    """``(hi·2³² + lo) mod m`` as uint64, exact, with no division on the
+    device: ``hi``, ``lo`` uint32 arrays, ``m`` a python int, ``0 < m <=
+    2**63``.
+
+    A chip with no integer divide emulates ``u64 % m`` as a 64-step long
+    division, ~1 900 lane instructions a value (PERF.md §5). For a modulus
+    known when the program is traced the quotient is a multiply-high by a
+    reciprocal computed here in python integers, put right by one or two
+    compare-and-subtracts; all on uint32 words, as the chip's lanes are.
+
+    - ``m`` a power of two: a mask.
+    - ``m < 2**32``: ``hi mod m`` by compare-and-subtract, then ``(that·2³²
+      + lo) mod m`` as a 2-by-1 word division by the reciprocal of the
+      normalised divisor (Möller & Granlund 2011, algorithm 4).
+    - else the quotient is below 2**32: ``q = mulhi(hi, floor(2**(64+e)/m))
+      >> e`` is it or one short (two where ``e`` finds no room), and
+      ``u - q·m`` is taken mod 2**64.
+    """
+    import jax.numpy as jnp
+
+    u32, u64 = jnp.uint32, jnp.uint64
+    if not (0 < m <= 1 << 63):
+        raise ValueError(f"modulus out of range: {m}")
+    bits = m.bit_length()
+
+    def join(r_hi, r_lo):
+        return (r_hi.astype(u64) << u64(32)) | r_lo.astype(u64)
+
+    if m & (m - 1) == 0:
+        mask = m - 1
+        return join(hi & u32(mask >> 32), lo & u32(mask & 0xFFFFFFFF))
+
+    if bits <= 32:
+        # hi mod m: hi < 2**(s+1)·m, a compare-and-subtract for each bit
+        s = 32 - bits
+        r = hi
+        for j in reversed(range(s + 1)):
+            r = jnp.where(r >= u32(m << j), r - u32(m << j), r)
+        # (r·2³² + lo) mod m, dividend and divisor shifted until the
+        # divisor's top bit is set: (u1·2³² + u0) mod d, u1 < d
+        d = m << s
+        v = ((1 << 64) - 1) // d - (1 << 32)
+        u1, u0 = ((r << u32(s)) | (lo >> u32(bits)), lo << u32(s)) if s else (r, lo)
+        q0 = u1 * u32(v) + u0
+        q1 = _mulhi32_const(u1, v) + u1 + (q0 < u0).astype(u32) + u32(1)
+        r = u0 - q1 * u32(d)
+        r = jnp.where(r > q0, r + u32(d), r)
+        r = jnp.where(r >= u32(d), r - u32(d), r)
+        return (r >> u32(s)).astype(u64)
+
+    # 2**32 < m: the quotient has 65 - bits bits. A reciprocal of 16 bits
+    # halves the multiply-high where that still leaves e >= 1.
+    e = bits - 49 if bits >= 50 else bits - 33
+    k = (1 << (64 + e)) // m  # < 2**16, or < 2**32
+    q = _mulhi32_const(hi, k) >> u32(e)
+    # u/m - q < 1 + 2**32/m + 2**-e: one correction where that is <= 2
+    corrections = 1 if (1 << (32 + e)) + m <= (m << e) else 2
+    m_hi, m_lo = m >> 32, m & 0xFFFFFFFF
+    p_lo = q * u32(m_lo)
+    p_hi = q * u32(m_hi)
+    if (65 - bits) + m_lo.bit_length() > 32:
+        p_hi = p_hi + _mulhi32_const(q, m_lo)
+
+    def sub64(a_hi, a_lo, b_hi, b_lo):
+        return a_hi - b_hi - (a_lo < b_lo).astype(u32), a_lo - b_lo
+
+    r_hi, r_lo = sub64(hi, lo, p_hi, p_lo)
+    for _ in range(corrections):
+        ge = (r_hi > u32(m_hi)) | ((r_hi == u32(m_hi)) & (r_lo >= u32(m_lo)))
+        s_hi, s_lo = sub64(r_hi, r_lo, u32(m_hi), u32(m_lo))
+        r_hi, r_lo = jnp.where(ge, s_hi, r_hi), jnp.where(ge, s_lo, r_lo)
+    return join(r_hi, r_lo)

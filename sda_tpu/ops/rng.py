@@ -8,7 +8,9 @@ Device path: counter-based draws from JAX's threefry PRNG for
 simulation/benchmark workloads (1M synthetic participants on a TPU mesh);
 uses a 64-bit draw reduced mod m, whose bias is < 2**-33 for m < 2**31 —
 fine for load simulation, NOT a substitute for the host CSPRNG in real
-deployments (documented trade-off).
+deployments (documented trade-off). The reduction is by a reciprocal of the
+modulus computed at trace time (``modular.mod_u64_const``), not the ``%``
+a chip without an integer divide would emulate as a long division.
 """
 
 from __future__ import annotations
@@ -63,21 +65,22 @@ def uniform_mod_device(key, shape, m: int):
     import jax.numpy as jnp
     from jax import random
 
+    from .modular import mod_u64_const
+
     hi = random.bits(key, shape=shape, dtype=jnp.uint32)
     k2 = random.fold_in(key, 1)
     lo = random.bits(k2, shape=shape, dtype=jnp.uint32)
-    u64 = (hi.astype(jnp.uint64) << 32) | lo.astype(jnp.uint64)
-    return (u64 % jnp.uint64(m)).astype(jnp.int64)
+    return mod_u64_const(hi, lo, m).astype(jnp.int64)
 
 
 def uniform_bits_device(key, shape, nbits: int):
     """Uniform draws over ``[0, 2**nbits)`` via masked random bits.
 
-    Exact (power-of-two range — zero modulo bias) and division-free: the
-    64-bit ``%`` in :func:`uniform_mod_device` is emulated on 32-bit TPU
-    lanes and dominates generation cost (~10x). The streaming benchmark
-    uses this for synthetic participant data with ``nbits = p.bit_length()
-    - 1``, a sub-range of the field that exercises identical arithmetic.
+    Exact (power-of-two range — zero modulo bias): one draw and a mask,
+    where :func:`uniform_mod_device` takes two draws and a reduction by the
+    modulus's reciprocal. ``bench.py`` uses this for synthetic participant
+    data with ``nbits = p.bit_length() - 1``, a sub-range of the field that
+    exercises identical arithmetic.
     Simulation only — protocol-plane randomness is host CSPRNG rejection
     sampling (``uniform_mod_host``), where full-range uniformity is a
     privacy requirement, not a convenience.

@@ -114,10 +114,11 @@ def value_limb_sums_chunk(secrets, key, plan: AggregationPlan, draw=None):
     Secrets and randomness are limb-summed separately and joined on the
     tiny ``(B, ·)`` results — the big ``(C, B, K)`` concatenation the share
     matmul needs never materializes. ``draw(key, shape, p) -> int64 in
-    [0, p)`` overrides the randomness generator (the benchmark passes a
-    division-free masked-bits draw; default is the simulation-grade
-    ``uniform_mod_device``, which keeps this bit-identical to
-    ``share_participants`` for the same key).
+    [0, p)`` overrides the randomness generator (``bench.py`` passes a
+    masked-bits draw over a power-of-two sub-range; the benchmark's cells
+    pass none). The default is the simulation-grade ``uniform_mod_device``
+    over the whole field, which keeps this bit-identical to
+    ``share_participants`` for the same key.
     """
     ensure_x64()
     import jax
