@@ -1,24 +1,27 @@
 """The benchmark's harness: finds a cell's files by the names in
-``BENCHMARK.json``, sets the cell up on the devices it is handed, runs rounds
-back to back for the window, and reduces what it saw to the result line.
+``BENCHMARK.json``, has the cell's round set itself up on the devices it is
+handed, runs rounds back to back for the window, and reduces what it saw to
+the result line.
 
-It knows no cell, configuration, traffic mix or metric by name:
+It knows no cell, configuration, traffic mix, metric, scheme or round by
+name, and imports nothing of the program:
 
 * a cell is one entry of ``BENCHMARK.json``'s ``workloads``;
 * its configuration is ``benchmark/configs/<config>.json`` (field, scheme,
   dim, dropped clerks, guarantees);
-* its traffic mix is ``benchmark/traffic/<traffic>.json`` (engine entry by
-  dotted name, rows, passes, chunk, mesh), read by the one generator in
-  :mod:`benchmark.traffic`;
+* its traffic mix is ``benchmark/traffic/<traffic>.json`` (rows, passes,
+  chunk, mesh), read by the one generator in :mod:`benchmark.traffic`;
+* its round is the module the traffic file names (``round``; by default
+  ``benchmark.rounds.packed_fold``), of the interface :mod:`benchmark.rounds`
+  describes: it builds the scheme and the step, makes the input and the
+  reference, and runs one round from key to comparison under its own spans;
 * a per-layer metric is one module in ``benchmark/layers/``, found by listing
   the directory.
 
-One round (the clock runs from key to comparison): fresh share key -> the
-engine's chunk step over every chunk of the resident input, with the
-program's default share randomness -> ``block_until_ready`` and transfer of
-the accumulator -> host epilogue to clerk sums -> drop the configuration's
-clerks -> reconstruct from exactly ``reconstruction_threshold`` survivors ->
-compare the whole aggregate with the plain reference.
+What is the harness's own, common to every round: the window with its
+failure rules, the spans' clock, the count of compiles inside the window,
+tracing and its reduction, the layer metrics, the result line and the run's
+record.
 
 The devices are handed in by the caller: ``run.py`` hands in TPU chips or
 exits, the tests hand in CPU devices. Nothing here picks a platform.
@@ -29,6 +32,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import gc
+import importlib
 import importlib.util
 import json
 import pathlib
@@ -39,11 +43,11 @@ import traceback
 
 import numpy as np
 
-from benchmark import reference, trace_reduce
+from benchmark import scopes, trace_reduce
 from benchmark import traffic as traffic_mod
 
-#: the benchmark's span names, outermost first; idle gaps are named by them
-SPAN_NAMES = ("round", "dispatch", "fold", "fetch", "epilogue", "check")
+#: the harness's own span, round every round; a round module names the rest
+ROUND_SPAN = "round"
 
 #: a traced window closes after this many seconds (or ``--seconds``, if
 #: shorter): traces are large and tracing slows the host, so the per-layer
@@ -130,6 +134,26 @@ def load_cell(root, workload: str) -> Cell:
     )
 
 
+def round_of(cell: Cell):
+    """The module of the cell's round, by the dotted path its traffic file
+    gives: a new kind of round is a new file."""
+    try:
+        module = importlib.import_module(cell.traffic.round)
+    except ImportError as e:
+        raise HarnessError(f"cell {cell.name!r}: no round {cell.traffic.round!r}: {e}") from e
+    for attr in ("span_names", "Session", "steps"):
+        if not hasattr(module, attr):
+            raise HarnessError(f"{cell.traffic.round}: a round module needs `{attr}`")
+    return module
+
+
+def span_names(cell: Cell) -> tuple:
+    """The spans of the cell's rounds, outermost first: the harness's
+    ``round`` and those its round module opens inside; idle gaps are named by
+    them and the trace's reader keeps host events of these names."""
+    return (ROUND_SPAN, *round_of(cell).span_names)
+
+
 def load_layers(root) -> dict:
     """Every per-layer metric module under ``benchmark/layers/``, by the
     metric's name. Found by listing the directory: a new metric is a new
@@ -206,187 +230,6 @@ class Spans:
         return [s.seconds for s in self.records if s.name == name]
 
 
-# ---------------------------------------------------------------------------
-# Set-up
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class Program:
-    """What a cell runs, built from its files: the program's plan and scheme,
-    the jitted chunk step, the host epilogue and reconstruct. Holds no array,
-    so the compile rehearsal builds it for described devices too."""
-
-    scheme: object
-    plan: object
-    modulus: int
-    chunk_fn: object  # fn(secrets, key) -> accumulator, one chunk
-    step: object  # jitted fn(acc, chunk, key, i) -> acc
-    epilogue: object  # fn(acc_host) -> (n, B) clerk sums
-    reconstruct: object
-    survivors: list  # exactly reconstruction_threshold surviving clerks
-    second_subset: list  # warm-up's second subset: another clerk left out
-
-
-def build_program(cell: Cell, mesh) -> Program:
-    import jax
-
-    jax.config.update("jax_enable_x64", True)
-    import jax.numpy as jnp
-    from jax import lax
-
-    from sda_tpu.ops import find_packed_parameters
-    from sda_tpu.parallel.engine import make_plan
-    from sda_tpu.protocol import PackedShamirSharing
-
-    spec = cell.config["scheme"]
-    if spec["kind"] != "packed_shamir":
-        raise HarnessError(f"unknown scheme kind {spec['kind']!r}")
-    k, t, n = spec["secret_count"], spec["privacy_threshold"], spec["share_count"]
-    p, w2, w3 = find_packed_parameters(
-        k, t, n, min_modulus_bits=spec["min_modulus_bits"], seed=spec["parameter_seed"]
-    )
-    scheme = PackedShamirSharing(k, n, t, p, w2, w3)
-    modulus = int(p)
-    plan = make_plan(scheme, cell.dim)
-    threshold = scheme.reconstruction_threshold
-    stated = cell.config["guarantees"]
-    if stated["reconstruction_threshold"] != threshold or stated["privacy_threshold"] != t:
-        raise HarnessError("the configuration's stated thresholds are not the scheme's")
-    dropped = set(cell.config["dropped_clerks"])
-    alive = [i for i in range(n) if i not in dropped]
-    if len(alive) < threshold:
-        raise HarnessError("fewer clerks survive than reconstruction needs")
-    survivors = alive[:threshold]
-
-    tr = cell.traffic
-    chunk_fn = traffic_mod.resolve(tr.engine_call)(
-        traffic_mod.resolve(tr.engine), plan, mesh
-    )
-    accumulate_mod_p = tr.accumulate == "sum_mod_p"
-
-    def step(acc, chunk, key, i):
-        # one chunk step: a chunk of the resident input, the round's key with
-        # the step's number folded in, the program's default share randomness
-        out = chunk_fn(chunk, jax.random.fold_in(key, i))
-        acc = acc + out
-        if accumulate_mod_p:
-            acc = lax.rem(acc, jnp.int64(modulus))
-        return acc
-
-    return Program(
-        scheme=scheme,
-        plan=plan,
-        modulus=modulus,
-        chunk_fn=chunk_fn,
-        step=jax.jit(step),
-        epilogue=traffic_mod.resolve(tr.epilogue_call)(
-            traffic_mod.resolve(tr.epilogue), plan
-        ),
-        reconstruct=traffic_mod.resolve(tr.reconstruct),
-        survivors=survivors,
-        second_subset=[i for i in range(n) if i != survivors[-1]][:threshold],
-    )
-
-
-class Session:
-    """One cell set up on its devices: input and reference resident, the
-    chunk step ready, to run rounds."""
-
-    def __init__(self, cell: Cell, seed: int, devices, stages=None):
-        """``stages``, if given, is filled with the seconds each part of
-        set-up took, for the run's record."""
-        import jax
-        import jax.numpy as jnp
-
-        clock = time.perf_counter()
-        stages = {} if stages is None else stages
-
-        def stage(name):
-            nonlocal clock
-            now = time.perf_counter()
-            stages[name] = now - clock
-            clock = now
-
-        if len(devices) < cell.chips:
-            raise HarnessError(
-                f"cell {cell.name!r} needs {cell.chips} devices, got {len(devices)}"
-            )
-        self.cell = cell
-        self.devices = list(devices[: cell.chips])
-        tr = cell.traffic
-        self.mesh = traffic_mod.make_mesh(tr, self.devices)
-        self.program = program = build_program(cell, self.mesh)
-        self.plan, self.modulus = program.plan, program.modulus
-        modulus = program.modulus
-        steps = tr.steps_per_pass
-
-        # everything a step takes besides its chunk sits on every chip before
-        # the window, so that a step moves nothing between chips but its psum
-        everywhere = traffic_mod.replicated(self.devices, self.mesh)
-        self.step_index = [
-            jax.device_put(jnp.int32(i), everywhere) for i in range(steps * tr.passes)
-        ]
-        self.fold_in = jax.jit(jax.random.fold_in, out_shardings=everywhere)
-        # the input and the reference's sums of it, made on the device from
-        # the seed by one program, chunk by chunk
-        stage("program")
-        make = traffic_mod.chunk_maker(tr, cell.dim, modulus, self.devices, self.mesh)
-        seed_key = jax.random.key(seed)
-        input_key = self.fold_in(seed_key, 0)
-        self.share_key = self.fold_in(seed_key, 1)
-        half_sums = jax.device_put(jnp.zeros((2, cell.dim), jnp.int64), everywhere)
-        self.chunks, columns = [], []
-        for i in self.step_index[:steps]:
-            chunk, half_sums, strided = make(input_key, i, half_sums)
-            self.chunks.append(chunk)
-            columns.append(strided)
-        half_sums, columns = np.asarray(half_sums), [np.asarray(c) for c in columns]
-        stage("input_on_device")
-        self.want = reference.aggregate(
-            half_sums, np.concatenate(columns), modulus, tr.passes, tr.rows
-        )
-        stage("reference_on_host")
-        acc_shape = jax.eval_shape(program.chunk_fn, self.chunks[0], self.share_key)
-        self.zero_acc = jax.device_put(jnp.zeros(acc_shape.shape, jnp.int64), everywhere)
-        self.chunk_bytes = int(self.chunks[0].nbytes)
-
-    def run_round(self, index: int, spans: Spans, subsets=None):
-        """One round. Returns ``(matched, clerk_sums)``; ``subsets`` (warm-up
-        only) are further clerk subsets that must reveal the same."""
-        with spans("round", index):
-            key = self.fold_in(self.share_key, index)
-            with spans("dispatch", index):
-                acc = self.zero_acc
-                for i, step_number in enumerate(self.step_index):
-                    chunk = self.chunks[i % len(self.chunks)]  # passes wrap
-                    acc = self.program.step(acc, chunk, key, step_number)
-            with spans("fold", index):
-                acc.block_until_ready()
-            with spans("fetch", index):
-                acc_host = np.asarray(acc)
-            with spans("epilogue", index):
-                clerk_sums = np.asarray(self.program.epilogue(acc_host))
-                got = self._reveal(clerk_sums, self.program.survivors)
-            with spans("check", index):
-                matched = bool(np.array_equal(got, self.want))
-        for subset in subsets or ():
-            matched = matched and bool(
-                np.array_equal(self._reveal(clerk_sums, subset), self.want)
-            )
-        return matched, clerk_sums
-
-    def _reveal(self, clerk_sums, subset):
-        out = self.program.reconstruct(clerk_sums, subset, self.program.scheme, self.cell.dim)
-        return np.mod(np.asarray(out).astype(np.int64), self.modulus)
-
-    def memory_peak_bytes(self) -> int:
-        """The peak on the fullest of the cell's chips (0 where the backend
-        reports none, as the CPU does)."""
-        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in self.devices]
-        return int(max(peaks))
-
-
 class CompileCounter:
     """Counts what JAX compiled or loaded from its cache while active, so a
     window that compiled is caught."""
@@ -409,10 +252,12 @@ class CompileCounter:
 
 
 def spread(values) -> float:
-    """Distance between the quartiles over the median."""
+    """Distance between the quartiles over the median, the quartiles as
+    ``statistics.quantiles(values, n=4)`` gives them: the driver's spread
+    (numpy's quartiles lie closer together)."""
     if len(values) < 2:
         return 0.0
-    q = statistics.quantiles(values, n=4, method="inclusive")
+    q = statistics.quantiles(values, n=4)
     return (q[2] - q[0]) / statistics.median(values)
 
 
@@ -442,17 +287,19 @@ def run_cell(
     out_dir.mkdir(parents=True, exist_ok=True)
     tag = f"{workload}-seed{seed}-trace{int(trace)}"
 
+    round_module = round_of(cell)
+    names = span_names(cell)
     compiles = CompileCounter()
     stages = {"to_harness": time.perf_counter() - process_start}  # imports, device
-    session = Session(cell, seed, devices, stages)
+    session = round_module.Session(cell, seed, devices, stages)
     warm = Spans()
     notes = []
     # warm-up is one whole round, epilogue and reconstruct included, so every
     # host path has run once and every program is compiled
-    warm_ok, previous = session.run_round(0, warm, subsets=[session.program.second_subset])
+    warm_ok, previous = session.run_round(0, warm, subsets=session.warmup_subsets)
     if not warm_ok:
         notes.append("warm-up: aggregate or second clerk subset differed")
-    stages["warm_up_round"] = warm.seconds("round")[0]
+    stages["warm_up_round"] = warm.seconds(ROUND_SPAN)[0]
 
     spans = Spans()
     window = min(seconds, TRACE_WINDOW_SECONDS) if trace else seconds
@@ -466,8 +313,7 @@ def run_cell(
         options.host_tracer_level = 2
         jax.profiler.start_trace(str(trace_dir), profiler_options=options)
 
-    attempted = failed = consecutive = 0
-    rounds_differ = True
+    attempted = failed = consecutive = repeated = 0
     gc.collect()
     gc.freeze()
     gc.disable()
@@ -477,14 +323,14 @@ def run_cell(
         while True:
             attempted += 1
             try:
-                matched, clerk_sums = session.run_round(attempted, spans)
+                matched, evidence = session.run_round(attempted, spans)
             except Exception:  # a round that raises is a failed round
-                matched, clerk_sums = False, None
+                matched, evidence = False, None
                 notes.append(traceback.format_exc(limit=4))
-            if clerk_sums is not None and previous is not None:
-                if np.array_equal(clerk_sums, previous):
-                    rounds_differ = False
-            previous = clerk_sums
+            if evidence is not None and previous is not None:
+                if np.array_equal(evidence, previous):
+                    repeated += 1
+            previous = evidence
             if matched:
                 consecutive = 0
             else:
@@ -503,18 +349,25 @@ def run_cell(
 
             jax.profiler.stop_trace()
 
-    if not rounds_differ:
-        notes.append("two consecutive rounds gave the same clerk sums")
+    if repeated:
+        notes.append("two consecutive rounds gave the same evidence (no fresh randomness)")
     if compiles.count:
         notes.append(f"{compiles.count} compilations or cache loads inside the window")
-    correct = (
-        warm_ok and failed == 0 and rounds_differ and compiles.count == 0 and attempted > 0
-    )
+    # every comparison is exact: each number beside its limit, for the line
+    # and for the end of the log
+    compared = {
+        "warmup_mismatched": {"value": int(not warm_ok), "limit": 0},
+        "rounds_mismatched": {"value": failed, "limit": 0},
+        "rounds_repeated": {"value": repeated, "limit": 0},
+        "compiles_in_window": {"value": compiles.count, "limit": 0},
+    }
+    correct = attempted > 0 and all(c["value"] <= c["limit"] for c in compared.values())
 
-    round_seconds = spans.seconds("round")
+    round_seconds = spans.seconds(ROUND_SPAN)
     finished = attempted - failed
     elapsed = last_end - first_start
     device = _device_line(session)
+    log(f"[benchmark] memory of the first chip: {session.devices[0].memory_stats()}")
     # what the harness takes on its own clock; the manifest says which of
     # these the line carries, the run's record keeps them all for the study
     values = {
@@ -537,20 +390,19 @@ def run_cell(
         "device": device,
         "round_s_each": round_seconds,
         "round_start_s_each": [
-            s.start - first_start for s in spans.records if s.name == "round"
+            s.start - first_start for s in spans.records if s.name == ROUND_SPAN
         ],
         "round_spread": spread(round_seconds),
         "window_s": elapsed,
-        "warmup_round_s": warm.seconds("round"),
-        "spans": {n: spans.seconds(n) for n in SPAN_NAMES if n != "round"},
+        "warmup_round_s": warm.seconds(ROUND_SPAN),
+        "spans": {n: spans.seconds(n) for n in names if n != ROUND_SPAN},
         "setup_stages_s": stages,
         "notes": notes,
         **values,
     }
 
     if trace:
-        reduced = _reduce_trace(trace_dir, log)
-        context = LayerContext(
+        told = dict(
             name=cell.name,
             chips=cell.chips,
             config=cell.config,
@@ -558,12 +410,30 @@ def run_cell(
             rounds=attempted,
             elements_per_round=cell.elements_per_round,
             chunk_bytes=session.chunk_bytes,
-            acc_bytes=int(session.zero_acc.nbytes),
-            steps_per_round=len(session.step_index),
+            acc_bytes=session.acc_bytes,
+            steps_per_round=session.steps_per_round,
             plan=session.plan,
-            peaks=load_peaks(root, device["kind"]) if reduced is not None else None,
             memory_peak_bytes=device["memory_peak_bytes"],
             log=log,
+        )
+        used = session.devices
+        del session  # the resident input goes before the step is compiled again
+        raw, reduced = _read_trace(trace_dir, names, log)
+        report = None
+        if reduced is not None:
+            report = scopes.split(
+                raw, scopes.join_table(round_module.steps(cell, used)), names
+            )
+            if report["absent"]:
+                log(
+                    f"[benchmark] {len(report['absent'])} operations of the step's module "
+                    "are not in its compiled text: no scope metric is reported"
+                )
+        context = LayerContext(
+            peaks=load_peaks(root, device["kind"]) if reduced is not None else None,
+            scopes=report if report and not report["absent"] else None,
+            host_spans=report["host_spans_s"] if report else None,
+            **told,
         )
         for metric in cell.per_layer:
             value = layers[metric["name"]].reduce(spans.records, reduced, context)
@@ -580,6 +450,7 @@ def run_cell(
             }
         record["per_layer"] = result["metrics"]
         record["breakdown"] = result.get("breakdown")
+        record["scopes"] = report
         if not keep_trace:
             shutil.rmtree(trace_dir, ignore_errors=True)
     else:
@@ -589,16 +460,18 @@ def run_cell(
             result["metrics"][metric["name"]] = {
                 "value": values[metric["name"]], "unit": metric["unit"],
             }
+    result["compared"] = record["compared"] = compared
 
     (out_dir / f"rounds-{tag}.json").write_text(json.dumps(record, indent=1))
     for note in notes:
         log(f"[benchmark] {note}")
-    log(f"[benchmark] memory of the first chip: {session.devices[0].memory_stats()}")
     log(
         f"[benchmark] {workload}: {attempted} rounds, {failed} failed, "
         f"round_s median {values['round_s']:.4f} (spread inside the run "
         f"{100 * record['round_spread']:.2f}%), set-up {values['setup_s']:.1f} s"
     )
+    for name, c in compared.items():
+        log(f"[benchmark] compared {name}: {c['value']} (limit {c['limit']})")
     return result
 
 
@@ -619,9 +492,17 @@ class LayerContext:
     peaks: dict | None  # this device kind's row of benchmark/peaks.json
     memory_peak_bytes: int
     log: object
+    #: ``scopes.split``'s report of the traced window (seconds a round by the
+    #: program's ``fabric.*`` scopes on each chip, ``unscoped``, ...); ``None``
+    #: with no device plane, or where an operation of the step was ``absent``
+    #: from its compiled text, so that no scope metric is reported
+    scopes: dict | None = None
+    #: the program's own host spans (``telemetry.span``), by name: seconds a
+    #: round, median over the window's rounds; ``None`` with no device plane
+    host_spans: dict | None = None
 
 
-def _device_line(session: Session) -> dict:
+def _device_line(session) -> dict:
     import jax
 
     first = session.devices[0]
@@ -633,16 +514,17 @@ def _device_line(session: Session) -> dict:
     }
 
 
-def _reduce_trace(trace_dir: pathlib.Path, log):
-    """The profiler's trace of the window, reduced; ``None`` where the trace
-    holds no device plane (a CPU rehearsal), so that trace metrics are left
-    out of the line rather than invented."""
+def _read_trace(trace_dir: pathlib.Path, names, log) -> tuple:
+    """``(raw, reduced)``: the profiler's trace of the window as the plain
+    structure, and reduced by the spans ``names``; ``reduced`` is ``None``
+    where the trace holds no device plane (a CPU rehearsal), so that trace
+    metrics are left out of the line rather than invented."""
     files = sorted(trace_dir.rglob("*.xplane.pb"))
     if not files:
         log("[benchmark] the profiler wrote no trace")
-        return None
-    raw = trace_reduce.load_xplane(files[-1], host_names=SPAN_NAMES)
-    reduced = trace_reduce.reduce(raw, SPAN_NAMES)
+        return None, None
+    raw = scopes.load(files[-1], names)
+    reduced = trace_reduce.reduce(raw, names)
     if reduced is None:
         log("[benchmark] the trace holds no device plane: trace metrics left out")
-    return reduced
+    return raw, reduced

@@ -1,0 +1,16 @@
+"""Host seconds a round in the program's span ``fabric.epilogue.share_matmul``:
+the participant sum times the share matrix mod p, once a round, in the
+sum-first epilogue.
+Median over the traced window's rounds, on the profiler's clock."""
+
+name = "epilogue.share_matmul_s"
+unit = "s"
+layer = "host epilogue and reconstruct"
+moves = "round_s"
+cells = ["c5-sumfirst", "c5-sumfirst-x4", "c4-sumfirst"]
+
+
+def reduce(spans, trace, cell):
+    if cell.host_spans is None:
+        return None
+    return cell.host_spans.get("fabric.epilogue.share_matmul") or None

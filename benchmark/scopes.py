@@ -7,8 +7,10 @@ The program names its device work with ``jax.named_scope`` (``fabric.rand``
 ⊃ ``draw``, ``limb_sum``; ...) and its host work with ``telemetry.span``
 (``fabric.epilogue.recombine``, ...), which in a process that holds JAX is
 also an annotation of the profiler's trace. This reader turns one
-``.xplane.pb`` into, inside the window of the harness's ``round``
-annotations and per round:
+``.xplane.pb`` into a report (:func:`split`). The harness makes it in every
+traced run and hands it to the layer files (``LayerContext.scopes``,
+``.host_spans``), so a manifest metric and this command read one reduction.
+Inside the window of the harness's ``round`` annotations and per round:
 
 * per chip, self seconds (``trace_reduce.self_times``) by outer scope and by
   path, and the ``unscoped`` rest by operation name and as a share of busy
@@ -22,8 +24,9 @@ annotations and per round:
 Where an operation's scope is found (read by hand on a v5e, jax 0.9.0): an
 ``XLA Ops`` event carries its whole HLO line as its name and three timing
 stats, no ``op_name``; the scope is in the executable's metadata only. So
-the operation's name is joined with the text of the cell's chunk step,
-compiled in this process with the compile cache off: JAX's cache key strips
+the operation's name is joined with the text of the round's step(s) (what
+the cell's round module gives as ``steps``), compiled in this process with
+the compile cache off: JAX's cache key strips
 debug info, scopes are debug info, and an executable loaded from a cache the
 parent commit filled names nothing. Instruction names do not depend on debug
 info, so the run itself may use the cache.
@@ -45,8 +48,8 @@ would go to the wrong scope. What the trace can show of that it does: an
 operation of the step's module whose name the text does not hold is
 ``absent``, and one such fails the run.
 
-``main`` runs the cell through ``harness.run_cell`` on the chips
-``run.acquire_chips`` gives, prints one JSON line, removes the trace, and
+``main`` runs the cell traced through ``harness.run_cell`` on the chips
+``run.acquire_chips`` gives, prints the run's report as one JSON line, and
 exits 1 where an operation is ``absent`` or ``unscoped`` is over half of busy
 time: the join failed or the program lost its scopes, and the split says
 nothing.
@@ -71,8 +74,7 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from benchmark import harness, trace_reduce, traffic
-from benchmark.harness import SPAN_NAMES
+from benchmark import trace_reduce
 from benchmark.trace_reduce import NS, merge, self_times, subtract
 
 #: what the program's own names start with, scopes and spans alike
@@ -147,45 +149,28 @@ def op_paths(hlo_text: str) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def program_span_names(path) -> list:
-    """The ``fabric.*`` host event names of a profiler file, so that
-    ``trace_reduce.load_xplane``, which keeps host events by exact name,
-    can be told to keep them."""
-    from jax.profiler import ProfileData
-
-    names = set()
-    for plane in ProfileData.from_file(str(path)).planes:
-        if trace_reduce.DEVICE_PLANE.fullmatch(plane.name):
-            continue
-        for line in plane.lines:
-            names.update(e.name for e in line.events if e.name.startswith(PROGRAM_PREFIX))
-    return sorted(names)
-
-
-def load(path) -> dict:
-    """A profiler ``.xplane.pb`` as ``trace_reduce``'s plain structure, with
-    the program's host spans kept beside the harness's."""
-    return trace_reduce.load_xplane(path, SPAN_NAMES + tuple(program_span_names(path)))
+def load(path, span_names) -> dict:
+    """A profiler ``.xplane.pb`` as ``trace_reduce``'s plain structure: of
+    the host's events the spans named, and every one of the program's."""
+    return trace_reduce.load_xplane(path, span_names, (PROGRAM_PREFIX,))
 
 
 def _seconds(intervals) -> float:
     return sum(e - s for s, e in intervals) * NS
 
 
-def split(raw: dict, paths: dict) -> dict | None:
-    """The plain structure of one traced window and the join table of its
-    step -> the report ``main`` prints; ``None`` where the trace holds no
-    device plane. Seconds are per round of the window, except the idle
-    seconds, which are the window's."""
-    program_spans = sorted({
-        name
-        for plane in raw["planes"] if not trace_reduce.DEVICE_PLANE.fullmatch(plane["name"])
-        for line in plane["lines"] for name, _s, _d in line["events"]
-        if name.startswith(PROGRAM_PREFIX)
-    })
-    reduced = trace_reduce.reduce(raw, SPAN_NAMES + tuple(program_spans))
+def split(raw: dict, paths: dict, span_names) -> dict | None:
+    """The plain structure of one traced window, the join table of its
+    step(s) and the harness's span names (``round`` first) -> the report
+    ``main`` prints; ``None`` where the trace holds no device plane. Seconds
+    are per round of the window, except the idle seconds, which are the
+    window's."""
+    reduced = trace_reduce.reduce(raw, span_names, (PROGRAM_PREFIX,))
     if reduced is None:
         return None
+    program_spans = sorted(
+        {n for n, _s, _e in reduced.host_spans if n.startswith(PROGRAM_PREFIX)}
+    )
     rounds = sorted((s, e) for n, s, e in reduced.host_spans if n == "round")
     per_round = 1.0 / max(len(rounds), 1)
     joined_modules = {name.split("/", 1)[0] for name in paths}
@@ -231,7 +216,7 @@ def split(raw: dict, paths: dict) -> dict | None:
     by_span = collections.Counter()
     # innermost first: among spans that nest the shorter is the inner one;
     # the program's spans before the harness's, whatever their lengths
-    for names in (set(program_spans), set(SPAN_NAMES)):
+    for names in (set(program_spans), set(span_names)):
         spans = [(e - s, s, e, n) for n, s, e in reduced.host_spans if n in names]
         for _length, s, e, name in sorted(spans):
             gaps = subtract(gaps, [[s, e]])
@@ -262,52 +247,58 @@ def split(raw: dict, paths: dict) -> dict | None:
 # ---------------------------------------------------------------------------
 
 
-def step_text(cell, devices) -> str:
-    """The text of the cell's chunk step as compiled here for ``devices``,
-    with its metadata: compiled anew, the compile cache off (module doc)."""
+def scope_seconds(report: dict | None, scope: str) -> float | None:
+    """Seconds a round under ``scope`` (``fabric.rand``, or ``unscoped``) on
+    the busiest chip; ``None`` where there is no report or nothing ran under
+    it. What a layer file reads."""
+    if report is None:
+        return None
+    chip = report["chips"][report["busiest_chip"]]
+    if scope == UNSCOPED:
+        return chip[UNSCOPED]["s"] or None
+    return chip["by_scope"].get(scope)
+
+
+def step_text(jitted, args) -> str:
+    """The text of one jitted program as compiled here for the devices its
+    example arguments are placed on, with its metadata: compiled anew, the
+    compile cache off (module doc)."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 
-    devices = list(devices[: cell.chips])
-    mesh = traffic.make_mesh(cell.traffic, devices)
-    program = harness.build_program(cell, mesh)
-    small = traffic.replicated(devices, mesh)
-
-    def placed(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=small)
-
-    key = jax.eval_shape(lambda: jax.random.key(0))
-    chunk = jax.ShapeDtypeStruct(
-        (cell.traffic.chunk, cell.dim), traffic.input_dtype(program.modulus),
-        sharding=traffic.chunk_sharding(devices, mesh),
-    )
-    acc = jax.eval_shape(program.chunk_fn, chunk, key)
-    args = (placed(acc.shape, "int64"), chunk, placed(key.shape, key.dtype), placed((), "int32"))
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        return program.step.lower(*args).compile().as_text()
+        return jitted.lower(*args).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
         compilation_cache.reset_cache()
 
 
+def join_table(steps) -> dict:
+    """:func:`op_paths` of every program a round runs in the window (a round
+    module's ``steps(cell, devices)``), in one table."""
+    paths = {}
+    for jitted, args in steps:
+        paths.update(op_paths(step_text(jitted, args)))
+    return paths
+
+
 def trace_cell(root, workload: str, seed: int, seconds: float, devices, log):
-    """Run the cell traced through the harness. Returns ``(line, raw,
-    paths)``: the harness's result line, the trace as the plain structure,
-    the join table of the cell's step. The trace's files are removed."""
-    cell = harness.load_cell(root, workload)
+    """Run the cell traced through the harness. Returns ``(line, report)``:
+    the harness's result line and the report it made of the same window
+    (``None`` where the trace held no device plane)."""
+    from benchmark import harness
+
     with tempfile.TemporaryDirectory() as tmp:
         line = harness.run_cell(
-            root, workload, seed, seconds, True, devices, PROCESS_START,
-            out_dir=tmp, log=log, keep_trace=True,
+            root, workload, seed, seconds, True, devices, PROCESS_START, out_dir=tmp, log=log
         )
-        traces = sorted(pathlib.Path(tmp).rglob("*.xplane.pb"))
-        if not traces:
-            raise harness.HarnessError("the profiler wrote no trace")
-        raw = load(traces[-1])
-    return line, raw, op_paths(step_text(cell, devices))
+        record = json.loads(
+            (pathlib.Path(tmp) / f"rounds-{workload}-seed{seed}-trace1.json").read_text()
+        )
+    return line, record["scopes"]
 
 
 def main(argv=None) -> int:
@@ -317,7 +308,7 @@ def main(argv=None) -> int:
     parser.add_argument("--seconds", type=float, default=12.0)
     args = parser.parse_args(argv)
 
-    from benchmark import run
+    from benchmark import harness, run
 
     devices = run.acquire_chips(harness.load_cell(ROOT, args.workload).chips)
 
@@ -326,8 +317,7 @@ def main(argv=None) -> int:
 
     ensure_x64()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    line, raw, paths = trace_cell(ROOT, args.workload, args.seed, args.seconds, devices, run.log)
-    report = split(raw, paths)
+    line, report = trace_cell(ROOT, args.workload, args.seed, args.seconds, devices, run.log)
     if report is None:
         run.log("[scopes] the trace holds no device plane. No result.")
         return run.EXIT_NO_DEVICE
@@ -345,7 +335,7 @@ def main(argv=None) -> int:
             f"its compiled text, so their seconds have no scope: {report['absent'][:10]}"
         )
         return EXIT_UNSCOPED
-    share = report["chips"][report["busiest_chip"]][UNSCOPED]["share_of_busy"]
+    share = report["chips"][str(report["busiest_chip"])][UNSCOPED]["share_of_busy"]
     if share > 0.5:
         run.log(
             f"[scopes] {100 * share:.0f} % of busy time is unscoped: the join on "

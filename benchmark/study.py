@@ -10,10 +10,14 @@ so the child gets the chips), one after another, each with another seed, and
 keeps each run's result line beside its per-round record. ``collect`` merges
 what it finds into ``benchmark/out/spread-<cell>.json`` and prints, per cell
 and window: each set's median and spread (distance between the quartiles over
-the median, as the driver takes it) per end-to-end metric, the scatter of
-rounds inside a run, and the shift of the median between runs. Windows
-shorter than the one run are cut from the kept rounds: the rounds that had
-started before the shorter window would have closed.
+the median, as the driver takes it) and range (largest less smallest, over
+the median) per end-to-end metric, the scatter of rounds inside a run, and the
+shift of the median between runs. At the whole window it also says where a
+shift lives: each run's median round split by the round's spans (the run's
+record holds every span of every round), and by span the range of those
+medians over the runs. Windows shorter than the one run are cut from the kept
+rounds: the rounds that had started before the shorter window would have
+closed.
 """
 
 from __future__ import annotations
@@ -79,6 +83,33 @@ def cut(record, window):
     }
 
 
+def value_range(values) -> float:
+    """Largest less smallest, over the median."""
+    return (max(values) - min(values)) / statistics.median(values)
+
+
+def span_shift(records) -> dict:
+    """Where a shift of ``round_s`` between runs lives: by span, the median
+    over the runs of a run's median seconds in it, and the range of those
+    medians over all runs and over the runs of the set where it is widest."""
+    by_span = {}
+    for record in records:
+        for name, seconds in record.get("spans", {}).items():
+            if seconds:
+                by_span.setdefault(name, {}).setdefault(record["set"], []).append(
+                    statistics.median(seconds)
+                )
+    return {
+        name: {
+            "median_s": statistics.median(v for runs in sets.values() for v in runs),
+            "range_s": max(max(runs) for runs in sets.values())
+            - min(min(runs) for runs in sets.values()),
+            "widest_set_range_s": max(max(runs) - min(runs) for runs in sets.values()),
+        }
+        for name, sets in by_span.items()
+    }
+
+
 def collect(directory, windows) -> int:
     directory = pathlib.Path(directory)
     for cell_dir in sorted(p for p in directory.iterdir() if p.is_dir()):
@@ -94,6 +125,8 @@ def collect(directory, windows) -> int:
                 {k: r[k] for k in ("set", "seed", "round_s", "elems_per_s", "setup_s",
                                    "window_s", "round_spread", "warmup_round_s",
                                    "round_s_each", "round_start_s_each")}
+                | {"span_median_s": {n: statistics.median(v)
+                                     for n, v in r.get("spans", {}).items() if v}}
                 | {"correct": r["line"]["correct"], "failed": r["line"]["failed"],
                    "memory_peak_bytes": r["line"]["device"]["memory_peak_bytes"]}
                 for r in records
@@ -110,7 +143,8 @@ def collect(directory, windows) -> int:
             for label, cuts in by_set.items():
                 row["sets"][label] = {
                     m: {"median": statistics.median(c[m] for c in cuts),
-                        "spread": spread([c[m] for c in cuts])}
+                        "spread": spread([c[m] for c in cuts]),
+                        "range": value_range([c[m] for c in cuts])}
                     for m in METRICS
                 }
             every = [c for cuts in by_set.values() for c in cuts]
@@ -118,6 +152,9 @@ def collect(directory, windows) -> int:
             row["between_run_shift"] = {m: spread([c[m] for c in every]) for m in METRICS}
             row["widest_set_spread"] = {
                 m: max(s[m]["spread"] for s in row["sets"].values()) for m in METRICS
+            }
+            row["widest_set_range"] = {
+                m: max(s[m]["range"] for s in row["sets"].values()) for m in METRICS
             }
             medians = {m: [s[m]["median"] for s in row["sets"].values()] for m in METRICS}
             row["set_medians_apart"] = {
@@ -128,11 +165,19 @@ def collect(directory, windows) -> int:
                 f"  window {window:>4g} s, {row['rounds']:g} rounds: inside-run scatter "
                 f"{100 * row['inside_run_scatter']:.3f}% | "
                 + " | ".join(
-                    f"{m}: widest set {100 * row['widest_set_spread'][m]:.3f}%, all runs "
+                    f"{m}: widest set {100 * row['widest_set_spread'][m]:.3f}% (range "
+                    f"{100 * row['widest_set_range'][m]:.3f}%), all runs "
                     f"{100 * row['between_run_shift'][m]:.3f}%, sets apart "
                     f"{100 * row['set_medians_apart'][m]:.3f}%"
                     for m in METRICS
                 )
+            )
+        study["span_shift"] = span_shift(records)
+        for name, shift in study["span_shift"].items():
+            print(
+                f"  span {name:>9}: median {shift['median_s']:.5f} s, its runs' medians range "
+                f"over {shift['range_s']:.5f} s ({shift['widest_set_range_s']:.5f} in the "
+                f"widest set)"
             )
         target = ROOT / "benchmark" / "out" / f"spread-{cell_dir.name}.json"
         target.write_text(json.dumps(study, indent=1))

@@ -8,7 +8,8 @@ kept with the tests:
 * :func:`load_xplane` reads an ``.xplane.pb`` with nothing but JAX into a
   plain structure ``{"planes": [{"name", "lines": [{"name", "events":
   [[name, start_ns, duration_ns], ...]}]}]}`` (host planes keep only the
-  benchmark's span names, or they would be most of the file);
+  span names asked for, by name or by prefix, or they would be most of the
+  file);
 * :func:`reduce` turns that structure into a :class:`Reduced`.
 
 What a TPU trace looks like (read by hand on a v5e, jax 0.9.0): one plane
@@ -39,13 +40,13 @@ COLLECTIVE = re.compile(
 NS = 1e-9
 
 
-def load_xplane(path, host_names=()) -> dict:
+def load_xplane(path, host_names=(), host_prefixes=()) -> dict:
     """Read a profiler ``.xplane.pb`` into the plain structure above. Lines
     of a device plane are kept whole; of any other plane only events whose
-    name is in ``host_names``."""
+    name is in ``host_names`` or starts with one of ``host_prefixes``."""
     from jax.profiler import ProfileData
 
-    keep = set(host_names)
+    keep, prefixes = set(host_names), tuple(host_prefixes)
     planes = []
     for plane in ProfileData.from_file(str(path)).planes:
         device = DEVICE_PLANE.fullmatch(plane.name) is not None
@@ -56,7 +57,7 @@ def load_xplane(path, host_names=()) -> dict:
             events = [
                 [short_name(e.name), float(e.start_ns), float(e.duration_ns)]
                 for e in line.events
-                if device or e.name in keep
+                if device or e.name in keep or e.name.startswith(prefixes)
             ]
             if events:
                 lines.append({"name": line.name, "events": events})
@@ -213,10 +214,11 @@ class Reduced:
         return [[name, ns * NS] for name, ns in ranked]
 
 
-def reduce(raw: dict, span_names) -> Reduced | None:
-    """The plain structure -> :class:`Reduced`; ``None`` where it holds no
-    device plane with operations."""
-    keep = set(span_names)
+def reduce(raw: dict, span_names, span_prefixes=()) -> Reduced | None:
+    """The plain structure -> :class:`Reduced`, its host spans those named
+    in ``span_names`` or by a prefix; ``None`` where it holds no device plane
+    with operations."""
+    keep, prefixes = set(span_names), tuple(span_prefixes)
     host_spans = []
     devices = {}
     for plane in raw["planes"]:
@@ -225,7 +227,8 @@ def reduce(raw: dict, span_names) -> Reduced | None:
         if match is None:
             for events in lines.values():
                 host_spans.extend(
-                    (n, s, s + d) for n, s, d in events if n in keep
+                    (n, s, s + d) for n, s, d in events
+                    if n in keep or n.startswith(prefixes)
                 )
         elif lines.get(OPS_LINE):
             devices[int(match.group(1))] = lines

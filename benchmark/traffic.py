@@ -1,12 +1,15 @@
 """The one general traffic generator: reads a traffic file's parameters and
 makes the resident input on the device, from the seed.
 
-A traffic mix is data (``benchmark/traffic/<name>.json``): engine entry by
-dotted name, rows, passes, chunk, mesh, whether the step makes the share
-matmul. The resident input is one
-``(chunk, dim)`` array per chunk step; over a mesh a chunk's rows are sharded
-over ``p`` (and the dim over ``d``), so every chip makes and keeps its own
-rows and a step moves none of them.
+A traffic mix is data (``benchmark/traffic/<name>.json``): rows, passes,
+chunk, mesh, whether the step makes the share matmul, and by dotted name the
+round that drives it (``round``, a module of the interface that
+:mod:`benchmark.rounds` describes; absent means
+``benchmark.rounds.packed_fold``). Whatever else the file holds (a round's
+engine entries, say) is kept as ``params`` for that round alone to read and
+to check. The resident input is one ``(chunk, dim)`` array per chunk step;
+over a mesh a chunk's rows are sharded over ``p`` (and the dim over ``d``),
+so every chip makes and keeps its own rows and a step moves none of them.
 
 The plain reference's sums are taken in the same jitted call
 (:mod:`benchmark.reference`), so the input is read once in set-up, and chunk
@@ -25,20 +28,20 @@ import pathlib
 from benchmark import reference
 
 
+#: the round of a traffic file that names none
+DEFAULT_ROUND = "benchmark.rounds.packed_fold"
+
+
 @dataclasses.dataclass(frozen=True)
 class Traffic:
     name: str
-    engine: str
-    engine_call: str
-    epilogue: str
-    epilogue_call: str
-    reconstruct: str
-    accumulate: str  # "sum" | "sum_mod_p"
     share_matmul_in_step: bool  # the chunk step shares every participant (int8 dots)
     rows: int
     passes: int
     chunk: int
     mesh: dict | None
+    round: str = DEFAULT_ROUND  # dotted module path of the round this mix drives
+    params: dict = dataclasses.field(default_factory=dict)  # the whole file, for its round
 
     @property
     def steps_per_pass(self) -> int:
@@ -58,18 +61,21 @@ class Traffic:
 
 def load(path: pathlib.Path) -> Traffic:
     raw = json.loads(pathlib.Path(path).read_text())
-    fields = {f.name for f in dataclasses.fields(Traffic)}
-    missing = fields - raw.keys()
-    if missing:
-        raise ValueError(f"{path}: traffic file lacks {sorted(missing)}")
-    t = Traffic(**{k: raw[k] for k in fields})
+    fields = {f.name for f in dataclasses.fields(Traffic)} - {"params"}
+    require(raw, fields - {"round"}, path)
+    t = Traffic(**{k: raw[k] for k in fields & raw.keys()}, params=raw)
     if t.rows <= 0 or t.chunk <= 0 or t.passes <= 0 or t.rows % t.chunk:
         raise ValueError(f"{path}: rows must be a positive multiple of chunk")
-    if t.accumulate not in ("sum", "sum_mod_p"):
-        raise ValueError(f"{path}: accumulate is 'sum' or 'sum_mod_p'")
     if t.mesh and t.chunk % t.mesh_shape[0]:
         raise ValueError(f"{path}: chunk must divide over mesh p")
     return t
+
+
+def require(params: dict, keys, where) -> None:
+    """Raise where a traffic file lacks one of the keys its reader needs."""
+    missing = set(keys) - params.keys()
+    if missing:
+        raise ValueError(f"{where}: traffic file lacks {sorted(missing)}")
 
 
 def resolve(dotted: str):

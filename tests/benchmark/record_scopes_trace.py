@@ -3,7 +3,7 @@
     chiprun --chips 4 -- python tests/benchmark/record_scopes_trace.py chiprun_out/recorded-trace-scopes.json
 
 The tiny twin of the four-chip cell is run traced on the TPU chips through
-``benchmark/scopes.py``; the trace (``trace_reduce``'s plain structure, the
+the harness; the trace (``trace_reduce``'s plain structure, the
 program's ``fabric.*`` host spans kept) is cut to its first rounds and
 written with the join table of the operations it holds and with the report
 the reader gave on the day, so that the test can hold the reader to it.
@@ -13,6 +13,7 @@ import json
 import pathlib
 import sys
 import tempfile
+import time
 
 HERE = pathlib.Path(__file__).resolve().parent
 sys.path[:0] = [str(HERE.parents[1]), str(HERE)]
@@ -24,7 +25,7 @@ def main(target: str) -> int:
     import jax
 
     import bench_tree
-    from benchmark import scopes
+    from benchmark import harness, scopes
 
     devices = jax.devices()
     if devices[0].platform != "tpu" or len(devices) < 4:
@@ -32,9 +33,14 @@ def main(target: str) -> int:
         return 2
     with tempfile.TemporaryDirectory() as tmp:
         root = bench_tree.tiny_tree(pathlib.Path(tmp) / "copy")
-        line, raw, paths = scopes.trace_cell(
-            root, "tiny-c5-sumfirst-x4", 1, 0.05, devices, lambda message: None
+        cell = harness.load_cell(root, "tiny-c5-sumfirst-x4")
+        names = harness.span_names(cell)
+        line = harness.run_cell(
+            root, cell.name, 1, 0.05, True, devices, time.perf_counter(), out_dir=root / "out",
+            log=lambda message: None, keep_trace=True,
         )
+        raw = scopes.load(sorted((root / "out").rglob("*.xplane.pb"))[-1], names)
+        paths = scopes.join_table(harness.round_of(cell).steps(cell, devices))
     rounds = sorted(
         (s, s + d)
         for plane in raw["planes"] for line_ in plane["lines"]
@@ -53,7 +59,7 @@ def main(target: str) -> int:
     raw["recorded"] = {
         "device": line["device"]["kind"],
         "jax": jax.__version__,
-        **scopes.split(raw, raw["paths"]),
+        **scopes.split(raw, raw["paths"], names),
     }
     pathlib.Path(target).parent.mkdir(parents=True, exist_ok=True)
     pathlib.Path(target).write_text(json.dumps(raw))

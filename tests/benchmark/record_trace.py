@@ -34,12 +34,13 @@ def main(target: str) -> int:
     with tempfile.TemporaryDirectory() as tmp:
         root = bench_tree.tiny_tree(pathlib.Path(tmp) / "copy")
         cell = "tiny-c5-sumfirst-x4"
+        names = harness.span_names(harness.load_cell(root, cell))
         line = harness.run_cell(
             root, cell, 1, 0.05, True, devices, time.perf_counter(), out_dir=root / "out",
             keep_trace=True,
         )
         trace = sorted((root / "out").rglob("*.xplane.pb"))[-1]
-        raw = trace_reduce.load_xplane(trace, harness.SPAN_NAMES)
+        raw = trace_reduce.load_xplane(trace, names)
     rounds = sorted(
         (s, s + d)
         for plane in raw["planes"] for line_ in plane["lines"]
@@ -51,7 +52,7 @@ def main(target: str) -> int:
             line_["events"] = [
                 e for e in line_["events"] if e[1] >= start and e[1] + e[2] <= end
             ]
-    reduced = trace_reduce.reduce(raw, harness.SPAN_NAMES)
+    reduced = trace_reduce.reduce(raw, names)
     total, exposed = reduced.collective_seconds()
     raw["recorded"] = {
         "device": line["device"]["kind"],

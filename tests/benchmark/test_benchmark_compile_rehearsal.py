@@ -47,28 +47,19 @@ def quiet_cache():
 
 
 def _described(cell, topo):
-    """The cell's program and argument shapes on the described devices."""
-    import jax
-    from benchmark import harness, traffic
+    """The programs of the cell's round, with the shapes they take, on the
+    described devices: the window's step(s), and the input's maker."""
+    from benchmark import harness
 
-    tr = cell.traffic
-    devices = list(topo.devices)[: cell.chips]
-    mesh = traffic.make_mesh(tr, devices)
-    program = harness.build_program(cell, mesh)
-    small = traffic.replicated(devices, mesh)
-    key_shape = jax.eval_shape(lambda: jax.random.key(0))
-    key = jax.ShapeDtypeStruct(key_shape.shape, key_shape.dtype, sharding=small)
-    chunk = jax.ShapeDtypeStruct(
-        (tr.chunk, cell.dim),
-        traffic.input_dtype(program.modulus),
-        sharding=traffic.chunk_sharding(devices, mesh),
-    )
-    acc = jax.eval_shape(program.chunk_fn, chunk, key_shape)
-    acc = jax.ShapeDtypeStruct(acc.shape, acc.dtype, sharding=small)
-    index = jax.ShapeDtypeStruct((), "int32", sharding=small)
-    halves = jax.ShapeDtypeStruct((2, cell.dim), "int64", sharding=small)
-    maker = traffic.chunk_maker(tr, cell.dim, program.modulus, devices, mesh)
-    return program, maker, (acc, chunk, key, index), (key, index, halves)
+    devices = list(topo.devices)
+    round_module = harness.round_of(cell)
+    return round_module.steps(cell, devices), round_module.input_maker(cell, devices)
+
+
+def _chunk(args):
+    """The step's chunk among its example arguments: the one that is sharded
+    like the resident input, the largest."""
+    return max(args, key=lambda a: a.size)
 
 
 def _resident_bytes(cell, chunk):
@@ -81,13 +72,14 @@ def test_chunk_step_compiles_for_v5e_and_fits(workload, topo, quiet_cache):
     from benchmark import harness
 
     cell = harness.load_cell(REPO, workload)
-    program, _maker, args, _maker_args = _described(cell, topo)
-    compiled = program.step.lower(*args).compile()
+    steps, _maker = _described(cell, topo)
+    (step, args), = steps  # these cells' round runs one program in the window
+    compiled = step.lower(*args).compile()
     memory = compiled.memory_analysis()
     # the resident input (the step's chunk is part of it) + the step's
     # temporaries and output, on one chip, inside that chip's memory
     total = (
-        _resident_bytes(cell, args[1]) + memory.temp_size_in_bytes
+        _resident_bytes(cell, _chunk(args)) + memory.temp_size_in_bytes
         + memory.output_size_in_bytes
     )
     assert total < HBM_BYTES, (workload, total, memory)
@@ -103,11 +95,11 @@ def test_input_and_reference_compile_for_v5e_and_fit(workload, topo, quiet_cache
     from benchmark import harness
 
     cell = harness.load_cell(REPO, workload)
-    _program, maker, args, maker_args = _described(cell, topo)
+    steps, (maker, maker_args) = _described(cell, topo)
     compiled = maker.lower(*maker_args).compile()
     memory = compiled.memory_analysis()
     total = (
-        _resident_bytes(cell, args[1]) + memory.argument_size_in_bytes
+        _resident_bytes(cell, _chunk(steps[0][1])) + memory.argument_size_in_bytes
         + memory.temp_size_in_bytes + memory.output_size_in_bytes
     )
     assert total < HBM_BYTES, (workload, total, memory)

@@ -125,12 +125,19 @@ def test_cell_entry_and_its_files_are_found_by_name(cell):
     for metric in loaded.per_layer:
         assert metric["name"] in layers
         assert metric["moves"] in names, "a layer metric where the metric it moves is not"
-    # the traffic's dotted names resolve to the program and the adapters
+    # the traffic's round is found by its dotted name (none names one: the
+    # default), and what the round reads of the file resolves to the program
+    # and the adapters
     from benchmark import traffic
 
-    for dotted in (loaded.traffic.engine, loaded.traffic.engine_call, loaded.traffic.epilogue,
-                   loaded.traffic.epilogue_call, loaded.traffic.reconstruct):
-        assert callable(traffic.resolve(dotted)), dotted
+    round_module = harness.round_of(loaded)
+    assert round_module.__name__ == loaded.traffic.round == traffic.DEFAULT_ROUND
+    assert "round" not in loaded.traffic.params
+    for key in round_module.TRAFFIC_KEYS:
+        if key != "accumulate":
+            assert callable(traffic.resolve(loaded.traffic.params[key])), key
+    assert loaded.traffic.params["accumulate"] in ("sum", "sum_mod_p")
+    assert harness.span_names(loaded) == ("round", "dispatch", "fold", "fetch", "epilogue", "check")
     assert loaded.traffic.chunk == loaded.config["chunk"]
 
 

@@ -7,6 +7,7 @@ import hashlib
 import json
 import os
 import pathlib
+import shutil
 import subprocess
 import sys
 import time
@@ -16,9 +17,18 @@ import pytest
 
 import bench_tree
 from benchmark import harness, reference
+from benchmark.rounds import packed_fold
 
 REPO = bench_tree.REPO
 TINY = [cell[0] for cell in bench_tree.TINY_CELLS]
+#: the four cells' spans: the harness's and those of their round
+SPANS = ("round", *packed_fold.span_names)
+#: the metrics that read the program's own names (PR 27)
+NEW_LAYERS = (
+    "engine.input_s", "engine.rand_s", "engine.layout_s", "engine.share_matmul_s",
+    "engine.unscoped_s", "epilogue.recombine_s", "epilogue.share_matmul_s",
+    "epilogue.reconstruct_s",
+)
 
 
 @pytest.fixture(scope="module")
@@ -39,7 +49,9 @@ def run(tree, workload, trace=False, seconds=0.2, seed=5):
 def test_rounds_agree_exactly_with_the_reference(tree, workload):
     line = run(tree, workload)
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 1
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics", "device", "compared"]
+    assert all(c == {"value": 0, "limit": 0} for c in line["compared"].values())
+    assert len(line["compared"]) == 4
     assert set(line["metrics"]) == {"round_s", "setup_s"}
     for name, metric in line["metrics"].items():
         assert metric["value"] > 0 and metric["unit"]
@@ -134,6 +146,84 @@ def test_a_new_layer_metric_is_a_new_file(tmp_path):
     assert "check.s" not in run(root, other, trace=True)["metrics"]
 
 
+def digests(root):
+    return {
+        p.relative_to(root).as_posix(): hashlib.sha256(p.read_bytes()).hexdigest()
+        for p in (root / "benchmark").rglob("*")
+        if p.is_file() and "out" not in p.parts and "__pycache__" not in p.parts
+    }
+
+
+def add_masked_cell(root, name, traffic_round=None):
+    """One tiny cell whose configuration states the toy round's scheme kind;
+    its traffic names ``traffic_round``, or no round at all."""
+    changes = {"round": traffic_round} if traffic_round else {}
+    bench_tree.add_cell(root, name, *bench_tree.TINY_CELLS[0][1:], **changes)
+    config_file = root / "benchmark/configs" / f"{name}-config.json"
+    config = json.loads(config_file.read_text())
+    config["scheme"]["kind"] = "toy_masked_packed_shamir"
+    config_file.write_text(json.dumps(config))
+    return name
+
+
+def test_a_new_kind_of_round_is_a_new_file(tmp_path, monkeypatch):
+    """What the next configuration's PR does: a round with a stage after
+    reconstruct that needs state the step handed on, under a span of its own,
+    comes as one module, a traffic file that names it and one ``workloads``
+    entry. No file that was there is edited."""
+    root = bench_tree.copy_benchmark(tmp_path / "copy")
+    before = digests(root)
+    shutil.copy(pathlib.Path(__file__).parent / "toy_round.py", tmp_path / "dropped_in_round.py")
+    monkeypatch.syspath_prepend(str(tmp_path))
+    name = add_masked_cell(root, "toy-masked", traffic_round="dropped_in_round")
+    line = run(root, name)
+    assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 1
+    record = json.loads((root / "out" / f"rounds-{name}-seed5-trace0.json").read_text())
+    assert list(record["spans"]) == ["dispatch", "fold", "fetch", "epilogue", "unmask", "check"]
+    assert len(record["spans"]["unmask"]) == line["attempted"]
+    assert all(seconds > 0 for seconds in record["spans"]["unmask"])
+    cell = harness.load_cell(root, name)
+    assert harness.span_names(cell)[0] == "round" and "unmask" in harness.span_names(cell)
+    # the stage did work: what the clerks revealed was masked, and without the
+    # state the steps handed on the round would not have matched
+    import jax
+
+    session = harness.round_of(cell).Session(cell, 5, jax.devices("cpu"), {})
+    matched, _evidence = session.run_round(1, harness.Spans())
+    assert matched and session.masks_total > 0 and session.masked_differs
+    after = digests(root)
+    assert {path: after[path] for path in before} == before, "a file that was there was edited"
+    assert before == digests(REPO)
+    assert set(after) - set(before) == {
+        f"benchmark/configs/{name}-config.json", f"benchmark/traffic/{name}-traffic.json",
+    }
+    # a traced run names idle gaps and keeps host events by the round's own spans
+    traced = run(root, name, trace=True)
+    assert traced["correct"] is True and "epilogue.s" in traced["metrics"]
+
+
+def test_a_scheme_kind_the_round_does_not_know_is_refused_by_the_round(tmp_path):
+    """The same configuration under a traffic file that names no round, so
+    the default: ``packed_fold`` refuses the kind. The harness has no opinion."""
+    root = bench_tree.copy_benchmark(tmp_path / "copy")
+    name = add_masked_cell(root, "masked-under-the-plain-round")
+    assert harness.load_cell(root, name).traffic.round == "benchmark.rounds.packed_fold"
+    with pytest.raises(harness.HarnessError, match="unknown scheme kind") as refused:
+        run(root, name)
+    assert pathlib.Path(refused.traceback[-1].path).name == "packed_fold.py"
+    assert "sda_tpu" not in (REPO / "benchmark/harness.py").read_text()
+    assert not any(hasattr(harness, gone) for gone in ("build_program", "Program", "Session"))
+
+
+def test_a_traffic_file_that_names_no_such_round_is_refused(tmp_path):
+    root = bench_tree.copy_benchmark(tmp_path / "copy")
+    name = bench_tree.add_cell(
+        root, "no-round", *bench_tree.TINY_CELLS[0][1:], round="benchmark.rounds.not_there"
+    )
+    with pytest.raises(harness.HarnessError, match="no round"):
+        run(root, name)
+
+
 def test_a_cell_whose_metric_has_no_layer_file_is_refused(tmp_path):
     root = bench_tree.copy_benchmark(tmp_path / "copy")
     name = bench_tree.add_cell(root, *bench_tree.TINY_CELLS[3])
@@ -194,7 +284,8 @@ def test_reference_sums_are_exact(bits, dtype):
 
 
 def test_spread_is_quartile_distance_over_median():
-    assert harness.spread([1.0, 2.0, 3.0, 4.0, 5.0]) == pytest.approx(2.0 / 3.0)
+    # as the driver takes them: statistics.quantiles(values, n=4), 1.5 and 4.5 here
+    assert harness.spread([1.0, 2.0, 3.0, 4.0, 5.0]) == pytest.approx(3.0 / 3.0)
     assert harness.spread([2.0]) == 0.0
 
 
@@ -204,9 +295,9 @@ def test_a_traced_run_with_a_device_plane_reports_all_layers_and_the_breakdown(t
     from benchmark import trace_reduce
 
     raw = json.loads((pathlib.Path(__file__).parent / "recorded-trace-x4.json").read_text())
-    reduced = trace_reduce.reduce(raw, harness.SPAN_NAMES)
+    reduced = trace_reduce.reduce(raw, SPANS)
     peaks = harness.load_peaks(REPO, "TPU v5 lite")
-    monkeypatch.setattr(harness, "_reduce_trace", lambda trace_dir, log: reduced)
+    monkeypatch.setattr(harness, "_read_trace", lambda trace_dir, names, log: (raw, reduced))
     monkeypatch.setattr(harness, "load_peaks", lambda root, kind: peaks)
     tree = bench_tree.copy_benchmark(tmp_path / "copy")  # its manifest is edited below
     name = bench_tree.add_cell(tree, "x4-layers", *bench_tree.TINY_CELLS[1][1:])
@@ -217,12 +308,16 @@ def test_a_traced_run_with_a_device_plane_reports_all_layers_and_the_breakdown(t
     (tree / "BENCHMARK.json").write_text(json.dumps(manifest))
     line = run(tree, name, trace=True)
     listed = {m["name"] for m in manifest["per_layer"]}
-    assert set(line["metrics"]) == listed - {"device.peak_gib"}  # the CPU reports no peak
+    # the CPU reports no peak; the recorded trace holds none of the program's
+    # names, and its operations are absent from the text compiled here
+    assert set(NEW_LAYERS) <= listed
+    assert set(line["metrics"]) == listed - {"device.peak_gib"} - set(NEW_LAYERS)
     assert line["device"]["busy_s"] == pytest.approx(reduced.mean_busy_seconds())
     assert line["device"]["window_s"] == pytest.approx(reduced.window_seconds)
     assert 0 < line["device"]["busy_s"] < line["device"]["window_s"]
     breakdown = line["breakdown"]
     assert set(breakdown) == {"device_ops", "idle_gaps"}
     assert 1 <= len(breakdown["device_ops"]) <= 10 and 1 <= len(breakdown["idle_gaps"]) <= 10
-    assert {name for name, _ in breakdown["idle_gaps"]} <= set(harness.SPAN_NAMES) | {"-"}
+    assert {name for name, _ in breakdown["idle_gaps"]} <= set(SPANS) | {"-"}
+    assert list(line)[-1] == "compared"
     json.dumps(line)  # the line is plain JSON

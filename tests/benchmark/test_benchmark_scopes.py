@@ -11,8 +11,11 @@ import time
 import pytest
 
 import bench_tree
-from benchmark import scopes
+from benchmark import harness, scopes
+from benchmark.rounds import packed_fold
 
+#: the four cells' spans: the harness's and those of their round
+SPANS = ("round", *packed_fold.span_names)
 HERE = pathlib.Path(__file__).resolve().parent
 US = 1000.0  # nanoseconds
 
@@ -133,7 +136,7 @@ def hand_made():
 
 @pytest.fixture(scope="module")
 def report():
-    return scopes.split(hand_made(), scopes.op_paths(HLO))
+    return scopes.split(hand_made(), scopes.op_paths(HLO), SPANS)
 
 
 def test_scoped_and_unscoped_self_seconds_are_the_busy_seconds(report):
@@ -163,7 +166,7 @@ def test_an_operation_the_steps_text_does_not_hold_is_absent_not_unscoped():
     chip ran is not in the text. Its seconds stay in the busy time, and the
     report says that the join failed for it."""
     paths = scopes.op_paths(HLO.replace("convert_reduce_fusion.3", "convert_reduce_fusion.4"))
-    report = scopes.split(hand_made(), paths)
+    report = scopes.split(hand_made(), paths, SPANS)
     assert report["absent"] == ["jit_step/convert_reduce_fusion.3"]
     chip = report["chips"][0]
     assert dict(chip["unscoped"]["by_name"])["jit_step/convert_reduce_fusion.3"] == pytest.approx(30e-6)
@@ -192,7 +195,7 @@ def test_idle_goes_to_the_innermost_program_span_then_the_harnesss_then_none(rep
 def test_a_trace_with_no_device_plane_gives_nothing():
     raw = hand_made()
     raw["planes"] = raw["planes"][2:]
-    assert scopes.split(raw, {}) is None
+    assert scopes.split(raw, {}, SPANS) is None
 
 
 @pytest.fixture(scope="module")
@@ -204,7 +207,7 @@ def test_the_recorded_trace_is_split_as_on_the_day(recorded):
     """The numbers ``record_scopes_trace.py`` wrote beside the trace on the
     chip: a change to the reader or to ``trace_reduce`` that moves one shows."""
     want = recorded["recorded"]
-    got = json.loads(json.dumps(scopes.split(recorded, recorded["paths"])))
+    got = json.loads(json.dumps(scopes.split(recorded, recorded["paths"], SPANS)))
     assert want["device"] == "TPU v5 lite" and want["rounds"] == got["rounds"] == 3
     assert sorted(got["chips"]) == ["0", "1", "2", "3"]
     for key in ("window_s", "busiest_chip", "host_spans_s"):
@@ -221,7 +224,7 @@ def test_the_recorded_trace_holds_the_programs_names(recorded):
     """What the chip's trace showed: every scope of the sharded sum-first
     step on every chip, the collective under its own, and the program's host
     spans as events of the same file, inside the harness's epilogue."""
-    report = scopes.split(recorded, recorded["paths"])
+    report = scopes.split(recorded, recorded["paths"], SPANS)
     assert report["absent"] == []
     for chip in report["chips"].values():
         assert {"fabric.input", "fabric.rand", "fabric.psum"} <= set(chip["by_scope"])
@@ -251,6 +254,77 @@ def test_the_recorded_trace_holds_the_programs_names(recorded):
             assert any(s <= start and end <= e for s, e in epilogues), name
 
 
+#: the layer files that read the report, and what each reads of it
+SCOPE_LAYERS = {
+    "engine.input_s": "fabric.input", "engine.rand_s": "fabric.rand",
+    "engine.layout_s": "fabric.values", "engine.share_matmul_s": "fabric.share_matmul",
+    "engine.unscoped_s": "unscoped",
+}
+HOST_LAYERS = {
+    "epilogue.recombine_s": "fabric.epilogue.recombine",
+    "epilogue.share_matmul_s": "fabric.epilogue.share_matmul",
+    "epilogue.reconstruct_s": "fabric.reconstruct",
+}
+
+
+def context(report):
+    """What the harness tells a layer file of a traced run with this report
+    (``harness.run_cell``: no scopes where an operation was absent)."""
+    cell = harness.load_cell(bench_tree.REPO, "c5-sumfirst-x4")
+    return harness.LayerContext(
+        name=cell.name, chips=4, config=cell.config, traffic=cell.traffic, rounds=report["rounds"],
+        elements_per_round=1, chunk_bytes=1, acc_bytes=1, steps_per_round=1, plan=None, peaks=None,
+        memory_peak_bytes=0, log=lambda message: None,
+        scopes=None if report["absent"] else report, host_spans=report["host_spans_s"],
+    )
+
+
+@pytest.mark.parametrize("metric", sorted(SCOPE_LAYERS) + sorted(HOST_LAYERS))
+def test_a_layer_file_returns_the_recorded_traces_seconds(recorded, metric):
+    """Each metric that reads the program's names is the report's own number:
+    the same reduction as ``scopes.py`` prints, so equal to it by construction."""
+    report = scopes.split(recorded, recorded["paths"], SPANS)
+    module = harness.load_layers(bench_tree.REPO)[metric]
+    value = module.reduce([], None, context(report))
+    busiest = report["chips"][report["busiest_chip"]]
+    if metric in HOST_LAYERS:
+        assert value == report["host_spans_s"][HOST_LAYERS[metric]] > 0
+    elif SCOPE_LAYERS[metric] == "unscoped":
+        assert value == busiest["unscoped"]["s"] > 0
+    elif SCOPE_LAYERS[metric] in busiest["by_scope"]:
+        assert value == busiest["by_scope"][SCOPE_LAYERS[metric]] > 0
+        want = recorded["recorded"]["chips"][str(report["busiest_chip"])]["by_path"]
+        under = sum(v for k, v in want.items() if k.split("/")[0] == SCOPE_LAYERS[metric])
+        assert value == pytest.approx(under)  # the seconds written on the chip, on the day
+    else:
+        # the sharded sum-first step has no share matmul and no layout of its
+        # own: a reader that finds nothing returns nothing, never 0
+        assert SCOPE_LAYERS[metric] in ("fabric.values", "fabric.share_matmul") and value is None
+
+
+def test_the_scope_metrics_and_unscoped_are_the_busy_seconds(recorded):
+    report = scopes.split(recorded, recorded["paths"], SPANS)
+    busiest = report["chips"][report["busiest_chip"]]
+    total = sum(busiest["by_scope"].values()) + scopes.scope_seconds(report, scopes.UNSCOPED)
+    assert total == pytest.approx(busiest["busy_s"], rel=1e-6)
+    assert scopes.scope_seconds(None, "fabric.rand") is None
+    assert scopes.scope_seconds(report, "fabric.nothing") is None
+
+
+def test_with_an_operation_absent_no_scope_metric_is_reported(recorded):
+    """A second compile numbered differently: seconds would go to the wrong
+    scope, so none is reported. The host's spans do not pass through the
+    join, and stay."""
+    paths = dict(recorded["paths"])
+    del paths[min(name for name, path in paths.items() if path == "fabric.psum/psum")]
+    report = scopes.split(recorded, paths, SPANS)
+    assert report["absent"]
+    layers = harness.load_layers(bench_tree.REPO)
+    told = context(report)
+    assert all(layers[name].reduce([], None, told) is None for name in SCOPE_LAYERS)
+    assert all(layers[name].reduce([], None, told) > 0 for name in HOST_LAYERS)
+
+
 def fake_run(monkeypatch, raw, paths):
     """``main`` with the chips and the traced run replaced by a recorded
     trace: what it prints and returns, past the run."""
@@ -260,8 +334,9 @@ def fake_run(monkeypatch, raw, paths):
         "correct": True, "device": {"kind": "TPU v5 lite"},
         "metrics": {"kernel.busy_s": {"value": 1.0, "unit": "s"}},
     }
+    report = json.loads(json.dumps(scopes.split(raw, paths, SPANS)))  # as the record holds it
     monkeypatch.setattr(run, "acquire_chips", lambda chips: ["chip"] * chips)
-    monkeypatch.setattr(scopes, "trace_cell", lambda *args: (line, raw, paths))
+    monkeypatch.setattr(scopes, "trace_cell", lambda *args: (line, report))
     return scopes.main(["--workload", "c4-sumfirst", "--seed", "3"])
 
 
@@ -319,11 +394,9 @@ def test_every_operation_of_a_cpu_trace_is_found_in_the_compiled_text(tree, tmp_
     import jax
     from jax.profiler import ProfileData
 
-    from benchmark import harness
-
     devices = jax.devices("cpu")
     cell = harness.load_cell(tree, workload)
-    paths = scopes.op_paths(scopes.step_text(cell, devices))
+    paths = scopes.join_table(harness.round_of(cell).steps(cell, devices))
     assert scoped <= set(paths.values())
     harness.run_cell(
         tree, workload, 2, 0.05, True, devices, time.perf_counter(),
@@ -340,9 +413,12 @@ def test_every_operation_of_a_cpu_trace_is_found_in_the_compiled_text(tree, tmp_
     assert len(ran) >= 5
     assert ran <= set(paths), sorted(ran - set(paths))
     assert {paths[name] for name in ran} & scoped
-    assert scopes.program_span_names(trace) == sorted(
+    raw = scopes.load(trace, SPANS)
+    # host events are kept by prefix: the program's spans beside the harness's
+    kept = {n for plane in raw["planes"] for line in plane["lines"] for n, _s, _d in line["events"]}
+    assert {n for n in kept if n.startswith("fabric.")} == {
         n for n in ("fabric.epilogue.recombine", "fabric.epilogue.share_matmul", "fabric.reconstruct")
         if n != "fabric.epilogue.share_matmul" or "sumfirst" in workload
-    )
-    raw = scopes.load(trace)
-    assert scopes.split(raw, paths) is None  # no device plane: no device number invented
+    }
+    assert set(SPANS) <= kept
+    assert scopes.split(raw, paths, SPANS) is None  # no device plane: no device number invented

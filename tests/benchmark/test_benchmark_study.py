@@ -17,6 +17,7 @@ def record(label, seed, round_s, setup_s=10.0, elements=1000.0):
         "round_s": sorted(round_s)[len(round_s) // 2], "setup_s": setup_s,
         "elems_per_s": len(round_s) * elements / window, "round_spread": 0.0,
         "warmup_round_s": [1.0],
+        "spans": {"fold": [0.25 * s for s in round_s], "epilogue": [0.75 * s for s in round_s]},
         "line": {"correct": True, "failed": 0, "device": {"memory_peak_bytes": 5}},
     }
 
@@ -51,9 +52,18 @@ def test_collect_writes_every_round_and_the_spread_of_each_set(tmp_path, monkeyp
     assert len(out["runs"]) == 6 and out["runs"][0]["round_s_each"] == [1.0] * 6
     assert set(out["windows"]) == {"3", "6"}
     whole = out["windows"]["6"]
-    # set A's quartiles of (1.0, 1.1, 1.2) are 1.05 and 1.15: 0.1 / 1.1
-    assert whole["sets"]["A"]["round_s"]["spread"] == pytest.approx(0.1 / 1.1)
+    # set A's quartiles of (1.0, 1.1, 1.2), as statistics.quantiles(n=4) and
+    # the driver take them, are 1.0 and 1.2: 0.2 / 1.1
+    assert whole["sets"]["A"]["round_s"]["spread"] == pytest.approx(0.2 / 1.1)
+    assert whole["sets"]["A"]["round_s"]["range"] == pytest.approx(0.2 / 1.1)
     assert whole["sets"]["B"]["round_s"]["spread"] == 0.0
-    assert whole["widest_set_spread"]["round_s"] == pytest.approx(0.1 / 1.1)
+    assert whole["widest_set_spread"]["round_s"] == pytest.approx(0.2 / 1.1)
+    assert whole["widest_set_range"]["round_s"] == pytest.approx(0.2 / 1.1)
+    # where the shift lives: three quarters of it in the epilogue
+    assert out["runs"][1]["span_median_s"] == pytest.approx({"fold": 0.275, "epilogue": 0.825})
+    assert out["span_shift"]["epilogue"] == pytest.approx(
+        {"median_s": 0.75, "range_s": 0.15, "widest_set_range_s": 0.15}
+    )
+    assert out["span_shift"]["fold"]["range_s"] == pytest.approx(0.05)
     assert whole["set_medians_apart"]["round_s"] == pytest.approx(0.1 / 1.05)
     assert whole["inside_run_scatter"] == 0.0
