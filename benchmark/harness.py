@@ -141,7 +141,7 @@ def round_of(cell: Cell):
         module = importlib.import_module(cell.traffic.round)
     except ImportError as e:
         raise HarnessError(f"cell {cell.name!r}: no round {cell.traffic.round!r}: {e}") from e
-    for attr in ("span_names", "Session", "steps"):
+    for attr in ("span_names", "Session", "steps", "input_maker"):
         if not hasattr(module, attr):
             raise HarnessError(f"{cell.traffic.round}: a round module needs `{attr}`")
     return module
@@ -157,7 +157,9 @@ def span_names(cell: Cell) -> tuple:
 def load_layers(root) -> dict:
     """Every per-layer metric module under ``benchmark/layers/``, by the
     metric's name. Found by listing the directory: a new metric is a new
-    file."""
+    file. Which cells report a metric the manifest alone says (``workloads``);
+    a layer file says which of the round's spans it reads (``reads_spans``),
+    so that a cell whose round opens no such span is found without a chip."""
     layers = {}
     directory = pathlib.Path(root) / "benchmark" / "layers"
     for path in sorted(directory.glob("*.py")):
@@ -166,7 +168,7 @@ def load_layers(root) -> dict:
         )
         module = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(module)
-        for attr in ("name", "unit", "layer", "moves", "cells", "reduce"):
+        for attr in ("name", "unit", "layer", "moves", "reads_spans", "reduce"):
             if not hasattr(module, attr):
                 raise HarnessError(f"{path}: a layer metric needs `{attr}`")
         if module.name in layers:
@@ -354,13 +356,18 @@ def run_cell(
     if compiles.count:
         notes.append(f"{compiles.count} compilations or cache loads inside the window")
     # every comparison is exact: each number beside its limit, for the line
-    # and for the end of the log
+    # and for the end of the log; after the harness's own four, those the
+    # round made of what only it knows
     compared = {
         "warmup_mismatched": {"value": int(not warm_ok), "limit": 0},
         "rounds_mismatched": {"value": failed, "limit": 0},
         "rounds_repeated": {"value": repeated, "limit": 0},
         "compiles_in_window": {"value": compiles.count, "limit": 0},
     }
+    for name, c in (session.compared() if hasattr(session, "compared") else {}).items():
+        if name in compared:
+            raise HarnessError(f"{cell.traffic.round}: `{name}` is the harness's comparison")
+        compared[name] = {"value": c["value"], "limit": c["limit"]}
     correct = attempted > 0 and all(c["value"] <= c["limit"] for c in compared.values())
 
     round_seconds = spans.seconds(ROUND_SPAN)
@@ -419,10 +426,13 @@ def run_cell(
         used = session.devices
         del session  # the resident input goes before the step is compiled again
         raw, reduced = _read_trace(trace_dir, names, log)
-        report = None
+        report = chunk_step = None
         if reduced is not None:
+            # the chunk step is the first of the round's programs
+            programs = round_module.steps(cell, used)
+            chunk_step = scopes.join_table(programs[:1])
             report = scopes.split(
-                raw, scopes.join_table(round_module.steps(cell, used)), names
+                raw, {**chunk_step, **scopes.join_table(programs[1:])}, names
             )
             if report["absent"]:
                 log(
@@ -433,6 +443,9 @@ def run_cell(
             peaks=load_peaks(root, device["kind"]) if reduced is not None else None,
             scopes=report if report and not report["absent"] else None,
             host_spans=report["host_spans_s"] if report else None,
+            chunk_step_modules=(
+                frozenset(name.split("/", 1)[0] for name in chunk_step) if chunk_step else None
+            ),
             **told,
         )
         for metric in cell.per_layer:
@@ -486,7 +499,7 @@ class LayerContext:
     rounds: int  # rounds in the window
     elements_per_round: int  # participants x dim
     chunk_bytes: int  # input bytes one chunk step reads, all chips together
-    acc_bytes: int  # bytes of the accumulator a step carries
+    acc_bytes: int  # bytes a chunk step carries in and hands on, on one chip
     steps_per_round: int
     plan: object  # the program's AggregationPlan
     peaks: dict | None  # this device kind's row of benchmark/peaks.json
@@ -500,6 +513,10 @@ class LayerContext:
     #: the program's own host spans (``telemetry.span``), by name: seconds a
     #: round, median over the window's rounds; ``None`` with no device plane
     host_spans: dict | None = None
+    #: the names the trace gives the chunk step's program(s) (``jit_step``):
+    #: the module(s) of the first of the round's ``steps``, from its compiled
+    #: text; ``None`` with no device plane
+    chunk_step_modules: frozenset | None = None
 
 
 def _device_line(session) -> dict:

@@ -5,7 +5,7 @@ name = "collective.exposed_share"
 unit = "%"
 layer = "collectives"
 moves = "round_s"
-cells = ["c5-sumfirst-x4"]
+reads_spans = ()
 
 
 def reduce(spans, trace, cell):

@@ -5,7 +5,7 @@ name = "collective.s"
 unit = "s"
 layer = "collectives"
 moves = "round_s"
-cells = ["c5-sumfirst-x4"]
+reads_spans = ()
 
 
 def reduce(spans, trace, cell):

@@ -5,7 +5,7 @@ name = "device.idle_share"
 unit = "%"
 layer = "device"
 moves = "round_s"
-cells = None
+reads_spans = ()
 
 
 def reduce(spans, trace, cell):

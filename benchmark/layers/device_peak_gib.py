@@ -5,7 +5,7 @@ name = "device.peak_gib"
 unit = "GiB"
 layer = "device"
 moves = "round_s"
-cells = None
+reads_spans = ()
 
 
 def reduce(spans, trace, cell):

@@ -8,7 +8,7 @@ name = "elems_per_s"
 unit = "elements/s"
 layer = "entry point"
 moves = "round_s"
-cells = None
+reads_spans = ("round",)
 
 
 def reduce(spans, trace, cell):

@@ -7,7 +7,7 @@ name = "engine.fold_s"
 unit = "s"
 layer = "fabric engines"
 moves = "round_s"
-cells = None  # every cell
+reads_spans = ("dispatch", "fold")
 
 
 def reduce(spans, trace, cell):

@@ -10,7 +10,7 @@ name = "engine.input_s"
 unit = "s"
 layer = "fabric engines"
 moves = "round_s"
-cells = ["c5-sumfirst", "c5-sumfirst-x4", "c4-sumfirst"]
+reads_spans = ()
 
 
 def reduce(spans, trace, cell):

@@ -11,7 +11,7 @@ name = "engine.rand_s"
 unit = "s"
 layer = "fabric engines"
 moves = "round_s"
-cells = ["c5-sumfirst", "c5-sumfirst-x4", "c4-participant", "c4-sumfirst"]
+reads_spans = ()
 
 
 def reduce(spans, trace, cell):

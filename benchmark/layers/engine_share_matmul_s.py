@@ -9,7 +9,7 @@ name = "engine.share_matmul_s"
 unit = "s"
 layer = "fabric engines"
 moves = "round_s"
-cells = ["c4-participant"]
+reads_spans = ()
 
 
 def reduce(spans, trace, cell):

@@ -10,7 +10,7 @@ name = "engine.unscoped_s"
 unit = "s"
 layer = "fabric engines"
 moves = "round_s"
-cells = ["c4-participant"]
+reads_spans = ()
 
 
 def reduce(spans, trace, cell):

@@ -6,7 +6,7 @@ name = "epilogue.reconstruct_s"
 unit = "s"
 layer = "host epilogue and reconstruct"
 moves = "round_s"
-cells = ["c5-sumfirst", "c5-sumfirst-x4", "c4-participant", "c4-sumfirst"]
+reads_spans = ()
 
 
 def reduce(spans, trace, cell):

@@ -7,7 +7,7 @@ name = "epilogue.s"
 unit = "s"
 layer = "host epilogue and reconstruct"
 moves = "round_s"
-cells = None
+reads_spans = ("epilogue",)
 
 
 def reduce(spans, trace, cell):

@@ -5,7 +5,7 @@ name = "kernel.busy_s"
 unit = "s"
 layer = "kernels"
 moves = "round_s"
-cells = None
+reads_spans = ()
 
 
 def reduce(spans, trace, cell):

@@ -146,14 +146,19 @@ class Reduced:
     def chips(self) -> list:
         return sorted(self.ops)
 
-    def busy_seconds(self, chip) -> float:
-        return union_seconds((s, e) for _n, s, e in self.ops[chip])
+    def busy_seconds(self, chip, modules=None) -> float:
+        """Seconds in which an operation ran on ``chip``; with ``modules``,
+        an operation of one of these programs (``jit_step``) only."""
+        return union_seconds(
+            (s, e) for n, s, e in self.ops[chip]
+            if modules is None or n.split("/", 1)[0] in modules
+        )
 
     def mean_busy_seconds(self) -> float:
         return sum(self.busy_seconds(c) for c in self.chips) / len(self.chips)
 
-    def max_busy_seconds(self) -> float:
-        return max(self.busy_seconds(c) for c in self.chips)
+    def max_busy_seconds(self, modules=None) -> float:
+        return max(self.busy_seconds(c, modules) for c in self.chips)
 
     def idlest_chip(self):
         return min(self.chips, key=self.busy_seconds)

@@ -1,8 +1,12 @@
-"""Compile rehearsal: the four cells' programs at their real shapes, compiled
+"""Compile rehearsal: every cell's programs at their real shapes, compiled
 for a described v5e:2x2 (one chip, and the p=4 mesh for the four-chip cell).
 Nothing runs and no time is taken: what the chip's compiler would refuse, a
 program that does not fit the chip's memory among it, is refused here, at no
-chip time, in every later PR.
+chip time, in every later PR. A round gives its window's programs as a list
+(``steps``) and its input's maker (``input_maker``); what is held of them is
+in ``cell_checks.py``, and runs here for every cell of the manifest and for a
+cell with a round of its own, two programs in its window, dropped into a
+temporary tree.
 
 The topology is described inside a module-scoped fixture, which skips where
 it cannot be; all these tests stay in this one file (one process loads the
@@ -16,8 +20,6 @@ import pathlib
 import pytest
 
 REPO = pathlib.Path(__file__).resolve().parents[2]
-#: a v5e chip's memory as its runtime reports it (`bytes_limit`, chip run, PR 23)
-HBM_BYTES = 16_909_336_064
 CELLS = [w["name"] for w in json.loads((REPO / "BENCHMARK.json").read_text())["workloads"]]
 
 
@@ -46,60 +48,53 @@ def quiet_cache():
     jax.config.update("jax_enable_compilation_cache", before)
 
 
-def _described(cell, topo):
-    """The programs of the cell's round, with the shapes they take, on the
-    described devices: the window's step(s), and the input's maker."""
-    from benchmark import harness
-
-    devices = list(topo.devices)
-    round_module = harness.round_of(cell)
-    return round_module.steps(cell, devices), round_module.input_maker(cell, devices)
-
-
-def _chunk(args):
-    """The step's chunk among its example arguments: the one that is sharded
-    like the resident input, the largest."""
-    return max(args, key=lambda a: a.size)
-
-
-def _resident_bytes(cell, chunk):
-    """Bytes of the whole resident input on one chip."""
-    return cell.traffic.steps_per_pass * chunk.size * chunk.dtype.itemsize // cell.chips
-
-
 @pytest.mark.parametrize("workload", CELLS)
 def test_chunk_step_compiles_for_v5e_and_fits(workload, topo, quiet_cache):
-    from benchmark import harness
+    import cell_checks
 
-    cell = harness.load_cell(REPO, workload)
-    steps, _maker = _described(cell, topo)
-    (step, args), = steps  # these cells' round runs one program in the window
-    compiled = step.lower(*args).compile()
-    memory = compiled.memory_analysis()
-    # the resident input (the step's chunk is part of it) + the step's
-    # temporaries and output, on one chip, inside that chip's memory
-    total = (
-        _resident_bytes(cell, _chunk(args)) + memory.temp_size_in_bytes
-        + memory.output_size_in_bytes
-    )
-    assert total < HBM_BYTES, (workload, total, memory)
-    text = compiled.as_text()
-    if cell.chips > 1:
-        assert "all-reduce" in text, "the sharded step lost its limb psum"
-    else:
-        assert "all-reduce" not in text
+    cell_checks.check_programs_compile_and_fit(REPO, workload, topo.devices)
 
 
 @pytest.mark.parametrize("workload", CELLS)
 def test_input_and_reference_compile_for_v5e_and_fit(workload, topo, quiet_cache):
+    import cell_checks
+
+    cell_checks.check_input_compiles_and_fits(REPO, workload, topo.devices)
+
+
+def test_a_round_of_two_programs_compiles_for_v5e_by_the_same_checks(
+    tmp_path, topo, quiet_cache
+):
+    """The toy round runs a second program in its window; both are compiled,
+    under names of their own, and the first is the chunk step."""
+    import bench_tree
+    import cell_checks
     from benchmark import harness
 
-    cell = harness.load_cell(REPO, workload)
-    steps, (maker, maker_args) = _described(cell, topo)
-    compiled = maker.lower(*maker_args).compile()
-    memory = compiled.memory_analysis()
-    total = (
-        _resident_bytes(cell, _chunk(steps[0][1])) + memory.argument_size_in_bytes
-        + memory.temp_size_in_bytes + memory.output_size_in_bytes
+    root = bench_tree.copy_benchmark(tmp_path / "copy")
+    name = bench_tree.add_toy_cell(root, "toy-masked", "toy_masked")
+    with bench_tree.rounds_importable_from(root):
+        cell = harness.load_cell(root, name)
+        programs = harness.round_of(cell).steps(cell, list(topo.devices))
+        assert [jitted.__name__ for jitted, _args in programs] == ["masked_step", "unmask_fold"]
+        cell_checks.check_programs_compile_and_fit(root, name, topo.devices)
+        cell_checks.check_input_compiles_and_fits(root, name, topo.devices)
+
+
+def test_two_programs_under_one_name_are_refused(tmp_path, topo, quiet_cache):
+    """The trace tells a round's programs apart by name: a round whose
+    second program is called what its chunk step is called is refused."""
+    import bench_tree
+    import cell_checks
+
+    source = (pathlib.Path(__file__).parent / "toy_round.py").read_text()
+    assert source.count("def unmask_fold(") == 1
+    root = bench_tree.copy_benchmark(tmp_path / "copy")
+    name = bench_tree.add_toy_cell(
+        root, "toy-one-name", "toy_one_name",
+        source=source.replace("unmask_fold(total, mask):", "masked_step(total, mask):")
+        .replace("jax.jit(unmask_fold)", "jax.jit(masked_step)"),
     )
-    assert total < HBM_BYTES, (workload, total, memory)
+    with bench_tree.rounds_importable_from(root):
+        with pytest.raises(AssertionError, match="jit_masked_step"):
+            cell_checks.check_programs_compile_and_fit(root, name, topo.devices)

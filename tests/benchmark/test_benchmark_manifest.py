@@ -1,5 +1,8 @@
 """The manifest against the benchmark's contract, and every file a cell needs
-found by the names the manifest gives."""
+found by the names the manifest gives. What one configuration and one cell
+are held to is in ``cell_checks.py``, as functions of the tree and the name:
+here they run on this repo's manifest, one case each, and in
+``test_benchmark_rounds.py`` on a tree with a new kind of round dropped in."""
 
 import json
 import pathlib
@@ -7,22 +10,18 @@ import re
 
 import pytest
 
+import cell_checks
 from benchmark import harness
+from cell_checks import NAME, one_line
 
 REPO = pathlib.Path(__file__).resolve().parents[2]
 MANIFEST = json.loads((REPO / "BENCHMARK.json").read_text())
-NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
 UNIT = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}")
 PATH = re.compile(r"[A-Za-z0-9_.\-/]{1,200}")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-WIDTH = re.compile(r"(_dim|_rank)$|hidden|intermediate|latent|state|proj|head|expansion|experts_per")
 
 METRICS = MANIFEST["end_to_end"] + MANIFEST["per_layer"]
 CELLS = MANIFEST["workloads"]
-
-
-def one_line(text, limit=200):
-    return 1 <= len(text) <= limit and "\n" not in text and "\t" not in text
 
 
 def test_manifest_has_exactly_the_contract_keys():
@@ -81,25 +80,33 @@ def test_metric_names_are_unique_and_setup_s_is_there():
     assert 1 <= len(MANIFEST["end_to_end"]) <= 16 and 1 <= len(MANIFEST["per_layer"]) <= 128
 
 
-@pytest.mark.parametrize("config", MANIFEST["configs"], ids=lambda c: c["name"])
+@pytest.mark.parametrize("config", [c["name"] for c in MANIFEST["configs"]])
 def test_config_entry_and_file(config):
-    assert set(config) == {"name", "source", "file", "reduced", "why"}
-    assert NAME.fullmatch(config["name"])
-    assert one_line(config["source"]) and one_line(config["why"])
-    assert any(config["file"].startswith(p + "/") for p in MANIFEST["paths"])
-    assert len(config["reduced"]) <= 16
-    for key in config["reduced"]:
-        assert NAME.fullmatch(key) and not WIDTH.search(key), key
-    stated = json.loads((REPO / config["file"]).read_text())
-    assert stated["name"] == config["name"] and stated["source"] == config["source"]
-    assert set(stated["reduced"]) == set(config["reduced"])
-    for key in ("deployment", "layout", "scheme", "dim", "dropped_clerks", "assumed", "guarantees"):
-        assert key in stated, key
-    guarantees = stated["guarantees"]
-    assert guarantees["privacy_threshold"] == stated["scheme"]["privacy_threshold"] == 2
-    assert guarantees["reconstruction_threshold"] == 7
-    assert "whole field [0, p)" in guarantees["share_randomness"]
-    assert any(w["config"] == config["name"] for w in CELLS), "a configuration no cell uses"
+    cell_checks.check_config(REPO, config)
+
+
+@pytest.mark.parametrize("scheme,privacy,reconstruction", [
+    ({"kind": "packed_shamir", "secret_count": 5, "privacy_threshold": 2, "share_count": 8}, 2, 7),
+    ({"kind": "basic_shamir", "privacy_threshold": 3, "share_count": 8}, 3, 4),
+    ({"kind": "additive", "share_count": 3}, 2, 3),
+])
+def test_stated_thresholds_follow_the_scheme_block(scheme, privacy, reconstruction):
+    assert cell_checks.stated_thresholds(scheme) == (privacy, reconstruction)
+
+
+@pytest.mark.parametrize("stated", ["privacy_threshold", "reconstruction_threshold"])
+def test_a_configuration_that_states_another_threshold_than_its_scheme_is_refused(tmp_path, stated):
+    import bench_tree
+
+    root = bench_tree.copy_benchmark(tmp_path / "copy")
+    name = bench_tree.add_cell(root, *bench_tree.TINY_CELLS[0])
+    cell_checks.check_config(root, f"{name}-config")
+    file = root / "benchmark/configs" / f"{name}-config.json"
+    config = json.loads(file.read_text())
+    config["guarantees"][stated] += 1
+    file.write_text(json.dumps(config))
+    with pytest.raises(AssertionError, match="scheme"):
+        cell_checks.check_config(root, f"{name}-config")
 
 
 def test_config_names_and_files_are_unique():
@@ -109,36 +116,9 @@ def test_config_names_and_files_are_unique():
     assert len({c["file"] for c in configs}) == len(configs)
 
 
-@pytest.mark.parametrize("cell", CELLS, ids=lambda w: w["name"])
+@pytest.mark.parametrize("cell", [w["name"] for w in CELLS])
 def test_cell_entry_and_its_files_are_found_by_name(cell):
-    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
-    for key in ("name", "config", "traffic"):
-        assert NAME.fullmatch(cell[key]), cell[key]
-    assert cell["chips"] in (1, 4) and one_line(cell["why"])
-    loaded = harness.load_cell(REPO, cell["name"])
-    assert loaded.traffic.name == cell["traffic"] and loaded.chips == cell["chips"]
-    assert loaded.config["name"] == cell["config"]
-    # every cell reports setup_s, another end-to-end metric and a layer metric
-    names = {m["name"] for m in loaded.end_to_end}
-    assert "setup_s" in names and len(names) >= 2 and loaded.per_layer
-    layers = harness.load_layers(REPO)
-    for metric in loaded.per_layer:
-        assert metric["name"] in layers
-        assert metric["moves"] in names, "a layer metric where the metric it moves is not"
-    # the traffic's round is found by its dotted name (none names one: the
-    # default), and what the round reads of the file resolves to the program
-    # and the adapters
-    from benchmark import traffic
-
-    round_module = harness.round_of(loaded)
-    assert round_module.__name__ == loaded.traffic.round == traffic.DEFAULT_ROUND
-    assert "round" not in loaded.traffic.params
-    for key in round_module.TRAFFIC_KEYS:
-        if key != "accumulate":
-            assert callable(traffic.resolve(loaded.traffic.params[key])), key
-    assert loaded.traffic.params["accumulate"] in ("sum", "sum_mod_p")
-    assert harness.span_names(loaded) == ("round", "dispatch", "fold", "fetch", "epilogue", "check")
-    assert loaded.traffic.chunk == loaded.config["chunk"]
+    cell_checks.check_cell(REPO, cell)
 
 
 def test_cells_are_unique_and_few_take_four_chips():
@@ -150,6 +130,10 @@ def test_cells_are_unique_and_few_take_four_chips():
 
 
 def test_layer_files_agree_with_the_manifest():
+    """Unit, layer and the metric moved are the manifest's. Which cells report
+    a metric is said once, in the manifest (``workloads``): a layer file has
+    no list to keep equal, so a cell that shares a metric's code adds its name
+    there and edits no file."""
     layers = harness.load_layers(REPO)
     listed = {m["name"]: m for m in MANIFEST["per_layer"]}
     assert set(layers) == set(listed)
@@ -158,7 +142,22 @@ def test_layer_files_agree_with_the_manifest():
         assert (module.unit, module.layer, module.moves) == (
             entry["unit"], entry["layer"], entry["moves"]
         ), name
-        assert module.cells == entry.get("workloads"), name
+        assert not hasattr(module, "cells"), name
+        assert isinstance(module.reads_spans, tuple), name
+
+
+def test_the_spans_every_round_opens_are_those_the_cell_wide_metrics_read():
+    """``benchmark.rounds`` says which spans every round opens; they are what
+    the metrics with no list of cells read, the harness's own span aside."""
+    from benchmark import rounds
+
+    layers = harness.load_layers(REPO)
+    read = {
+        span
+        for metric in MANIFEST["per_layer"] if "workloads" not in metric
+        for span in layers[metric["name"]].reads_spans
+    }
+    assert read - {harness.ROUND_SPAN} == set(rounds.CELL_WIDE_SPANS)
 
 
 def test_files_under_paths_are_named_from_a_names_characters():

@@ -3,11 +3,9 @@ agree exactly with the plain reference, faults are caught, and new cells,
 configurations, traffic mixes and layer metrics are new files. The CPU
 devices are handed in here; the benchmark has no option for them."""
 
-import hashlib
 import json
 import os
 import pathlib
-import shutil
 import subprocess
 import sys
 import time
@@ -16,6 +14,7 @@ import numpy as np
 import pytest
 
 import bench_tree
+import cell_checks
 from benchmark import harness, reference
 from benchmark.rounds import packed_fold
 
@@ -107,17 +106,9 @@ def test_a_round_that_raises_is_a_failed_round(tree):
 
 
 def test_new_cells_are_new_files_and_edit_none_that_was_there(tree):
-    def digest(path):
-        return hashlib.sha256(path.read_bytes()).hexdigest()
-
-    originals = [
-        p for p in (REPO / "benchmark").rglob("*")
-        if p.is_file() and "out" not in p.parts and "__pycache__" not in p.parts
-    ]
+    originals, copies = bench_tree.digests(REPO, ["benchmark"]), bench_tree.digests(tree, ["benchmark"])
     assert originals
-    for path in originals:
-        copy = tree / path.relative_to(REPO)
-        assert digest(copy) == digest(path), path
+    assert {path: copies[path] for path in originals} == originals
     kept = json.loads((REPO / "BENCHMARK.json").read_text())
     grown = json.loads((tree / "BENCHMARK.json").read_text())
     for key in ("configs", "workloads", "end_to_end", "per_layer"):
@@ -130,7 +121,8 @@ def test_a_new_layer_metric_is_a_new_file(tmp_path):
     name = bench_tree.add_cell(root, *bench_tree.TINY_CELLS[3])
     (root / "benchmark/layers/check_s.py").write_text(
         "import statistics\n"
-        "name, unit, layer, moves, cells = 'check.s', 's', 'reference check', 'round_s', None\n"
+        "name, unit, layer, moves = 'check.s', 's', 'reference check', 'round_s'\n"
+        "reads_spans = ('check',)\n"
         "def reduce(spans, trace, cell):\n"
         "    return statistics.median(s.seconds for s in spans if s.name == 'check')\n"
     )
@@ -146,60 +138,154 @@ def test_a_new_layer_metric_is_a_new_file(tmp_path):
     assert "check.s" not in run(root, other, trace=True)["metrics"]
 
 
-def digests(root):
-    return {
-        p.relative_to(root).as_posix(): hashlib.sha256(p.read_bytes()).hexdigest()
-        for p in (root / "benchmark").rglob("*")
-        if p.is_file() and "out" not in p.parts and "__pycache__" not in p.parts
+# ---------------------------------------------------------------------------
+# A new kind of round is new files, in everything under the benchmark's paths
+# ---------------------------------------------------------------------------
+
+TOY = "toy-masked"
+
+
+@pytest.fixture(scope="module")
+def toy_tree(tmp_path_factory):
+    """What the next configuration's PR does, in a copy of everything under
+    the benchmark's ``paths``: ``bench_tree.add_toy_cell`` drops the toy
+    round in as ``benchmark/rounds/toy_masked.py`` with its files. Yields
+    ``(root, digests before)``."""
+    root = bench_tree.copy_benchmark(tmp_path_factory.mktemp("toy") / "copy", bench_tree.PATHS)
+    before = bench_tree.digests(root)
+    bench_tree.add_toy_cell(root, TOY, "toy_masked")
+    with bench_tree.rounds_importable_from(root):
+        yield root, before
+
+
+def test_a_new_kind_of_round_is_a_new_file(toy_tree):
+    """A round with a stage after reconstruct that needs state the step handed
+    on, under a span of its own, with a second device program in the window,
+    comes as one module under ``benchmark/rounds/``, a configuration, a
+    traffic file that names the module, one ``workloads`` entry, a layer file
+    and its name in one shared metric's list. No file that was there, under
+    either of the benchmark's paths, is edited."""
+    root, before = toy_tree
+    line = run(root, TOY)
+    assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 1
+    record = json.loads((root / "out" / f"rounds-{TOY}-seed5-trace0.json").read_text())
+    assert list(record["spans"]) == ["dispatch", "fold", "fetch", "epilogue", "unmask", "check"]
+    assert len(record["spans"]["unmask"]) == line["attempted"]
+    assert all(seconds > 0 for seconds in record["spans"]["unmask"])
+    cell = harness.load_cell(root, TOY)
+    module = harness.round_of(cell)
+    assert module.__name__ == cell.traffic.round == "benchmark.rounds.toy_masked"
+    assert pathlib.Path(module.__file__).parent == root / "benchmark/rounds"
+    assert harness.span_names(cell)[0] == "round" and "unmask" in harness.span_names(cell)
+    import jax
+
+    programs = module.steps(cell, jax.devices("cpu"))
+    assert [jitted.__name__ for jitted, _args in programs] == ["masked_step", "unmask_fold"]
+    after = bench_tree.digests(root)
+    assert {path: after[path] for path in before} == before, "a file that was there was edited"
+    assert before == bench_tree.digests(REPO)
+    assert any(path.startswith("tests/benchmark/") for path in before)
+    assert set(after) - set(before) == {
+        "benchmark/rounds/toy_masked.py", "benchmark/layers/unmask_s.py",
+        f"benchmark/configs/{TOY}-config.json", f"benchmark/traffic/{TOY}-traffic.json",
     }
+    kept = json.loads((REPO / "BENCHMARK.json").read_text())
+    grown = json.loads((root / "BENCHMARK.json").read_text())
+    assert [m["name"] for m in grown["per_layer"]] == [m["name"] for m in kept["per_layer"]] + ["unmask.s"]
+    for was, now in zip(kept["per_layer"], grown["per_layer"]):
+        shared = was["name"] == bench_tree.TOY_SHARED_METRIC
+        assert now == (dict(was, workloads=[*was["workloads"], TOY]) if shared else was)
+    for key in ("configs", "workloads", "end_to_end"):
+        assert grown[key][: len(kept[key])] == kept[key], "entries are added, none changed"
 
 
-def add_masked_cell(root, name, traffic_round=None):
-    """One tiny cell whose configuration states the toy round's scheme kind;
-    its traffic names ``traffic_round``, or no round at all."""
-    changes = {"round": traffic_round} if traffic_round else {}
-    bench_tree.add_cell(root, name, *bench_tree.TINY_CELLS[0][1:], **changes)
+def test_the_new_rounds_cell_passes_the_checks_the_four_cells_pass(toy_tree):
+    root, _before = toy_tree
+    cell_checks.check_config(root, f"{TOY}-config")
+    cell_checks.check_cell(root, TOY)
+    for cell in json.loads((REPO / "BENCHMARK.json").read_text())["workloads"]:
+        cell_checks.check_cell(root, cell["name"])
+
+
+def test_a_traced_run_of_the_new_round_reports_its_own_and_the_shared_metrics(toy_tree):
+    """Idle gaps are named and host events kept by the round's own spans; its
+    own layer file reads its ``unmask`` span, and the cell-wide metrics read
+    the three spans every round opens."""
+    root, _before = toy_tree
+    traced = run(root, TOY, trace=True)
+    assert traced["correct"] is True
+    assert set(traced["metrics"]) == {"engine.fold_s", "epilogue.s", "elems_per_s", "unmask.s"}
+    assert traced["metrics"]["unmask.s"]["value"] > 0
+
+
+def test_a_rounds_own_comparisons_reach_the_line(toy_tree, monkeypatch):
+    """The round compares what only it knows (the reveal before unmasking
+    must not equal the plain aggregate); the harness writes that into the
+    line after its own four and counts it into ``correct``."""
+    root, _before = toy_tree
+    line = run(root, TOY)
+    assert list(line["compared"]) == [
+        "warmup_mismatched", "rounds_mismatched", "rounds_repeated", "compiles_in_window",
+        "unmasked_reveals",
+    ]
+    assert line["compared"]["unmasked_reveals"] == {"value": 0, "limit": 0}
+    assert line["correct"] is True
+    # the stage did work: with masks that are all nought the clerks reveal
+    # the plain aggregate itself, every round still matches, and only the
+    # round's own comparison says that nothing was masked
+    module = harness.round_of(harness.load_cell(root, TOY))
+    monkeypatch.setattr(module, "MASK_BELOW", 1)
+    bare = run(root, TOY)
+    assert bare["failed"] == 0 and bare["compared"]["rounds_mismatched"]["value"] == 0
+    assert bare["compared"]["unmasked_reveals"]["value"] == bare["attempted"] + 1
+    assert bare["correct"] is False
+
+
+def test_a_round_may_not_take_the_name_of_a_comparison_of_the_harness(toy_tree, monkeypatch):
+    root, _before = toy_tree
+    module = harness.round_of(harness.load_cell(root, TOY))
+    monkeypatch.setattr(
+        module.Session, "compared", lambda self: {"rounds_mismatched": {"value": 0, "limit": 9}}
+    )
+    with pytest.raises(harness.HarnessError, match="rounds_mismatched"):
+        run(root, TOY)
+
+
+def test_a_round_that_lacks_a_span_a_listed_metric_reads_fails_the_cell_check(tmp_path):
+    """A cell whose round does not open what a metric it reports reads is
+    found here, on the CPU, by the metric's and the span's names: on the chip
+    the traced line would lack the metric and the run be refused."""
+    source = (pathlib.Path(__file__).parent / "toy_round.py").read_text()
+    root = bench_tree.copy_benchmark(tmp_path / "copy")
+    name = bench_tree.add_toy_cell(
+        root, "toy-renamed", "toy_renamed", source=source.replace('"epilogue"', '"reveal"')
+    )
+    with bench_tree.rounds_importable_from(root):
+        assert "reveal" in harness.span_names(harness.load_cell(root, name))
+        with pytest.raises(AssertionError, match="'epilogue.s', which reads the span 'epilogue'"):
+            cell_checks.check_cell(root, name)
+        # the same through a metric with a list of cells, by the metric's name:
+        # a cell of the plain round listed under the toy's own metric
+        other = bench_tree.add_cell(root, *bench_tree.TINY_CELLS[0])
+        cell_checks.check_cell(root, other)
+        manifest = json.loads((root / "BENCHMARK.json").read_text())
+        for metric in manifest["per_layer"]:
+            if metric["name"] == "unmask.s":
+                metric["workloads"].append(other)
+        (root / "BENCHMARK.json").write_text(json.dumps(manifest))
+        with pytest.raises(AssertionError, match="'unmask.s', which reads the span 'unmask'"):
+            cell_checks.check_cell(root, other)
+
+
+def add_masked_cell(root, name):
+    """One tiny cell whose configuration states the toy round's scheme kind
+    under a traffic file that names no round at all."""
+    bench_tree.add_cell(root, name, *bench_tree.TINY_CELLS[0][1:])
     config_file = root / "benchmark/configs" / f"{name}-config.json"
     config = json.loads(config_file.read_text())
     config["scheme"]["kind"] = "toy_masked_packed_shamir"
     config_file.write_text(json.dumps(config))
     return name
-
-
-def test_a_new_kind_of_round_is_a_new_file(tmp_path, monkeypatch):
-    """What the next configuration's PR does: a round with a stage after
-    reconstruct that needs state the step handed on, under a span of its own,
-    comes as one module, a traffic file that names it and one ``workloads``
-    entry. No file that was there is edited."""
-    root = bench_tree.copy_benchmark(tmp_path / "copy")
-    before = digests(root)
-    shutil.copy(pathlib.Path(__file__).parent / "toy_round.py", tmp_path / "dropped_in_round.py")
-    monkeypatch.syspath_prepend(str(tmp_path))
-    name = add_masked_cell(root, "toy-masked", traffic_round="dropped_in_round")
-    line = run(root, name)
-    assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 1
-    record = json.loads((root / "out" / f"rounds-{name}-seed5-trace0.json").read_text())
-    assert list(record["spans"]) == ["dispatch", "fold", "fetch", "epilogue", "unmask", "check"]
-    assert len(record["spans"]["unmask"]) == line["attempted"]
-    assert all(seconds > 0 for seconds in record["spans"]["unmask"])
-    cell = harness.load_cell(root, name)
-    assert harness.span_names(cell)[0] == "round" and "unmask" in harness.span_names(cell)
-    # the stage did work: what the clerks revealed was masked, and without the
-    # state the steps handed on the round would not have matched
-    import jax
-
-    session = harness.round_of(cell).Session(cell, 5, jax.devices("cpu"), {})
-    matched, _evidence = session.run_round(1, harness.Spans())
-    assert matched and session.masks_total > 0 and session.masked_differs
-    after = digests(root)
-    assert {path: after[path] for path in before} == before, "a file that was there was edited"
-    assert before == digests(REPO)
-    assert set(after) - set(before) == {
-        f"benchmark/configs/{name}-config.json", f"benchmark/traffic/{name}-traffic.json",
-    }
-    # a traced run names idle gaps and keeps host events by the round's own spans
-    traced = run(root, name, trace=True)
-    assert traced["correct"] is True and "epilogue.s" in traced["metrics"]
 
 
 def test_a_scheme_kind_the_round_does_not_know_is_refused_by_the_round(tmp_path):

@@ -2,6 +2,7 @@
 can be worked out on paper, and on a small trace recorded on the chip
 (``recorded-trace-x4.json``, made by ``record_trace.py``)."""
 
+import dataclasses
 import json
 import pathlib
 
@@ -69,6 +70,16 @@ def test_busy_is_the_union_of_operation_intervals(reduced):
     assert reduced.busy_seconds(1) == pytest.approx(20e-6)
     assert reduced.mean_busy_seconds() == pytest.approx(43e-6)
     assert reduced.max_busy_seconds() == pytest.approx(66e-6)
+
+
+def test_busy_of_named_programs_leaves_the_others_operations_out(reduced):
+    # chip 0's operations all run inside jit_step; chip 1's trace names no
+    # module, so its operation is no program's
+    assert reduced.busy_seconds(0, {"jit_step"}) == pytest.approx(66e-6)
+    assert reduced.busy_seconds(1, {"jit_step"}) == 0.0
+    assert reduced.max_busy_seconds({"jit_step"}) == pytest.approx(66e-6)
+    assert reduced.max_busy_seconds({"jit_other"}) == 0.0
+    assert reduced.max_busy_seconds(frozenset()) == 0.0
 
 
 def test_idle_share_is_the_idlest_chips(reduced):
@@ -198,7 +209,7 @@ def test_every_layer_metric_reduces_the_recorded_trace_to_a_number(recorded):
         name=cell.name, chips=4, config=cell.config, traffic=cell.traffic, rounds=rounds,
         elements_per_round=8 * 62, chunk_bytes=8 * 62 * 8, acc_bytes=2 * 13 * 7 * 8, steps_per_round=4, plan=plan,
         peaks=harness.load_peaks(repo, "TPU v5 lite"), memory_peak_bytes=5 << 30,
-        log=lines.append,
+        log=lines.append, chunk_step_modules=frozenset({"jit_step"}),
     )
     values = {
         name: module.reduce(spans, reduced, context)
@@ -221,7 +232,21 @@ def test_every_layer_metric_reduces_the_recorded_trace_to_a_number(recorded):
     assert values["collective.exposed_share"] == pytest.approx(100.0)
     least, binds = models.least_seconds(8 * 62 * 8 // 4 + 2 * 2 * 13 * 7 * 8, 0, context.peaks)
     assert binds == "hbm" and "hbm binds" in lines[0]
-    assert values["chunk_step_roofline"] == pytest.approx(100 * least / (busy / (rounds * 4)))
+    # the chunk step's share is of its own program's device time: the rounds'
+    # jit_fold_in, which the trace holds too, is not the step's
+    step_busy = reduced.max_busy_seconds({"jit_step"})
+    for chip in reduced.chips:
+        others = reduced.busy_seconds(chip, {"jit_fold_in"})
+        assert 0 < others < 0.1 * reduced.busy_seconds(chip)
+        assert reduced.busy_seconds(chip, {"jit_step"}) + others == pytest.approx(
+            reduced.busy_seconds(chip), rel=1e-9
+        )
+    assert step_busy < busy
+    assert values["chunk_step_roofline"] == pytest.approx(100 * least / (step_busy / (rounds * 4)))
+    silent_context = dataclasses.replace(context, chunk_step_modules=frozenset({"jit_not_there"}))
+    layers = harness.load_layers(repo)
+    assert layers["chunk_step_roofline"].reduce(spans, reduced, silent_context) is None
+    assert layers["kernel.busy_s"].reduce(spans, reduced, silent_context) == pytest.approx(busy / rounds)
     assert values["engine.fold_s"] > 0 and values["epilogue.s"] > 0
     whole = [s for s in spans if s.name == "round"]
     elapsed = max(s.end for s in whole) - min(s.start for s in whole)
