@@ -20,13 +20,21 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import telemetry
+
 # Fast int64 plane: exact only for moduli below 2**31 (products < 2**62,
 # sums of < 2**32 reduced terms). Larger moduli (up to WIDE_MAX_MODULUS,
 # covering the 61-bit federated config) route through the wide paths:
-# halving mod-sums (pair sums < 2**63 stay exact) and exact object-dtype /
-# limb-space multiplication.
+# halving mod-sums (pair sums < 2**63 stay exact) and limb-space
+# multiplication reduced by ``mod_limbs_np``, all in machine integers.
 MAX_SAFE_MODULUS = 1 << 31
 WIDE_MAX_MODULUS = 1 << 62
+
+#: quotient bound of ``mod_limbs_np``: its float64 quotient estimate is
+#: within 1 of the true one while the true one stays below this
+_MAX_LIMB_QUOTIENT = 1 << 50
+#: most limbs ``mod_limbs_np`` takes (each adds a rounding to the estimate)
+_MAX_LIMBS = 4
 
 
 def rust_rem_np(x, m):
@@ -91,16 +99,177 @@ def mod_sum_wide_np(x: np.ndarray, m: int, axis: int = 0) -> np.ndarray:
     return x[0]
 
 
+def mod_limbs_np(limbs, shift: int, p: int) -> np.ndarray:
+    """``(Σ_j limbs[j] · 2**(shift·j)) mod p``, canonical in ``[0, p)``,
+    exact, in machine integers: the one reduction under the wide host paths
+    (recombine, ``modmatmul_np``'s wide branch).
+
+    ``limbs``: at most 4 int64 arrays of one shape, every entry
+    non-negative; ``1 < p < 2**62``; the value ``V`` they spell must stay
+    below ``2**50 · p``. All three are checked (the last from each limb's
+    maximum) and a breach raises ``ValueError``: nothing is trusted.
+
+    Why it is exact. ``V`` itself fits no machine integer, but two images
+    of it do. In float64, ``x = V_float / p`` carries a relative error of
+    at most ``(len(limbs) + 2) · 2**-53`` (one rounding a limb's
+    conversion and addition, one for ``float(p)``, one for the division;
+    the weights are powers of two), so with ``V / p < 2**50`` and four
+    limbs ``|x − V/p| < 6/8`` and ``q̂ = floor(x)`` is the true quotient or
+    one off either way. Mod 2**64, in uint64 wrap-around arithmetic,
+    ``V − q̂·p`` is formed exactly; the true difference lies in
+    ``[−p, 2p)``, inside int64 since ``2p < 2**63``, so reading the
+    wrapped bits as int64 gives it, and one ``±p`` makes it canonical.
+    """
+    if not 1 < p < WIDE_MAX_MODULUS:
+        raise ValueError(f"modulus out of range: {p}")
+    limbs = [np.asarray(t, dtype=np.int64) for t in limbs]
+    if not 1 <= len(limbs) <= _MAX_LIMBS:
+        raise ValueError(f"1 to {_MAX_LIMBS} limbs, got {len(limbs)}")
+    shape = limbs[0].shape
+    tops = []
+    for j, t in enumerate(limbs):
+        if t.shape != shape:
+            raise ValueError(f"limb {j} has shape {t.shape}, limb 0 {shape}")
+        if t.size and int(t.min()) < 0:
+            raise ValueError(f"limb {j} has a negative entry")
+        tops.append(int(t.max(initial=0)))
+    if sum(top << (shift * j) for j, top in enumerate(tops)) >= _MAX_LIMB_QUOTIENT * p:
+        raise ValueError("limbs spell a value of 2**50 · p or more; reduce them first")
+    v_float = np.zeros(limbs[0].size, dtype=np.float64)
+    v_wrap = np.zeros(limbs[0].size, dtype=np.uint64)
+    for j, t in enumerate(limbs):
+        if not tops[j]:
+            continue  # adds nothing (and its weight may be beyond a float)
+        t = t.reshape(-1)  # 1-d: numpy's scalars warn where its arrays wrap
+        weight = 1 << (shift * j)
+        v_float += t.astype(np.float64) * float(weight)
+        v_wrap += t.astype(np.uint64) * np.uint64(weight & 0xFFFFFFFFFFFFFFFF)
+    quotient = np.floor(v_float / p).astype(np.uint64)
+    r = (v_wrap - quotient * np.uint64(p)).view(np.int64)
+    r += np.where(r < 0, np.int64(p), np.int64(0))
+    r -= np.where(r >= p, np.int64(p), np.int64(0))
+    return r.reshape(shape)
+
+
+def count_wide_product(path: str) -> None:
+    """One host mod-p product at a modulus of 2**31 or more, by the road it
+    took: ``limb`` (``mod_limbs_np``, machine integers) or ``object``
+    (python integers). A run reads from it how often the vectorised branch
+    engaged and whether anything still fell back."""
+    telemetry.counter(
+        "sda_wide_mod_products_total",
+        "host mod-p products at moduli >= 2**31, by path (limb | object)",
+        path=path,
+    ).inc()
+
+
+def _wide_operands(A, B, m: int):
+    """``(A, B)`` as canonical int64 arrays in ``[0, m)`` where the limb
+    road can take them, else ``None`` and the object road does: a modulus
+    of 2**62 or more, an entry no int64 holds, a negative entry (the object
+    road's result carries the exact product's sign, which machine integers
+    cannot tell), an empty operand, or shapes other than ``(..., K) @ (K,
+    N)``. Non-negative entries of ``m`` or more are reduced here, exactly."""
+    if m >= WIDE_MAX_MODULUS:
+        return None
+    try:
+        A = np.asarray(A).astype(np.int64, casting="unsafe", copy=False)
+        B = np.asarray(B).astype(np.int64, casting="unsafe", copy=False)
+    except (OverflowError, TypeError, ValueError):
+        return None
+    if not A.size or not B.size or A.ndim < 1 or B.ndim != 2 or A.shape[-1] != B.shape[0]:
+        return None
+    if A.min() < 0 or B.min() < 0:
+        return None
+    if A.max() >= m:
+        A = A % m
+    if B.max() >= m:
+        B = B % m
+    return A, B
+
+
+def modmatmul_path(A, B, m: int) -> str:
+    """The road ``modmatmul_np(A, B, m)`` takes, for a span's ``path``
+    attribute: ``int64`` below 2**31 (one machine-integer matmul and rem),
+    else ``limb`` or ``object`` as ``_wide_operands`` decides from the
+    operands."""
+    if m < MAX_SAFE_MODULUS:
+        return "int64"
+    return "object" if _wide_operands(A, B, m) is None else "limb"
+
+
+def _modmatmul_limbs(A: np.ndarray, B: np.ndarray, m: int) -> np.ndarray:
+    """``(A @ B) mod m``, canonical, for canonical int64 ``A`` ``(..., K)``
+    and ``B`` ``(K, N)``, ``2**31 <= m < 2**62``, without a python integer.
+
+    With ``w = ceil(bits(m) / 3)``, ``A = Σ_i a_i·2**(w·i)`` in three limbs
+    below ``2**w``, and ``A @ B ≡ Σ_i a_i @ B_i`` for ``B_i = 2**(w·i)·B mod
+    m`` (each made from the one before by ``mod_limbs_np``: a quotient below
+    ``2**w``). Split every ``B_i`` the same way, ``B_i = Σ_j b_ij·2**(w·j)``,
+    and the product is ``Σ_j T_j·2**(w·j)`` with ``T_j = [a_0|a_1|a_2] @
+    [b_0j; b_1j; b_2j]``: one matmul of ``(M, 3K) @ (3K, 3N)`` whose
+    entries stay below ``3K·2**(2w)``, exact in float64 (so on BLAS) while
+    that is under 2**53; a longer ``K`` is cut into chunks that keep it so,
+    their residues added mod m. The three ``T_j`` go through
+    ``mod_limbs_np`` (``V < 3K·2**(4w+1)`` is far below ``2**50·m`` for
+    every ``w >= 11``, and checked there).
+    """
+    if A.ndim == 2 and B.size > A.size:
+        # the weights are folded into the smaller operand
+        return _modmatmul_limbs(B.T, A.T, m).T
+    w = -(-m.bit_length() // 3)
+    mask = np.int64((1 << w) - 1)
+    K, N = B.shape
+    lead, A = A.shape[:-1], A.reshape(-1, K)
+
+    def split(x):
+        return np.concatenate([(x >> np.int64(w * i)) & mask for i in range(3)], axis=1)
+
+    scaled, folded = B, []
+    for i in range(3):
+        if i:
+            scaled = mod_limbs_np([np.zeros_like(scaled), scaled], w, m)
+        folded.append(split(scaled))  # (K, 3N): b_i0 | b_i1 | b_i2
+    chunk = ((1 << 53) - 1) // (3 * int(mask) ** 2)
+    out = None
+    for k0 in range(0, K, chunk):
+        k1 = min(K, k0 + chunk)
+        a_limbs = split(A[:, k0:k1]).astype(np.float64)  # (M, 3·(k1-k0))
+        b_limbs = np.concatenate([f[k0:k1] for f in folded], axis=0).astype(np.float64)
+        # transposed, (3N, 3K) @ (3K, M): every T_j comes out contiguous, and
+        # with the long axis last the skinny dgemm was the quicker and the
+        # steadier on the hosts it was timed on (PERF.md §6, PR 29)
+        T = (b_limbs.T @ a_limbs.T).astype(np.int64)  # T_0; T_1; T_2, each (N, M)
+        part = mod_limbs_np([T[j * N : (j + 1) * N] for j in range(3)], w, m)
+        if out is None:
+            out = part
+        else:
+            out += part
+            out -= np.where(out >= m, np.int64(m), np.int64(0))
+    return out.T.reshape(lead + (N,))
+
+
 def modmatmul_np(A: np.ndarray, B: np.ndarray, m: int) -> np.ndarray:
     """Exact (A @ B) mod m.
 
     m < 2**31: int64 path — products reduced before the K-sum so the
-    accumulator cannot overflow for any K < 2**32. Larger m (to 2**62):
-    exact arbitrary-precision object-dtype path (the host protocol plane is
-    not the hot loop; the device hot loop uses limb kernels instead).
+    accumulator cannot overflow for any K < 2**32. Larger m (to 2**62),
+    non-negative operands (all the fabric and the share build feed): three
+    limbs of a third of m's width, one float64 matmul and ``mod_limbs_np``
+    (``_modmatmul_limbs``), canonical result, no python integer. What that
+    road cannot take (``_wide_operands``: a negative entry, m >= 2**62,
+    values beyond int64) takes the arbitrary-precision object-dtype road
+    it took before; the choice is made from the operands, and both roads
+    return the same bits wherever both apply. ``sda_wide_mod_products_total``
+    counts the wide calls by road.
     Result keeps truncated-remainder representatives in (-m, m).
     """
     if m >= MAX_SAFE_MODULUS:
+        operands = _wide_operands(A, B, m)
+        if operands is not None:
+            count_wide_product("limb")
+            return _modmatmul_limbs(*operands, m)
+        count_wide_product("object")
         A = np.asarray(A, dtype=object)
         B = np.asarray(B, dtype=object)
         out = A @ B

@@ -44,7 +44,7 @@ import numpy as np
 
 from .. import telemetry
 from .lagrange import lagrange_matrix
-from .modular import modmatmul_np, positive
+from .modular import modmatmul_np, modmatmul_path, positive
 from .ntt import dft_matrix, inverse_dft_matrix
 
 
@@ -165,9 +165,13 @@ def reconstruct_clerk_sums_host(clerk_sums, indices, scheme, dim: int) -> np.nda
     ``parallel.engine.reconstruct`` and the sum-first bench epilogue."""
     p = scheme.prime_modulus
     rows = np.asarray(clerk_sums)[list(indices)]  # (R, B)
-    with telemetry.span("fabric.reconstruct", modulus_bits=p.bit_length(), shape=rows.shape):
+    with telemetry.span(
+        "fabric.reconstruct", modulus_bits=p.bit_length(), shape=rows.shape
+    ) as record:
         L = reconstruction_matrix(scheme, list(indices))  # (k, R)
         secrets = reconstruct_batches(rows.T, L, p)  # (B, k)
+        if record is not None:
+            record["attrs"]["path"] = modmatmul_path(rows.T, L.T, p)
         return np.asarray(secrets).reshape(-1)[:dim]
 
 

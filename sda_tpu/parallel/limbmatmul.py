@@ -220,19 +220,33 @@ def limb_modmatmul_const(A, B_host, p: int):
 
 
 def limb_recombine_host(partials, p: int):
-    """Exact host recombine for wide moduli (p >= 2^31): the weighted sum
-    ``sum_w partials[w] * 128^w mod p`` overflows int64 on device, but the
-    accumulator this runs on is tiny (W x batches x clerks), so python-int
-    arithmetic is fine. Returns canonical int64 values."""
+    """Exact host recombine ``sum_w partials[w] * 128^w mod p`` of the tiny
+    ``(W, batches, clerks)`` accumulator, for any modulus width (the device
+    recombine overflows int64 from p = 2^31 up). Machine integers: below
+    2^31 every reduced term times its weight is under 2^62, an int64
+    multiply-add and ``%``; from 2^31 up it is ``modmatmul_np`` by the
+    column of weights (its limb road; the span's ``path`` says which was
+    taken). Returns canonical int64 values."""
     import numpy as np
 
     from .. import telemetry
+    from ..ops.modular import MAX_SAFE_MODULUS, modmatmul_np, modmatmul_path, positive
 
     with telemetry.span(
         "fabric.epilogue.recombine", modulus_bits=int(p).bit_length(), shape=np.shape(partials)
-    ):
-        arr = np.asarray(partials, dtype=object)
-        out = np.zeros(arr.shape[1:], dtype=object)
-        for w in range(arr.shape[0]):
-            out = (out + arr[w] * pow(128, w, p)) % p
-        return out.astype(np.int64)
+    ) as record:
+        arr = np.asarray(partials)
+        weights = [pow(128, w, p) for w in range(arr.shape[0])]
+        if p < MAX_SAFE_MODULUS and arr.dtype.kind == "i":
+            path = "int64"
+            out = np.zeros(arr.shape[1:], dtype=np.int64)
+            for w, weight in enumerate(weights):
+                out = (out + (arr[w].astype(np.int64, copy=False) % p) * weight) % p
+        else:
+            rows = np.moveaxis(arr, 0, -1)  # (..., W)
+            column = np.array(weights, dtype=np.int64).reshape(-1, 1)
+            path = modmatmul_path(rows, column, p)
+            out = positive(modmatmul_np(rows, column, p), p)[..., 0]
+        if record is not None:
+            record["attrs"]["path"] = path
+        return out

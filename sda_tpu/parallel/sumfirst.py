@@ -21,8 +21,11 @@ values ``v < p < 2⁶²`` split into ``lo = v & (2³²−1)`` and ``hi = v ≫ 3
 limb sums over ``C_total`` participants are bounded by ``C_total · (2³²−1)``,
 so int64 accumulators are exact for up to 2³¹ participants (2048× the 1M
 north star). For ``p < 2³¹`` a single limb suffices. The epilogue
-(recombine mod p + share matmul) runs host-side with exact python-int
-arithmetic on the tiny accumulator.
+(recombine mod p + share matmul) runs host-side on the tiny accumulator,
+exact and in machine integers: each limb reduced by an int64 ``%``, the
+two of a wide modulus joined by ``ops.modular.mod_limbs_np``, the share
+matmul by ``modmatmul_np``. Python integers remain only where a caller asks
+for the unreduced sums (``exact_value_sums``).
 """
 
 from __future__ import annotations
@@ -32,7 +35,14 @@ import numpy as np
 from .. import telemetry
 from ..ops import shamir
 from ..ops.jaxcfg import ensure_x64
-from ..ops.modular import modmatmul_np
+from ..ops.modular import (
+    MAX_SAFE_MODULUS,
+    WIDE_MAX_MODULUS,
+    count_wide_product,
+    mod_limbs_np,
+    modmatmul_np,
+    modmatmul_path,
+)
 from .engine import AggregationPlan, _batch_secrets, _device_randomness
 
 #: participant bound for exact int64 limb accumulation (see module doc)
@@ -164,6 +174,26 @@ def exact_value_sums(limb_acc):
     return out
 
 
+def _value_sums_mod(limb_acc, p: int, exact=None):
+    """``(L, B, K)`` limb accumulator -> ``((B, K)`` canonical int64 value
+    sums mod p, the road taken``)``. Each limb is reduced first by an int64
+    ``%`` (exact; so whatever the participant count, up to limb sums of
+    2⁶³ − 1, the joined value stays under 2³³·p): one limb is that alone
+    (``int64``), two go through ``mod_limbs_np`` (``limb``). A handed-in
+    ``exact``, more than two limbs or p ≥ 2⁶² take python integers
+    (``object``)."""
+    acc = np.asarray(limb_acc)
+    if exact is None and acc.dtype.kind == "i":
+        acc = acc.astype(np.int64, copy=False)
+        if acc.shape[0] == 1:
+            return acc[0] % p, "int64"
+        if acc.shape[0] == 2 and p < WIDE_MAX_MODULUS:
+            return mod_limbs_np([acc[0] % p, acc[1] % p], 32, p), "limb"
+    if exact is None:
+        exact = exact_value_sums(limb_acc)
+    return (exact % p).astype(np.int64), "object"
+
+
 def clerk_sums_from_limb_acc(limb_acc, plan: AggregationPlan, exact=None):
     """Host epilogue: ``(L, B, K)`` int64 limb accumulator -> clerk sums.
 
@@ -172,22 +202,32 @@ def clerk_sums_from_limb_acc(limb_acc, plan: AggregationPlan, exact=None):
     sharing + clerk-combine produces), ``value_sums`` the ``(B, K)``
     canonical participant-sums (whose first ``k`` columns are the plain
     batched secret sums — the free verification handle). All arithmetic on
-    this tiny accumulator is exact python-int / object-dtype. Pass a
-    precomputed ``exact_value_sums(limb_acc)`` as ``exact`` to reuse it.
+    this tiny accumulator is exact and vectorised (``_value_sums_mod``,
+    ``modmatmul_np``); each span's ``path`` says the road it took. Pass a
+    precomputed ``exact_value_sums(limb_acc)`` as ``exact`` to reuse it:
+    its python integers are then what is reduced.
     """
     p = plan.modulus
     if plan.share_matrix is None:
         raise ValueError("sum-first epilogue requires a packed share matrix")
     bits = p.bit_length()
-    with telemetry.span("fabric.epilogue.recombine", modulus_bits=bits, shape=np.shape(limb_acc)):
-        if exact is None:
-            exact = exact_value_sums(limb_acc)
-        vsum = exact % p  # exact sums >= 0: % == canonical rem
-    with telemetry.span("fabric.epilogue.share_matmul", modulus_bits=bits, shape=vsum.shape):
+    with telemetry.span(
+        "fabric.epilogue.recombine", modulus_bits=bits, shape=np.shape(limb_acc)
+    ) as record:
+        vsum, path = _value_sums_mod(limb_acc, p, exact)
+        if path != "int64" and p >= MAX_SAFE_MODULUS:
+            count_wide_product(path)
+        if record is not None:
+            record["attrs"]["path"] = path
+    with telemetry.span(
+        "fabric.epilogue.share_matmul", modulus_bits=bits, shape=vsum.shape
+    ) as record:
         S_T = plan.share_matrix.T.astype(np.int64)  # (K, n)
         clerk = modmatmul_np(vsum, S_T, p)  # (B, n) in (-p, p)
         clerk = np.where(clerk < 0, clerk + p, clerk).astype(np.int64)
-    return clerk.T.copy(), vsum.astype(np.int64)
+        if record is not None:
+            record["attrs"]["path"] = modmatmul_path(vsum, S_T, p)
+    return clerk.T.copy(), vsum
 
 
 def clerk_sums_sum_first(secrets, key, plan: AggregationPlan):

@@ -200,6 +200,50 @@ def test_host_spans_are_records_with_the_modulus_width_and_the_inputs_shape(fres
     assert spans["fabric.epilogue.recombine"]["attrs"]["shape"] == acc.shape
     assert spans["fabric.epilogue.share_matmul"]["attrs"]["shape"] == acc.shape[1:]
     assert spans["fabric.reconstruct"]["attrs"]["shape"] == (7, clerk_sums.shape[1])
+    # the road each took: at 61 bits all three are mod_limbs_np's, no python integer
+    assert {s["attrs"]["path"] for s in spans.values()} == {"limb"}
+    assert set(wide_products()) == {"limb"}
+
+
+def wide_products():
+    """Host mod-p products at wide moduli since the last reset, by path."""
+    return {
+        dict(labels)["path"]: value
+        for (name, labels), value in telemetry.get_registry().snapshot()["counters"].items()
+        if name == "sda_wide_mod_products_total"
+    }
+
+
+@pytest.mark.parametrize("bits,participant,path", [
+    (30, False, "int64"),  # a 31-bit prime, as c4-w31-d50k's: one limb, the narrow matmul
+    (30, True, "int64"),
+    (31, False, "limb"),  # a 32-bit prime: two limbs already
+    (31, True, "limb"),
+    (61, True, "limb"),
+])
+def test_every_host_span_says_the_path_it_took(bits, participant, path, fresh_telemetry):
+    round_trip(bits, participant)
+    spans = telemetry.spans(name="fabric.")
+    assert [s["attrs"]["path"] for s in spans] == [path] * (2 if participant else 3)
+    assert set(spans[0]["attrs"]) == {"modulus_bits", "shape", "path"}
+    # the plan's share matrix is built by wide products too: none by python integers
+    assert set(wide_products()) == ({"limb"} if path == "limb" else set())
+
+
+def test_a_handed_in_exact_sum_is_reduced_as_python_integers_and_says_so(fresh_telemetry):
+    import jax
+
+    from sda_tpu.parallel import sumfirst
+
+    plan = plan_for(61)[1]
+    acc = np.asarray(chunk_entry(plan)(secrets_for(plan), jax.random.key(7)))
+    want = sumfirst.clerk_sums_from_limb_acc(acc, plan)
+    telemetry.reset()  # the plan's and the first epilogue's products are counted no more
+    got = sumfirst.clerk_sums_from_limb_acc(acc, plan, exact=sumfirst.exact_value_sums(acc))
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    assert [s["attrs"]["path"] for s in telemetry.spans(name="fabric.")] == ["object", "limb"]
+    assert wide_products() == {"object": 1, "limb": 1}
 
 
 def test_the_participant_engines_recombine_is_the_same_span(fresh_telemetry):
@@ -207,7 +251,7 @@ def test_the_participant_engines_recombine_is_the_same_span(fresh_telemetry):
     spans = telemetry.spans(name="fabric.")
     assert [s["name"] for s in spans] == ["fabric.epilogue.recombine", "fabric.reconstruct"]
     bits = plan_for(61)[1].modulus.bit_length()
-    assert spans[0]["attrs"] == {"modulus_bits": bits, "shape": acc.shape}
+    assert spans[0]["attrs"] == {"modulus_bits": bits, "shape": acc.shape, "path": "limb"}
 
 
 def test_host_spans_are_annotations_of_a_profiler_trace(tmp_path, fresh_telemetry):
