@@ -1,36 +1,21 @@
-"""Benchmark: packed-Shamir secure aggregation throughput on TPU.
+"""The host riders: the protocol plane's own measurements, all on the host.
 
-Drives the BASELINE.md ladder config "packed Shamir, 10K-dim, many
-participants" as a chunked streaming pipeline: synthetic participant
-vectors are generated on device, turned into per-clerk share sums, and
-finally reconstructed + verified against an independently computed
-plaintext sum.
+``python bench.py`` takes no argument and no accelerator. It runs the
+crypto-plane and REST-ingest microbenchmarks, then each protocol-plane
+rider of ``_RIDERS`` in order: full REST rounds over loopback that price
+batched ingest, the binary wire, the clerking and reveal pipelines,
+committee, shard and replication scaling, the tier fan-out and the sketch
+accuracy. A rider prints its own metric lines as it finishes and banks one
+artifact under ``bench-artifacts/`` (``SDA_BENCH_ARTIFACTS=0`` banks none;
+``scripts/sweep_report.py`` and ``scripts/bench_compare.py`` read them). The
+last stdout line carries every block under ``crypto``. A rider that raises
+ends the run non-zero with its traceback.
 
-Engines (``--engine``):
-
-- ``sumfirst`` (default): the linearity restructure
-  (sda_tpu/parallel/sumfirst.py) — ``share(Σ v) = Σ share(v)``, so the hot
-  loop is one exact limb-space integer reduction over the participant
-  stream and the share matmul runs once on the tiny participant-sum.
-  Bit-exact same clerk sums as per-participant sharing (tested), ~10x
-  faster; the right algorithm whenever the fabric's goal is the aggregate
-  (individual shares never leave the chip anyway).
-- ``participant``: per-participant share matmuls on the MXU via int8 limbs
-  (sda_tpu/parallel/limbmatmul.py), then the participant reduction — the
-  path a deployment uses when every participant's shares must exist
-  individually (e.g. for sealed transport).
-
-Prints ONE JSON line:
-    {"metric": ..., "value": N, "unit": "...", "vs_baseline": N}
-
-The reference publishes no numbers (BASELINE.md: "None exist"), so
-``vs_baseline`` is measured against the driver's north-star target rate —
-1M participants x 100K dims on a v5e-8 in 60 s = 1.042e9 shared
-elements/s/chip (8 chips) — i.e. vs_baseline >= 1.0 means this single chip
-is already at north-star per-chip pace.
+Nothing here touches the chip. What the fabric costs there is measured by
+``python benchmark/run.py --workload <cell>`` (``BENCHMARK.json``), and
+``chip_smoke.py`` proves the system still starts on it.
 """
 
-import argparse
 import contextlib
 import json
 import os
@@ -38,196 +23,22 @@ import pathlib
 import sys
 import threading
 import time
-import traceback
 
 import numpy as np
 
 from sda_tpu import telemetry
 
 
-NORTH_STAR_ELEMS_PER_S_PER_CHIP = (1_000_000 * 100_000) / 60.0 / 8.0
-
-METRIC_NAME = "packed_shamir_secure_sum_throughput_single_chip"
-
 #: one trace id for the whole run — bound in main() and stamped on every
 #: metric line, so stdout lines, the banked telemetry-<stamp>.json, and
 #: the server-side spans from the ingest riders all correlate
 RUN_TRACE_ID = telemetry.new_trace_id()
 
-#: published single-chip peaks for the roofline fields, keyed by the
-#: ``device_kind`` JAX reports (Google Cloud documentation, "TPU v5e":
-#: 819 GB/s HBM, 393 int8 TOP/s). A kind that is not in the table gets no
-#: percent-of-peak field.
-DEVICE_PEAKS = {
-    "TPU v5 lite": {"hbm_gbps": 819.0, "int8_tops": 393.0},
-}
-
-#: the device this process holds — ``jax.devices()[0].platform``,
-#: ``.device_kind`` and the device count — filled by acquire_device() and
-#: stamped on every metric line
-_DEVICE: dict = {}
-
-#: host-side crypto-plane rates, filled once by main() and attached to
-#: whichever metric line (success or error) the run emits
-_CRYPTO_STATS: dict = {}
-
-#: on-device parity evidence (filled after device acquisition)
-_PARITY_STATS: dict = {}
-
-
-class NoAccelerator(RuntimeError):
-    """JAX found no TPU and the caller did not ask for the CPU."""
-
-
-class VerificationFailed(RuntimeError):
-    """The reconstructed aggregate does not match the plaintext sum."""
-
-
-class ParityError(AssertionError):
-    """A device kernel's bits differ from its reference."""
-
-
-def acquire_device(*, allow_pinned_cpu: bool) -> dict:
-    """First (and only) device touch of the process, in-process: a chip
-    belongs to one process, so nothing here starts a child to look.
-
-    A TPU is the only device a measurement may come from. The CPU is
-    accepted only where the caller allows it AND ``JAX_PLATFORMS`` names
-    it explicitly (the tier-1 smoke children do) — a CPU that JAX fell
-    back to on its own is a failure, not a device."""
-    import jax
-
-    devices = jax.devices()
-    dev = {
-        "platform": devices[0].platform,
-        "kind": devices[0].device_kind,
-        "count": len(devices),
-    }
-    pinned_cpu = allow_pinned_cpu and "cpu" in [
-        name.strip() for name in os.environ.get("JAX_PLATFORMS", "").lower().split(",")
-    ]
-    if dev["platform"] != "tpu" and not pinned_cpu:
-        raise NoAccelerator(
-            f"JAX found no TPU (devices: {dev}); a run without one reports "
-            "nothing. For the CPU rehearsal set JAX_PLATFORMS=cpu explicitly."
-        )
-    _DEVICE.clear()
-    _DEVICE.update(dev)
-    return dev
-
-
-def _stamp(line: dict) -> dict:
-    """Every metric line — rider, error or final — names the run and the
-    device the process holds."""
-    line.setdefault("trace_id", RUN_TRACE_ID)
-    line.setdefault("device", dict(_DEVICE))
-    return line
-
 
 def _print_line(line: dict) -> None:
-    print(json.dumps(_stamp(line)), flush=True)
-
-
-#: atomic check-and-set guard around the run's FINAL metric line: the
-#: main thread and the pre-measurement deadline watchdog can both try to
-#: print the concluding JSON line, and exactly one of them may win (the
-#: driver parses the LAST stdout line).
-_FINAL_EMIT_LOCK = threading.Lock()
-_FINAL_EMITTED = False
-
-
-def _error_bank_path() -> pathlib.Path | None:
-    """Where the current error metric line is banked ON DISK. Stdout can
-    be lost (a driver that SIGKILLs bench and discards the pipe, a tee
-    that never flushed); the banked file survives anything short of disk
-    loss. ``SDA_BENCH_ERROR_FILE`` overrides; otherwise
-    bench-artifacts/error-latest.json, suppressed (like every artifact)
-    under SDA_BENCH_ARTIFACTS=0 unless the override names a path."""
-    explicit = os.environ.get("SDA_BENCH_ERROR_FILE")
-    if explicit:
-        return pathlib.Path(explicit)
-    if os.environ.get("SDA_BENCH_ARTIFACTS") == "0":
-        return None
-    return pathlib.Path(__file__).resolve().parent / "bench-artifacts" / "error-latest.json"
-
-
-def _bank_error_line(line: dict) -> None:
-    """Atomically persist the error line (tmp + os.replace): a reader
-    sees either the previous complete line or this complete line, never
-    a torn write."""
-    path = _error_bank_path()
-    if path is None:
-        return
-    try:
-        path.parent.mkdir(exist_ok=True)
-        tmp = path.with_name(path.name + ".tmp")
-        tmp.write_text(json.dumps(line) + "\n")
-        os.replace(tmp, path)
-    except OSError as exc:  # read-only checkout: keep the stdout evidence
-        print(f"[bench] error line not banked: {exc}", file=sys.stderr)
-
-
-def _clear_banked_error() -> None:
-    """A successful final line supersedes any banked error — a stale
-    error file next to a healthy run would misreport the round."""
-    path = _error_bank_path()
-    if path is None:
-        return
-    try:
-        path.unlink(missing_ok=True)
-    except OSError:
-        pass
-
-
-def emit_final(line: dict) -> bool:
-    """Print the run's final metric line unless another thread already
-    did. Returns whether this call won (and printed)."""
-    global _FINAL_EMITTED
-    with _FINAL_EMIT_LOCK:
-        if _FINAL_EMITTED:
-            return False
-        _FINAL_EMITTED = True
-    if "error" not in line:
-        _clear_banked_error()
-    _print_line(line)
-    return True
-
-
-def emit_error(msg: str) -> None:
-    """Once a device is held, whatever goes wrong afterwards leaves a
-    well-formed error-tagged metric line as the LAST stdout line (never a
-    raw traceback, never silence), banked on disk as well (see
-    _bank_error_line). Details go to stderr. A run that found no device
-    prints no metric line at all (see acquire_device)."""
-    line = _stamp(
-        {
-            "metric": METRIC_NAME,
-            "value": 0,
-            "unit": "shared_elements_per_second",
-            "vs_baseline": 0.0,
-            "error": msg,
-        }
-    )
-    if _CRYPTO_STATS:
-        line["crypto"] = _CRYPTO_STATS
-    if _PARITY_STATS:
-        line["tpu_parity"] = _PARITY_STATS
-    _bank_error_line(line)
-    emit_final(line)
-
-
-def _env_float(name: str, default: float) -> float:
-    raw = os.environ.get(name)
-    if raw is None:
-        return default
-    try:
-        return float(raw)
-    except ValueError:
-        print(
-            f"[bench] ignoring non-numeric {name}={raw!r}; using {default:g}",
-            file=sys.stderr,
-        )
-        return default
+    """Every metric line, rider or last, names the run."""
+    line.setdefault("trace_id", RUN_TRACE_ID)
+    print(json.dumps(line), flush=True)
 
 
 def measure_crypto_plane() -> dict:
@@ -2865,890 +2676,20 @@ def measure_sketch_accuracy() -> dict:
     return out
 
 
-def kernel_parity(
-    *,
-    seeds: int = 64,
-    dim: int = 100_000,
-    chunk: int = 2_000,
-    limb_dim: int = 10_000,
-) -> dict:
-    """Bit-parity of every device kernel against its reference — the one
-    kernel-parity routine, run by ``bench.py`` (at its own run's sizes,
-    capped at these) and by ``chip_smoke.py`` at the defaults, which are
-    the main path's shapes: the reveal's ChaCha mask combine at ``seeds``
-    x ``dim`` over the 61-bit field, and the per-participant share+combine
-    at the participant preset (``chunk`` x ``limb_dim``, 31-bit, K = 7).
-
-    The backend decides what runs, nothing is caught: on a TPU the
-    compiled Pallas kernels (a kernel that does not compile raises here);
-    on the CPU the jnp twin that backend uses plus the kernel source
-    under the Pallas interpreter. The first mismatch raises ParityError.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from sda_tpu.native import chacha_expand
-    from sda_tpu.ops import find_packed_parameters
-    from sda_tpu.ops.chacha_pallas import combine_masks_device
-    from sda_tpu.ops.modular import mod_sum_wide_np, positive
-    from sda_tpu.parallel.engine import make_plan, reconstruct, share_combine_limb
-    from sda_tpu.parallel.limb_pallas import share_combine_limb_pallas
-    from sda_tpu.parallel.limbmatmul import limb_recombine_host
-    from sda_tpu.protocol import PackedShamirSharing
-
-    on_tpu = jax.default_backend() == "tpu"
-    out: dict = {"platform": jax.default_backend()}
-    inject = os.environ.get("SDA_BENCH_INJECT_FAULT") == "parity"
-
-    def same(name, got, want):
-        got = np.asarray(got)
-        if inject:  # test hook: prove a mismatch is fatal, not a note
-            got = got.copy()
-            got.flat[0] += 1
-        if not np.array_equal(got, np.asarray(want)):
-            raise ParityError(f"{name}: device bits differ from the reference")
-        out[name] = "ok"
-
-    k, t, n = 5, 2, 8
-    p61, w2, w3 = find_packed_parameters(k, t, n, min_modulus_bits=60, seed=0)
-
-    # ChaCha: the recipient's mask combine, against the host expansion
-    rng = np.random.default_rng(3)
-    seed_rows = rng.integers(0, 2**32, size=(seeds, 4), dtype=np.uint32)
-    want = mod_sum_wide_np(
-        np.stack([chacha_expand(row, dim, p61) for row in seed_rows]), p61, axis=0
-    )
-    backends = ["pallas"] if on_tpu else ["jnp", "interpret"]
-    out["chacha_backends"] = backends
-    for backend in backends:
-        got = combine_masks_device(seed_rows, dim, p61, backend=backend)
-        same(f"chacha_{backend}", got, want)
-
-    # fused Pallas participant kernel vs the XLA int8-limb path, same key
-    p31, v2, v3 = find_packed_parameters(k, t, n, min_modulus_bits=30, seed=0)
-    plan = make_plan(PackedShamirSharing(k, n, t, p31, v2, v3), limb_dim)
-    secrets = jnp.asarray(
-        np.random.default_rng(4).integers(0, p31, size=(chunk, limb_dim))
-    )
-    key = jax.random.key(9)
-    xla = jax.jit(lambda s, kk: share_combine_limb(s, kk, plan))(secrets, key)
-    fused = jax.jit(
-        lambda s, kk: share_combine_limb_pallas(s, kk, plan, interpret=not on_tpu)
-    )(secrets, key)
-    same("limb", fused, xla)
-
-    # wide field: limb accumulators -> exact host recombine -> reconstruct
-    scheme = PackedShamirSharing(k, n, t, p61, w2, w3)
-    wide_dim = 25
-    wplan = make_plan(scheme, wide_dim)
-    wsecrets = (
-        p61 - np.random.default_rng(5).integers(1, 10_000, size=(32, wide_dim))
-    ).astype(np.int64)
-    acc = np.asarray(
-        jax.jit(lambda s, kk: share_combine_limb(s, kk, wplan))(
-            jnp.asarray(wsecrets), jax.random.key(2)
-        )
-    )
-    clerk_sums = limb_recombine_host(acc, p61).T  # exact, host-side
-    revealed = positive(
-        np.asarray(reconstruct(jnp.asarray(clerk_sums), range(n), scheme, wide_dim)),
-        p61,
-    )
-    plain = np.array(
-        [sum(int(v) for v in wsecrets[:, j]) % p61 for j in range(wide_dim)],
-        dtype=np.int64,
-    )
-    same("wide61", revealed, plain)
-    out["ok"] = True
-    return out
-
-
 @contextlib.contextmanager
-def stage(name: str, interval: float = 30.0):
-    """stderr breadcrumb + ticker: while a stage runs long (a first
-    compile takes minutes), keep printing elapsed time so a hang is
-    attributable to a stage, not the script."""
+def stage(name: str):
+    """stderr breadcrumbs around one measurement: a start line, and a done
+    line with its seconds."""
     t0 = time.perf_counter()
     print(f"[bench] {name}...", file=sys.stderr, flush=True)
-    done = threading.Event()
-
-    def tick():
-        while not done.wait(interval):
-            print(
-                f"[bench] {name} still running "
-                f"({time.perf_counter() - t0:.0f}s)",
-                file=sys.stderr,
-                flush=True,
-            )
-
-    t = threading.Thread(target=tick, daemon=True)
-    t.start()
     try:
         yield
     finally:
-        done.set()
         print(
             f"[bench] {name} done in {time.perf_counter() - t0:.2f}s",
             file=sys.stderr,
             flush=True,
         )
-
-
-def arm_deadline(seconds: float):
-    """Last-resort watchdog for the pre-measurement window (parity
-    checks and first compile). If the deadline passes before the first
-    segment lands, emit a diagnosable JSON metric line and hard-exit
-    (a blocked native call can't be interrupted from Python, so the
-    thread prints and ``os._exit``s). Disarmed once measurements exist —
-    from then on --budget governs. ``seconds <= 0`` disables it."""
-    if seconds <= 0:
-        return None
-
-    def fire():
-        print(
-            f"[bench] DEADLINE: no result after {seconds:.0f}s",
-            file=sys.stderr,
-            flush=True,
-        )
-        emit_error(
-            f"deadline {seconds:.0f}s exceeded before any measurement"
-        )
-        os._exit(2)
-
-    t = threading.Timer(seconds, fire)
-    t.daemon = True
-    t.start()
-    return t
-
-
-def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
-    parser = argparse.ArgumentParser()
-    parser.add_argument("--participants", type=int, default=None)
-    parser.add_argument("--dim", type=int, default=None)
-    parser.add_argument("--chunk", type=int, default=None)
-    parser.add_argument("--secret-count", type=int, default=5)
-    parser.add_argument("--privacy-threshold", type=int, default=2)
-    parser.add_argument("--share-count", type=int, default=8)
-    parser.add_argument("--no-limbs", action="store_true")
-    parser.add_argument(
-        "--wide",
-        action="store_true",
-        help="61-bit modulus (BASELINE config 5); forces the limb path with "
-        "exact host recombine of the tiny accumulator",
-    )
-    parser.add_argument(
-        "--engine",
-        choices=["sumfirst", "participant"],
-        default=None,
-        help="sumfirst = linearity-restructured hot loop (default); "
-        "participant = per-participant MXU share matmuls",
-    )
-    parser.add_argument(
-        "--northstar",
-        action="store_true",
-        help="(now the default) the literal BASELINE config-5 shape on "
-        "this one chip: 1M participants x 100K dims, 61-bit modulus, "
-        "streamed in memory-sized chunks (the 8-chip target is <60 s; one "
-        "chip does it in ~15 s steady)",
-    )
-    parser.add_argument(
-        "--pallas",
-        action="store_true",
-        help="participant engine only: fused Pallas limb kernel (per-block "
-        "share matmul + participant reduce in VMEM; narrow fields)",
-    )
-    parser.add_argument(
-        "--quick",
-        action="store_true",
-        help="smaller 100K x 10K / 31-bit shape (~30 s total) for smoke runs",
-    )
-    parser.add_argument(
-        "--budget",
-        type=float,
-        default=1200.0,
-        help="wall-clock budget in seconds: the participant stream is "
-        "processed in segments and stops early (still verified, metric "
-        "marked partial) once the budget is spent",
-    )
-    parser.add_argument(
-        "--segments",
-        type=int,
-        default=10,
-        help="split the stream into this many jit calls for progress "
-        "reporting and budget checks (same compiled fn each time)",
-    )
-    parser.add_argument(
-        "--trace-dir",
-        default=None,
-        metavar="DIR",
-        help="capture a JAX profiler trace of the steady-state segments "
-        "into DIR (view with xprof/tensorboard)",
-    )
-    parser.add_argument(
-        "--deadline",
-        type=float,
-        default=None,
-        help="hard wall-clock limit for the pre-measurement window "
-        "(parity checks + first compile): if nothing has been "
-        "measured by then, print an error-tagged metric line and exit 2 "
-        "instead of hanging forever. 0 disables. Default: "
-        "$SDA_BENCH_DEADLINE or 1800",
-    )
-    parser.add_argument(
-        "--no-parity",
-        action="store_true",
-        help="skip the on-device bit-parity checks (chacha/limb/wide61 "
-        "vs their references; kernel_parity) that otherwise run once "
-        "before the measurement, fail the run on a mismatch, and ride "
-        "along in the metric line",
-    )
-    parser.add_argument(
-        "--rng",
-        choices=("threefry", "rbg"),
-        default="threefry",
-        help="PRNG for the synthetic stream: threefry (default; same "
-        "counter-based draws as the engine's simulation path) or rbg "
-        "(XLA RngBitGenerator — much cheaper per word on TPU). The "
-        "stream is synthetic and self-verified within the same jit, so "
-        "the choice affects only generation cost, never correctness; "
-        "the metric line records which one ran",
-    )
-    parser.add_argument(
-        "--check",
-        choices=("full", "probe", "off"),
-        default="full",
-        help="independent wraparound-sum verification of the synthetic "
-        "stream (sumfirst engine): full (default) accumulates a second, "
-        "implementation-independent int64 sum over every column; probe "
-        "covers ~1024 strided columns (same byte-exact comparison, "
-        "~dim/1024x less check arithmetic riding the timed loop — the "
-        "check is bench scaffolding, not fabric work: a real clerk never "
-        "sees plaintext); off skips it (reconstruction is then verified "
-        "only against the limb accumulator itself). The metric line "
-        "records the mode; headline artifacts use full",
-    )
-    parser.add_argument(
-        "--roofline",
-        action="store_true",
-        help="after the measured run, time two extra compiled variants "
-        "of the same segment (independent check removed; RNG replaced by "
-        "an iota fill) to attribute the steady rate to check / "
-        "rng_expand / limb_reduce (sumfirst) or share_combine "
-        "(participant) and name the binding stage; ~2 extra compiles "
-        "plus a few re-timed segments of device time. Modeled HBM/MXU "
-        "traffic fields are emitted on every run regardless",
-    )
-    args = parser.parse_args(argv)
-    if args.deadline is None:
-        args.deadline = _env_float("SDA_BENCH_DEADLINE", 1800.0)
-    if args.engine is None:
-        # --no-limbs selects the int64 variant of the per-participant path;
-        # honor pre-existing invocations rather than silently ignoring it
-        args.engine = "participant" if args.no_limbs else "sumfirst"
-    elif args.no_limbs and args.engine == "sumfirst":
-        parser.error("--no-limbs only applies to --engine participant")
-    if args.quick and args.northstar:
-        parser.error("--quick and --northstar are mutually exclusive")
-    if args.check != "full" and args.engine != "sumfirst":
-        parser.error("--check probe/off applies to the sumfirst engine")
-    # presets fill only what the user left unset — explicit flags win.
-    # Default = the driver's north-star config 5 itself: measuring the
-    # headline metric at its true shape, not a proxy. The per-participant
-    # engine is ~10x slower by design (it materializes every participant's
-    # shares), so it defaults to the smaller smoke shape instead.
-    quick = args.quick or (args.engine == "participant" and not args.northstar)
-    preset = (100_000, 10_000, 2_000) if quick else (1_000_000, 100_000, 500)
-    if not quick:
-        args.wide = True
-    for name, value in zip(("participants", "dim", "chunk"), preset):
-        if getattr(args, name) is None:
-            setattr(args, name, value)
-    # after preset resolution: args.wide is final here
-    if args.pallas and (args.engine != "participant" or args.no_limbs or args.wide):
-        parser.error("--pallas applies to the narrow-field limb participant engine")
-    return args
-
-
-def run_fabric(args: argparse.Namespace, watchdog=None) -> dict:
-    """The timed fabric loop ``python bench.py`` runs, for one parsed
-    argument set: parity checks, the segmented stream, reconstruct +
-    verify, the metric line (returned, not printed). ``chip_smoke.py``
-    calls this too. Needs acquire_device() to have run. Raises
-    ParityError / VerificationFailed; nothing else is caught."""
-    from sda_tpu.ops.jaxcfg import ensure_x64
-
-    import jax
-
-    ensure_x64()
-    import jax.numpy as jnp
-    from jax import lax
-
-    from sda_tpu.ops import find_packed_parameters
-    from sda_tpu.ops.modular import positive
-    from sda_tpu.parallel import TpuAggregator
-    from sda_tpu.parallel.engine import (
-        clerk_combine,
-        reconstruct,
-        share_combine_limb,
-        share_participants,
-    )
-    from sda_tpu.parallel.limbmatmul import limb_count
-    from sda_tpu.protocol import PackedShamirSharing
-
-    if not _DEVICE:
-        raise RuntimeError("run_fabric needs acquire_device() first")
-    on_tpu = _DEVICE["platform"] == "tpu"
-
-    if not args.no_parity:
-        # bit-parity of the device kernels vs their references, at this
-        # run's sizes capped at the main path's; a mismatch ends the run
-        with stage("device parity checks"):
-            _PARITY_STATS.clear()
-            _PARITY_STATS.update(
-                kernel_parity(
-                    dim=min(args.dim, 100_000),
-                    chunk=min(args.chunk, 2_000),
-                    limb_dim=min(args.dim, 10_000),
-                )
-            )
-        print(f"[bench] parity: {_PARITY_STATS}", file=sys.stderr, flush=True)
-
-    k, t, n = args.secret_count, args.privacy_threshold, args.share_count
-    bits = 60 if args.wide else 30
-    p, w2, w3 = find_packed_parameters(k, t, n, min_modulus_bits=bits, seed=0)
-    scheme = PackedShamirSharing(k, n, t, p, w2, w3)
-    dim = args.dim
-    agg = TpuAggregator(scheme, dim, use_limbs=not args.no_limbs)
-    plan = agg.plan
-
-    n_chunks = args.participants // args.chunk
-    chunk = args.chunk
-
-    from sda_tpu.ops.modular import mod_sum_wide_jnp
-
-    B = plan.n_batches
-    use_limbs = not args.no_limbs or args.wide
-
-    def plain_step(plain, secrets):
-        # independent verification path: halving mod-sums (wide) / rem sums
-        if args.wide:
-            return lax.rem(plain + mod_sum_wide_jnp(secrets, p, axis=0), jnp.int64(p))
-        return lax.rem(
-            plain + lax.rem(jnp.sum(secrets.astype(jnp.int64), axis=0), jnp.int64(p)),
-            jnp.int64(p),
-        )
-
-    def iota_fill_bits(shape, bits, out_dtype):
-        """Deterministic row+lane-varying mix for --roofline fill
-        variants, shared by both engines: generation is ~free (two iotas
-        + one mul-add) and XLA cannot strength-reduce its reduction, so
-        a fill-variant segment isolates everything BUT the RNG. A change
-        here changes the rng_expand attribution of both engines at once
-        — that coupling is the point."""
-        r = lax.broadcasted_iota(jnp.uint32, shape, 0)
-        c = lax.broadcasted_iota(jnp.uint32, shape, len(shape) - 1)
-        # cap: int32 outputs must stay nonneg (bit 31 clear); uint32/int64
-        # outputs keep the full 32-bit mix
-        cap = 31 if out_dtype == jnp.int32 else 32
-        u = (r * jnp.uint32(2654435761) + c) & jnp.uint32((1 << min(bits, cap)) - 1)
-        return u.astype(out_dtype)
-
-    def gen_selectors(draw_bits, mask_draw, narrow, fill):
-        """(gen_bits, gen_mask) for one body variant: the real draws, or
-        the iota fill in the same dtypes — ONE wiring for both engines so
-        their rng_expand attribution can't drift apart."""
-        if not fill:
-            return draw_bits, mask_draw
-
-        def fill_bits(key, shape, bits):
-            return iota_fill_bits(shape, bits, jnp.int32 if narrow else jnp.int64)
-
-        def fill_mask(key, shape, m):
-            return fill_bits(key, shape, m.bit_length() - 1)
-
-        return fill_bits, fill_mask
-
-    if args.engine == "sumfirst":
-        from sda_tpu.ops.rng import (
-            uniform_bits_device,
-            uniform_bits_device_narrow,
-            uniform_bits_device_pair,
-        )
-        from sda_tpu.parallel.sumfirst import (
-            MAX_NARROW_CHUNK,
-            clerk_sums_from_limb_acc,
-            exact_value_sums,
-            limb_count_sum,
-            reconstruct_from_clerk_sums,
-            value_limb_sums_chunk,
-            value_limb_sums_chunk_pair,
-        )
-
-        acc_shape = (limb_count_sum(p), B, k + t)
-        # synthetic draws over [0, 2^(bits(p)-1)) — a sub-range of the field
-        # with zero modulo bias and no emulated 64-bit division (the 64-bit
-        # `%` otherwise dominates the whole pipeline ~10x; see ops/rng.py)
-        nbits = p.bit_length() - 1
-        # narrow lanes when the field fits int32: same masked-uint32 bits
-        # (identical values for the same key), but the big tensors and the
-        # whole reduction stay in native int32 ops (sumfirst narrow path)
-        narrow = nbits <= 31 and chunk <= MAX_NARROW_CHUNK
-        # wide fields get the same property via (hi, lo) uint32 pairs: the
-        # value never exists as an emulated int64 on device (sumfirst pair
-        # path; base-2^32 limb sums are exactly sum(lo) and sum(hi))
-        pair = nbits > 31 and chunk <= MAX_NARROW_CHUNK
-
-        # roofline model inputs: bytes per generated value element as the
-        # stream representation stores it, and MXU work per secret element
-        # (none here — the share matmul runs ONCE on the tiny participant
-        # sum; the hot loop is pure generation + reduction)
-        elem_bytes = 8.0 if pair else (4.0 if narrow else 8.0)
-        macs_per_elem = 0.0
-        extra_bytes_per_elem = 0.0
-
-        def draw_bits(key, shape, bits):
-            if narrow:
-                return uniform_bits_device_narrow(key, shape, bits)
-            return uniform_bits_device(key, shape, bits)
-
-        def mask_draw(key, shape, m):
-            return draw_bits(key, shape, m.bit_length() - 1)
-
-        def pair_draw(key, shape):
-            return uniform_bits_device_pair(key, shape, nbits)
-
-        # --check: which columns the independent wraparound sums cover.
-        # full -> every column; probe -> ~1024 strided columns (identical
-        # byte-exact comparison on those, ~dim/1024x less emulated-int64
-        # check arithmetic riding the timed loop); off -> none.
-        check_stride = max(1, dim // 1024) if args.check == "probe" else 1
-
-        def check_cols(x):  # static strided column subset of (C, dim)
-            return x[:, ::check_stride]
-
-        n_check = 0 if args.check == "off" else len(range(0, dim, check_stride))
-
-        def make_body(check, fill=False):
-            """Scan body for one (check-mode, generator) variant.
-
-            The measured run uses ``make_body(args.check)``. The roofline
-            decomposition (--roofline) additionally compiles the same
-            segment with ``check='off'`` (isolates the independent-check
-            cost) and with ``fill=True`` (RNG replaced by a cheap iota
-            mix — the reduction still consumes a full-rate value stream
-            with row- and column-varying data XLA cannot strength-reduce,
-            so the remaining time is the limb reduction + its memory
-            traffic, and nocheck-minus-fill is the RNG expansion cost).
-            """
-            stride = max(1, dim // 1024) if check == "probe" else 1
-
-            def ccols(x):
-                return x[:, ::stride]
-
-            def fill_pair(key, shape):
-                # pair twin of iota_fill_bits: lo keeps the full 32-bit
-                # mix, hi re-masks it to the top field bits
-                lo = iota_fill_bits(shape, 32, jnp.uint32)
-                hi = lo & jnp.uint32((1 << max(1, nbits - 32)) - 1)
-                return hi, lo
-
-            gen_bits, gen_mask = gen_selectors(draw_bits, mask_draw, narrow, fill)
-
-            if pair:
-                gen = fill_pair if fill else pair_draw
-
-                def body(carry, i):
-                    acc, plain, key = carry
-                    key, sk, rk = jax.random.split(key, 3)
-                    shi, slo = gen(sk, (chunk, dim))
-                    acc = acc + value_limb_sums_chunk_pair(shi, slo, rk, plan, gen)
-                    if check == "off":
-                        return (acc, plain, key), ()
-                    # independent check: direct int64 half-sums (a different
-                    # reduction than the 16-bit-split narrow sums being
-                    # checked); wraps mod 2^64 like the int64-path sums
-                    chi, clo = ccols(shi), ccols(slo)
-                    csum = jnp.sum(clo.astype(jnp.int64), axis=0) + (
-                        jnp.sum(chi.astype(jnp.int64), axis=0) << jnp.int64(32)
-                    )
-                    return (acc, plain + csum, key), ()
-
-                return body
-
-            def body(carry, i):
-                acc, plain, key = carry
-                key, sk, rk = jax.random.split(key, 3)
-                secrets = gen_bits(sk, (chunk, dim), nbits)
-                acc = acc + value_limb_sums_chunk(secrets, rk, plan, draw=gen_mask)
-                if check == "off":
-                    return (acc, plain, key), ()
-                # check path: plain int64 sums (wraparound-exact mod 2^64) —
-                # deliberately NOT exact_sum_narrow, so the verification stays
-                # independent of the limb reduction it is checking
-                csum = jnp.sum(ccols(secrets).astype(jnp.int64), axis=0)
-                return (acc, plain + csum, key), ()
-
-            return body
-
-        body = make_body(args.check)
-
-        def finalize(acc, plain):
-            # cross-check the limb reduction against the independent
-            # wraparound sums over the same stream, at full 2^64 strength
-            # (full: every column; probe: the strided subset)
-            exact = exact_value_sums(acc)
-            flat = exact[:, :k].reshape(-1)[:dim]
-            if n_check:
-                covered = flat[::check_stride]
-                wrap = np.array(
-                    [int(v) & (2**64 - 1) for v in covered], dtype=np.uint64
-                )
-                if not np.array_equal(wrap, plain.view(np.uint64)):
-                    return None
-            clerk_sums, vsums = clerk_sums_from_limb_acc(acc, plan, exact=exact)
-            indices = list(range(1, 1 + scheme.reconstruction_threshold))
-            out = reconstruct_from_clerk_sums(clerk_sums, indices, scheme, dim)
-            got = positive(np.asarray(out), p)
-            want = vsums[:, :k].reshape(-1)[:dim]
-            return got if np.array_equal(got, want) else None
-
-    else:
-        from sda_tpu.ops.rng import uniform_bits_device, uniform_bits_device_narrow
-        from sda_tpu.parallel.limbmatmul import limb_recombine_host
-
-        n_check = dim  # participant engine: always the full plain check
-
-        # const-folded limb partials: one weight group per limb of p
-        W = limb_count(p)
-        acc_shape = (W, B, n) if use_limbs else (n, B)
-        # same synthetic draws as the sumfirst branch: masked bits over a
-        # power-of-two sub-range (zero modulo bias; one threefry draw where
-        # uniform_mod_device takes two and a reduction mod p)
-        nbits = p.bit_length() - 1
-        narrow = use_limbs and p <= (1 << 31)
-
-        # roofline model inputs. MXU work: the fused limb path runs L
-        # const-folded matmuls of (C·B, L·K) @ (L·K, n) per chunk (or the
-        # generic L² of (C·B, K) @ (K, n) — same MAC count either way):
-        # K·n·L² int8 MACs per row, K = k+t rows per k secrets. The limb
-        # extraction also materializes an int8 (C·B, L·K) operand the
-        # dots then read: L·K/k extra bytes per secret element, twice.
-        elem_bytes = 4.0 if narrow else 8.0
-        L_limbs = limb_count(p) if use_limbs else 0
-        macs_per_elem = (k + t) * n * L_limbs * L_limbs / k if use_limbs else 0.0
-        extra_bytes_per_elem = 2.0 * L_limbs * (k + t) / k
-
-        def draw_bits(key, shape, bits):
-            if narrow:
-                return uniform_bits_device_narrow(key, shape, bits)
-            return uniform_bits_device(key, shape, bits)
-
-        def mask_draw(key, shape, m):
-            return draw_bits(key, shape, m.bit_length() - 1)
-
-        def make_body(check, fill=False):
-            """Scan body for one (check-mode, generator) variant — the
-            participant-engine twin of the sumfirst factory above, so
-            --roofline can attribute this engine's steady rate too:
-            check='off' drops the independent plain sum, fill=True
-            replaces the draws with a row+lane-varying iota mix (XLA
-            cannot strength-reduce it), leaving the share matmul + clerk
-            reduction as the remainder."""
-
-            gen_bits, gen_mask = gen_selectors(draw_bits, mask_draw, narrow, fill)
-
-            def body(carry, i):
-                acc, plain, key = carry
-                key, sk, rk = jax.random.split(key, 3)
-                secrets = gen_bits(sk, (chunk, dim), nbits)
-                if use_limbs:
-                    # fused limb path: no 64-bit mul/div on the big tensors
-                    if args.pallas:
-                        from sda_tpu.parallel.limb_pallas import (
-                            share_combine_limb_pallas,
-                        )
-
-                        chunk_acc = share_combine_limb_pallas(
-                            secrets, rk, plan, draw=gen_mask, interpret=not on_tpu
-                        )
-                    else:
-                        chunk_acc = share_combine_limb(
-                            secrets, rk, plan, draw=gen_mask
-                        )
-                    acc = lax.rem(acc + chunk_acc, jnp.int64(p))
-                else:
-                    shares = share_participants(
-                        secrets, rk, plan, False, draw=gen_mask
-                    )
-                    acc = lax.rem(
-                        acc + lax.rem(clerk_combine(shares), jnp.int64(p)),
-                        jnp.int64(p),
-                    )
-                if check == "off":
-                    return (acc, plain, key), ()
-                return (acc, plain_step(plain, secrets), key), ()
-
-            return body
-
-        body = make_body("full")
-
-        def finalize(acc, plain):
-            if use_limbs:
-                acc = limb_recombine_host(acc, p).T  # (n, B) canonical, exact
-            indices = list(range(1, 1 + scheme.reconstruction_threshold))
-            out = reconstruct(jnp.asarray(acc), indices, scheme, dim)
-            got = positive(np.asarray(out), p)
-            return got if np.array_equal(got, positive(plain, p)) else None
-
-    # segmented execution: the stream runs as n_segments identical jit
-    # calls (one compile), giving per-segment progress lines, a wall-clock
-    # budget check between segments, and a steady-state rate measured
-    # from segment 2 on (segment 1 absorbs the compile)
-    n_segments = max(1, min(args.segments, n_chunks))
-    seg_chunks = n_chunks // n_segments
-    dropped = n_chunks - seg_chunks * n_segments
-    if dropped:
-        print(
-            f"[bench] dropping {dropped} remainder chunks "
-            f"({dropped * chunk} participants) to keep one compiled "
-            "segment shape",
-            file=sys.stderr,
-        )
-
-    @jax.jit
-    def run_seg(acc, plain, key):
-        (acc, plain, key), _ = lax.scan(
-            body, (acc, plain, key), jnp.arange(seg_chunks)
-        )
-        return acc, plain, key
-
-    acc = jnp.zeros(acc_shape, dtype=jnp.int64)
-    # never 0-length: the per-segment np.asarray(plain) is the execution
-    # fence, and transferring a zero-element array moves no bytes — it
-    # could complete without awaiting the device, silently turning the
-    # --check off timings into async-dispatch measurements. A 1-element
-    # carry still rides the executable, so its D2H transfer awaits
-    # execution like any other output.
-    plain = jnp.zeros((max(1, n_check),), dtype=jnp.int64)
-    # rbg keys flow through the same split/fold_in/bits calls; only the
-    # per-word generation cost changes (threefry is ~a dozen VPU ops per
-    # 32-bit word, RngBitGenerator is near-free on TPU). impl=None keeps
-    # jax's default (threefry2x32) — "threefry" is not a registered name.
-    key = jax.random.key(42, impl=None if args.rng == "threefry" else args.rng)
-
-    bench_t0 = time.perf_counter()
-    with stage(f"compile + segment 1/{n_segments} ({seg_chunks} chunks)"):
-        t0 = time.perf_counter()
-        acc, plain, key = run_seg(acc, plain, key)
-        np.asarray(plain)  # host transfer: the execution fence
-        compile_and_first = time.perf_counter() - t0
-    # a measurement exists: disarm the hang watchdog; --budget governs now
-    if watchdog is not None:
-        watchdog.cancel()
-
-    done_segments = 1
-    steady_elems = 0
-    steady_s = 0.0
-    trace = contextlib.nullcontext()
-    if args.trace_dir:
-        if n_segments > 1:
-            trace = jax.profiler.trace(args.trace_dir)
-            print(f"[bench] tracing steady segments into {args.trace_dir}",
-                  file=sys.stderr)
-        else:
-            print(
-                "[bench] --trace-dir ignored: only one segment (the trace "
-                "covers steady-state segments 2+; raise --segments or the "
-                "workload)",
-                file=sys.stderr,
-            )
-    with trace:
-        for _ in range(1, n_segments):
-            if time.perf_counter() - bench_t0 > args.budget:
-                print(
-                    f"[bench] budget {args.budget:.0f}s spent after "
-                    f"{done_segments}/{n_segments} segments; stopping early",
-                    file=sys.stderr,
-                )
-                break
-            t0 = time.perf_counter()
-            acc, plain, key = run_seg(acc, plain, key)
-            np.asarray(plain)
-            dt = time.perf_counter() - t0
-            steady_s += dt
-            steady_elems += seg_chunks * chunk * dim
-            done_segments += 1
-            print(
-                f"[bench] segment {done_segments}/{n_segments}: {dt:.2f}s",
-                file=sys.stderr,
-            )
-
-    # reconstruct + verify (any t+k of n clerks; drop one for the dropout path)
-    acc_host = np.asarray(acc).copy()
-    if os.environ.get("SDA_BENCH_INJECT_FAULT") == "acc":
-        # test hook: corrupt one accumulator cell so the acceptance suite
-        # can prove the verification below actually catches a broken
-        # fabric (exit 1 + error metric line), not just bless a good one
-        acc_host[(0,) * acc_host.ndim] += 1
-        print("[bench] FAULT INJECTED into the accumulator", file=sys.stderr)
-    with stage("reconstruct + verify"):
-        got = finalize(acc_host, np.asarray(plain))
-    if got is None:
-        print("VERIFICATION FAILED", file=sys.stderr)
-        raise VerificationFailed(
-            "verification failed: reconstructed aggregate does not match "
-            "the independent plaintext sum"
-        )
-
-    participants_done = done_segments * seg_chunks * chunk
-    if steady_elems:
-        rate = steady_elems / steady_s
-        includes_compile = False
-    else:
-        # single segment (tiny run or budget spent immediately): the only
-        # timing available includes compile — report it, flagged
-        rate = seg_chunks * chunk * dim / compile_and_first
-        includes_compile = True
-
-    # traffic model (always emitted; set against the peaks of the device
-    # kind that ran when DEVICE_PEAKS lists it): every generated value
-    # element (the
-    # secrets plus the t/k randomness overhead riding with them) written
-    # once and read once by the reduction, the check re-reading its
-    # column subset, plus any limb-operand materialization — an upper
-    # bound on required HBM traffic (XLA fusing gen into reduce only
-    # lowers it, which is exactly what the --roofline decomposition
-    # distinguishes from a genuinely bandwidth-bound loop).
-    over = 1.0 + t / k
-    check_frac = (n_check / dim) if dim else 0.0
-    gen_bps = rate * over * elem_bytes
-    hbm_bps = rate * (
-        over * 2.0 * elem_bytes + check_frac * elem_bytes + extra_bytes_per_elem
-    )
-    roofline = {
-        "model": "gen(write+read) + check re-read + limb operands",
-        "gen_gbps": round(gen_bps / 1e9, 2),
-        "hbm_gbps_model": round(hbm_bps / 1e9, 2),
-    }
-    if macs_per_elem:
-        roofline["int8_tops"] = round(rate * macs_per_elem / 1e12, 4)
-    peaks = DEVICE_PEAKS.get(_DEVICE["kind"])
-    if peaks:
-        roofline["peaks_of"] = _DEVICE["kind"]
-        roofline["hbm_pct_peak"] = round(
-            100.0 * hbm_bps / (peaks["hbm_gbps"] * 1e9), 2
-        )
-        if macs_per_elem:
-            roofline["mxu_pct_peak"] = round(
-                100.0 * rate * macs_per_elem / (peaks["int8_tops"] * 1e12), 3
-            )
-
-    partial = done_segments < n_segments or dropped > 0
-    print(
-        f"verified {participants_done} participants x {dim} dims "
-        f"(p={p}, k={k}, t={t}, n={n}); compile+first={compile_and_first:.2f}s "
-        f"steady={steady_s:.3f}s rate={rate:.3e} elems/s",
-        file=sys.stderr,
-    )
-    result = {
-        "metric": METRIC_NAME,
-        "value": round(rate, 1),
-        "unit": "shared_elements_per_second",
-        "vs_baseline": round(rate / NORTH_STAR_ELEMS_PER_S_PER_CHIP, 4),
-        "engine": args.engine + ("+pallas" if args.pallas else ""),
-        "modulus_bits": p.bit_length(),
-        "participants": participants_done,
-        "dim": dim,
-        "chunk": args.chunk,
-        "steady_s": round(steady_s, 3),
-        "compile_and_first_s": round(compile_and_first, 3),
-        "roofline": roofline,
-    }
-    if args.rng != "threefry":
-        result["rng"] = args.rng
-    if args.check != "full":
-        result["check"] = args.check
-        if args.check == "probe":
-            result["check_cols"] = n_check
-    if partial:
-        result["partial"] = True
-    if includes_compile:
-        result["includes_compile"] = True
-    if _CRYPTO_STATS:
-        result["crypto"] = _CRYPTO_STATS
-    if _PARITY_STATS:
-        result["tpu_parity"] = _PARITY_STATS
-
-    # --roofline: attribute the measured steady segment to its stages by
-    # timing the SAME compiled segment shape with (a) the independent
-    # check removed and (b) RNG additionally replaced by an iota fill;
-    # the deltas are the check and rng-expansion costs, the remainder is
-    # the limb reduction + its memory traffic. A failure here fails the
-    # run like any other.
-    if args.roofline:
-        budget_left = args.budget - (time.perf_counter() - bench_t0)
-        if steady_elems == 0:
-            roofline["decomposition"] = {"skipped": "no steady segments"}
-        elif budget_left < 120:
-            roofline["decomposition"] = {
-                "skipped": f"only {budget_left:.0f}s budget left (<120)"
-            }
-        else:
-            with stage("roofline decomposition (2 variant compiles)"):
-
-                def time_seg(seg, plain_len=1, warm=True):
-                    a = jnp.zeros(acc_shape, dtype=jnp.int64)
-                    pl = jnp.zeros((plain_len,), dtype=jnp.int64)
-                    kk = jax.random.key(
-                        43, impl=None if args.rng == "threefry" else args.rng
-                    )
-                    if warm:  # variants: compile + warm; run_seg is
-                        a, pl, kk = seg(a, pl, kk)  # already both
-                        np.asarray(pl)
-                    reps = 2
-                    t0 = time.perf_counter()
-                    for _ in range(reps):
-                        a, pl, kk = seg(a, pl, kk)
-                        np.asarray(pl)
-                    return (time.perf_counter() - t0) / reps
-
-                def variant_seg(body_fn):
-                    return jax.jit(
-                        lambda a, pl, kk: lax.scan(
-                            body_fn, (a, pl, kk), jnp.arange(seg_chunks)
-                        )[0]
-                    )
-
-                # all three points timed the same way back-to-back
-                # (same reps, fresh carries, same chip state) so the
-                # stage fractions compare like with like; the full
-                # point reuses run_seg's existing compile. The
-                # steady-run segment time rides in seg_steady_s for
-                # cross-reference but does not enter the fractions.
-                t_full = time_seg(run_seg, max(1, n_check), warm=False)
-                t_nc = time_seg(variant_seg(make_body("off")))
-                t_fl = time_seg(variant_seg(make_body("off", fill=True)))
-                stage3 = (
-                    "limb_reduce" if args.engine == "sumfirst" else "share_combine"
-                )
-                parts = {
-                    "check": max(0.0, t_full - t_nc),
-                    "rng_expand": max(0.0, t_nc - t_fl),
-                    stage3: t_fl,
-                }
-                roofline["decomposition"] = {
-                    "seg_full_s": round(t_full, 3),
-                    "seg_steady_s": round(steady_s / (done_segments - 1), 3),
-                    "seg_nocheck_s": round(t_nc, 3),
-                    "seg_fill_s": round(t_fl, 3),
-                    **{
-                        f"frac_{name}": round(v / t_full, 3)
-                        for name, v in parts.items()
-                    },
-                    "binding_stage": max(parts, key=parts.get),
-                }
-
-    return result
 
 
 #: the protocol-plane riders, in run order: (key in the crypto block,
@@ -3767,52 +2708,27 @@ _RIDERS = (
 
 
 def main() -> int:
-    args = parse_args()
+    if sys.argv[1:]:
+        print(
+            "usage: python bench.py   (no arguments: runs the host riders; the "
+            "chip is measured by python benchmark/run.py --workload <cell>)",
+            file=sys.stderr,
+        )
+        return 2
     # bind the run trace id so client requests in the ingest riders carry
     # X-SDA-Trace and server-side spans correlate with the metric lines
     telemetry.set_trace_id(RUN_TRACE_ID)
-    # the device first, in this process: a run that finds no TPU (and was
-    # not explicitly given the CPU) ends here, before any metric line
-    try:
-        with stage("acquire device"):
-            device = acquire_device(allow_pinned_cpu=True)
-    except NoAccelerator as exc:
-        print(f"[bench] {exc}", file=sys.stderr, flush=True)
-        return 2
-    print(f"[bench] device: {device}", file=sys.stderr, flush=True)
-    watchdog = None
-    try:
-        # host-plane rates: pure CPU, attached to success AND error lines
-        # (SURVEY hard part #5 evidence). A rider that fails fails the run.
-        with stage("crypto-plane host bench"):
-            _CRYPTO_STATS.update(measure_crypto_plane())
-        with stage("rest-ingest loopback bench"):
-            _CRYPTO_STATS.update(measure_rest_ingest())
-        # the protocol-plane riders each drive full REST rounds (~30s of
-        # wall on one core across the set); SDA_BENCH_RIDERS=0 skips them
-        # so callers that only need the device metric line (the CLI
-        # acceptance children) don't pay for measurements they never read
-        if os.environ.get("SDA_BENCH_RIDERS") == "0":
-            print("[bench] protocol-plane riders skipped (SDA_BENCH_RIDERS=0)",
-                  file=sys.stderr)
-        else:
-            for key, label, measure in _RIDERS:
-                with stage(label):
-                    _CRYPTO_STATS[key] = measure()
-        watchdog = arm_deadline(args.deadline)
-        result = run_fabric(args, watchdog)
-    except (SystemExit, KeyboardInterrupt):
-        # operator Ctrl-C is a deliberate abort, not a failed measurement
-        raise
-    except BaseException as exc:  # noqa: BLE001 — the metric-line contract
-        # covers *any* failure once a device is held: never a raw
-        # traceback on stdout, never silence. Details still go to stderr.
-        if watchdog is not None:
-            watchdog.cancel()  # exactly ONE metric line, even at the deadline
-        traceback.print_exc()
-        emit_error(f"{type(exc).__name__}: {exc}")
-        return 1 if isinstance(exc, VerificationFailed) else 2
-    emit_final(result)
+    # host-plane rates: pure CPU (SURVEY hard part #5 evidence)
+    crypto: dict = {}
+    with stage("crypto-plane host bench"):
+        crypto.update(measure_crypto_plane())
+    with stage("rest-ingest loopback bench"):
+        crypto.update(measure_rest_ingest())
+    # the protocol-plane riders each drive full REST rounds
+    for key, label, measure in _RIDERS:
+        with stage(label):
+            crypto[key] = measure()
+    _print_line({"crypto": crypto})
     return 0
 
 

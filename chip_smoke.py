@@ -12,13 +12,18 @@ field, dim 100 000), through the entry points a user calls:
   eight clerks with one committee member dropped, ``reveal_aggregation`` —
   whose mask combine runs the compiled Pallas ChaCha kernel; the reveal
   equals the python-int sum exactly;
-- fabric leg: the kernel parity routine and the loop ``python bench.py``
-  runs (``bench.run_fabric``): sum-first 61-bit at dim 100 000, then the
-  per-participant engine at its preset width on the XLA int8-limb path and
-  the fused Pallas kernel;
+- fabric leg: every device kernel's bits against its reference
+  (``kernel_parity``), then two chunks of seeded input folded through the
+  entry points the benchmark's cells bind (``benchmark/traffic/*.json``),
+  with the program's default draw, accumulated as that engine's traffic
+  file says, through the matching host epilogue, revealed from 7 of 8
+  clerks and compared with the exact column sums: sum-first 61-bit at dim
+  100 000, then the per-participant engine at dim 10 000 on the XLA
+  int8-limb path and on the fused Pallas kernel, whose accumulators must be
+  the same bits;
 - sharded leg, with more than one chip: every fabric
   ``__graft_entry__.dryrun_multichip`` walks, plus the sum-first limb psum
-  at dim 100 000.
+  at dim 100 000, revealed and compared the same way.
 
 Rows are cut (never width); weights are seeded random. Any leg that raises
 ends the run non-zero. Wall times are printed as information only. The
@@ -31,6 +36,7 @@ them on the CPU at tiny sizes; run as a script, this accepts no CPU.
 from __future__ import annotations
 
 import json
+import os
 import pathlib
 import subprocess
 import sys
@@ -46,6 +52,10 @@ K, T, N = 5, 2, 8
 
 class SmokeFailure(RuntimeError):
     """A leg ran and its result is wrong."""
+
+
+class NoAccelerator(RuntimeError):
+    """JAX found no TPU and the caller did not ask for the CPU."""
 
 
 def say(msg: str) -> None:
@@ -73,12 +83,32 @@ def build_leg() -> None:
 
 
 def device_line(*, allow_pinned_cpu: bool = False) -> dict:
-    """Acquire the device in this process and print what JAX reports."""
+    """First (and only) device touch of the process, in-process: a chip
+    belongs to one process, so nothing here starts a child to look. Prints
+    what JAX reports.
+
+    A TPU is the only device the smoke may run on. The CPU is accepted only
+    where the caller allows it AND ``JAX_PLATFORMS`` names it explicitly
+    (tier-1's rehearsal does) — a CPU that JAX fell back to on its own is a
+    failure, not a device."""
     import importlib.metadata
 
-    import bench
+    import jax
 
-    device = bench.acquire_device(allow_pinned_cpu=allow_pinned_cpu)
+    devices = jax.devices()
+    device = {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+    pinned_cpu = allow_pinned_cpu and "cpu" in [
+        name.strip() for name in os.environ.get("JAX_PLATFORMS", "").lower().split(",")
+    ]
+    if device["platform"] != "tpu" and not pinned_cpu:
+        raise NoAccelerator(
+            f"JAX found no TPU (devices: {device}); the smoke runs on nothing "
+            "else. For the CPU rehearsal see tests/test_chip_smoke.py."
+        )
 
     def version(dist: str) -> str:
         try:
@@ -94,11 +124,14 @@ def device_line(*, allow_pinned_cpu: bool = False) -> dict:
     return device
 
 
-def _wide_scheme():
+def _scheme(min_modulus_bits: int):
+    """Packed Shamir over the 61-bit field (60) or the 31-bit one (30)."""
     from sda_tpu.ops import find_packed_parameters
     from sda_tpu.protocol import PackedShamirSharing
 
-    p, w2, w3 = find_packed_parameters(K, T, N, min_modulus_bits=60, seed=0)
+    p, w2, w3 = find_packed_parameters(
+        K, T, N, min_modulus_bits=min_modulus_bits, seed=0
+    )
     return PackedShamirSharing(K, N, T, p, w2, w3)
 
 
@@ -139,7 +172,7 @@ def protocol_leg(*, dim: int = 100_000, participants: int = 64) -> None:
             f"{participants} x {dim} is below the device-combine threshold: "
             "the reveal would never reach the device plane"
         )
-    scheme = _wide_scheme()
+    scheme = _scheme(60)
     p = scheme.prime_modulus
     vectors = np.random.default_rng(21).integers(0, p, size=(participants, dim))
     path = default_backend()
@@ -220,56 +253,212 @@ def protocol_leg(*, dim: int = 100_000, participants: int = 64) -> None:
     )
 
 
+def kernel_parity(
+    *,
+    seeds: int = 64,
+    dim: int = 100_000,
+    chunk: int = 2_000,
+    limb_dim: int = 10_000,
+) -> dict:
+    """Bit-parity of every device kernel against its reference, at the main
+    path's shapes by default: the reveal's ChaCha mask combine at ``seeds``
+    x ``dim`` over the 61-bit field, and the per-participant share+combine
+    at ``chunk`` x ``limb_dim``, 31-bit, K = 7.
+
+    The backend decides what runs, nothing is caught: on a TPU the
+    compiled Pallas kernels (a kernel that does not compile raises here);
+    on the CPU the jnp twin that backend uses plus the kernel source
+    under the Pallas interpreter. The first mismatch raises SmokeFailure.
+    """
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from sda_tpu.native import chacha_expand
+    from sda_tpu.ops.chacha_pallas import combine_masks_device
+    from sda_tpu.ops.modular import mod_sum_wide_np, positive
+    from sda_tpu.parallel.engine import make_plan, reconstruct, share_combine_limb
+    from sda_tpu.parallel.limb_pallas import share_combine_limb_pallas
+    from sda_tpu.parallel.limbmatmul import limb_recombine_host
+
+    on_tpu = jax.default_backend() == "tpu"
+    out: dict = {"platform": jax.default_backend()}
+
+    def same(name, got, want):
+        if not np.array_equal(np.asarray(got), np.asarray(want)):
+            raise SmokeFailure(f"{name}: device bits differ from the reference")
+        out[name] = "ok"
+
+    wide = _scheme(60)
+    p61 = wide.prime_modulus
+
+    # ChaCha: the recipient's mask combine, against the host expansion
+    rng = np.random.default_rng(3)
+    seed_rows = rng.integers(0, 2**32, size=(seeds, 4), dtype=np.uint32)
+    want = mod_sum_wide_np(
+        np.stack([chacha_expand(row, dim, p61) for row in seed_rows]), p61, axis=0
+    )
+    backends = ["pallas"] if on_tpu else ["jnp", "interpret"]
+    out["chacha_backends"] = backends
+    for backend in backends:
+        got = combine_masks_device(seed_rows, dim, p61, backend=backend)
+        same(f"chacha_{backend}", got, want)
+
+    # fused Pallas participant kernel vs the XLA int8-limb path, same key
+    narrow = _scheme(30)
+    plan = make_plan(narrow, limb_dim)
+    secrets = jnp.asarray(
+        np.random.default_rng(4).integers(0, plan.modulus, size=(chunk, limb_dim))
+    )
+    key = jax.random.key(9)
+    xla = jax.jit(lambda s, kk: share_combine_limb(s, kk, plan))(secrets, key)
+    fused = jax.jit(
+        lambda s, kk: share_combine_limb_pallas(s, kk, plan, interpret=not on_tpu)
+    )(secrets, key)
+    same("limb", fused, xla)
+
+    # wide field: limb accumulators -> exact host recombine -> reconstruct
+    wide_dim = 25
+    wplan = make_plan(wide, wide_dim)
+    wsecrets = (
+        p61 - np.random.default_rng(5).integers(1, 10_000, size=(32, wide_dim))
+    ).astype(np.int64)
+    acc = np.asarray(
+        jax.jit(lambda s, kk: share_combine_limb(s, kk, wplan))(
+            jnp.asarray(wsecrets), jax.random.key(2)
+        )
+    )
+    clerk_sums = limb_recombine_host(acc, p61).T  # exact, host-side
+    revealed = positive(
+        np.asarray(reconstruct(jnp.asarray(clerk_sums), range(N), wide, wide_dim)),
+        p61,
+    )
+    plain = np.array(
+        [sum(int(v) for v in wsecrets[:, j]) % p61 for j in range(wide_dim)],
+        dtype=np.int64,
+    )
+    same("wide61", revealed, plain)
+    out["ok"] = True
+    return out
+
+
+def _check_reveal(leg: str, clerk_sums, scheme, secrets) -> None:
+    """The reveal from 7 of the 8 clerks' sums (clerk 0 left out) must be the
+    exact column sums of ``secrets`` (host int64, canonical) mod p."""
+    import numpy as np
+
+    from sda_tpu.ops.modular import positive
+    from sda_tpu.ops.shamir import reconstruct_clerk_sums_host
+
+    p, dim = scheme.prime_modulus, secrets.shape[1]
+    survivors = list(range(1, 1 + scheme.reconstruction_threshold))
+    out = reconstruct_clerk_sums_host(clerk_sums, survivors, scheme, dim)
+    # exact column sums without python-int loops over the big tensor:
+    # 32-bit halves summed in uint64, joined as python ints per column
+    lo = (secrets & 0xFFFFFFFF).astype(np.uint64).sum(axis=0)
+    hi = (secrets >> 32).astype(np.uint64).sum(axis=0)
+    want = [((int(h) << 32) + int(l)) % p for h, l in zip(hi, lo)]
+    if [int(v) for v in positive(np.asarray(out), p)] != want:
+        raise SmokeFailure(
+            f"{leg}: the reveal from {len(survivors)} of {scheme.share_count} "
+            "clerks != the exact column sums"
+        )
+
+
+def fold_engine(engine: str, *, dim: int, chunk: int):
+    """One engine of the fabric leg, paired with its accumulate rule and its
+    epilogue as its traffic file pairs them (``benchmark/traffic/``):
+    ``sumfirst`` as ``sumfirst-wide.json`` (61-bit, ``+``),
+    ``participant`` as ``participant-narrow.json`` (31-bit, ``+`` then
+    ``rem p``), ``participant+pallas`` the same round on the fused kernel.
+    Two chunks of seeded input through the entry point with the program's
+    default draw, the epilogue, the reveal compared. Returns the
+    accumulator."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from sda_tpu.ops.jaxcfg import ensure_x64
+    from sda_tpu.parallel import engine as engine_mod
+    from sda_tpu.parallel import limb_pallas, limbmatmul, sumfirst
+
+    ensure_x64()
+    t0 = time.perf_counter()
+    # field width, input lanes, accumulate ``+`` then ``rem p``, chunk entry;
+    # the Pallas kernel never decides by itself to interpret: its caller does
+    fused = functools.partial(
+        limb_pallas.share_combine_limb_pallas,
+        interpret=jax.default_backend() != "tpu",
+    )
+    bits, dtype, mod_p, entry = {
+        "sumfirst": (60, np.int64, False, sumfirst.value_limb_sums_chunk),
+        "participant": (30, np.int32, True, engine_mod.share_combine_limb),
+        "participant+pallas": (30, np.int32, True, fused),
+    }[engine]
+    scheme = _scheme(bits)
+    p = scheme.prime_modulus
+    plan = engine_mod.make_plan(scheme, dim)
+
+    def epilogue(acc):  # -> (n, B) clerk sums
+        if engine == "sumfirst":
+            return sumfirst.clerk_sums_from_limb_acc(acc, plan)[0]
+        return limbmatmul.limb_recombine_host(acc, p).T
+
+    secrets = np.random.default_rng(23).integers(0, p, size=(2 * chunk, dim))
+    step = jax.jit(lambda rows, key: entry(rows, key, plan))
+    key = jax.random.key(7)
+    acc = 0
+    for i in range(2):
+        rows = jnp.asarray(secrets[i * chunk : (i + 1) * chunk].astype(dtype))
+        acc = acc + step(rows, jax.random.fold_in(key, i))
+        if mod_p:
+            acc = lax.rem(acc, jnp.int64(p))
+    acc = np.asarray(acc)
+    _check_reveal(f"fabric leg, {engine}", epilogue(acc), scheme, secrets)
+    say(
+        f"fabric leg ok: {engine} {p.bit_length()}-bit, {2 * chunk} rows x dim "
+        f"{dim} in 2 chunks, default draw, reveal from 7 of 8 clerks exact "
+        f"({time.perf_counter() - t0:.1f} s)"
+    )
+    return acc
+
+
 def fabric_leg(
     *,
     dim: int = 100_000,
     chunk: int = 500,
-    participants: int = 100_000,
     preset_dim: int = 10_000,
     preset_chunk: int = 2_000,
-    preset_participants: int = 8_000,
     seeds: int = 64,
 ) -> None:
-    """Kernel parity at the main path's shapes, then the loop bench.py
-    runs: sum-first at full width, per-participant at its preset width on
-    both the XLA limb path and the fused Pallas kernel (the parity routine
-    holds the two bit-identical on the same key)."""
-    import bench
+    """Kernel parity at the main path's shapes, then each engine's round
+    as the cells run it: sum-first at full width, per-participant at its
+    preset width on the XLA limb path and on the fused Pallas kernel, which
+    must leave the same accumulator."""
+    import numpy as np
 
     t0 = time.perf_counter()
-    parity = bench.kernel_parity(
+    parity = kernel_parity(
         seeds=seeds, dim=dim, chunk=preset_chunk, limb_dim=preset_dim
     )
     say(f"fabric leg: kernel parity {parity} ({time.perf_counter() - t0:.1f} s)")
-
-    def sized(rows: int, width: int, per_chunk: int) -> list[str]:
-        # two segments, so one is steady; parity already ran above
-        return ["--participants", str(rows), "--dim", str(width),
-                "--chunk", str(per_chunk), "--segments", "2", "--no-parity"]
-
-    preset = ["--engine", "participant",
-              *sized(preset_participants, preset_dim, preset_chunk)]
-    runs = {
-        "sumfirst": sized(participants, dim, chunk),
-        "participant": preset,
-        "participant+pallas": [*preset, "--pallas"],
-    }
-    for engine, argv in runs.items():
-        t0 = time.perf_counter()
-        line = bench.run_fabric(bench.parse_args(argv))  # raises unless verified
-        if line["engine"] != engine or line.get("partial") or line.get("includes_compile"):
-            raise SmokeFailure(f"fabric leg: {engine} did not run whole: {line}")
-        say(
-            f"fabric leg ok: {engine} {line['modulus_bits']}-bit, "
-            f"{line['participants']} rows x dim {line['dim']}, chunk "
-            f"{line['chunk']}, reconstruct exact ({time.perf_counter() - t0:.1f} s, "
-            f"compile + first segment {line['compile_and_first_s']:.1f} s)"
+    fold_engine("sumfirst", dim=dim, chunk=chunk)
+    xla = fold_engine("participant", dim=preset_dim, chunk=preset_chunk)
+    fused = fold_engine("participant+pallas", dim=preset_dim, chunk=preset_chunk)
+    if not np.array_equal(fused, xla):
+        raise SmokeFailure(
+            "fabric leg: the Pallas kernel's accumulator differs from the XLA "
+            "path's on the same key"
         )
 
 
 def sharded_leg(*, dim: int = 100_000, rows_per_shard: int = 256) -> None:
     """On every local chip: the six fabrics of the multi-chip dry run, then
-    the sum-first limb psum at full width, checked against exact sums."""
+    the sum-first limb psum at full width, revealed and compared with the
+    exact column sums."""
     import jax
     import numpy as np
 
@@ -281,43 +470,32 @@ def sharded_leg(*, dim: int = 100_000, rows_per_shard: int = 256) -> None:
 
     import jax.numpy as jnp
 
-    from sda_tpu.ops.modular import positive
     from sda_tpu.parallel import (
         make_mesh,
         make_plan,
         shard_participants,
         sharded_value_limb_sums,
     )
-    from sda_tpu.parallel.sumfirst import (
-        clerk_sums_from_limb_acc,
-        reconstruct_from_clerk_sums,
-    )
+    from sda_tpu.parallel.sumfirst import clerk_sums_from_limb_acc
 
     t0 = time.perf_counter()
     dryrun_multichip(n_devices)
 
     d_size = 2 if n_devices % 2 == 0 else 1
     mesh = make_mesh(p_size=n_devices // d_size, d_size=d_size)
-    scheme = _wide_scheme()
-    p = scheme.prime_modulus
+    scheme = _scheme(60)
     plan = make_plan(scheme, dim)
     rows = rows_per_shard * mesh.shape["p"]
-    secrets = np.random.default_rng(22).integers(0, p, size=(rows, dim))
+    secrets = np.random.default_rng(22).integers(
+        0, scheme.prime_modulus, size=(rows, dim)
+    )
     acc = np.asarray(
         sharded_value_limb_sums(plan, mesh)(
             shard_participants(jnp.asarray(secrets), mesh), jax.random.key(5)
         )
     )
     clerk_sums, _ = clerk_sums_from_limb_acc(acc, plan)
-    survivors = list(range(1, 1 + scheme.reconstruction_threshold))
-    out = reconstruct_from_clerk_sums(clerk_sums, survivors, scheme, dim)
-    # exact column sums without python-int loops over the big tensor:
-    # 32-bit halves summed in uint64, joined as python ints per column
-    lo = (secrets & 0xFFFFFFFF).astype(np.uint64).sum(axis=0)
-    hi = (secrets >> 32).astype(np.uint64).sum(axis=0)
-    want = [(int(h) << 32) + int(l) for h, l in zip(hi, lo)]
-    if [int(v) for v in positive(np.asarray(out), p)] != [w % p for w in want]:
-        raise SmokeFailure("sharded leg: sum-first limb psum != exact sums")
+    _check_reveal("sharded leg", clerk_sums, scheme, secrets)
     say(
         f"sharded leg ok: {n_devices} devices, six dry-run fabrics + sum-first "
         f"limb psum over p={mesh.shape['p']} d={d_size} at dim {dim}, {rows} "
@@ -328,11 +506,9 @@ def sharded_leg(*, dim: int = 100_000, rows_per_shard: int = 256) -> None:
 def main() -> int:
     t0 = time.perf_counter()
     build_leg()
-    import bench
-
     try:
         device = device_line()
-    except bench.NoAccelerator as exc:
+    except NoAccelerator as exc:
         print(f"chip_smoke: {exc}", file=sys.stderr, flush=True)
         return 2
     protocol_leg()

@@ -45,7 +45,7 @@ set -e
 cd "$(dirname "$0")"
 
 echo "=== ci 0/6: build native extension (Jenkinsfile 'build' stage) ==="
-# in-place so the suite, bench.py, and the CLI all pick it up from the
+# in-place so the suite, the host riders (bench.py), and the CLI all pick it up from the
 # checkout; the crypto plane falls back to Python if this fails, so a
 # missing toolchain degrades rates, not correctness
 python setup.py build_ext --inplace || echo "ci: native build failed; Python fallback paths will carry the crypto plane" >&2

@@ -15,8 +15,9 @@ Configs (BASELINE.md "Target configs"):
    an independent plaintext sum. The per-phone protocol plane at this
    scale is the TPU fabric's job (SURVEY §2.3), not a 1-core host loop —
    the host-protocol configs above already witness the transport plane.
-5. the north star (1M x 100K, 61-bit, TPU) — measured by bench.py on
-   real hardware; recorded here as a pointer, not re-run.
+5. the north star (1M x 100K, 61-bit, TPU) — its configuration is the
+   benchmark's (``python benchmark/run.py --workload c5-sumfirst``,
+   PERF.md); recorded here as a pointer, not re-run.
 
 Plus ``sumfirst-1m``: a genuine 1M-participant sum-first run (dim 1024,
 61-bit) exercising the documented int64 exactness bound
@@ -603,7 +604,7 @@ def main() -> int:
               f"{time.perf_counter() - t0:.1f}s", file=sys.stderr, flush=True)
         results["configs"].append(entry)
     results["config5_north_star"] = (
-        "measured by bench.py on TPU hardware; not re-run here"
+        "the benchmark's c5-w61-d100k cells (benchmark/run.py, PERF.md); not re-run here"
     )
     payload = json.dumps(results, indent=1)
     print(payload)

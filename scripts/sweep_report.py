@@ -1,18 +1,10 @@
-"""Summarize banked experiment artifacts into a defaults table.
+"""Summarize the host riders' banked artifacts, one table per rider.
 
-Budget-capped north-star variants (``python bench.py --rng/--chunk/--check
-...`` run on the chip, each metric line saved as
-``bench-artifacts/exp-<tag>-<stamp>.json``) are read here, grouped by
-configuration (rng x chunk x check), and printed as per-config best rates plus
-a recommendation line — the evidence trail for changing bench defaults
-(e.g. ``--chunk``) between rounds. Partial runs are rate-bearing (the
-bench verifies what it measured before stopping), so they count, flagged.
-
-Also summarizes the batched-ingest rider artifacts
+Summarizes the batched-ingest rider artifacts
 (``bench-artifacts/ingest-<stamp>.json``, written by bench.py's
 measure_batched_ingest): host sealing, client build, and REST ingest
 rates plus the measured telemetry overhead, one row per run — the
-host-plane trend line next to the device-plane sweep table.
+host-plane trend line.
 
 Also tabulates the clerking-pipeline rider artifacts
 (``bench-artifacts/clerking-<stamp>.json``, written by bench.py's
@@ -87,33 +79,6 @@ from __future__ import annotations
 import json
 import pathlib
 import sys
-
-
-def load(artdir: pathlib.Path):
-    rows = []
-    for f in sorted(artdir.glob("exp-*.json")):
-        try:
-            d = json.loads(f.read_text())
-        except (OSError, ValueError):
-            continue
-        if not isinstance(d, dict) or not d.get("value"):
-            continue  # error lines / empty artifacts carry no rate
-        rows.append(
-            {
-                "artifact": f.name,
-                # None = not recorded (pre-r5): tag_of falls back to the
-                # filename tag instead of assuming the defaults
-                "rng": d.get("rng"),
-                "check": d.get("check"),
-                "chunk": d.get("chunk"),
-                "value": d["value"],
-                "steady_s": d.get("steady_s"),
-                "partial": bool(d.get("partial")),
-                "dim": d.get("dim"),
-                "participants": d.get("participants"),
-            }
-        )
-    return rows
 
 
 #: rate/overhead columns lifted from each ingest artifact (absent keys —
@@ -899,33 +864,8 @@ def print_scenarios(cells, overheads) -> None:
         )
 
 
-def tag_of(row):
-    # prefer the metric line (bench.py records rng/chunk/check since r5,
-    # ADVICE r4 #2); filename tag as fallback for pre-r5 artifacts
-    # (exp-<rng>-c<chunk>-<stamp>.json / exp-<rng>-<check>-<stamp>.json).
-    # The old fallback recovered only the c<chunk> part, so pre-r5
-    # check-variant artifacts (exp-rbg-probe-*, exp-threefry-off-*) fell
-    # through to check="full" and collapsed into the full-check group —
-    # mislabeled, and eligible to win the full-check recommendation with
-    # a rate the full check never produced.
-    rng, chunk, check = row.get("rng"), row.get("chunk"), row.get("check")
-    for p in row["artifact"].rsplit(".", 1)[0].split("-")[1:]:
-        if chunk is None and p.startswith("c") and p[1:].isdigit():
-            chunk = p[1:]
-        elif check is None and p in ("probe", "off"):
-            check = p
-        elif rng is None and p in ("threefry", "rbg"):
-            rng = p
-    return (
-        rng or "threefry",
-        str(chunk) if chunk is not None else None,
-        check or "full",
-    )
-
-
 def main() -> int:
     artdir = pathlib.Path(sys.argv[1] if len(sys.argv) > 1 else "bench-artifacts")
-    rows = load(artdir)
     ingest_rows = load_ingest(artdir)
     clerking_rows = load_clerking(artdir)
     reveal_rows = load_reveal(artdir)
@@ -940,8 +880,7 @@ def main() -> int:
     sketch_rows = load_sketch(artdir)
     scenario_cells, overhead_rows = load_scenarios(artdir)
     if (
-        not rows
-        and not ingest_rows
+        not ingest_rows
         and not clerking_rows
         and not reveal_rows
         and not committee_rows
@@ -953,43 +892,13 @@ def main() -> int:
         and not scenario_cells
     ):
         print(
-            f"no rate-bearing exp-*.json, ingest-*.json, clerking-*.json, "
+            f"no rate-bearing ingest-*.json, clerking-*.json, "
             f"reveal-*.json, committee-*.json, wire-*.json, tier-*.json, "
             f"soak-*.json, flagship-*.json, sketch-*.json, or "
             f"scenario-*.json artifacts under {artdir}/",
             file=sys.stderr,
         )
         return 1
-
-    if rows:
-        best: dict[tuple, dict] = {}
-        for r in rows:
-            key = tag_of(r)
-            if key not in best or r["value"] > best[key]["value"]:
-                best[key] = r
-
-        print(f"{'rng':>9} {'chunk':>6} {'check':>6} {'elems/s':>12} "
-              f"{'steady_s':>9} {'partial':>7}  artifact")
-        for key in sorted(best, key=lambda k: tuple(x or "" for x in k)):
-            r = best[key]
-            rng, chunk, check = key
-            print(
-                f"{rng:>9} {chunk or '-':>6} {check:>6} {r['value']:>12.3e} "
-                f"{r['steady_s'] if r['steady_s'] is not None else float('nan'):>9} "
-                f"{'yes' if r['partial'] else 'no':>7}  {r['artifact']}"
-            )
-
-        # recommendation: fastest full-check config is eligible to become the
-        # bench default (the headline keeps the strongest verification); the
-        # fastest overall quantifies the scaffolding/rng headroom
-        full = [r for k, r in best.items() if k[2] == "full"]
-        if full:
-            top = max(full, key=lambda r: r["value"])
-            print(f"\nfastest full-check config: {tag_of(top)} at {top['value']:.3e} el/s "
-                  f"({top['artifact']})")
-        top_any = max(best.values(), key=lambda r: r["value"])
-        print(f"fastest overall:           {tag_of(top_any)} at {top_any['value']:.3e} el/s "
-              f"({top_any['artifact']})")
 
     if ingest_rows:
         print_ingest(ingest_rows)

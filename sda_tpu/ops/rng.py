@@ -71,62 +71,3 @@ def uniform_mod_device(key, shape, m: int):
     k2 = random.fold_in(key, 1)
     lo = random.bits(k2, shape=shape, dtype=jnp.uint32)
     return mod_u64_const(hi, lo, m).astype(jnp.int64)
-
-
-def uniform_bits_device(key, shape, nbits: int):
-    """Uniform draws over ``[0, 2**nbits)`` via masked random bits.
-
-    Exact (power-of-two range — zero modulo bias): one draw and a mask,
-    where :func:`uniform_mod_device` takes two draws and a reduction by the
-    modulus's reciprocal. ``bench.py`` uses this for synthetic participant
-    data with ``nbits = p.bit_length() - 1``, a sub-range of the field that
-    exercises identical arithmetic.
-    Simulation only — protocol-plane randomness is host CSPRNG rejection
-    sampling (``uniform_mod_host``), where full-range uniformity is a
-    privacy requirement, not a convenience.
-    """
-    import jax.numpy as jnp
-    from jax import random
-
-    if not (0 < nbits <= 62):
-        raise ValueError(f"nbits out of range: {nbits}")
-    dtype = jnp.uint32 if nbits <= 32 else jnp.uint64
-    u = random.bits(key, shape=shape, dtype=dtype)
-    return (u & dtype((1 << nbits) - 1)).astype(jnp.int64)
-
-
-def uniform_bits_device_pair(key, shape, nbits: int):
-    """``uniform_bits_device`` for ``32 <= nbits <= 62``, returned as a
-    ``(hi, lo)`` pair of uint32 tensors with value ``hi·2³² + lo``
-    (``nbits == 32`` yields an all-zero hi half — still exact).
-
-    The value never exists as an int64 on device: wide (61-bit) hot paths
-    consume the halves directly in native 32-bit lanes
-    (``sumfirst.value_limb_sums_chunk_pair``), skipping the emulated
-    64-bit ops that otherwise dominate. Simulation only, like the other
-    masked-bits draws."""
-    import jax.numpy as jnp
-    from jax import random
-
-    if not (32 <= nbits <= 62):
-        raise ValueError(f"pair draw needs 32 <= nbits <= 62, got {nbits}")
-    hi = random.bits(key, shape=shape, dtype=jnp.uint32) & jnp.uint32(
-        (1 << (nbits - 32)) - 1
-    )
-    lo = random.bits(random.fold_in(key, 1), shape=shape, dtype=jnp.uint32)
-    return hi, lo
-
-
-def uniform_bits_device_narrow(key, shape, nbits: int):
-    """``uniform_bits_device`` for ``nbits <= 31``, kept int32.
-
-    Same bits as the wide variant for the same key (uint32 draw, masked),
-    but never widened — feeds the narrow (int32) hot paths where emulated
-    64-bit lanes would halve throughput (parallel/sumfirst.py)."""
-    import jax.numpy as jnp
-    from jax import random
-
-    if not (0 < nbits <= 31):
-        raise ValueError(f"narrow draw needs nbits <= 31, got {nbits}")
-    u = random.bits(key, shape=shape, dtype=jnp.uint32)
-    return (u & jnp.uint32((1 << nbits) - 1)).astype(jnp.int32)

@@ -162,7 +162,7 @@ def reconstruct_clerk_sums_host(clerk_sums, indices, scheme, dim: int) -> np.nda
     """Host-exact reconstruction of a ``(n, B)`` clerk-sum tensor from the
     surviving 0-based clerk ``indices`` -> ``(dim,)`` aggregate (pad
     truncated). Exact for any modulus width; shared by the wide branch of
-    ``parallel.engine.reconstruct`` and the sum-first bench epilogue."""
+    ``parallel.engine.reconstruct`` and the sum-first rounds' epilogue."""
     p = scheme.prime_modulus
     rows = np.asarray(clerk_sums)[list(indices)]  # (R, B)
     with telemetry.span(

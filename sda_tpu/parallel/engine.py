@@ -32,7 +32,9 @@ the recipient needs.
 
 dtype discipline: values live in int32 (p < 2^31), arithmetic widens to
 int64 only where products/sums require it. The int8-limb MXU path
-(``limbmatmul``) replaces the widening matmul on TPU for the bench path.
+(``limbmatmul``) replaces the widening matmul on TPU in ``share_combine_limb``,
+the chunk step of the benchmark's per-participant cell
+(``benchmark/rounds/packed_fold.py``).
 """
 
 from __future__ import annotations
@@ -136,25 +138,16 @@ def _device_randomness(key, shape, modulus):
         return uniform_mod_device(key, shape, modulus)
 
 
-def share_participants(
-    secrets, key, plan: AggregationPlan, use_limbs: bool = False, draw=None
-):
-    """(P, dim) secrets -> (P, n, B) per-clerk share tensor.
-
-    ``draw(key, shape, p) -> int in [0, p)`` overrides the randomness
-    generator (benchmarks pass a division-free masked-bits draw; default is
-    the simulation-grade ``uniform_mod_device``).
-    """
+def share_participants(secrets, key, plan: AggregationPlan, use_limbs: bool = False):
+    """(P, dim) secrets -> (P, n, B) per-clerk share tensor."""
     jnp = _jnp()
     from jax import lax
 
-    if draw is None:
-        draw = _device_randomness
     p = plan.modulus
     if plan.share_matrix is None:
         # additive: n-1 uniform draws + closing share (additive.rs:42-48)
         P, d = secrets.shape
-        draws = draw(key, (P, plan.share_count - 1, d), p)  # (P, n-1, d)
+        draws = _device_randomness(key, (P, plan.share_count - 1, d), p)  # (P, n-1, d)
         # a plain int64 sum of the n-1 draws overflows once
         # (n-1)*(p-1) >= 2^63, silently corrupting the closing share;
         # the auto dispatch switches to the halving mod-sum there
@@ -166,7 +159,7 @@ def share_participants(
 
     batches = _batch_secrets(secrets, plan)  # (P, b, k)
     P, nb = batches.shape[0], batches.shape[1]
-    randomness = draw(key, (P, nb, plan.rand_size), p)
+    randomness = _device_randomness(key, (P, nb, plan.rand_size), p)
     if use_limbs:
         from .limbmatmul import limb_modmatmul_const
 
@@ -192,28 +185,25 @@ def share_participants(
     return jnp.swapaxes(shares, 1, 2)  # (P, n, B)
 
 
-def share_combine_limb(secrets, key, plan: AggregationPlan, draw=None):
+def share_combine_limb(secrets, key, plan: AggregationPlan):
     """Fused share + clerk-combine in limb space: (C, d) -> (W, b, n) int64.
 
     The hot loop stays division-free: int8 MXU matmuls produce weight-grouped
     partials, which are *summed over the participant axis first* (linearity)
     and only then carried as a tiny (W, b, n) accumulator. Callers reduce
     accumulators across chunks with ``lax.rem`` (values stay < p) and call
-    ``limb_recombine`` once at the very end. This is what makes the bench
-    path ~10x the naive int64 formulation on TPU: emulated 64-bit
-    multiply/divide never touches the (participants x dim) tensor.
+    ``limb_recombine`` once at the very end: emulated 64-bit multiply/divide
+    never touches the (participants x dim) tensor.
     """
     jnp = _jnp()
     import jax
 
     from .limbmatmul import fold_const_limbs, limb_partials_const
 
-    if draw is None:
-        draw = _device_randomness
     p = plan.modulus
     batches = _batch_secrets(secrets, plan)  # (C, b, k)
     C, nb = batches.shape[0], batches.shape[1]
-    randomness = draw(key, (C, nb, plan.rand_size), p)
+    randomness = _device_randomness(key, (C, nb, plan.rand_size), p)
     with jax.named_scope("fabric.values"):
         # keep the big tensor in native int32 lanes when the field fits
         dt = jnp.int32 if p <= (1 << 31) else jnp.int64
@@ -235,8 +225,9 @@ def clerk_combine(shares):
     """(P, n, B) -> (n, B) local modular sums — the clerk hot loop
     (combiner.rs:16-30) as one reduction; caller supplies the modulus rem.
 
-    Exact only while P*(p-1) < 2^63 — use :func:`clerk_combine_mod` when
-    the modulus/participant count may exceed that bound."""
+    Exact only while P*(p-1) < 2^63. No engine calls this: every path goes
+    through :func:`clerk_combine_mod`. It stays as the plain reference the
+    tests hold the sum-first path to (tests/test_sumfirst.py)."""
     jnp = _jnp()
     return jnp.sum(shares.astype(jnp.int64), axis=0)
 
@@ -400,8 +391,8 @@ class TpuAggregator:
         participant shard, partial accumulators psum over ``p`` — tiny
         ``(W, B, n)`` int64 tensors riding ICI — and the exact mod-p
         recombine of the reduced accumulator happens once on host
-        (``limbmatmul.limb_recombine_host``), exactly like the single-chip
-        streaming bench epilogue.
+        (``limbmatmul.limb_recombine_host``), exactly like the epilogue of
+        the single-chip rounds (``benchmark/rounds/packed_fold.py``).
 
         Exactness: per-device partials are bounded by ``C_local·L·K·127²``;
         the psum multiplies by the number of participant shards, so int64

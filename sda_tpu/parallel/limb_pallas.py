@@ -1,6 +1,6 @@
 """Pallas TPU kernel: fused per-participant limb share matmul + reduce.
 
-The per-participant engine path (bench ``--engine participant``) computes
+The per-participant engine path (``engine.share_combine_limb``) computes
 every participant's share limb-partials individually — (L, C·nb, n) int32
 — and then reduces over participants. Under XLA those partials round-trip
 HBM between the dot and the reduction. This kernel fuses them: each grid
@@ -20,7 +20,7 @@ Zero padding on every axis is exact: a zero value has zero limbs.
 
 Everything in-kernel is int32: partials are bounded by L·K·127² and the
 participant accumulation by C_total·L·K·127², which must stay < 2^31
-(checked at trace time — the bench chunk of 2000 is well inside). The
+(checked at trace time — a chunk of 2000 is well inside). The
 mod-p recombine (int64 multiply + one rem) happens outside on the reduced
 accumulator, exactly like the jnp path.
 
@@ -137,23 +137,21 @@ def participant_limb_sums_pallas(values, stacks, *, interpret: bool = False):
     return out[:, :, :nb]
 
 
-def share_combine_limb_pallas(secrets, key, plan, draw=None, *, interpret: bool = False):
+def share_combine_limb_pallas(secrets, key, plan, *, interpret: bool = False):
     """Fused-kernel twin of ``engine.share_combine_limb`` for p < 2^31:
     same (W, b, n) int64 contract (weights 128^m), bit-identical results
-    for the same key/draw."""
+    for the same key."""
     ensure_x64()
     import jax.numpy as jnp
 
     from .engine import _batch_secrets, _device_randomness
 
-    if draw is None:
-        draw = _device_randomness
     p = plan.modulus
     if p >= (1 << 31):
         raise ValueError("pallas participant path is narrow-field only (p < 2^31)")
     batches = _batch_secrets(secrets, plan)  # (C, b, k)
     C, nb = batches.shape[0], batches.shape[1]
-    randomness = draw(key, (C, nb, plan.rand_size), p)
+    randomness = _device_randomness(key, (C, nb, plan.rand_size), p)
     values = jnp.concatenate(
         [batches.astype(jnp.int32), randomness.astype(jnp.int32)], axis=-1
     )

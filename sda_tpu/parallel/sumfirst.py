@@ -58,60 +58,25 @@ def limb_count_sum(p: int) -> int:
 
 
 def exact_sum_narrow(x):
-    """Exact axis-0 sums of nonneg int32 values < 2^31 using only native
-    int32 lane ops — delegates to the uint32 variant (the int32→uint32
-    bit-cast is lossless for nonneg values, and logical shift equals
-    arithmetic shift there). ``(C, ...) -> (...)`` int64."""
-    import jax.numpy as jnp
-
-    # canonical values < 2^31: int32 cast lossless, uint32 view identical
-    return exact_sum_narrow_u32(x.astype(jnp.int32).astype(jnp.uint32))
-
-
-def exact_sum_narrow_u32(x):
-    """Exact axis-0 sums of uint32 values using only native 32-bit lane
-    ops: split into 2^16 halves (logical shift on uint32), sum each in
-    int32 (exact while ``x.shape[0] <= MAX_NARROW_CHUNK``), widen only the
-    reduced result. ``(C, ...) -> (...)`` int64."""
+    """Exact axis-0 sums using only native 32-bit lane ops: split into 2^16
+    halves (logical shift on uint32), sum each in int32 (exact while
+    ``x.shape[0] <= MAX_NARROW_CHUNK``), widen only the reduced result.
+    ``(C, ...) -> (...)`` int64. Takes uint32 values as they are (the halves
+    of a wide value), or nonneg signed values < 2^31, whose int32 cast is
+    lossless and whose uint32 view is identical."""
     ensure_x64()
     import jax.numpy as jnp
 
     if x.shape[0] > MAX_NARROW_CHUNK:
         raise ValueError(f"narrow reduction bound is {MAX_NARROW_CHUNK} rows")
-    x = x.astype(jnp.uint32)
+    if x.dtype != jnp.uint32:
+        x = x.astype(jnp.int32).astype(jnp.uint32)
     lo = jnp.sum((x & jnp.uint32(0xFFFF)).astype(jnp.int32), axis=0, dtype=jnp.int32)
     hi = jnp.sum((x >> jnp.uint32(16)).astype(jnp.int32), axis=0, dtype=jnp.int32)
     return lo.astype(jnp.int64) + (hi.astype(jnp.int64) << jnp.int64(16))
 
 
-def value_limb_sums_chunk_pair(hi, lo, key, plan: AggregationPlan, draw_pair):
-    """The wide-modulus twin of :func:`value_limb_sums_chunk` over
-    ``(hi, lo)`` uint32 pair tensors (value = hi·2³² + lo < p, p < 2⁶²).
-
-    The base-2³² limb sums the epilogue needs are exactly ``Σ lo`` and
-    ``Σ hi`` — so when values arrive as halves, no int64 tensor (emulated
-    on 32-bit TPU lanes) ever materializes: both halves reduce via the
-    16-bit-split narrow int32 sums. ``draw_pair(key, shape) -> (hi, lo)``
-    supplies the share randomness in the same representation. Returns
-    ``(2, B, K)`` int64 exact limb sums — accumulate and feed
-    ``clerk_sums_from_limb_acc`` exactly like the int64-path chunks
-    (parity-tested bit-exact against :func:`value_limb_sums_chunk`).
-    """
-    ensure_x64()
-    import jax
-    import jax.numpy as jnp
-
-    C = hi.shape[0]
-    batches_hi = _batch_secrets(hi, plan)  # (C, b, k) — pad/reshape, dtype-agnostic
-    batches_lo = _batch_secrets(lo, plan)
-    with jax.named_scope("fabric.rand/draw"):
-        rand_hi, rand_lo = draw_pair(key, (C, batches_hi.shape[1], plan.rand_size))
-    cols_hi = jnp.concatenate([batches_hi, rand_hi], axis=-1)  # (C, b, K)
-    cols_lo = jnp.concatenate([batches_lo, rand_lo], axis=-1)
-    return jnp.stack([exact_sum_narrow_u32(cols_lo), exact_sum_narrow_u32(cols_hi)])
-
-
-def value_limb_sums_chunk(secrets, key, plan: AggregationPlan, draw=None):
+def value_limb_sums_chunk(secrets, key, plan: AggregationPlan):
     """One streaming chunk of the sum-first hot loop.
 
     ``(C, dim)`` canonical secrets -> ``(L, B, K)`` int64 *exact integer*
@@ -123,12 +88,10 @@ def value_limb_sums_chunk(secrets, key, plan: AggregationPlan, draw=None):
 
     Secrets and randomness are limb-summed separately and joined on the
     tiny ``(B, ·)`` results — the big ``(C, B, K)`` concatenation the share
-    matmul needs never materializes. ``draw(key, shape, p) -> int64 in
-    [0, p)`` overrides the randomness generator (``bench.py`` passes a
-    masked-bits draw over a power-of-two sub-range; the benchmark's cells
-    pass none). The default is the simulation-grade ``uniform_mod_device``
-    over the whole field, which keeps this bit-identical to
-    ``share_participants`` for the same key.
+    matmul needs never materializes. The randomness is the program's draw
+    (``engine._device_randomness``: the simulation-grade
+    ``uniform_mod_device`` over the whole field), which keeps this
+    bit-identical to ``share_participants`` for the same key.
     """
     ensure_x64()
     import jax
@@ -137,9 +100,7 @@ def value_limb_sums_chunk(secrets, key, plan: AggregationPlan, draw=None):
     p = plan.modulus
     batches = _batch_secrets(secrets, plan)  # (C, b, k)
     C, nb = batches.shape[0], batches.shape[1]
-    if draw is None:
-        draw = _device_randomness
-    randomness = draw(key, (C, nb, plan.rand_size), p)
+    randomness = _device_randomness(key, (C, nb, plan.rand_size), p)
 
     # narrow path (p <= 2^31, chunk <= 2^15): all big-tensor ops stay in
     # native int32 lanes (exact_sum_narrow) and only the tiny (b, cols)
@@ -174,27 +135,25 @@ def exact_value_sums(limb_acc):
     return out
 
 
-def _value_sums_mod(limb_acc, p: int, exact=None):
+def _value_sums_mod(limb_acc, p: int):
     """``(L, B, K)`` limb accumulator -> ``((B, K)`` canonical int64 value
     sums mod p, the road taken``)``. Each limb is reduced first by an int64
     ``%`` (exact; so whatever the participant count, up to limb sums of
     2⁶³ − 1, the joined value stays under 2³³·p): one limb is that alone
-    (``int64``), two go through ``mod_limbs_np`` (``limb``). A handed-in
-    ``exact``, more than two limbs or p ≥ 2⁶² take python integers
-    (``object``)."""
+    (``int64``), two go through ``mod_limbs_np`` (``limb``). What that road
+    refuses (an accumulator that is not machine integers, more than two
+    limbs, p ≥ 2⁶²) takes python integers (``object``)."""
     acc = np.asarray(limb_acc)
-    if exact is None and acc.dtype.kind == "i":
+    if acc.dtype.kind == "i":
         acc = acc.astype(np.int64, copy=False)
         if acc.shape[0] == 1:
             return acc[0] % p, "int64"
         if acc.shape[0] == 2 and p < WIDE_MAX_MODULUS:
             return mod_limbs_np([acc[0] % p, acc[1] % p], 32, p), "limb"
-    if exact is None:
-        exact = exact_value_sums(limb_acc)
-    return (exact % p).astype(np.int64), "object"
+    return (exact_value_sums(limb_acc) % p).astype(np.int64), "object"
 
 
-def clerk_sums_from_limb_acc(limb_acc, plan: AggregationPlan, exact=None):
+def clerk_sums_from_limb_acc(limb_acc, plan: AggregationPlan):
     """Host epilogue: ``(L, B, K)`` int64 limb accumulator -> clerk sums.
 
     Returns ``(clerk_sums, value_sums)``: ``clerk_sums`` is the ``(n, B)``
@@ -203,9 +162,7 @@ def clerk_sums_from_limb_acc(limb_acc, plan: AggregationPlan, exact=None):
     canonical participant-sums (whose first ``k`` columns are the plain
     batched secret sums — the free verification handle). All arithmetic on
     this tiny accumulator is exact and vectorised (``_value_sums_mod``,
-    ``modmatmul_np``); each span's ``path`` says the road it took. Pass a
-    precomputed ``exact_value_sums(limb_acc)`` as ``exact`` to reuse it:
-    its python integers are then what is reduced.
+    ``modmatmul_np``); each span's ``path`` says the road it took.
     """
     p = plan.modulus
     if plan.share_matrix is None:
@@ -214,7 +171,7 @@ def clerk_sums_from_limb_acc(limb_acc, plan: AggregationPlan, exact=None):
     with telemetry.span(
         "fabric.epilogue.recombine", modulus_bits=bits, shape=np.shape(limb_acc)
     ) as record:
-        vsum, path = _value_sums_mod(limb_acc, p, exact)
+        vsum, path = _value_sums_mod(limb_acc, p)
         if path != "int64" and p >= MAX_SAFE_MODULUS:
             count_wide_product(path)
         if record is not None:
@@ -234,8 +191,9 @@ def clerk_sums_sum_first(secrets, key, plan: AggregationPlan):
     """Single-shot convenience: ``(P, dim)`` -> ``(n, B)`` clerk sums.
 
     Parity twin of ``share_participants`` + ``clerk_combine`` + rem (see
-    tests/test_parallel_engine.py); the streaming bench drives the chunk /
-    epilogue pieces directly.
+    tests/test_sumfirst.py); the benchmark's rounds
+    (``benchmark/rounds/packed_fold.py``) drive the chunk / epilogue pieces
+    directly.
     """
     if secrets.shape[0] > MAX_PARTICIPANTS:
         raise ValueError(f"chunk the input: exact bound is {MAX_PARTICIPANTS}")
@@ -246,7 +204,7 @@ def clerk_sums_sum_first(secrets, key, plan: AggregationPlan):
 
 def reconstruct_from_clerk_sums(clerk_sums, indices, scheme, dim: int):
     """Host-exact reconstruction for any modulus width (tiny inputs; the
-    bench epilogue). Same helper backs ``engine.reconstruct``'s wide path."""
+    rounds' epilogue). Same helper backs ``engine.reconstruct``'s wide path."""
     return shamir.reconstruct_clerk_sums_host(clerk_sums, indices, scheme, dim)
 
 
@@ -255,7 +213,7 @@ def sharded_value_limb_sums(plan: AggregationPlan, mesh):
     own participant shard (``value_limb_sums_chunk``), then one int64
     ``psum`` over the participant axis ``p`` carries only the tiny
     ``(L, B, K)`` accumulator across ICI — the sharded twin of the
-    streaming single-chip bench loop, with the same exactness bound
+    single-chip chunk loop, with the same exactness bound
     (``MAX_PARTICIPANTS`` *total*, summed over shards, since the psum adds
     pre-bounded per-shard limb sums).
 

@@ -2,7 +2,8 @@
 
 Force JAX onto a virtual 8-device CPU platform before anything imports jax:
 multi-chip sharding logic is exercised on a host-only mesh (the driver
-separately dry-runs the multichip path; real TPU is reserved for bench.py).
+separately dry-runs the multichip path; a real TPU is reserved for
+benchmark/run.py and chip_smoke.py).
 """
 
 import os
@@ -13,7 +14,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
-# the suite (and the bench children it spawns, through the env) compiles
+# the suite (and the script children it spawns, through the env) compiles
 # hundreds of throwaway CPU programs: keep them out of the persistent
 # cache ops/jaxcfg.py places inside the checkout
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"

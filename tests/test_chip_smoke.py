@@ -50,13 +50,12 @@ def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
     assert '"ok"' not in out.stdout
 
 
-def test_chip_smoke_legs_at_tiny_sizes(monkeypatch, capsys):
-    """Every device leg, in this process, on the 8-device CPU mesh."""
-    import chip_smoke
-    from sda_tpu.crypto.masking import ChaChaMasker
+#: the fabric leg at sizes the CPU folds in a second
+TINY = {"dim": 60, "chunk": 100}
 
-    device = chip_smoke.device_line(allow_pinned_cpu=True)
-    assert device == {"platform": "cpu", "kind": "cpu", "count": 8}
+
+def _protocol_leg(chip_smoke, monkeypatch):
+    from sda_tpu.crypto.masking import ChaChaMasker
 
     # sized below the device-combine threshold the leg refuses to run: the
     # reveal would never reach the device plane
@@ -64,21 +63,135 @@ def test_chip_smoke_legs_at_tiny_sizes(monkeypatch, capsys):
         chip_smoke.protocol_leg(dim=40, participants=6)
     monkeypatch.setattr(ChaChaMasker, "DEVICE_COMBINE_THRESHOLD", 1)
     chip_smoke.protocol_leg(dim=40, participants=6)
-    chip_smoke.fabric_leg(
-        dim=60, chunk=100, participants=400,
-        preset_dim=60, preset_chunk=100, preset_participants=400, seeds=4,
-    )
-    chip_smoke.sharded_leg(dim=40, rows_per_shard=4)
+    # the CPU's path, and a committee member really was dropped
+    return ["mask combine on jnp, reveal exact", "7 of 8 clerks"]
 
+
+def _engine_leg(engine, bits):
+    def run(chip_smoke, monkeypatch):
+        chip_smoke.fold_engine(engine, **TINY)
+        return [
+            f"fabric leg ok: {engine} {bits}-bit, 200 rows x dim 60 in 2 chunks, "
+            "default draw, reveal from 7 of 8 clerks exact"
+        ]
+
+    return run
+
+
+def _sharded_leg(chip_smoke, monkeypatch):
+    chip_smoke.sharded_leg(dim=40, rows_per_shard=4)
+    return ["sharded leg ok: 8 devices, six dry-run fabrics"]
+
+
+LEGS = {
+    "protocol": _protocol_leg,
+    "sumfirst": _engine_leg("sumfirst", 61),
+    "participant": _engine_leg("participant", 31),
+    "participant+pallas": _engine_leg("participant+pallas", 31),
+    "sharded": _sharded_leg,
+}
+
+
+@pytest.mark.parametrize("leg", sorted(LEGS))
+def test_chip_smoke_legs_at_tiny_sizes(leg, monkeypatch, capsys):
+    """Every device leg, and every engine of the fabric leg, in this
+    process, on the 8-device CPU mesh."""
+    import chip_smoke
+
+    device = chip_smoke.device_line(allow_pinned_cpu=True)
+    assert device == {"platform": "cpu", "kind": "cpu", "count": 8}
+    said = LEGS[leg](chip_smoke, monkeypatch)
     out = capsys.readouterr().out
-    assert "mask combine on jnp, reveal exact" in out  # the CPU's path
-    assert "7 of 8 clerks" in out  # a committee member really was dropped
-    for engine in ("sumfirst 61-bit", "participant 31-bit", "participant+pallas 31-bit"):
-        assert f"fabric leg ok: {engine}" in out
-    assert "sharded leg ok: 8 devices, six dry-run fabrics" in out
-    # information only: nothing under a metric name, no JSON metric line
-    assert "shared_elements_per_second" not in out
+    for line in said:
+        assert line in out
+    # information only: a leg prints no JSON line, so nothing reads as a metric
     assert not [ln for ln in out.splitlines() if ln.startswith("{")]
+
+
+def test_the_fabric_leg_holds_parity_and_the_two_participant_paths_to_one_accumulator(
+    monkeypatch, capsys
+):
+    """The whole leg: kernel parity on the CPU's backends (the jnp twin and
+    the kernel source under the interpreter, chosen from the backend,
+    nothing caught), the three engines, and the Pallas accumulator held to
+    the XLA one: a kernel path that leaves other bits fails the leg though
+    its own reveal is exact."""
+    import chip_smoke
+
+    chip_smoke.fabric_leg(**TINY, preset_dim=60, preset_chunk=100, seeds=4)
+    out = capsys.readouterr().out
+    assert "'chacha_backends': ['jnp', 'interpret']" in out
+    for name in ("chacha_jnp", "chacha_interpret", "limb", "wide61"):
+        assert f"'{name}': 'ok'" in out
+    assert out.count("fabric leg ok: ") == 3
+
+    fold = chip_smoke.fold_engine
+    monkeypatch.setattr(
+        chip_smoke, "fold_engine",
+        lambda engine, **sizes: fold(engine, **sizes) + (engine.endswith("pallas")),
+    )
+    monkeypatch.setattr(chip_smoke, "kernel_parity", lambda **sizes: {"ok": True})
+    with pytest.raises(chip_smoke.SmokeFailure, match="differs from the XLA"):
+        chip_smoke.fabric_leg(**TINY, preset_dim=60, preset_chunk=100, seeds=4)
+
+
+@pytest.mark.parametrize("engine,module,epilogue,cell", [
+    # (L, B, K): the low limb of the first secret column's sum
+    ("sumfirst", "sumfirst", "clerk_sums_from_limb_acc", 0),
+    # (W, B, n): a share sum of clerk 7, one of the seven that reveal
+    ("participant", "limbmatmul", "limb_recombine_host", -1),
+    ("participant+pallas", "limbmatmul", "limb_recombine_host", -1),
+])
+def test_a_corrupted_accumulator_fails_the_fabric_leg(
+    engine, module, epilogue, cell, monkeypatch
+):
+    """The leg's self-check can fail, not only bless: one cell of the
+    accumulator off by one on its way into the epilogue the leg calls, and
+    the reveal no longer matches the exact column sums."""
+    import importlib
+
+    import chip_smoke
+
+    owner = importlib.import_module(f"sda_tpu.parallel.{module}")
+    real = getattr(owner, epilogue)
+
+    def corrupted(acc, *rest):
+        acc = acc.copy()
+        acc[(cell,) * acc.ndim] += 1
+        return real(acc, *rest)
+
+    monkeypatch.setattr(owner, epilogue, corrupted)
+    with pytest.raises(chip_smoke.SmokeFailure, match="exact column sums"):
+        chip_smoke.fold_engine(engine, **TINY)
+
+
+@pytest.mark.parametrize("kernel,module,function", [
+    ("chacha_jnp", "sda_tpu.ops.chacha_pallas", "combine_masks_device"),
+    ("limb", "sda_tpu.parallel.limb_pallas", "share_combine_limb_pallas"),
+])
+def test_a_kernel_whose_bits_differ_fails_the_parity(
+    kernel, module, function, monkeypatch
+):
+    """A mismatch is fatal, not a note: one element of the kernel's output
+    off by one and ``kernel_parity`` raises, naming the kernel."""
+    import importlib
+
+    import numpy as np
+
+    import chip_smoke
+
+    owner = importlib.import_module(module)
+    real = getattr(owner, function)
+
+    def off_by_one(*args, **kwargs):
+        out = real(*args, **kwargs)
+        hot = np.zeros(out.shape, dtype=np.int64)
+        hot.flat[0] = 1
+        return out + hot
+
+    monkeypatch.setattr(owner, function, off_by_one)
+    with pytest.raises(chip_smoke.SmokeFailure, match=f"{kernel}: device bits differ"):
+        chip_smoke.kernel_parity(seeds=4, dim=60, chunk=100, limb_dim=60)
 
 
 def test_chip_smoke_sharded_leg_names_its_skip(monkeypatch, capsys):

@@ -277,10 +277,6 @@ def test_the_sumfirst_epilogue_is_the_python_integer_epilogue(bits, kind):
     want = object_epilogue(acc, plan)
     for g, w in zip(got, want):
         assert g.dtype == np.int64 and g.shape == w.shape and np.array_equal(g, w)
-    # the handed-in exact sums keep their meaning: the same bits
-    again = sumfirst.clerk_sums_from_limb_acc(acc, plan, exact=sumfirst.exact_value_sums(acc))
-    for g, w in zip(again, want):
-        assert g.dtype == np.int64 and np.array_equal(g, w)
 
 
 @pytest.mark.parametrize("config,path", [("c5-w61-d100k", "limb"), ("c4-w31-d50k", "int64")])

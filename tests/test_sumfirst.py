@@ -141,60 +141,22 @@ def test_exact_sum_narrow_matches_int64(jax_mods):
         exact_sum_narrow(jnp.zeros((MAX_NARROW_CHUNK + 1, 2), dtype=jnp.int32))
 
 
-def test_narrow_draws_match_wide(jax_mods):
-    """uniform_bits_device_narrow must produce the same values as the wide
-    variant for the same key (same masked uint32 stream, different dtype) —
-    the bench switches between them by modulus width."""
-    import jax
+def test_exact_sum_narrow_takes_the_uint32_halves_of_a_wide_value(jax_mods):
+    """uint32 values are summed as they are, bit 31 included: what a wide
+    value's (hi, lo) halves need (ROADMAP S4), at the row bound too."""
     import jax.numpy as jnp
 
-    from sda_tpu.ops.rng import uniform_bits_device, uniform_bits_device_narrow
+    from sda_tpu.parallel.sumfirst import MAX_NARROW_CHUNK, exact_sum_narrow
 
-    key = jax.random.key(9)
-    wide = uniform_bits_device(key, (64, 5), 30)
-    narrow = uniform_bits_device_narrow(key, (64, 5), 30)
-    assert narrow.dtype == jnp.int32
-    np.testing.assert_array_equal(np.asarray(narrow), np.asarray(wide))
-
-
-def test_pair_chunk_matches_int64_chunk(jax_mods):
-    """The (hi, lo) uint32 pair formulation of the wide-field hot loop —
-    no int64 tensor ever materializes on device — produces bit-identical
-    limb sums to the int64 formulation for the same values and
-    randomness."""
-    import jax.numpy as jnp
-    from jax import random
-
-    from sda_tpu.parallel.engine import make_plan
-    from sda_tpu.parallel.sumfirst import (
-        value_limb_sums_chunk,
-        value_limb_sums_chunk_pair,
+    values = np.random.default_rng(6).integers(0, 1 << 61, size=(257, 9))
+    values[0, :] = (1 << 61) - 1
+    lo = (values & 0xFFFFFFFF).astype(np.uint32)
+    hi = (values >> 32).astype(np.uint32)
+    joined = np.asarray(exact_sum_narrow(jnp.asarray(lo))).astype(object) + (
+        np.asarray(exact_sum_narrow(jnp.asarray(hi))).astype(object) << 32
     )
+    assert joined.tolist() == [sum(int(v) for v in col) for col in values.T]
 
-    scheme = _wide_scheme()
-    p = scheme.prime_modulus
-    dim = 14  # pad path
-    plan = make_plan(scheme, dim)
-    rng = np.random.default_rng(11)
-    values = rng.integers(0, 1 << 60, size=(21, dim)).astype(np.int64)
-    randomness = rng.integers(0, 1 << 60, size=(21, plan.n_batches, plan.rand_size)).astype(np.int64)
-
-    acc_int64 = value_limb_sums_chunk(
-        jnp.asarray(values),
-        random.key(0),
-        plan,
-        draw=lambda k, s, m: jnp.asarray(randomness),
-    )
-
-    mask32 = (1 << 32) - 1
-    acc_pair = value_limb_sums_chunk_pair(
-        jnp.asarray((values >> 32).astype(np.uint32)),
-        jnp.asarray((values & mask32).astype(np.uint32)),
-        random.key(0),
-        plan,
-        draw_pair=lambda k, s: (
-            jnp.asarray((randomness >> 32).astype(np.uint32)),
-            jnp.asarray((randomness & mask32).astype(np.uint32)),
-        ),
-    )
-    np.testing.assert_array_equal(np.asarray(acc_int64), np.asarray(acc_pair))
+    worst = np.full((MAX_NARROW_CHUNK, 3), 0xFFFFFFFF, dtype=np.uint32)
+    got = np.asarray(exact_sum_narrow(jnp.asarray(worst)))
+    np.testing.assert_array_equal(got, worst.astype(np.int64).sum(axis=0))
