@@ -19,13 +19,32 @@ Overflow discipline: the reduction is carried as *exact integer* sums in
 base-2³² limb space — no mod ops touch the big tensor at all. Canonical
 values ``v < p < 2⁶²`` split into ``lo = v & (2³²−1)`` and ``hi = v ≫ 32``;
 limb sums over ``C_total`` participants are bounded by ``C_total · (2³²−1)``,
-so int64 accumulators are exact for up to 2³¹ participants (2048× the 1M
-north star). For ``p < 2³¹`` a single limb suffices. The epilogue
-(recombine mod p + share matmul) runs host-side on the tiny accumulator,
-exact and in machine integers: each limb reduced by an int64 ``%``, the
-two of a wide modulus joined by ``ops.modular.mod_limbs_np``, the share
-matmul by ``modmatmul_np``. Python integers remain only where a caller asks
-for the unreduced sums (``exact_value_sums``).
+so the int64 *accumulator* is exact for up to 2³¹ participants (2048× the 1M
+north star). For ``p ≤ 2³¹`` a single limb suffices.
+
+Which road a chunk's limb sums take (``limb_sum_road``: the modulus' limb
+count and the chunk's row count, nothing else) — the chip's lanes are 32
+bits wide, and a 64-bit tensor costs it a re-laying and carries besides:
+
+- ``int32`` — one limb, at most ``MAX_NARROW_CHUNK`` rows: the values as
+  they are through ``exact_sum_narrow`` (16-bit quarters summed in int32
+  lanes, exact while ``C · 65 535 < 2³¹``).
+- ``halves32`` — two limbs, at most ``MAX_NARROW_CHUNK`` rows: the low and
+  the high 32-bit word of each value as uint32, each through
+  ``exact_sum_narrow`` under the same bound; no 64-bit tensor of C rows is
+  reduced. The share randomness is drawn flat, ``(C, B·t)``, the same
+  values in a full tile, and each value once.
+- ``int64`` — a larger chunk, at either width: plain int64 sums, exact as
+  the accumulator is.
+
+Only the reduced ``(B, cols)`` rows widen to int64; all three hand on the
+same ``(L, B, K)`` accumulator, element for element.
+
+The epilogue (recombine mod p + share matmul) runs host-side on the tiny
+accumulator, exact and in machine integers: each limb reduced by an int64
+``%``, the two of a wide modulus joined by ``ops.modular.mod_limbs_np``, the
+share matmul by ``modmatmul_np``. Python integers remain only where a caller
+asks for the unreduced sums (``exact_value_sums``).
 """
 
 from __future__ import annotations
@@ -57,6 +76,26 @@ def limb_count_sum(p: int) -> int:
     return 1 if p <= (1 << 31) else 2
 
 
+def limb_sum_road(p: int, rows: int) -> str:
+    """The road a chunk's limb sums take (module doc), chosen from what
+    ``value_limb_sums_chunk`` sees: the modulus' limb count and the chunk's
+    row count."""
+    if rows > MAX_NARROW_CHUNK:
+        return "int64"
+    return "int32" if limb_count_sum(p) == 1 else "halves32"
+
+
+def count_limb_sum_road(road: str) -> None:
+    """One limb-sum reduction of a chunk step, by its road. The road is a
+    property of the traced program, not of a step: counted where it is
+    chosen, once for each ``limb_sums`` call of a trace."""
+    telemetry.counter(
+        "sda_limb_sum_roads_total",
+        "limb-sum reductions traced, by road (int32 | halves32 | int64)",
+        road=road,
+    ).inc()
+
+
 def exact_sum_narrow(x):
     """Exact axis-0 sums using only native 32-bit lane ops: split into 2^16
     halves (logical shift on uint32), sum each in int32 (exact while
@@ -84,7 +123,8 @@ def value_limb_sums_chunk(secrets, key, plan: AggregationPlan):
     rows ``[batched secrets | fresh randomness]`` (the same rows
     ``engine.share_participants`` feeds the share matmul). ``L`` is
     ``limb_count_sum(p)``. Accumulate chunks with plain ``+`` — no mod ops —
-    while total participants stay below ``MAX_PARTICIPANTS``.
+    while total participants stay below ``MAX_PARTICIPANTS``. The sums take
+    the road ``limb_sum_road`` names, to the same integers by each.
 
     Secrets and randomness are limb-summed separately and joined on the
     tiny ``(B, ·)`` results — the big ``(C, B, K)`` concatenation the share
@@ -100,28 +140,33 @@ def value_limb_sums_chunk(secrets, key, plan: AggregationPlan):
     p = plan.modulus
     batches = _batch_secrets(secrets, plan)  # (C, b, k)
     C, nb = batches.shape[0], batches.shape[1]
-    randomness = _device_randomness(key, (C, nb, plan.rand_size), p)
+    road = limb_sum_road(p, C)
+    rand_shape = (C, nb, plan.rand_size)
+    if road == "halves32":
+        # a minor dimension of t = 2 fills two of a tile's eight sublanes
+        # (3.4x the device time, PERF.md §6, PR 31). Threefry's bits depend
+        # on a value's linear index alone: drawn flat, the values are the same
+        rand_shape = (C, nb * plan.rand_size)
+    randomness = _device_randomness(key, rand_shape, p)
 
-    # narrow path (p <= 2^31, chunk <= 2^15): all big-tensor ops stay in
-    # native int32 lanes (exact_sum_narrow) and only the tiny (b, cols)
-    # result widens. ~2x over emulated int64 lanes on TPU.
-    narrow = limb_count_sum(p) == 1 and C <= MAX_NARROW_CHUNK
-
-    def limb_sums(x):  # (C, b, cols) -> (L, b, cols) exact integer sums
-        if narrow:
+    def limb_sums(x):  # (C, ...) -> (L, ...) exact integer sums
+        count_limb_sum_road(road)
+        if road == "int32":
             return exact_sum_narrow(x)[None]
         x = x.astype(jnp.int64)
         if limb_count_sum(p) == 1:
             return jnp.sum(x, axis=0)[None]
-        mask = jnp.int64(0xFFFFFFFF)
-        return jnp.stack(
-            [jnp.sum(x & mask, axis=0), jnp.sum(x >> jnp.int64(32), axis=0)]
-        )
+        words = x & jnp.int64(0xFFFFFFFF), x >> jnp.int64(32)
+        if road == "halves32":
+            # the compiler reads the two words as they lie and moves the
+            # batching onto the reduced row; the draw stays in registers
+            return jnp.stack([exact_sum_narrow(w.astype(jnp.uint32)) for w in words])
+        return jnp.stack([jnp.sum(w, axis=0) for w in words])
 
     with jax.named_scope("fabric.input/limb_sum"):
         input_sums = limb_sums(batches)
     with jax.named_scope("fabric.rand/limb_sum"):
-        rand_sums = limb_sums(randomness)
+        rand_sums = limb_sums(randomness).reshape(-1, nb, plan.rand_size)
     return jnp.concatenate([input_sums, rand_sums], axis=-1)
 
 

@@ -134,7 +134,10 @@ def test_engines_fold_what_they_folded_under_the_old_remainder(jax_mods, monkeyp
 
     monkeypatch.setattr(rng_mod, "uniform_mod_device", old_draw)
     old = np.asarray(fold(secrets, random.key(11), plan))
-    assert calls == [(17, 5, 2)] and new.any()
+    # one draw a step; the sum-first engine draws a wide field's (C, B, t)
+    # flat, the same values (sumfirst.value_limb_sums_chunk, `halves32`)
+    flat = wide and engine == "value_limb_sums_chunk"
+    assert calls == [(17, 10) if flat else (17, 5, 2)] and new.any()
     np.testing.assert_array_equal(new, old)
 
 
