@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import telemetry
 from ..native import chacha_combine, chacha_expand as expand_seed
 from ..ops.modular import mod_sum_wide_np, rust_rem_np
 from ..ops.rng import uniform_mod_host
@@ -140,25 +141,38 @@ class ChaChaMasker(SecretMasker, MaskCombiner, SecretUnmasker):
     #: below this many expanded elements the host loop beats device dispatch
     DEVICE_COMBINE_THRESHOLD = 1 << 22
 
-    def combine(self, seeds):
+    def combine(self, seeds, *, chunk: int | None = None):
+        """Sum of the seeds' masks mod m. ``chunk`` is how many seeds one
+        device fold expands (``combine_masks_device``'s default fits its own
+        memory budget; a recipient that shares the chip says less)."""
         seed_rows = [np.asarray(s, dtype=np.int64).astype(np.uint32) for s in seeds]
-        if len(seed_rows) * self.dimension >= self.DEVICE_COMBINE_THRESHOLD:
-            # reveal hot loop (receive.rs:102-118): expand + sum on device
-            # (ops/chacha_pallas.py). Above the threshold this path runs or
-            # raises — a device failure must not hide behind the host loop.
-            from ..ops.chacha_pallas import combine_masks_device
+        on_device = len(seed_rows) * self.dimension >= self.DEVICE_COMBINE_THRESHOLD
+        with telemetry.span(
+            "fabric.unmask.combine", seeds=len(seed_rows),
+            path="device" if on_device else "host",
+        ):
+            if on_device:
+                # reveal hot loop (receive.rs:102-118): expand + sum on device
+                # (ops/chacha_pallas.py). Above the threshold this path runs or
+                # raises — a device failure must not hide behind the host loop.
+                from ..ops.chacha_pallas import combine_masks_device
 
-            return np.asarray(
-                combine_masks_device(np.stack(seed_rows), self.dimension, self.modulus)
-            )
-        if not seed_rows:
-            return np.zeros(self.dimension, dtype=np.int64)
-        # one C call expands + folds the whole cohort (19x the numpy loop;
-        # falls back to it when the extension isn't built)
-        return chacha_combine(np.stack(seed_rows), self.dimension, self.modulus)
+                return np.asarray(
+                    combine_masks_device(
+                        np.stack(seed_rows), self.dimension, self.modulus, chunk=chunk
+                    )
+                )
+            if not seed_rows:
+                return np.zeros(self.dimension, dtype=np.int64)
+            # one C call expands + folds the whole cohort (19x the numpy loop;
+            # falls back to it when the extension isn't built)
+            return chacha_combine(np.stack(seed_rows), self.dimension, self.modulus)
 
     def unmask(self, mask, masked):
-        return rust_rem_np(np.asarray(masked, np.int64) - np.asarray(mask, np.int64), self.modulus)
+        with telemetry.span("fabric.unmask.subtract", shape=np.shape(masked)):
+            return rust_rem_np(
+                np.asarray(masked, np.int64) - np.asarray(mask, np.int64), self.modulus
+            )
 
 
 def new_secret_masker(scheme) -> SecretMasker:

@@ -76,8 +76,10 @@ def chacha_blocks_pallas(
 
 
 def default_backend() -> str:
-    """Which rounds implementation ``"auto"`` means on this process's JAX
-    backend: the compiled kernel on a TPU, the jnp twin anywhere else
+    """Which rounds implementation a caller that must *name* one ahead of
+    the compile takes on this process's JAX backend (``combine_masks_device``:
+    the name is a static argument of its jitted fold and the label of its
+    counter): the compiled kernel on a TPU, the jnp twin anywhere else
     (there Pallas only offers its interpreter, slower than jnp). A kernel
     that fails to compile on the TPU raises at its call site."""
     import jax
@@ -88,12 +90,16 @@ def default_backend() -> str:
 def _rounds(states, backend: str):
     """Dispatch ``(N, 16) -> (N, 16)`` rounds by backend name.
 
-    ``auto`` = :func:`default_backend`; ``pallas`` / ``interpret`` / ``jnp``
-    force a specific path (interpret = Pallas interpreter, for CPU tests of
-    the kernel source).
+    ``auto`` is decided where the program is lowered, from the devices it is
+    compiled for (``lax.platform_dependent``): the compiled kernel for a TPU,
+    attached or only described, the jnp twin for anything else. ``pallas`` /
+    ``interpret`` / ``jnp`` force a specific path (interpret = Pallas
+    interpreter, for CPU tests of the kernel source).
     """
     if backend == "auto":
-        backend = default_backend()
+        from jax import lax
+
+        return lax.platform_dependent(states, tpu=_rounds_pallas, default=chacha_rounds_jnp)
     if backend == "pallas":
         return _rounds_pallas(states)
     if backend == "interpret":
@@ -133,6 +139,47 @@ def _window_pairs(dim: int, modulus: int) -> int:
     return dim + int(expected - dim + margin) + 8
 
 
+def _first_accepted(hi, lo, ok, dim: int):
+    """Stable compaction: ``(P, window)`` uint32 word pairs and which of them
+    are accepted -> ``(P, dim)`` pairs, the first ``dim`` accepted of each
+    row in stream order; slots past a row's last accepted draw are 0.
+
+    An accepted draw moves left by the number of rejected draws before it,
+    at most ``window - dim`` in a row that holds ``dim`` accepted ones. It
+    moves there bit by bit of that number, lowest first, each stage one
+    shift of the whole row by a power of two and a select: no two draws
+    ever claim one slot (two accepted draws lie further apart than their
+    shifts differ), and the order holds. Why not simpler: a scatter by the
+    prefix sum writes one element at a time on a TPU (6.9 s of a 7.0 s fold
+    of 500 seeds at dim 100 000), and a stable sort of rows this wide takes
+    its compiler 37 s (PERF.md section 6, PR 32). Draws that would move
+    further (only in a row that comes short, whose mask the caller drops by
+    its count) are let go."""
+    import jax.numpy as jnp
+
+    rows, window = ok.shape
+    max_shift = window - dim
+    rejected = (~ok).astype(jnp.int32)
+    before = jnp.cumsum(rejected, axis=1) - rejected
+    # the shift each slot's draw still has to make, as a whole; -1: no draw
+    shift = jnp.where(ok & (before <= max_shift), before, -1)
+
+    def from_right(x, step, fill):
+        pad = jnp.full((rows, step), fill, x.dtype)
+        return jnp.concatenate([x[:, step:], pad], axis=1)
+
+    for bit in range(max_shift.bit_length()):
+        step = 1 << bit
+        coming = from_right(shift, step, -1)
+        arrives = (coming >= 0) & ((coming >> bit) & 1 == 1)
+        stays = (shift >= 0) & ((shift >> bit) & 1 == 0)
+        hi = jnp.where(arrives, from_right(hi, step, 0), hi)
+        lo = jnp.where(arrives, from_right(lo, step, 0), lo)
+        shift = jnp.where(arrives, coming, jnp.where(stays, shift, -1))
+    held = shift[:, :dim] >= 0
+    return jnp.where(held, hi[:, :dim], 0), jnp.where(held, lo[:, :dim], 0)
+
+
 def expand_seeds_counts(seed_words, dim: int, modulus: int, backend: str = "jnp"):
     """Jit-safe core of :func:`expand_seeds_batch`: ``(P, w<=8)`` uint32
     seeds -> ``((P, dim) int64 masks, (P,) int32 accepted-draw counts)``.
@@ -161,23 +208,15 @@ def expand_seeds_counts(seed_words, dim: int, modulus: int, backend: str = "jnp"
     states = jax.vmap(lambda s: chacha_state_jnp(s, 0, n_blocks))(seed_words)
     words = _rounds(states.reshape(P * n_blocks, 16), backend)
     words = words.reshape(P, n_blocks * 16)
-    u64 = (words[:, 0::2].astype(jnp.uint64) << jnp.uint64(32)) | words[:, 1::2].astype(
-        jnp.uint64
-    )
-    ok = u64 < jnp.uint64(zone)
+    # a draw is (high word, low word); the two stay apart, in the chip's own
+    # 32-bit lanes, until the first ``dim`` accepted ones are picked
+    hi, lo = words[:, 0::2], words[:, 1::2]
+    zone_hi, zone_lo = jnp.uint32(zone >> 32), jnp.uint32(zone & 0xFFFFFFFF)
+    ok = (hi < zone_hi) | ((hi == zone_hi) & (lo < zone_lo))
     counts = jnp.sum(ok, axis=1).astype(jnp.int32)
-    # stable compaction by prefix sum + scatter (linear scan; an argsort
-    # here lowers to a full sort network on TPU): accepted draw k lands
-    # in slot (#accepted before k), rejected draws scatter out of bounds
-    # and drop. Slots past the last accepted draw stay 0 but are never
-    # read once the caller has validated ``counts``.
-    window = u64.shape[1]
-    pos = jnp.cumsum(ok.astype(jnp.int32), axis=1) - 1
-    idx = jnp.where(ok, pos, window)  # out-of-bounds marker for rejected
-    compact = jnp.zeros_like(u64).at[
-        jnp.arange(P)[:, None], idx
-    ].set(u64, mode="drop")
-    masks = (compact[:, :dim] % jnp.uint64(modulus)).astype(jnp.int64)
+    hi, lo = _first_accepted(hi, lo, ok, dim)
+    compact = (hi.astype(jnp.uint64) << jnp.uint64(32)) | lo.astype(jnp.uint64)
+    masks = (compact % jnp.uint64(modulus)).astype(jnp.int64)
     return masks, counts
 
 
@@ -208,15 +247,18 @@ def expand_seeds_batch(seed_words, dim: int, modulus: int, *, backend: str = "au
 def _fold_chunk(batch, dim: int, modulus: int, backend: str):
     """One reveal fold: expand + reduce fused on device; only the tiny
     (dim,) partial and (P,) accepted counts come back to host."""
+    import jax
     import jax.numpy as jnp
 
     from .modular import mod_sum_wide_jnp
 
-    masks, counts = expand_seeds_counts(batch, dim, modulus, backend)
-    if modulus <= (1 << 31):
-        part = jnp.sum(masks, axis=0) % jnp.int64(modulus)
-    else:
-        part = mod_sum_wide_jnp(masks, modulus, axis=0)
+    with jax.named_scope("fabric.unmask/expand"):
+        masks, counts = expand_seeds_counts(batch, dim, modulus, backend)
+    with jax.named_scope("fabric.unmask/sum"):
+        if modulus <= (1 << 31):
+            part = jnp.sum(masks, axis=0) % jnp.int64(modulus)
+        else:
+            part = mod_sum_wide_jnp(masks, modulus, axis=0)
     return part, counts
 
 
@@ -226,18 +268,34 @@ def _fold_chunk(batch, dim: int, modulus: int, backend: str):
 _FOLD_CHUNK_JIT = None
 
 
-def _fold_chunk_jit(batch, dim: int, modulus: int, backend: str):
+def fold_chunk_jit():
+    """The recipient's jitted fold, ``fn(seeds (P, w) uint32, dim, modulus,
+    backend) -> ((dim,) partial mask sum mod m, (P,) accepted counts)``, the
+    last three static: the one program ``combine_masks_device`` runs, fold
+    after fold. Public so that whoever times or rehearses a reveal lowers the
+    very program it runs (``benchmark/rounds/masked_fold.py``'s ``steps``)."""
     global _FOLD_CHUNK_JIT
     if _FOLD_CHUNK_JIT is None:
         import jax
 
         _FOLD_CHUNK_JIT = jax.jit(_fold_chunk, static_argnums=(1, 2, 3))
-    return _FOLD_CHUNK_JIT(batch, dim, modulus, backend)
+    return _FOLD_CHUNK_JIT
+
+
+def count_slack_exhausted(side: str, rows: int) -> None:
+    """``rows`` more seeds whose window held fewer than ``dim`` accepted
+    draws, on the ``participant``'s side (a chunk step's counts, checked by
+    ``parallel.masked.count_short_windows``) or the ``recipient``'s."""
+    telemetry.counter(
+        "sda_mask_slack_exhausted_total",
+        "rows whose ChaCha rejection window held fewer than dim accepted draws",
+        side=side,
+    ).inc(rows)
 
 
 #: transient device-memory budget per fold of combine_masks_device; the
-#: expansion materializes ~5 chunk x dim x 8 B tensors at peak (u64 pairs,
-#: rejection mask, scatter indices, compacted pairs, final masks)
+#: expansion materializes ~5 chunk x dim x 8 B tensors at peak (the word
+#: pairs and their shifts before and after a stage, the final masks)
 _COMBINE_BYTES_BUDGET = 2 << 30
 
 
@@ -275,8 +333,7 @@ def combine_masks_device(
         path=backend,
     ).inc(int(seed_words.shape[0]))
 
-    def fold_chunk(batch):
-        return _fold_chunk_jit(batch, dim, modulus, backend)
+    fold = fold_chunk_jit()
 
     def host_fold(batch):
         # ~1e-9-per-row event: host-expand just this chunk (the host path
@@ -291,8 +348,9 @@ def combine_masks_device(
     total = jnp.zeros((dim,), dtype=jnp.int64)
     for start in range(0, seed_words.shape[0], chunk):
         batch = seed_words[start : start + chunk]
-        part, counts = fold_chunk(jnp.asarray(batch))
+        part, counts = fold(jnp.asarray(batch), dim, modulus, backend)
         if counts.shape[0] and int(jnp.min(counts)) < dim:
+            count_slack_exhausted("recipient", int(jnp.sum(counts < dim)))
             logging.getLogger(__name__).info(
                 "rejection slack exhausted in chunk at %d; host-expanding it", start
             )
