@@ -1,32 +1,45 @@
-"""Pallas TPU kernel for ChaCha20 keystream expansion.
+"""Pallas TPU kernels for ChaCha20 mask expansion: the rounds, the compaction.
 
 The ChaCha masking scheme (crypto/masking.py; reference:
 client/src/crypto/masking/chacha.rs) makes the *recipient* re-expand every
 participant's seed to a dim-length mask at reveal time — for 1M
 participants x 100K dims that is ~3e9 ChaCha blocks, the single biggest
 VPU-bound workload in the system (reference hot loop:
-client/src/receive.rs:102-118 + chacha.rs:56-77). The jnp twin
-(ops/chacha.py) is correct but materializes 16 full word tensors between
-every one of the 80 quarter rounds, bouncing through HBM; this kernel keeps
-the whole 16-word state in VMEM/registers for all 20 rounds and touches HBM
-exactly twice per block (load initial state, store keystream).
+client/src/receive.rs:102-118 + chacha.rs:56-77). Two stages of that
+expansion bounce whole tensors through HBM when written in jax.numpy, and
+each has a kernel here that keeps them on the chip:
 
-Layout: states are carried as ``(16, n_blocks)`` uint32 — one word per
-sublane row, blocks along the 128-wide lane axis — so every quarter-round
-op is a full-width VPU op on ``(tile,)`` lanes. The grid tiles the block
-axis; each kernel instance processes ``tile`` blocks independently (ChaCha
-blocks share no state). Multi-seed batches flatten (seeds x blocks) onto
-the same lane axis — one kernel launch expands every participant's stream.
+* ``chacha_rounds``. The jnp twin (ops/chacha.py) materializes 16 full word
+  tensors between every one of the 80 quarter rounds; the kernel keeps the
+  whole 16-word state in VMEM/registers for all 20 rounds and touches HBM
+  exactly twice per block (load initial state, store keystream).
+* ``chacha_compact``. Picking a row's first ``dim`` accepted draws is a
+  stable compaction, a shift-and-select stage for every bit of the largest
+  shift (``_first_accepted``); in XLA each stage is a pass over three
+  ``(P, window)`` tensors, in the kernel a tile of eight rows stays in VMEM
+  from the first stage to the last and HBM sees the draws once, going in,
+  and the first ``dim`` of them once, coming out.
+
+Layout of the rounds: states are carried as ``(16, n_blocks)`` uint32 — one
+word per sublane row, blocks along the 128-wide lane axis — so every
+quarter-round op is a full-width VPU op on ``(tile,)`` lanes. The grid tiles
+the block axis; each kernel instance processes ``tile`` blocks independently
+(ChaCha blocks share no state). Multi-seed batches flatten (seeds x blocks)
+onto the same lane axis — one kernel launch expands every participant's
+stream. Layout of the compaction: rows on the sublanes, eight a tile, a
+row's draws along the lanes (``_compact_plan``).
 
 Bit parity: every path (numpy host, jnp, Pallas) runs the same djb quarter
-round over states from the one state builder (``chacha_state_jnp``), so
-outputs are bit-identical — asserted in tests/test_ops_field.py on the
-interpreter and by ``chip_smoke.py`` on the TPU. ``ChaChaMasker.combine``
-(crypto/masking.py) dispatches here for large reveal batches.
+round over states from the one state builder (``chacha_state_jnp``) and the
+same compaction stages, so outputs are bit-identical — asserted in
+tests/test_ops_field.py on the interpreter and by ``chip_smoke.py`` on the
+TPU. ``ChaChaMasker.combine`` (crypto/masking.py) dispatches here for large
+reveal batches.
 """
 
 from __future__ import annotations
 
+import functools
 import logging
 
 from .. import telemetry
@@ -154,7 +167,15 @@ def _first_accepted(hi, lo, ok, dim: int):
     of 500 seeds at dim 100 000), and a stable sort of rows this wide takes
     its compiler 37 s (PERF.md section 6, PR 32). Draws that would move
     further (only in a row that comes short, whose mask the caller drops by
-    its count) are let go."""
+    its count) are let go.
+
+    This is the prefix sum and the stages in XLA, every stage a pass of
+    three ``(P, window)`` tensors through HBM (0.0767 s for 500 rows of
+    107 200 on a v5e, 0.0668 of it the thirteen stages): the path of every
+    platform that is not a TPU. On a TPU the same prefix sum and stages run
+    in the kernel ``chacha_compact`` (:func:`_compact_pallas`), a tile of
+    eight rows held in VMEM from the first stage to the last (PERF.md
+    section 6, PR 33; :func:`_compact` chooses)."""
     import jax.numpy as jnp
 
     rows, window = ok.shape
@@ -178,6 +199,192 @@ def _first_accepted(hi, lo, ok, dim: int):
         shift = jnp.where(arrives, coming, jnp.where(stays, shift, -1))
     held = shift[:, :dim] >= 0
     return jnp.where(held, hi[:, :dim], 0), jnp.where(held, lo[:, :dim], 0)
+
+
+#: lanes of a row one loop step handles, thirty-two vregs a tensor: a step
+#: costs the same forty-odd cycles around its vregs' five each, so the stages
+#: alone took 20.0, 12.3, 7.9, 5.9, 5.3, 5.9 ms at 256 to 8192 lanes and the
+#: whole kernel 12.6, 8.8, 7.5 ms at 1024, 2048, 4096 (500 rows of 107 200, a
+#: v5e; 5.65 ms at 4096 since a step's lanes and their right neighbours share a
+#: load; PERF.md section 6, PR 33)
+_COMPACT_LANES = 4096
+
+#: what the compaction kernel may hold in VMEM: a v5e has 128 MiB of it
+_COMPACT_VMEM_BUDGET = 100 << 20
+
+
+def _compact_plan(window: int, dim: int):
+    """How the compaction kernel lays a tile of eight rows out, from the
+    shapes alone: ``(stages, lanes in, lanes worked on, scratch lanes, lanes
+    out, VMEM bytes)``. The stages work on the window rounded up to whole
+    loop steps; a stage looks ``step`` lanes to the right (and a step under
+    a vreg one vreg more), so the input carries one vreg beyond and the
+    scratch a halo of the largest step, kept at "no draw"."""
+    stages = (window - dim).bit_length()
+    body = -(-window // _COMPACT_LANES) * _COMPACT_LANES
+    lanes_in = body + 128
+    scratch = body + max(1 << max(stages - 1, 0), 128) + 128
+    lanes_out = -(-dim // _COMPACT_LANES) * _COMPACT_LANES
+    # inputs and outputs are double-buffered by the pipeline
+    vmem = 8 * 4 * (2 * 3 * lanes_in + 3 * scratch + 2 * 2 * lanes_out)
+    return stages, lanes_in, body, scratch, lanes_out, vmem
+
+
+def _compact_fits(window: int, dim: int) -> bool:
+    """Whether the kernel is worth lowering for this shape: a window of at
+    least one lane tile with something to move, and a tile of rows that
+    fits VMEM. Otherwise the compaction runs in XLA (``_first_accepted``)."""
+    stages, *_rest, vmem = _compact_plan(window, dim)
+    return window >= 128 and stages > 0 and vmem <= _COMPACT_VMEM_BUDGET
+
+
+def _compact_kernel(
+    hi_ref, lo_ref, ok_ref, out_hi_ref, out_lo_ref, hi_s, lo_s, shift_s, *, stages, window, dim, body
+):
+    """``_first_accepted`` on one tile of eight rows, in VMEM. First the
+    shifts, a prefix sum of the rejected draws down the row, a loop step's
+    lanes at a time with the count so far carried on (the blocks reach past
+    the window: what lies there is anything, and reads "no draw"). Then the
+    stages in place: a stage walks the row left to right in steps of
+    ``_COMPACT_LANES`` lanes, reads a step's lanes and those ``step`` to their
+    right, and writes the step's lanes back; it never reads what it has
+    written (a draw only moves left). The first stage reads the input words,
+    the last writes the outputs."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes = _COMPACT_LANES
+    # typed constants throughout: under x64 a python number traces 64 bits wide
+    none, zero, one = jnp.int32(-1), jnp.int32(0), jnp.int32(1)
+    shift_s[:, body:] = jnp.full((8, shift_s.shape[1] - body), none)
+    lane = lax.broadcasted_iota(jnp.int32, (8, lanes), 1)
+
+    def shifts(c, so_far):
+        at = pl.multiple_of(c * jnp.int32(lanes), lanes)
+        ok = (ok_ref[:, pl.ds(at, lanes)] != zero) & (lane < jnp.int32(window) - at)
+        rejected = jnp.where(ok, zero, one)
+        upto, reach = rejected, 1  # rejected draws in the ``reach`` lanes ending here
+        while reach < lanes:
+            left = pltpu.roll(upto, jnp.int32(reach), axis=1)
+            upto = upto + jnp.where(lane >= jnp.int32(reach), left, zero)
+            reach *= 2
+        before = so_far + upto - rejected
+        shift_s[:, pl.ds(at, lanes)] = jnp.where(
+            ok & (before <= jnp.int32(window - dim)), before, none
+        )
+        return so_far + jnp.sum(rejected, axis=1, keepdims=True, dtype=jnp.int32)
+
+    lax.fori_loop(zero, jnp.int32(body // lanes), shifts, jnp.zeros((8, 1), jnp.int32))
+    for bit in range(stages):
+        step = 1 << bit
+        last = bit == stages - 1
+        src = (hi_ref, lo_ref) if bit == 0 else (hi_s, lo_s)
+        dst = (out_hi_ref, out_lo_ref) if last else (hi_s, lo_s)
+        # a draw (sign clear) whose shift has this stage's bit set, or clear
+        draw_bit = jnp.int32(step - (1 << 31))
+
+        def chunk(c, carry):  # traced here and now, under this stage's names
+            at = pl.multiple_of(c * jnp.int32(lanes), lanes)
+
+            def here_and_right(ref):
+                if step >= 128:  # whole vregs: a re-indexing
+                    return ref[:, pl.ds(at, lanes)], ref[:, pl.ds(at + jnp.int32(step), lanes)]
+                wide = ref[:, pl.ds(at, lanes + 128)]
+                moved = pltpu.roll(wide, jnp.int32(lanes + 128 - step), axis=1)
+                return wide[:, :lanes], moved[:, :lanes]
+
+            shift, coming = here_and_right(shift_s)
+            arrives = (coming & draw_bit) == jnp.int32(step)
+            stays = (shift & draw_bit) == zero
+            shift = jnp.where(arrives, coming, jnp.where(stays, shift, none))
+            if not last:
+                shift_s[:, pl.ds(at, lanes)] = shift
+            for word_src, word_dst in zip(src, dst):
+                word, from_right = here_and_right(word_src)
+                word = jnp.where(arrives, from_right, word)
+                if last:
+                    word = jnp.where(shift >= zero, word, jnp.uint32(0))
+                word_dst[:, pl.ds(at, lanes)] = word
+            return carry
+
+        steps = (out_hi_ref.shape[1] if last else body) // lanes
+        lax.fori_loop(zero, jnp.int32(steps), chunk, zero)
+
+
+def _compact_pallas(hi, lo, ok, dim: int, *, interpret: bool = False):
+    """``_first_accepted`` as the kernel ``chacha_compact``: ``(P, window)``
+    word pairs and accepted flags -> ``(P, dim)`` pairs, the same bits.
+    Nothing is padded or cut in HBM: a block is eight rows by
+    ``_compact_plan``'s lanes, wider than the arrays, so the pipeline copies
+    what there is of a tile and the kernel takes the rest for "no draw"."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from .jaxcfg import I32_ZERO as zero  # literal 0 would trace as i64
+
+    rows, window = ok.shape
+    stages, lanes_in, body, scratch, lanes_out, vmem = _compact_plan(window, dim)
+    row_tile = lambda lanes: pl.BlockSpec((8, lanes), lambda i: (i, zero))
+    out = jax.ShapeDtypeStruct((rows, dim), jnp.uint32)
+    return pl.pallas_call(
+        functools.partial(_compact_kernel, stages=stages, window=window, dim=dim, body=body),
+        grid=(-(-rows // 8),),
+        in_specs=[row_tile(lanes_in)] * 3,
+        out_specs=[row_tile(lanes_out)] * 2,
+        out_shape=[out, out],
+        scratch_shapes=[
+            pltpu.VMEM((8, scratch), jnp.uint32),
+            pltpu.VMEM((8, scratch), jnp.uint32),
+            pltpu.VMEM((8, scratch), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=vmem + (8 << 20)
+        ),
+        interpret=interpret,
+        name="chacha_compact",
+    )(hi, lo, ok.astype(jnp.int32))
+
+
+def count_compaction(path: str, rows: int) -> None:
+    """``rows`` more keystream rows compacted by ``path``. The path is a
+    property of the traced program, as a limb sum's road is
+    (``parallel.sumfirst.count_limb_sum_road``): counted where it is chosen,
+    once for each expansion of a trace."""
+    telemetry.counter(
+        "sda_crypto_chacha_compactions_total",
+        "keystream rows compacted to their first dim accepted draws, by path "
+        "(pallas | interpret | jnp)",
+        path=path,
+    ).inc(rows)
+
+
+def _compact(hi, lo, ok, dim: int, backend: str):
+    """:func:`_first_accepted` by backend name, as ``_rounds`` dispatches the
+    rounds: the kernel where the program is compiled for a TPU (``auto``
+    decides where it is lowered; counted as this process's backend, which is
+    what runs it), its source on the interpreter, or the compaction in XLA. A
+    shape the kernel is not worth or cannot hold (``_compact_fits``) takes
+    XLA's under every name."""
+    from jax import lax
+
+    rows, window = ok.shape
+    if backend == "jnp" or not _compact_fits(window, dim):
+        count_compaction("jnp", rows)
+        return _first_accepted(hi, lo, ok, dim)
+    count_compaction(default_backend() if backend == "auto" else backend, rows)
+    if backend == "auto":
+        return lax.platform_dependent(
+            hi,
+            lo,
+            ok,
+            tpu=functools.partial(_compact_pallas, dim=dim),
+            default=functools.partial(_first_accepted, dim=dim),
+        )
+    return _compact_pallas(hi, lo, ok, dim, interpret=backend == "interpret")
 
 
 def expand_seeds_counts(seed_words, dim: int, modulus: int, backend: str = "jnp"):
@@ -214,7 +421,7 @@ def expand_seeds_counts(seed_words, dim: int, modulus: int, backend: str = "jnp"
     zone_hi, zone_lo = jnp.uint32(zone >> 32), jnp.uint32(zone & 0xFFFFFFFF)
     ok = (hi < zone_hi) | ((hi == zone_hi) & (lo < zone_lo))
     counts = jnp.sum(ok, axis=1).astype(jnp.int32)
-    hi, lo = _first_accepted(hi, lo, ok, dim)
+    hi, lo = _compact(hi, lo, ok, dim, backend)
     compact = (hi.astype(jnp.uint64) << jnp.uint64(32)) | lo.astype(jnp.uint64)
     masks = (compact % jnp.uint64(modulus)).astype(jnp.int64)
     return masks, counts
@@ -294,8 +501,10 @@ def count_slack_exhausted(side: str, rows: int) -> None:
 
 
 #: transient device-memory budget per fold of combine_masks_device; the
-#: expansion materializes ~5 chunk x dim x 8 B tensors at peak (the word
-#: pairs and their shifts before and after a stage, the final masks)
+#: expansion materializes ~5 chunk x dim x 8 B tensors at peak (the
+#: keystream, its word pairs and their shifts, the compacted pairs, the final
+#: masks): as many with the compaction in its kernel as with its stages in XLA
+#: (the compiler reserves 3.86e9 B for a fold of 500 x 100 000 either way)
 _COMBINE_BYTES_BUDGET = 2 << 30
 
 

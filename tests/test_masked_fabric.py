@@ -42,6 +42,14 @@ def field():
     return scheme, make_plan(scheme, DIM), ChaChaMasking(int(p), DIM, 128)
 
 
+def ticks(name, **labels):
+    """What the counter ``name`` reads under these labels."""
+    return sum(
+        c["value"] for c in telemetry.snapshot()["counters"]
+        if c["name"] == name and all(c["labels"].get(k) == v for k, v in labels.items())
+    )
+
+
 def secrets_of(seed, p, rows=ROWS):
     rng = np.random.default_rng(seed)
     return rng.integers(0, 1 << 60, size=(rows, DIM), dtype=np.int64) % p
@@ -95,6 +103,50 @@ def test_masked_entry_agrees_with_the_references_row_by_row(field, backend):
     assert np.array_equal(got, plain)
     want_mask = reference_chacha.mask_sum(np.stack(uploads), DIM, p)
     assert np.array_equal(masker.combine(uploads), want_mask)
+
+
+def test_masked_step_on_the_compaction_kernel_cancels_against_the_recipients_fold(field):
+    """At a dim whose window fills a lane tile both sides compact in the
+    kernel ``chacha_compact`` (its source, on the interpreter): the step's
+    masks cancel against the recipient's fold, and the counter reads the rows
+    each side compacted under the path taken."""
+    import jax
+    import jax.numpy as jnp
+
+    from sda_tpu.ops.shamir import reconstruct_clerk_sums_host
+    from sda_tpu.parallel import masked
+    from sda_tpu.parallel.engine import make_plan
+    from sda_tpu.parallel.sumfirst import clerk_sums_from_limb_acc, value_limb_sums_chunk
+
+    scheme, small_plan, _masking = field
+    p, dim = small_plan.modulus, 150
+    plan, masking = make_plan(scheme, dim), ChaChaMasking(int(p), dim, 128)
+    window = (chacha_pallas._window_pairs(dim, p) * 2 + 15) // 16 * 8
+    assert chacha_pallas._compact_fits(window, dim) and not chacha_pallas._compact_fits(88, DIM)
+
+    def compacted(path):
+        return ticks("sda_crypto_chacha_compactions_total", path=path)
+
+    rng = np.random.default_rng(5)
+    secrets = rng.integers(0, 1 << 60, size=(CHUNK, dim), dtype=np.int64) % p
+    before, twin_before = compacted("interpret"), compacted("jnp")
+    fn = jax.jit(masked.masked_chunk(value_limb_sums_chunk, plan, masking, backend="interpret"))
+    acc, seeds, counts = fn(jnp.asarray(secrets), jax.random.key(7))
+    assert compacted("interpret") == before + CHUNK
+    assert masked.count_short_windows(counts, dim) == 0
+    clerk_sums, _ = clerk_sums_from_limb_acc(np.asarray(acc), plan)
+    revealed = np.mod(
+        np.asarray(reconstruct_clerk_sums_host(clerk_sums, list(range(7)), scheme, dim)), p
+    )
+    plain = np.array([sum(int(v) for v in column) % p for column in secrets.T])
+    assert not np.array_equal(revealed, plain), "the clerks' sums carry no mask"
+    mask_sum = chacha_pallas.combine_masks_device(
+        np.asarray(seeds), dim, p, chunk=CHUNK // 2, backend="interpret"
+    )
+    assert compacted("interpret") == before + CHUNK + CHUNK // 2  # the fold is traced once
+    assert compacted("jnp") == twin_before
+    assert np.array_equal(mask_sum, reference_chacha.mask_sum(np.asarray(seeds), dim, p))
+    assert np.array_equal((revealed - np.asarray(mask_sum)) % p, plain)
 
 
 def test_masked_entry_refuses_what_it_cannot_mask(field):
@@ -176,12 +228,6 @@ def test_slack_check_fires_on_a_short_window(field, monkeypatch):
     fn = masked.masked_chunk(value_limb_sums_chunk, plan, masking, backend="jnp")
     _acc, seeds, counts = fn(jnp.asarray(secrets_of(1, p, CHUNK)), jax.random.key(2))
     assert int(jnp.min(counts)) < DIM
-
-    def ticks(name, **labels):
-        return sum(
-            c["value"] for c in telemetry.snapshot()["counters"]
-            if c["name"] == name and all(c["labels"].get(k) == v for k, v in labels.items())
-        )
 
     before = ticks("sda_mask_slack_exhausted_total", side="participant")
     rows_before = ticks("sda_fabric_masked_rows_total")

@@ -282,6 +282,104 @@ def test_chacha_batch_expand_high_rejection_modulus():
     np.testing.assert_array_equal(got, want)
 
 
+#: the masked cell's prime (benchmark/configs/c5-w61-d100k-chacha.json): 1/16 of the draws rejected
+CELL_PRIME = int(find_packed_parameters(5, 2, 8, min_modulus_bits=60, seed=0)[0])
+
+#: (modulus, dim, rows, pairs, lanes): ``pairs`` cuts the keystream window by
+#: hand (None: ``_window_pairs``'); a window is whole blocks, eight pairs each.
+#: ``lanes`` is the kernel's loop step (None: the module's, one step a stage
+#: at these sizes): a vreg's width makes a stage walk a row in several steps,
+#: in place, as it does at a deployment's width
+COMPACTION_CASES = {
+    "cell-prime-7-rows": (CELL_PRIME, 300, 7, None, 128),
+    "cell-prime-9-rows": (CELL_PRIME, 400, 9, None, 256),
+    "cell-prime-1-row": (CELL_PRIME, 200, 1, None, None),
+    "31-bit-prime-dim-not-a-lane-multiple": ((1 << 31) - 1, 257, 8, None, 128),
+    "31-bit-prime-dim-under-128": ((1 << 31) - 1, 100, 7, 160, None),
+    "power-of-two-dim-under-128": (1 << 32, 127, 8, 168, 128),
+    "2^63-half-rejected": (1 << 63, 130, 9, None, 128),
+    "2^63-dim-under-128-1-row": (1 << 63, 100, 1, None, None),
+    "max-shift-a-power-of-two": (CELL_PRIME, 192, 8, 256, 128),
+    "max-shift-one-less": (CELL_PRIME, 193, 9, 256, None),
+    "short-rows-window-cut": (CELL_PRIME, 300, 9, 312, 128),
+    "short-rows-half-rejected": (1 << 63, 150, 7, 256, None),
+    "window-under-a-lane-tile-takes-the-twin": (CELL_PRIME, 64, 8, None, None),
+}
+
+
+def compaction_ticks(path):
+    from sda_tpu import telemetry
+
+    return sum(
+        c["value"] for c in telemetry.snapshot()["counters"]
+        if c["name"] == "sda_crypto_chacha_compactions_total" and c["labels"].get("path") == path
+    )
+
+
+@pytest.mark.parametrize("case", COMPACTION_CASES)
+def test_compaction_kernel_is_the_stages_bit_for_bit(case, monkeypatch):
+    """The kernel ``chacha_compact`` (its source, on the interpreter) against
+    ``_first_accepted`` and the plain definition, on a real keystream with a
+    row that rejects nothing and one that accepts nothing put in by hand; then
+    the whole expansion by ``interpret`` against ``jnp`` and the host's
+    ``expand_seed``, masks and counts, rows that come short among them (their
+    kept draws intact, zeros after); and the counter says which path ran."""
+    import jax.numpy as jnp
+
+    from sda_tpu.ops import chacha_pallas as cp
+
+    modulus, dim, rows, pairs, lanes = COMPACTION_CASES[case]
+    if pairs is not None:
+        monkeypatch.setattr(cp, "_window_pairs", lambda dim, modulus: pairs)
+    if lanes is not None:
+        monkeypatch.setattr(cp, "_COMPACT_LANES", lanes)
+    n_blocks = (cp._window_pairs(dim, modulus) * 2 + 15) // 16
+    window, zone = n_blocks * 8, chacha.rand03_zone(modulus)
+    rng = np.random.default_rng(sorted(COMPACTION_CASES).index(case))
+    seeds = rng.integers(0, 1 << 32, size=(rows, 4), dtype=np.uint64).astype(np.uint32)
+    words = np.stack([chacha.chacha_blocks(s, 0, n_blocks).reshape(-1) for s in seeds])
+    hi, lo = words[:, 0::2], words[:, 1::2]
+    ok = ((hi.astype(np.uint64) << np.uint64(32)) | lo) < np.uint64(zone)
+    counts = ok.sum(axis=1)
+
+    # the kernel alone: at any shape, whatever the dispatcher would choose
+    forced = ok.copy()
+    forced[0] = True  # a row with no rejected draw
+    if rows > 2:
+        forced[2] = False  # and one with no draw at all
+    want_hi, want_lo = np.zeros((2, rows, dim), np.uint32)
+    for r in range(rows):
+        kept = np.flatnonzero(forced[r])
+        before = kept - np.arange(len(kept))  # rejected draws before each
+        kept = kept[before <= window - dim][:dim]
+        want_hi[r, : len(kept)], want_lo[r, : len(kept)] = hi[r, kept], lo[r, kept]
+    args = jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(forced)
+    twin = cp._first_accepted(*args, dim)
+    kernel = cp._compact_pallas(*args, dim, interpret=True)
+    for got in (twin, kernel):
+        assert got[0].shape == (rows, dim) and got[0].dtype == jnp.uint32
+        assert np.array_equal(got[0], want_hi) and np.array_equal(got[1], want_lo)
+
+    # the whole expansion, by the path the shape takes
+    path = "interpret" if cp._compact_fits(window, dim) else "jnp"
+    assert (path == "jnp") == ("takes-the-twin" in case)
+    before = compaction_ticks(path)
+    masks, got_counts = cp.expand_seeds_counts(jnp.asarray(seeds), dim, modulus, "interpret")
+    assert compaction_ticks(path) == before + rows
+    twin_masks, twin_counts = cp.expand_seeds_counts(jnp.asarray(seeds), dim, modulus, "jnp")
+    assert np.array_equal(masks, twin_masks) and masks.dtype == jnp.int64
+    assert np.array_equal(got_counts, counts) and np.array_equal(twin_counts, counts)
+    assert (int(counts.min()) < dim) == ("short-rows" in case), counts
+    for r in range(rows):
+        host = chacha.expand_seed(seeds[r], dim, modulus)
+        if counts[r] >= dim:
+            assert np.array_equal(masks[r], host), r
+            continue
+        # draws pushed out of a short row are let go: those kept are a prefix
+        kept = int(np.count_nonzero(np.flatnonzero(ok[r]) - np.arange(counts[r]) <= window - dim))
+        assert np.array_equal(masks[r, :kept], host[:kept]) and not np.any(masks[r, kept:]), r
+
+
 def test_verify_scheme_accepts_valid_and_rejects_degenerate(monkeypatch):
     """verify_scheme proves t-privacy (every t-subset of share rows fully
     randomized) and universal reconstruction for real schemes, and flags a
