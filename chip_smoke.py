@@ -366,58 +366,41 @@ def _check_reveal(leg: str, clerk_sums, scheme, secrets) -> None:
 
 
 def fold_engine(engine: str, *, dim: int, chunk: int):
-    """One engine of the fabric leg, paired with its accumulate rule and its
-    epilogue as its traffic file pairs them (``benchmark/traffic/``):
-    ``sumfirst`` as ``sumfirst-wide.json`` (61-bit, ``+``),
-    ``participant`` as ``participant-narrow.json`` (31-bit, ``+`` then
-    ``rem p``), ``participant+pallas`` the same round on the fused kernel.
-    Two chunks of seeded input through the entry point with the program's
-    default draw, the epilogue, the reveal compared. Returns the
-    accumulator."""
+    """One engine of the fabric leg through the program's round driver
+    (``sda_tpu.parallel.fold_round``), which pairs the entry with its
+    accumulate rule and its epilogue as the cells' traffic files do:
+    ``sumfirst`` as ``sumfirst-wide.json`` (61-bit, ``+``), ``participant`` as
+    ``participant-narrow.json`` (31-bit, ``+`` then ``rem p``),
+    ``participant+pallas`` the same round on the fused kernel. Two chunks of
+    seeded host rows through the driver's feed with the program's default
+    draw, the epilogue, the reveal compared. Returns the accumulator."""
     import functools
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
-    from jax import lax
 
-    from sda_tpu.ops.jaxcfg import ensure_x64
     from sda_tpu.parallel import engine as engine_mod
-    from sda_tpu.parallel import limb_pallas, limbmatmul, sumfirst
+    from sda_tpu.parallel import fold_round, limb_pallas, sumfirst
 
-    ensure_x64()
     t0 = time.perf_counter()
-    # field width, input lanes, accumulate ``+`` then ``rem p``, chunk entry;
-    # the Pallas kernel never decides by itself to interpret: its caller does
+    # field width and chunk entry; the Pallas kernel never decides by itself
+    # to interpret: its caller does
     fused = functools.partial(
         limb_pallas.share_combine_limb_pallas,
         interpret=jax.default_backend() != "tpu",
     )
-    bits, dtype, mod_p, entry = {
-        "sumfirst": (60, np.int64, False, sumfirst.value_limb_sums_chunk),
-        "participant": (30, np.int32, True, engine_mod.share_combine_limb),
-        "participant+pallas": (30, np.int32, True, fused),
+    bits, entry = {
+        "sumfirst": (60, sumfirst.value_limb_sums_chunk),
+        "participant": (30, engine_mod.share_combine_limb),
+        "participant+pallas": (30, fused),
     }[engine]
     scheme = _scheme(bits)
     p = scheme.prime_modulus
-    plan = engine_mod.make_plan(scheme, dim)
-
-    def epilogue(acc):  # -> (n, B) clerk sums
-        if engine == "sumfirst":
-            return sumfirst.clerk_sums_from_limb_acc(acc, plan)[0]
-        return limbmatmul.limb_recombine_host(acc, p).T
-
+    driver = fold_round(scheme, dim, entry, chunk)
     secrets = np.random.default_rng(23).integers(0, p, size=(2 * chunk, dim))
-    step = jax.jit(lambda rows, key: entry(rows, key, plan))
-    key = jax.random.key(7)
-    acc = 0
-    for i in range(2):
-        rows = jnp.asarray(secrets[i * chunk : (i + 1) * chunk].astype(dtype))
-        acc = acc + step(rows, jax.random.fold_in(key, i))
-        if mod_p:
-            acc = lax.rem(acc, jnp.int64(p))
-    acc = np.asarray(acc)
-    _check_reveal(f"fabric leg, {engine}", epilogue(acc), scheme, secrets)
+    blocks = [secrets[i * chunk : (i + 1) * chunk].astype(driver.input_dtype) for i in range(2)]
+    acc = np.asarray(driver.fold_host_rows(blocks, jax.random.key(7), in_flight=2))
+    _check_reveal(f"fabric leg, {engine}", driver.clerk_sums(acc), scheme, secrets)
     say(
         f"fabric leg ok: {engine} {p.bit_length()}-bit, {2 * chunk} rows x dim "
         f"{dim} in 2 chunks, default draw, reveal from 7 of 8 clerks exact "

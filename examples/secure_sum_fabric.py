@@ -8,9 +8,11 @@ Three stages, each verified against an independent plaintext sum:
    materialized on device (MXU int8-limb matmuls), clerk-combined,
    reconstructed;
 2. sum-first streaming — share linearity (`share(Σv) = Σ share(v)`)
-   reduces the hot loop to one exact limb-space integer reduction; a
-   clerk row is corrupted and DROPPED to show t+k-of-n reconstruction
-   never reads it;
+   reduces the hot loop to one exact limb-space integer reduction; the
+   round driver (`sda_tpu.parallel.fold_round`) feeds host blocks to the
+   device with a bounded number in flight and pairs the chunk entry with
+   its epilogue; a clerk row is corrupted and DROPPED to show t+k-of-n
+   reconstruction never reads it;
 3. the sharded fabric — the same sum-first loop over a device Mesh
    (participants sharded over axis ``p``, dims over ``d``), one int64
    ``psum`` carrying the tiny accumulator across the mesh.
@@ -49,8 +51,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sda_tpu.ops.modular import positive
-from sda_tpu.parallel import TpuAggregator
-from sda_tpu.parallel.engine import make_plan
+from sda_tpu.parallel import TpuAggregator, fold_round
 from sda_tpu.parallel.sumfirst import (
     clerk_sums_from_limb_acc,
     reconstruct_from_clerk_sums,
@@ -81,20 +82,20 @@ def main():
     print(f"1. single-device secure sum OK: {participants} x {dim}, p={p}")
 
     # --- 2. sum-first streaming + clerk dropout -------------------------
-    plan = make_plan(scheme, dim)
-    key = jax.random.key(2)
-    acc, plain = None, np.zeros(dim, dtype=np.int64)
-    for start in range(0, 2_048, 512):  # four streamed chunks
-        chunk = rng.integers(0, p, size=(512, dim))
-        key, sub = jax.random.split(key)
-        a = np.asarray(value_limb_sums_chunk(jnp.asarray(chunk), sub, plan))
-        acc = a if acc is None else acc + a
-        plain += chunk.sum(axis=0)
-    clerk_sums, _ = clerk_sums_from_limb_acc(acc, plan)
+    driver = fold_round(scheme, dim, value_limb_sums_chunk, 512)
+    plain = np.zeros(dim, dtype=np.int64)
+
+    def streamed():  # four host blocks, each made as the feed asks for it
+        for _ in range(0, 2_048, 512):
+            block = rng.integers(0, p, size=(512, dim))
+            np.add(plain, block.sum(axis=0), out=plain)
+            yield block.astype(driver.input_dtype)
+
+    acc = driver.fold_host_rows(streamed(), jax.random.key(2), in_flight=2)
+    clerk_sums = driver.clerk_sums(acc)
     clerk_sums[3] = -7  # corrupt the dropped clerk: must never be read
     survivors = [i for i in range(n) if i != 3][: scheme.reconstruction_threshold]
-    out = reconstruct_from_clerk_sums(clerk_sums, survivors, scheme, dim)
-    assert np.array_equal(positive(np.asarray(out), p), plain % p)
+    assert np.array_equal(driver.reveal(clerk_sums, survivors), plain % p)
     print(f"2. sum-first stream OK: 2048 participants, clerk 3 dropped, "
           f"reconstructed from {len(survivors)} of {n} clerk sums")
 
@@ -106,6 +107,7 @@ def main():
     p_size = min(4, len(devs) // d_size)
     devices = np.array(devs[: p_size * d_size]).reshape(p_size, d_size)
     mesh = Mesh(devices, axis_names=("p", "d"))
+    plan = driver.plan
     fabric = sharded_value_limb_sums(plan, mesh)
     shard = rng.integers(0, p, size=(1_024, dim))
     sharded = jax.device_put(

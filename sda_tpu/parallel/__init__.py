@@ -1,11 +1,12 @@
 """sda_tpu.parallel — the TPU aggregation fabric.
 
-Mesh sharding, the end-to-end ``TpuAggregator`` engine, and the int8-limb
-MXU mod-p matmul.
+Mesh sharding, the end-to-end ``TpuAggregator`` engine, the int8-limb MXU
+mod-p matmul, and the round driver with its host feed (``round.py``).
 """
 
 from .engine import AggregationPlan, TpuAggregator, full_training_step, make_plan
 from .mesh import make_mesh, shard_participants
+from .round import FoldRound, fold_round
 from .sumfirst import clerk_sums_sum_first, sharded_value_limb_sums
 
 __all__ = [
@@ -15,6 +16,8 @@ __all__ = [
     "full_training_step",
     "make_mesh",
     "shard_participants",
+    "FoldRound",
+    "fold_round",
     "clerk_sums_sum_first",
     "sharded_value_limb_sums",
 ]

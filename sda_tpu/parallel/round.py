@@ -1,0 +1,290 @@
+"""The round driver: one object that carries a fold round's chunk step, its
+accumulate rule, its host epilogue and its reveal — and the host feed, which
+brings a round's rows from host memory while the chip folds them.
+
+A fabric round is ``accumulator -> chunk step over every chunk -> host
+epilogue to clerk sums -> reveal from a subset of clerks``. Which accumulate
+rule and which epilogue belong to a chunk entry is a property of the entry:
+
+==========================================  ============  ==================================
+chunk entry ``entry(secrets, key, plan)``   accumulate    host epilogue to ``(n, B)``
+==========================================  ============  ==================================
+``sumfirst.value_limb_sums_chunk``          ``sum``       ``sumfirst.clerk_sums_from_limb_acc``
+``engine.share_combine_limb``               ``sum_mod_p`` ``limbmatmul.limb_recombine_host``
+``limb_pallas.share_combine_limb_pallas``   ``sum_mod_p`` ``limbmatmul.limb_recombine_host``
+==========================================  ============  ==================================
+
+``sum``: exact integer limb sums, added with ``+`` (``sumfirst``'s bound of
+2³¹ participants a round); ``sum_mod_p``: ``+`` then ``rem p``, so that the
+partials stay below p. :func:`fold_round` looks the pair up from the entry
+(a ``functools.partial`` of an entry is that entry), so no caller pairs them
+by hand. The driver adds no arithmetic: its step is the entry on the round's
+key with the step's number folded in, then the accumulate rule.
+
+**The feed** (:meth:`FoldRound.fold_host_rows`). A cohort that does not fit
+the chip's memory sits in host memory and crosses the host link every round.
+The feed takes the round's rows as host blocks ``(block_rows, dim)``,
+``block_rows`` a multiple of the chunk, and puts each block on the device
+chunk by chunk: one ``jax.device_put`` of a ``(chunk, dim)`` row slice (a view
+of the host block: no host copy, and no slicing program on the device), the
+chunk's step dispatched behind it at once. Transfers and steps are
+asynchronous: the link carries the next chunks while the chip folds the landed
+one, and the runtime frees a chunk when its step has run and the feed has let
+go of it. Two bounds hold the host back, and nothing else does:
+
+* **the caller's, on memory**: a block is *alive* from its first put until
+  the step that folds its last chunk has finished; before the first put of a
+  block that would make more than ``in_flight`` blocks alive, the host waits
+  for the oldest alive block's last step;
+* **the link's own** (:data:`LINK_BYTES`): a chunk is *crossing* from its put
+  until the feed has seen it landed; before a put that would make more than
+  ``LINK_BYTES`` cross at once, the host waits for the oldest crossing chunk
+  to land. The TPU runtime stages host transfers in a pinned pool of 4 GiB,
+  and a transfer issued when the pool cannot hold it takes another road at
+  0.2 GB/s instead of 10-12 (chip runs, PR 34: three 2.0e9 B blocks put back
+  to back take 9.7 s where two take 0.35). The link is as busy with three
+  chunks queued as with ten, so the bound keeps clear of the pool's edge.
+
+The feed keeps its reference to a chunk only while it is crossing. Nothing is
+kept from one call to the next: no block outlives the call, and nothing is
+keyed on a block's identity or content.
+
+Spans (``telemetry.span``, also a ``TraceAnnotation`` of the profiler's
+trace): ``fabric.feed.put``, one a ``device_put`` call (``rows``, ``bytes``);
+``fabric.feed.wait``, the host blocked on a bound (``on``: ``in_flight`` or
+``link``). Counters: ``sda_fabric_fed_blocks_total``,
+``sda_fabric_fed_rows_total``, ``sda_fabric_fed_bytes_total``; the gauge
+``sda_fabric_feed_in_flight_max``: the most blocks alive at once in the last
+call. The chunk step's device scopes (``fabric.input``, ``fabric.rand``) are
+the entry's own.
+"""
+
+from __future__ import annotations
+
+import collections
+import dataclasses
+
+import numpy as np
+
+from .. import telemetry
+from ..ops import shamir
+from ..ops.jaxcfg import ensure_x64
+from . import engine, limb_pallas, limbmatmul, sumfirst
+
+#: bytes the feed lets cross the host link at once (module doc): three quarters
+#: of the runtime's staging pool. Measured on a v5e (chip runs, PR 34; a round
+#: of 8.0e9 B, eight rounds a bound): 0.63 s at 1.2e9, 0.64 at 2.0e9, 0.65 at
+#: 3.2e9, none slow; at 4.0e9 one round in eight to twenty takes 2.3 s (a chunk
+#: off the pool). The most that stays clear of the pool's edge: the more is
+#: queued, the longer the host may be away before the link runs dry
+LINK_BYTES = 3_200_000_000
+
+
+class _Crossing:
+    """The chunks put on the device and not yet seen landed, oldest first,
+    held to ``limit`` bytes. Holds the feed's only reference to a chunk."""
+
+    def __init__(self, limit: int):
+        self.limit, self.nbytes, self.chunks = limit, 0, collections.deque()
+
+    def make_room(self, nbytes: int) -> None:
+        """Let go of the chunks that have landed, and wait for the oldest
+        others to land until ``nbytes`` more may cross."""
+        chunks = self.chunks
+        while chunks and (chunks[0].is_ready() or self.nbytes + nbytes > self.limit):
+            oldest = chunks.popleft()
+            if not oldest.is_ready():
+                with telemetry.span("fabric.feed.wait", on="link"):
+                    oldest.block_until_ready()
+            self.nbytes -= oldest.nbytes
+
+    def add(self, chunk) -> None:
+        self.chunks.append(chunk)
+        self.nbytes += chunk.nbytes
+
+
+def _limb_acc_epilogue(acc, plan):
+    return sumfirst.clerk_sums_from_limb_acc(acc, plan)[0]
+
+
+def _limb_recombine_epilogue(acc, plan):
+    return limbmatmul.limb_recombine_host(acc, plan.modulus).T
+
+
+#: a chunk entry's accumulate rule and its host epilogue (module doc)
+_PAIRED = {
+    sumfirst.value_limb_sums_chunk: ("sum", _limb_acc_epilogue),
+    engine.share_combine_limb: ("sum_mod_p", _limb_recombine_epilogue),
+    limb_pallas.share_combine_limb_pallas: ("sum_mod_p", _limb_recombine_epilogue),
+}
+
+
+def _input_dtype(modulus: int):
+    """int32 where every canonical value fits it, else int64: the type the
+    engines keep the big tensor in."""
+    return np.dtype(np.int32 if modulus <= (1 << 31) else np.int64)
+
+
+def _make_step(entry, plan, accumulate: str):
+    """The jitted ``step(acc, chunk, key, i) -> acc``."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    modulus = plan.modulus
+
+    def step(acc, chunk, key, i):
+        # one chunk step: the round's key with the step's number folded in,
+        # the entry's default share randomness, the accumulate rule
+        out = entry(chunk, jax.random.fold_in(key, i), plan)
+        acc = acc + out
+        if accumulate == "sum_mod_p":
+            acc = lax.rem(acc, jnp.int64(modulus))
+        return acc
+
+    return jax.jit(step)
+
+
+@dataclasses.dataclass(frozen=True)
+class FoldRound:
+    """One fold round of a scheme at a dim and a chunk size: the jitted chunk
+    step, the zero accumulator, the folds (of resident chunks, of host
+    blocks), the host epilogue and the reveal. Built by :func:`fold_round`;
+    holds no array."""
+
+    scheme: object
+    plan: engine.AggregationPlan
+    chunk: int  # rows a chunk step folds
+    entry: object  # entry(secrets, key, plan) -> accumulator, one chunk
+    accumulate: str  # "sum" | "sum_mod_p"
+    acc_shape: tuple  # of the int64 accumulator a step takes and hands on
+    step: object  # jitted step(acc, chunk, key, i) -> acc
+    epilogue: object  # fn(acc_host, plan) -> (n, B) clerk sums
+
+    @property
+    def modulus(self) -> int:
+        return self.plan.modulus
+
+    @property
+    def input_dtype(self):
+        return _input_dtype(self.modulus)
+
+    def zero_acc(self):
+        import jax.numpy as jnp
+
+        return jnp.zeros(self.acc_shape, jnp.int64)
+
+    def fold_chunks(self, chunks, key):
+        """The accumulator of ``chunks`` (``(chunk, dim)`` arrays, resident
+        or not), step ``i`` over the ``i``-th of them."""
+        acc = self.zero_acc()
+        for i, chunk in enumerate(chunks):
+            acc = self.step(acc, chunk, key, np.int32(i))
+        return acc
+
+    def fold_host_rows(self, blocks, key, *, in_flight: int):
+        """The accumulator of the rows of ``blocks``, host arrays ``(rows,
+        dim)`` of :attr:`input_dtype` with ``rows`` a multiple of
+        :attr:`chunk`, at most ``in_flight`` of them alive on the device at
+        once and at most :data:`LINK_BYTES` crossing the link (module doc).
+        Returns with the last transfers and steps still running: the caller's
+        ``block_until_ready`` on the accumulator is the wait for them. The
+        same accumulator as :meth:`fold_chunks` over the same rows in the same
+        order."""
+        import jax
+
+        if in_flight < 1:
+            raise ValueError("in_flight counts blocks: at least 1")
+        fed_blocks = telemetry.counter(
+            "sda_fabric_fed_blocks_total", "host blocks the feed put on the device"
+        )
+        fed_rows = telemetry.counter("sda_fabric_fed_rows_total", "rows the feed put on the device")
+        fed_bytes = telemetry.counter(
+            "sda_fabric_fed_bytes_total", "bytes the feed put on the device"
+        )
+        acc = self.zero_acc()
+        # the accumulator after each alive block's last step, oldest first:
+        # when it is ready, that block's chunks have been folded
+        alive = collections.deque()
+        crossing = _Crossing(LINK_BYTES)
+        steps = most = 0
+        for block in blocks:
+            block = self._checked(block)
+            while len(alive) >= in_flight:
+                with telemetry.span("fabric.feed.wait", on="in_flight"):
+                    alive.popleft().block_until_ready()
+            most = max(most, len(alive) + 1)
+            for start in range(0, block.shape[0], self.chunk):
+                rows = block[start : start + self.chunk]
+                crossing.make_room(rows.nbytes)
+                with telemetry.span("fabric.feed.put", rows=self.chunk, bytes=rows.nbytes):
+                    chunk = jax.device_put(rows)
+                crossing.add(chunk)
+                acc = self.step(acc, chunk, key, np.int32(steps))
+                del chunk  # the feed's reference is the crossing's alone
+                steps += 1
+            fed_blocks.inc()
+            fed_rows.inc(block.shape[0])
+            fed_bytes.inc(block.nbytes)
+            alive.append(acc)
+        telemetry.gauge(
+            "sda_fabric_feed_in_flight_max", "most blocks alive at once in the feed's last call"
+        ).set(most)
+        return acc
+
+    def _checked(self, block):
+        block = np.asarray(block)
+        if block.ndim != 2 or block.shape[1] != self.plan.dim:
+            raise ValueError(f"a block is (rows, {self.plan.dim}), not {block.shape}")
+        if block.shape[0] == 0 or block.shape[0] % self.chunk:
+            raise ValueError(
+                f"a block's rows are a positive multiple of the chunk, {self.chunk}: "
+                f"not {block.shape[0]}"
+            )
+        if block.dtype != self.input_dtype:
+            raise ValueError(f"a block is {self.input_dtype}, not {block.dtype}")
+        return block
+
+    def clerk_sums(self, acc):
+        """Host epilogue: the (fetched) accumulator -> ``(n, B)`` int64
+        canonical clerk sums."""
+        return np.asarray(self.epilogue(np.asarray(acc), self.plan))
+
+    def reveal(self, clerk_sums, clerks):
+        """The ``(dim,)`` canonical int64 aggregate from the sums of the
+        0-based ``clerks`` (at least ``reconstruction_threshold`` of them)."""
+        out = shamir.reconstruct_clerk_sums_host(clerk_sums, clerks, self.scheme, self.plan.dim)
+        return np.mod(np.asarray(out).astype(np.int64), self.modulus)
+
+
+def fold_round(scheme, dim: int, entry, chunk: int) -> FoldRound:
+    """The round of ``scheme`` at ``dim`` through the chunk entry ``entry``
+    (``entry(secrets, key, plan) -> accumulator``, one of the module doc's
+    table, or a ``functools.partial`` of one), ``chunk`` rows a step."""
+    ensure_x64()
+    paired = _PAIRED.get(getattr(entry, "func", entry))
+    if paired is None:
+        raise ValueError(
+            f"no accumulate rule and epilogue are known for the chunk entry {entry!r}"
+        )
+    if chunk < 1:
+        raise ValueError("a chunk holds at least one row")
+    import jax
+
+    accumulate, epilogue = paired
+    plan = engine.make_plan(scheme, dim)
+    acc = jax.eval_shape(
+        lambda rows, key: entry(rows, key, plan),
+        jax.ShapeDtypeStruct((chunk, dim), _input_dtype(plan.modulus)),
+        jax.eval_shape(lambda: jax.random.key(0)),
+    )
+    return FoldRound(
+        scheme=scheme,
+        plan=plan,
+        chunk=int(chunk),
+        entry=entry,
+        accumulate=accumulate,
+        acc_shape=tuple(acc.shape),
+        step=_make_step(entry, plan, accumulate),
+        epilogue=epilogue,
+    )
