@@ -405,6 +405,8 @@ def expand_seeds_counts(seed_words, dim: int, modulus: int, backend: str = "jnp"
     import jax
     import jax.numpy as jnp
 
+    from .modular import mod_u64_const
+
     seed_words = jnp.asarray(seed_words, dtype=jnp.uint32)
     P = seed_words.shape[0]
     if P == 0:
@@ -422,8 +424,8 @@ def expand_seeds_counts(seed_words, dim: int, modulus: int, backend: str = "jnp"
     ok = (hi < zone_hi) | ((hi == zone_hi) & (lo < zone_lo))
     counts = jnp.sum(ok, axis=1).astype(jnp.int32)
     hi, lo = _compact(hi, lo, ok, dim, backend)
-    compact = (hi.astype(jnp.uint64) << jnp.uint64(32)) | lo.astype(jnp.uint64)
-    masks = (compact % jnp.uint64(modulus)).astype(jnp.int64)
+    # the accepted draws mod p, still as word pairs: no division on the device
+    masks = mod_u64_const(hi, lo, modulus).astype(jnp.int64)
     return masks, counts
 
 

@@ -354,13 +354,16 @@ def mod_sum_jnp(x, m, axis):
 
 
 def mod_sum_wide_jnp(x, m, axis: int = 0):
-    """Device halving sum-mod-m along ``axis``; exact for m < 2**62.
+    """Device halving sum-mod-m along ``axis``; exact for ``|x| < m < 2**62``.
 
     Static log2 unrolled pairing (jit-friendly): pads to a power of two
-    with zeros, pair sums stay within int64.
+    with zeros, pair sums stay within int64. A pair sum lies inside
+    ``(-2m, 2m)``, so its truncated remainder is the sum less m where it
+    reaches m and plus m where it reaches -m: what ``lax.rem`` gives, sign
+    for sign, without the 64-step division a chip with no integer divide
+    makes of it.
     """
     import jax.numpy as jnp
-    from jax import lax
 
     from .jaxcfg import ensure_x64
 
@@ -374,7 +377,8 @@ def mod_sum_wide_jnp(x, m, axis: int = 0):
     mm = jnp.int64(m)
     for _ in range(levels):
         half = x.shape[0] // 2
-        x = lax.rem(x[:half] + x[half:], mm)
+        s = x[:half] + x[half:]
+        x = jnp.where(s >= mm, s - mm, jnp.where(s <= -mm, s + mm, s))
     return x[0]
 
 
