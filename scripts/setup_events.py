@@ -1,31 +1,29 @@
 #!/usr/bin/env python3
-"""What one run of ``benchmark/run.py`` traces, lowers, compiles and loads,
-by JAX's own monitoring events and its compiler's log: every event's count
-and seconds by name, and every program that asked the persistent compile
-cache, in order, with ``hit`` or ``miss`` and its key. The harness is not
-edited: the listeners are registered here, then the tree's ``run.py`` runs as
-``__main__`` in this process.
+"""Where one run of ``benchmark/run.py`` spends its set-up and its window, by
+the program's own timeline: the tree's ``run.py`` runs as ``__main__`` in this
+process; afterwards the program's span ring and counters (``telemetry.snapshot``:
+JAX's trace / lower / compile / load events by program name, the fabric's host
+spans) are cut by the run's own record into set-up and window. The compiler's
+log adds what no event says: every program that asked the persistent compile
+cache, in order, with ``hit`` or ``miss`` and its key.
 
     python scripts/setup_events.py [--tree <checkout>] [--tag <t>] -- \
         --workload c5-masked --seed 7 --seconds 5 --trace 0
 
-An untraced run compiles nothing in or after its window (``compiles_in_window``
-is held to 0), so what is counted is set-up's. Importing jax before ``run.py``
-moves the import out of ``to_harness``: read counts and programs here, and
-seconds from plain runs. Writes ``chiprun_out/setup-events-<tag>.json``; the
-run's own line passes through on stdout.
+Writes ``chiprun_out/timeline-<tag>.json`` (the snapshot, ``programs``, ``run``
+and the two ``reports`` with their intervals; ``scripts/trace_report.py`` reads
+it), prints the reports on stderr, lets the run's own line through on stdout.
+A tree from before the program kept a timeline gets ``programs`` alone.
 """
 
-from __future__ import annotations
-
 import argparse
-import collections
 import json
 import logging
 import os
 import pathlib
 import runpy
 import sys
+import time
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
 
@@ -53,20 +51,8 @@ def main() -> int:
     args = ap.parse_args()
     tree = pathlib.Path(args.tree).resolve()
     rest = args.rest[1:] if args.rest[:1] == ["--"] else args.rest
+    said = dict(zip(rest[::2], rest[1::2]))
 
-    import jax.monitoring
-
-    events = collections.defaultdict(lambda: [0, 0.0])
-
-    def on_duration(name, seconds, **_kw):
-        events[name][0] += 1
-        events[name][1] += seconds
-
-    def on_event(name, **_kw):
-        events[name][0] += 1
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    jax.monitoring.register_event_listener(on_event)
     programs = _Programs()
     compiler_log = logging.getLogger("jax._src.compiler")
     compiler_log.setLevel(logging.DEBUG)
@@ -75,24 +61,37 @@ def main() -> int:
 
     os.chdir(tree)
     sys.argv = [str(tree / "benchmark" / "run.py"), *rest]
+    started = time.perf_counter()
     try:
         runpy.run_path(sys.argv[0], run_name="__main__")
         rc = 0
     except SystemExit as e:
         rc = e.code if isinstance(e.code, int) else int(bool(e.code))
 
-    out = {
-        "tree": str(tree),
-        "argv": rest,
-        "rc": rc,
-        "events": {k: {"count": c, "seconds": s} for k, (c, s) in sorted(events.items())},
-        "programs": programs.rows,
-        "hits": sum(kind == "hit" for _n, kind, _k in programs.rows),
-        "misses": sum(kind == "miss" for _n, kind, _k in programs.rows),
-    }
+    out = {"programs": programs.rows, "run": {"tree": str(tree), "argv": rest, "rc": rc}}
+    if rc == 0:
+        from sda_tpu.telemetry import flight  # the tree's own program, as run.py imported it
+    if rc == 0 and hasattr(flight, "interval_report"):
+        import sda_tpu.telemetry as telemetry
+        from trace_report import print_interval  # beside this script
+
+        record = "benchmark/out/rounds-{--workload}-seed{--seed}-trace{--trace}.json"
+        rec = json.loads((tree / record.format(**said)).read_text())
+        first = started + rec["setup_s"]
+        each = zip(rec["round_start_s_each"], rec["round_s_each"])
+        rounds = [[first + at, first + at + took] for at, took in each]
+        out.update(telemetry.snapshot(telemetry.RING_RECORDS))
+        out["reports"] = {
+            "setup": flight.interval_report(out["spans"], started, first),
+            "window": flight.interval_report(
+                out["spans"], first, first + rec["window_s"], rounds=rounds
+            ),
+        }
+        for title, report in out["reports"].items():
+            print_interval(f"{said['--workload']} {title}", report, file=sys.stderr)
     os.makedirs(REPO / "chiprun_out", exist_ok=True)
-    tag = args.tag or tree.name
-    (REPO / "chiprun_out" / f"setup-events-{tag}.json").write_text(json.dumps(out, indent=1))
+    dump = REPO / "chiprun_out" / f"timeline-{args.tag or tree.name}.json"
+    dump.write_text(json.dumps(out, indent=1))
     return rc
 
 

@@ -2,9 +2,13 @@
 that has not imported JAX, in one that has (an open span is then also a
 ``jax.profiler.TraceAnnotation``), in one that is taking a profile, and with
 ``SDA_TELEMETRY=0``; beside each, the annotation alone, the part that the
-profiler's clock costs. One JSON line. ``sda_tpu`` is taken from
-``PYTHONPATH``, so that the same file measures another checkout (one from
-before the annotation reports the span alone):
+profiler's clock costs. Then what the timeline costs: one call of the listener
+on JAX's events (a traced jaxpr under the record's floor, the six thousand a
+set-up; one that is kept; with telemetry off), and ``telemetry.snapshot`` of a
+full ring, what a reader pays once after the window. One JSON line. ``sda_tpu``
+is taken from ``PYTHONPATH``, so that the same file measures another checkout
+(one from before the annotation reports the span alone, one from before the
+listener no listener):
 
     PYTHONPATH=. python scripts/span_cost.py
     PYTHONPATH=.archive_tree/parent python scripts/span_cost.py
@@ -37,6 +41,27 @@ def one_annotation():
         pass
 
 
+def listener_and_snapshot() -> dict:
+    """Run last: it fills the ring."""
+    out = {}
+    ring = getattr(telemetry, "RING_RECORDS", 4096)
+    for _ in range(ring):
+        one_span()
+    took = timeit.repeat(lambda: telemetry.snapshot(ring), number=5, repeat=5)
+    out["snapshot_full_ring"] = min(took) / 5 * 1e9
+    try:
+        events = importlib.import_module("sda_tpu.telemetry.jaxevents")
+    except ImportError:
+        return out
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    out["listener_counted"] = least_ns(lambda: events._on_duration(trace, 1e-5, fun_name="f"))
+    out["listener_kept"] = least_ns(lambda: events._on_duration(trace, 1e-2, fun_name="f"))
+    telemetry.set_enabled(False)
+    out["listener_telemetry_off"] = least_ns(lambda: events._on_duration(trace, 1e-2, fun_name="f"))
+    telemetry.set_enabled(True)
+    return out
+
+
 def measure() -> dict:
     out = {"span": least_ns(one_span)}
     if hasattr(spans, "_profiler_annotation"):
@@ -61,6 +86,8 @@ def main() -> int:
             jax.profiler.stop_trace()
     telemetry.set_enabled(False)
     out["telemetry_off"] = {"span": least_ns(one_span)}
+    telemetry.set_enabled(True)
+    out["timeline"] = listener_and_snapshot()
     print(json.dumps({"ns": out, "jax": jax.__version__}))
     return 0
 

@@ -3,7 +3,9 @@
 The math plane works in int64 (moduli up to 61 bits); JAX defaults to 32-bit,
 so every module that touches jax calls ``ensure_x64()`` before tracing. The
 same once-per-process initialisation places the persistent compilation
-cache, so no entry point can forget it.
+cache and puts telemetry's listeners on JAX's own trace / lower / compile /
+cache-load events (``telemetry/jaxevents.py``), so no entry point can forget
+either: what a program costs before its first dispatch is counted from here on.
 """
 
 from __future__ import annotations
@@ -32,8 +34,11 @@ def ensure_x64() -> None:
         return
     import jax
 
+    from ..telemetry import jaxevents
+
     jax.config.update("jax_enable_x64", True)
     _place_compilation_cache()
+    jaxevents.register()
     _done = True
 
 

@@ -50,9 +50,12 @@ kept from one call to the next: no block outlives the call, and nothing is
 keyed on a block's identity or content.
 
 Spans (``telemetry.span``, also a ``TraceAnnotation`` of the profiler's
-trace): ``fabric.feed.put``, one a ``device_put`` call (``rows``, ``bytes``);
-``fabric.feed.wait``, the host blocked on a bound (``on``: ``in_flight`` or
-``link``). Counters: ``sda_fabric_fed_blocks_total``,
+trace): ``fabric.feed``, one a call of the feed (``in_flight``, and ``bytes``,
+what the call put); inside it by time ``fabric.feed.put``, one a
+``device_put`` call (``rows``, ``bytes``), and ``fabric.feed.wait``, the host
+blocked on a bound (``on``: ``in_flight`` or ``link``). The call less its puts
+and waits is the host's own seconds: the slicing and the step dispatches.
+Counters: ``sda_fabric_fed_blocks_total``,
 ``sda_fabric_fed_rows_total``, ``sda_fabric_fed_bytes_total``; the gauge
 ``sda_fabric_feed_in_flight_max``: the most blocks alive at once in the last
 call. The chunk step's device scopes (``fabric.input``, ``fabric.rand``) are
@@ -191,10 +194,20 @@ class FoldRound:
         ``block_until_ready`` on the accumulator is the wait for them. The
         same accumulator as :meth:`fold_chunks` over the same rows in the same
         order."""
-        import jax
-
         if in_flight < 1:
             raise ValueError("in_flight counts blocks: at least 1")
+        # the program's own `dispatch`: the puts and the waits nest inside it
+        # by time, and what is left of it is the host's own seconds
+        with telemetry.span("fabric.feed", in_flight=in_flight) as call:
+            acc, nbytes = self._feed(blocks, key, in_flight)
+            if call is not None:  # telemetry is on
+                call["attrs"]["bytes"] = nbytes
+        return acc
+
+    def _feed(self, blocks, key, in_flight: int):
+        """:meth:`fold_host_rows`' accumulator, and the bytes it put."""
+        import jax
+
         fed_blocks = telemetry.counter(
             "sda_fabric_fed_blocks_total", "host blocks the feed put on the device"
         )
@@ -207,7 +220,7 @@ class FoldRound:
         # when it is ready, that block's chunks have been folded
         alive = collections.deque()
         crossing = _Crossing(LINK_BYTES)
-        steps = most = 0
+        steps = most = nbytes = 0
         for block in blocks:
             block = self._checked(block)
             while len(alive) >= in_flight:
@@ -226,11 +239,12 @@ class FoldRound:
             fed_blocks.inc()
             fed_rows.inc(block.shape[0])
             fed_bytes.inc(block.nbytes)
+            nbytes += block.nbytes
             alive.append(acc)
         telemetry.gauge(
             "sda_fabric_feed_in_flight_max", "most blocks alive at once in the feed's last call"
         ).set(most)
-        return acc
+        return acc, nbytes
 
     def _checked(self, block):
         block = np.asarray(block)

@@ -27,6 +27,7 @@ from .prom import CONTENT_TYPE as PROMETHEUS_CONTENT_TYPE
 from .prom import render as render_prometheus
 from .registry import DEFAULT_BUCKETS, Counter, Gauge, Histogram, Registry
 from .spans import (
+    RING_RECORDS,
     TRACE_HEADER,
     SpanLog,
     current_trace_id,
@@ -72,13 +73,23 @@ def span(name: str, **attrs):
     return _SPANS.span(name, **attrs)
 
 
-def spans(name: str | None = None, trace_id: str | None = None) -> list:
-    return _SPANS.recent(name=name, trace_id=trace_id)
+def spans(
+    name: str | None = None,
+    trace_id: str | None = None,
+    since_mono: float | None = None,
+    until_mono: float | None = None,
+) -> list:
+    """Finished span records, oldest first: by name prefix, by trace id, and
+    by the interval of ``time.perf_counter()`` they start in."""
+    return _SPANS.recent(
+        name=name, trace_id=trace_id, since_mono=since_mono, until_mono=until_mono
+    )
 
 
 def snapshot(include_spans: int = 200) -> dict:
     """JSON-ready merged view: all series, metadata, and the newest
-    ``include_spans`` span records."""
+    ``include_spans`` span records, each with its monotonic ``start_mono``
+    (``RING_RECORDS`` or more: the whole ring)."""
     snap = _REGISTRY.snapshot()
     out = {
         "enabled": _REGISTRY.enabled,
@@ -121,6 +132,7 @@ __all__ = [
     "read_rss_mib",
     "DEFAULT_BUCKETS",
     "PROMETHEUS_CONTENT_TYPE",
+    "RING_RECORDS",
     "TRACE_HEADER",
     "counter",
     "gauge",

@@ -7,13 +7,20 @@ every ``span()`` recorded below — service, stores, crypto — carries it.
 Propagation rides a ``contextvars.ContextVar``, so it is correct per
 thread *and* per async task without any locking.
 
-Spans are deliberately cheap records (name, trace_id, wall start,
-duration, attrs), kept in a bounded ring buffer for inspection
+Spans are deliberately cheap records (name, trace_id, wall start, attrs,
+monotonic start, duration), kept in a bounded ring buffer for inspection
 (``recent()`` / the ``/v1/metrics.json`` view) and optionally mirrored as
 structured JSON log lines keyed by trace-id (see :mod:`.logsink`). In a
 process that has imported JAX an open span is also a
 ``jax.profiler.TraceAnnotation``: an event of the profiler's trace, on its
 clock, beside the device's operations.
+
+A record holds one clock for its interval: ``start_mono`` and ``duration_s``
+are both ``time.perf_counter()``, so ``start_mono + duration_s`` is the span's
+end and a reader that keeps its own ``perf_counter()`` marks (a benchmark's
+round spans, say) can place a record inside them, or cut the ring by an
+interval (:func:`between`). ``start`` stays wall time (``time.time()``): the
+JSON-lines sink and the REST plane's reports are keyed on it.
 """
 
 from __future__ import annotations
@@ -27,6 +34,8 @@ import time
 import uuid
 from collections import deque
 
+from .logsink import emit as _log_emit
+
 #: the wire header carrying the trace id (client -> REST -> service -> store)
 TRACE_HEADER = "X-SDA-Trace"
 
@@ -37,6 +46,12 @@ _TRACE_RE = re.compile(r"[A-Za-z0-9_.:-]{1,64}")
 _trace_var: contextvars.ContextVar = contextvars.ContextVar(
     "sda_trace_id", default=None
 )
+
+#: records the ring keeps. The busiest untraced 30 s window of the benchmark
+#: (``c5-hostfed``: 46 rounds of about 45 records) leaves about 2 100 behind a
+#: set-up's few hundred; what is overwritten unread is counted
+#: (``sda_telemetry_spans_dropped_total``)
+RING_RECORDS = 4096
 
 
 def _profiler_annotation(name: str):
@@ -83,17 +98,34 @@ def set_trace_id(trace_id: str | None):
     return _trace_var.set(trace_id)
 
 
+def between(spans, since_mono: float | None = None, until_mono: float | None = None) -> list:
+    """The records of ``spans`` that start in ``[since_mono, until_mono)`` on
+    the monotonic clock (``None``: open on that side). Half-open, so the cuts
+    of consecutive intervals share no record and miss none. A record with no
+    ``start_mono`` (one banked before the field existed) is in no interval."""
+    if since_mono is None and until_mono is None:
+        return list(spans)
+    lo = float("-inf") if since_mono is None else since_mono
+    hi = float("inf") if until_mono is None else until_mono
+    return [s for s in spans if s.get("start_mono") is not None and lo <= s["start_mono"] < hi]
+
+
 class SpanLog:
     """Bounded ring of finished spans + the span() timing entry point."""
 
-    def __init__(self, registry, maxlen: int = 4096):
+    def __init__(self, registry, maxlen: int = RING_RECORDS):
         self._registry = registry
         self._lock = threading.Lock()
         self._spans: deque = deque(maxlen=maxlen)
+        self._dropped = registry.counter(
+            "sda_telemetry_spans_dropped_total",
+            "span records the ring overwrote (its oldest) to take a new one",
+        )
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        """Time a block; record {name, trace_id, start, duration_s, attrs}.
+        """Time a block; record {name, trace_id, start, attrs, start_mono,
+        duration_s}.
 
         Disabled telemetry short-circuits to a bare yield — no clock
         reads, no record, no log line, no profiler annotation."""
@@ -107,27 +139,51 @@ class SpanLog:
             "attrs": attrs or None,
         }
         with _profiler_annotation(name):
-            t0 = time.perf_counter()
+            t0 = record["start_mono"] = time.perf_counter()
             try:
                 yield record
             finally:
                 record["duration_s"] = time.perf_counter() - t0
-                with self._lock:
-                    self._spans.append(record)
-                from .logsink import emit as _log_emit
+                self._keep(record)
 
-                _log_emit("span", record)
+    def record(self, name: str, start_mono: float, duration_s: float, **attrs) -> None:
+        """Keep a span that somebody else timed (JAX's own events): it took
+        ``duration_s`` from ``start_mono`` on ``time.perf_counter()``."""
+        if not self._registry.enabled:
+            return
+        self._keep({
+            "name": name,
+            "trace_id": _trace_var.get(),
+            "start": time.time() - (time.perf_counter() - start_mono),
+            "attrs": attrs or None,
+            "start_mono": start_mono,
+            "duration_s": duration_s,
+        })
 
-    def recent(self, name: str | None = None, trace_id: str | None = None) -> list:
+    def _keep(self, record: dict) -> None:
+        with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self._dropped.inc()
+            self._spans.append(record)
+        _log_emit("span", record)
+
+    def recent(
+        self,
+        name: str | None = None,
+        trace_id: str | None = None,
+        since_mono: float | None = None,
+        until_mono: float | None = None,
+    ) -> list:
         """Finished spans, oldest first, optionally filtered by name
-        prefix and/or exact trace id."""
+        prefix, exact trace id, and the interval they start in
+        (:func:`between`)."""
         with self._lock:
             spans = list(self._spans)
         if name is not None:
             spans = [s for s in spans if s["name"].startswith(name)]
         if trace_id is not None:
             spans = [s for s in spans if s["trace_id"] == trace_id]
-        return spans
+        return between(spans, since_mono, until_mono)
 
     def reset(self) -> None:
         with self._lock:

@@ -223,6 +223,53 @@ def test_the_feed_waits_for_the_link_where_the_bound_is_small(monkeypatch):
     assert np.array_equal(np.asarray(driver.fold_host_rows(blocks, key, in_flight=3)), want)
 
 
+def test_a_call_of_the_feed_is_one_span_with_its_puts_and_waits_inside():
+    """``fabric.feed``: the program's own ``dispatch``, one a call, on the
+    clock its puts and waits are on, so that the call less what nests inside it
+    is the host's own seconds; ``bytes`` is what the call put."""
+    from sda_tpu.telemetry import flight
+
+    driver = fold_round(scheme_of(60), DIM, sumfirst.value_limb_sums_chunk, CHUNK)
+    blocks = [rows_of(driver, 2 * CHUNK, seed=i) for i in range(4)]
+    telemetry.reset()
+    calls = []
+    for key, some in ((1, blocks), (2, blocks[:3])):
+        fed_before = fed()[2]
+        driver.fold_host_rows(some, jax.random.key(key), in_flight=2)
+        calls.append(fed()[2] - fed_before)
+    feeds = [s for s in telemetry.spans("fabric.feed") if s["name"] == "fabric.feed"]
+    assert [s["attrs"] for s in feeds] == [
+        {"in_flight": 2, "bytes": nbytes} for nbytes in calls
+    ]
+    assert calls == [sum(b.nbytes for b in blocks), sum(b.nbytes for b in blocks[:3])]
+    inner = [s for s in telemetry.spans("fabric.feed.") if s["name"] != "fabric.feed"]
+    waits = [s for s in inner if s["name"] == "fabric.feed.wait"]
+    assert [s["attrs"] for s in waits] == [{"on": "in_flight"}] * (2 + 1)  # blocks beyond the bound
+    inside = 0
+    for call in feeds:
+        begin, end = call["start_mono"], call["start_mono"] + call["duration_s"]
+        mine = [s for s in inner if begin <= s["start_mono"] < end]
+        assert all(s["start_mono"] + s["duration_s"] <= end for s in mine)
+        puts = [s for s in mine if s["name"] == "fabric.feed.put"]
+        assert sum(s["attrs"]["bytes"] for s in puts) == call["attrs"]["bytes"]
+        inside += len(mine)
+    assert inside == len(inner), "a put or a wait outside every call"
+    # the flight recorder's own seconds of the call: what the puts and the waits leave
+    names = flight.interval_report(telemetry.spans("fabric.feed"))["names"]
+    nested = sum(row["seconds"] for name, row in names.items() if name != "fabric.feed")
+    assert names["fabric.feed"]["own_s"] == pytest.approx(names["fabric.feed"]["seconds"] - nested)
+    assert names["fabric.feed"]["own_s"] > 0 and names["fabric.feed"]["count"] == 2
+    # with telemetry off the feed folds the same and records nothing
+    telemetry.set_enabled(False)
+    try:
+        acc = driver.fold_host_rows(blocks, jax.random.key(1), in_flight=2)
+    finally:
+        telemetry.set_enabled(True)
+    assert len(telemetry.spans("fabric.feed")) == len(feeds) + len(inner)
+    want = column_sums(np.concatenate(blocks), driver.modulus)
+    assert np.array_equal(driver.reveal(driver.clerk_sums(acc), range(7)), want)
+
+
 def test_nothing_is_kept_from_one_call_to_the_next():
     """The same host arrays, changed in place between two calls: the second
     call's aggregate is of what they hold then."""

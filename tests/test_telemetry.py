@@ -100,6 +100,86 @@ def test_disabled_mode_records_nothing():
     assert telemetry.spans(name="t.off") == []
 
 
+# -- the span ring: one clock, cut by interval, overwrites counted -----------
+
+
+def test_a_span_record_holds_its_interval_on_the_monotonic_clock():
+    before = time.perf_counter()
+    with telemetry.span("t.mono", k=1) as record:
+        inside = time.perf_counter()
+        assert before <= record["start_mono"] <= inside
+    after = time.perf_counter()
+    end = record["start_mono"] + record["duration_s"]
+    assert inside <= end <= after
+    # every field a record had stays, the wall-clock start included
+    assert list(record) == ["name", "trace_id", "start", "attrs", "start_mono", "duration_s"]
+    assert abs(record["start"] - time.time()) < 60
+    assert telemetry.snapshot(include_spans=5)["spans"][-1] is record
+
+
+def test_since_and_until_cut_the_ring_exactly():
+    marks = []
+    for i in range(5):
+        with telemetry.span("t.cut", i=i) as record:
+            pass
+        marks.append(record["start_mono"])
+
+    def picked(**cut):
+        return [s["attrs"]["i"] for s in telemetry.spans(name="t.cut", **cut)]
+
+    assert picked() == [0, 1, 2, 3, 4]
+    # half-open: a record that starts at `since` is in, one at `until` is out
+    assert picked(since_mono=marks[1], until_mono=marks[3]) == [1, 2]
+    assert picked(since_mono=marks[3]) == [3, 4] and picked(until_mono=marks[3]) == [0, 1, 2]
+    assert picked(since_mono=marks[4] + 1e-9) == []
+    # consecutive intervals share no record and miss none
+    edges = [None, *marks[1::2], None]
+    cuts = [picked(since_mono=a, until_mono=b) for a, b in zip(edges, edges[1:])]
+    assert sum(cuts, []) == [0, 1, 2, 3, 4]
+    other = telemetry.spans(trace_id="nobody", since_mono=marks[0])
+    assert other == []
+
+
+def test_an_overwritten_record_ticks_the_dropped_counter():
+    from sda_tpu.telemetry import Registry, SpanLog
+
+    registry = Registry(enabled=True)
+    ring = SpanLog(registry, maxlen=3)
+
+    def dropped():
+        return registry.snapshot()["counters"].get(("sda_telemetry_spans_dropped_total", ()), 0)
+
+    for i in range(3):
+        with ring.span("t.ring", i=i):
+            pass
+    assert dropped() == 0 and len(ring.recent()) == 3
+    for i in range(3, 5):
+        with ring.span("t.ring", i=i):
+            pass
+    ring.record("t.told", time.perf_counter() - 0.5, 0.5)
+    assert dropped() == 3
+    assert [s["name"] for s in ring.recent()] == ["t.ring", "t.ring", "t.told"]
+    told = ring.recent(name="t.told")[0]
+    assert told["duration_s"] == 0.5 and abs(told["start"] - (time.time() - 0.5)) < 1
+    # the process's own ring is the documented size, and says so
+    assert telemetry.RING_RECORDS == 4096
+    assert "sda_telemetry_spans_dropped_total" in telemetry.prometheus_text()
+
+
+def test_disabled_mode_reads_no_clock(monkeypatch):
+    telemetry.set_enabled(False)
+
+    def no_clock():
+        raise AssertionError("a disabled span read a clock")
+
+    monkeypatch.setattr(time, "perf_counter", no_clock)
+    monkeypatch.setattr(time, "time", no_clock)
+    with telemetry.span("t.off", k=1) as record:
+        assert record is None
+    monkeypatch.undo()
+    assert telemetry.spans() == []
+
+
 def test_overhead_guard_counter_hot_path():
     """Loose absolute guard against accidentally heavy instrumentation:
     a counter inc must stay in single-digit microseconds (bench.py owns
