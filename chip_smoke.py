@@ -18,9 +18,10 @@ field, dim 100 000), through the entry points a user calls:
   with the program's default draw, accumulated as that engine's traffic
   file says, through the matching host epilogue, revealed from 7 of 8
   clerks and compared with the exact column sums: sum-first 61-bit at dim
-  100 000, then the per-participant engine at dim 10 000 on the XLA
-  int8-limb path and on the fused Pallas kernel, whose accumulators must be
-  the same bits;
+  100 000, then the per-participant engine at dim 10 000 in XLA's named
+  formulation, through the entry the cell binds (the fused Pallas kernel on
+  a TPU) and through the kernel's explicit entry, whose accumulators must
+  all be the same bits;
 - sharded leg, with more than one chip: every fabric
   ``__graft_entry__.dryrun_multichip`` walks, plus the sum-first limb psum
   at dim 100 000, revealed and compared the same way.
@@ -277,7 +278,12 @@ def kernel_parity(
     from sda_tpu.native import chacha_expand
     from sda_tpu.ops.chacha_pallas import combine_masks_device
     from sda_tpu.ops.modular import mod_sum_wide_np, positive
-    from sda_tpu.parallel.engine import make_plan, reconstruct, share_combine_limb
+    from sda_tpu.parallel.engine import (
+        make_plan,
+        reconstruct,
+        share_combine_limb,
+        share_combine_limb_xla,
+    )
     from sda_tpu.parallel.limb_pallas import share_combine_limb_pallas
     from sda_tpu.parallel.limbmatmul import limb_recombine_host
 
@@ -304,17 +310,24 @@ def kernel_parity(
         got = combine_masks_device(seed_rows, dim, p61, backend=backend)
         same(f"chacha_{backend}", got, want)
 
-    # fused Pallas participant kernel vs the XLA int8-limb path, same key
+    # the per-participant engine's fused kernel vs XLA's named formulation,
+    # same key: on a TPU through the engine's own entry, which takes the
+    # compiled kernel there; elsewhere the kernel's source on the interpreter
     narrow = _scheme(30)
     plan = make_plan(narrow, limb_dim)
     secrets = jnp.asarray(
-        np.random.default_rng(4).integers(0, plan.modulus, size=(chunk, limb_dim))
+        np.random.default_rng(4)
+        .integers(0, plan.modulus, size=(chunk, limb_dim))
+        .astype(np.int32)
     )
     key = jax.random.key(9)
-    xla = jax.jit(lambda s, kk: share_combine_limb(s, kk, plan))(secrets, key)
-    fused = jax.jit(
-        lambda s, kk: share_combine_limb_pallas(s, kk, plan, interpret=not on_tpu)
-    )(secrets, key)
+    xla = jax.jit(lambda s, kk: share_combine_limb_xla(s, kk, plan))(secrets, key)
+    if on_tpu:
+        fused = jax.jit(lambda s, kk: share_combine_limb(s, kk, plan))(secrets, key)
+    else:
+        fused = jax.jit(
+            lambda s, kk: share_combine_limb_pallas(s, kk, plan, interpret=True)
+        )(secrets, key)
     same("limb", fused, xla)
 
     # wide field: limb accumulators -> exact host recombine -> reconstruct
@@ -370,8 +383,10 @@ def fold_engine(engine: str, *, dim: int, chunk: int):
     (``sda_tpu.parallel.fold_round``), which pairs the entry with its
     accumulate rule and its epilogue as the cells' traffic files do:
     ``sumfirst`` as ``sumfirst-wide.json`` (61-bit, ``+``), ``participant`` as
-    ``participant-narrow.json`` (31-bit, ``+`` then ``rem p``),
-    ``participant+pallas`` the same round on the fused kernel. Two chunks of
+    ``participant-narrow.json`` (31-bit, ``+`` then ``rem p``; the fused
+    kernel where the step is compiled for a TPU), ``participant+xla`` the same
+    round in XLA's named formulation, ``participant+pallas`` on the kernel's
+    explicit entry. Two chunks of
     seeded host rows through the driver's feed with the program's default
     draw, the epilogue, the reveal compared. Returns the accumulator."""
     import functools
@@ -392,6 +407,7 @@ def fold_engine(engine: str, *, dim: int, chunk: int):
     bits, entry = {
         "sumfirst": (60, sumfirst.value_limb_sums_chunk),
         "participant": (30, engine_mod.share_combine_limb),
+        "participant+xla": (30, engine_mod.share_combine_limb_xla),
         "participant+pallas": (30, fused),
     }[engine]
     scheme = _scheme(bits)
@@ -419,8 +435,8 @@ def fabric_leg(
 ) -> None:
     """Kernel parity at the main path's shapes, then each engine's round
     as the cells run it: sum-first at full width, per-participant at its
-    preset width on the XLA limb path and on the fused Pallas kernel, which
-    must leave the same accumulator."""
+    preset width in XLA's named formulation, through the cell's entry and on
+    the fused Pallas kernel by name, which must leave the same accumulator."""
     import numpy as np
 
     t0 = time.perf_counter()
@@ -429,13 +445,13 @@ def fabric_leg(
     )
     say(f"fabric leg: kernel parity {parity} ({time.perf_counter() - t0:.1f} s)")
     fold_engine("sumfirst", dim=dim, chunk=chunk)
-    xla = fold_engine("participant", dim=preset_dim, chunk=preset_chunk)
-    fused = fold_engine("participant+pallas", dim=preset_dim, chunk=preset_chunk)
-    if not np.array_equal(fused, xla):
-        raise SmokeFailure(
-            "fabric leg: the Pallas kernel's accumulator differs from the XLA "
-            "path's on the same key"
-        )
+    xla = fold_engine("participant+xla", dim=preset_dim, chunk=preset_chunk)
+    for engine in ("participant", "participant+pallas"):
+        if not np.array_equal(fold_engine(engine, dim=preset_dim, chunk=preset_chunk), xla):
+            raise SmokeFailure(
+                f"fabric leg: {engine}'s accumulator differs from the XLA "
+                "formulation's on the same key"
+            )
 
 
 def sharded_leg(*, dim: int = 100_000, rows_per_shard: int = 256) -> None:

@@ -185,31 +185,79 @@ def share_participants(secrets, key, plan: AggregationPlan, use_limbs: bool = Fa
     return jnp.swapaxes(shares, 1, 2)  # (P, n, B)
 
 
-def share_combine_limb(secrets, key, plan: AggregationPlan):
-    """Fused share + clerk-combine in limb space: (C, d) -> (W, b, n) int64.
+def count_share_combine(path: str) -> None:
+    """One per-participant share + combine traced, by the path it takes. The
+    path is a property of the traced program, as a limb sum's road is
+    (``sumfirst.count_limb_sum_road``): counted where it is chosen, once a
+    trace."""
+    telemetry.counter(
+        "sda_fabric_share_combine_total",
+        "per-participant share + combine programs traced, by path "
+        "(fused | interpret | xla)",
+        path=path,
+    ).inc()
 
-    The hot loop stays division-free: int8 MXU matmuls produce weight-grouped
-    partials, which are *summed over the participant axis first* (linearity)
-    and only then carried as a tiny (W, b, n) accumulator. Callers reduce
-    accumulators across chunks with ``lax.rem`` (values stay < p) and call
-    ``limb_recombine`` once at the very end: emulated 64-bit multiply/divide
-    never touches the (participants x dim) tensor.
-    """
+
+def _values_by_dim(secrets, randomness, plan: AggregationPlan):
+    """(C, d) secrets and their (C, b, t) draw as the kernel takes them
+    (``limb_pallas.participant_limb_sums_pallas``), participants on the
+    lanes: the chunk transposed once, (b·k, C) int32, its dim tail
+    zero-padded as ``_batch_secrets`` pads it (value ``s`` of batch ``j`` is
+    row ``j·k + s``: the de-interleave is left to the kernel's loads), and
+    the draw as (t, b, C): a layout to the compiler, not a copy. Narrow
+    fields only (canonical values fit int32)."""
+    jnp = _jnp()
+    import jax
+    from jax import lax
+
+    k = plan.input_size
+    d = secrets.shape[1]
+    with jax.named_scope("fabric.values"):
+        padded = jnp.pad(secrets.astype(jnp.int32), ((0, 0), (0, -d % k)))
+        # the transpose of a step's parameter is a layout change to the
+        # compiler, a copy that carries the parameter's name and no scope:
+        # behind a barrier (no operation: it is gone before code is made) the
+        # copy is this scope's, and the cell's layout seconds have a name
+        by_dim = lax.optimization_barrier(padded).T
+    with jax.named_scope("fabric.rand/layout"):
+        return by_dim, jnp.transpose(randomness.astype(jnp.int32), (2, 1, 0))
+
+
+def _combine_fused(secrets, randomness, *, plan: AggregationPlan, interpret=False):
+    """The fold on the kernel ``limb_share_combine``: the transposed chunk
+    and the draw in, the (W, b, n) accumulator out; no per-participant
+    tensor in between."""
+    jnp = _jnp()
+    import jax
+
+    from .limb_pallas import participant_limb_sums_pallas
+    from .limbmatmul import fold_const_limbs
+
+    stacks = fold_const_limbs(plan.share_matrix.T, plan.modulus)  # (L, L*(k+t), n)
+    by_dim, draws = _values_by_dim(secrets, randomness, plan)
+    with jax.named_scope("fabric.share_matmul/dot"):
+        acc = participant_limb_sums_pallas(by_dim, draws, stacks, interpret=interpret)
+    with jax.named_scope("fabric.combine"):
+        return acc.astype(jnp.int64)  # (W=L, b, n)
+
+
+def _combine_xla(secrets, randomness, *, plan: AggregationPlan):
+    """The fold as XLA's own operations: (C·b, k+t) value rows, L int8 dots,
+    the per-participant partials summed over the participant axis."""
     jnp = _jnp()
     import jax
 
     from .limbmatmul import fold_const_limbs, limb_partials_const
 
     p = plan.modulus
+    stacks = fold_const_limbs(plan.share_matrix.T, p)  # (L, L*(k+t), n)
     batches = _batch_secrets(secrets, plan)  # (C, b, k)
     C, nb = batches.shape[0], batches.shape[1]
-    randomness = _device_randomness(key, (C, nb, plan.rand_size), p)
     with jax.named_scope("fabric.values"):
         # keep the big tensor in native int32 lanes when the field fits
         dt = jnp.int32 if p <= (1 << 31) else jnp.int64
         values = jnp.concatenate([batches.astype(dt), randomness.astype(dt)], axis=-1)
         values = values.reshape(C * nb, -1)
-    stacks = fold_const_limbs(plan.share_matrix.T, p)  # (L, L*(k+t), n)
     partials = limb_partials_const(values, stacks, p)  # (W=L, C*nb, n)
     W, LK = stacks.shape[0], stacks.shape[1]
     with jax.named_scope("fabric.combine"):
@@ -219,6 +267,62 @@ def share_combine_limb(secrets, key, plan: AggregationPlan):
         if C * LK * 127 * 127 < 2**31:
             return jnp.sum(per_part, axis=1).astype(jnp.int64)  # (W, b, n)
         return jnp.sum(per_part.astype(jnp.int64), axis=1)  # (W, b, n)
+
+
+def _share_draw(secrets, key, plan: AggregationPlan):
+    """A chunk's share randomness, (C, b, t): the one draw of a step, the
+    same for every formulation of the fold."""
+    C, d = secrets.shape
+    nb = -(-d // plan.input_size)
+    return _device_randomness(key, (C, nb, plan.rand_size), plan.modulus)
+
+
+def share_combine_limb_xla(secrets, key, plan: AggregationPlan):
+    """:func:`share_combine_limb` in XLA's formulation at every width and
+    shape: what runs off the TPU, over a wide field and past the kernel's
+    int32 bound, and the plain reference the fused kernel is held to, bit for
+    bit for one key (tests/test_parallel_engine.py,
+    ``chip_smoke.kernel_parity``). No engine calls it by name."""
+    return _combine_xla(secrets, _share_draw(secrets, key, plan), plan=plan)
+
+
+def share_combine_limb(secrets, key, plan: AggregationPlan):
+    """Fused share + clerk-combine in limb space: (C, d) -> (W, b, n) int64.
+
+    The hot loop stays division-free: int8 MXU matmuls produce weight-grouped
+    partials, which are *summed over the participant axis first* (linearity)
+    and only then carried as a tiny (W, b, n) accumulator. Callers reduce
+    accumulators across chunks with ``lax.rem`` (values stay < p) and call
+    ``limb_recombine`` once at the very end: emulated 64-bit multiply/divide
+    never touches the (participants x dim) tensor.
+
+    One algorithm, two layouts, chosen from the platform the program is
+    lowered for (``lax.platform_dependent``), the field's width and the
+    chunk's shape (``limb_pallas.fused_fits``): on a TPU a narrow field's
+    chunk goes transposed, participants on the lanes, through the kernel
+    ``limb_share_combine`` (``limb_pallas``); everything else takes
+    :func:`share_combine_limb_xla`'s operations. Same draw, same accumulator,
+    bit for bit. ``sda_fabric_share_combine_total{path}`` counts the choice
+    as this process's backend makes it, which is what runs it. Inside a
+    ``shard_map`` a narrow call would take the kernel on its local block; no
+    caller in the tree makes one (the sharded limb fabric is the wide field's).
+    """
+    import functools
+
+    import jax
+    from jax import lax
+
+    from .limb_pallas import fused_fits
+
+    randomness = _share_draw(secrets, key, plan)
+    xla = functools.partial(_combine_xla, plan=plan)
+    if not fused_fits(plan.modulus, secrets.shape[0], plan.input_size + plan.rand_size):
+        count_share_combine("xla")
+        return xla(secrets, randomness)
+    count_share_combine("fused" if jax.default_backend() == "tpu" else "xla")
+    return lax.platform_dependent(
+        secrets, randomness, tpu=functools.partial(_combine_fused, plan=plan), default=xla
+    )
 
 
 def clerk_combine(shares):

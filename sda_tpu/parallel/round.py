@@ -11,6 +11,7 @@ chunk entry ``entry(secrets, key, plan)``   accumulate    host epilogue to ``(n,
 ==========================================  ============  ==================================
 ``sumfirst.value_limb_sums_chunk``          ``sum``       ``sumfirst.clerk_sums_from_limb_acc``
 ``engine.share_combine_limb``               ``sum_mod_p`` ``limbmatmul.limb_recombine_host``
+``engine.share_combine_limb_xla``           ``sum_mod_p`` ``limbmatmul.limb_recombine_host``
 ``limb_pallas.share_combine_limb_pallas``   ``sum_mod_p`` ``limbmatmul.limb_recombine_host``
 ==========================================  ============  ==================================
 
@@ -118,6 +119,7 @@ def _limb_recombine_epilogue(acc, plan):
 _PAIRED = {
     sumfirst.value_limb_sums_chunk: ("sum", _limb_acc_epilogue),
     engine.share_combine_limb: ("sum_mod_p", _limb_recombine_epilogue),
+    engine.share_combine_limb_xla: ("sum_mod_p", _limb_recombine_epilogue),
     limb_pallas.share_combine_limb_pallas: ("sum_mod_p", _limb_recombine_epilogue),
 }
 

@@ -87,6 +87,7 @@ LEGS = {
     "protocol": _protocol_leg,
     "sumfirst": _engine_leg("sumfirst", 61),
     "participant": _engine_leg("participant", 31),
+    "participant+xla": _engine_leg("participant+xla", 31),
     "participant+pallas": _engine_leg("participant+pallas", 31),
     "sharded": _sharded_leg,
 }
@@ -113,9 +114,10 @@ def test_the_fabric_leg_holds_parity_and_the_two_participant_paths_to_one_accumu
 ):
     """The whole leg: kernel parity on the CPU's backends (the jnp twin and
     the kernel source under the interpreter, chosen from the backend,
-    nothing caught), the three engines, and the Pallas accumulator held to
-    the XLA one: a kernel path that leaves other bits fails the leg though
-    its own reveal is exact."""
+    nothing caught), the engines, and the accumulators of the cell's entry
+    and of the Pallas kernel held to the named XLA formulation's: a kernel
+    path that leaves other bits fails the leg though its own reveal is
+    exact."""
     import chip_smoke
 
     chip_smoke.fabric_leg(**TINY, preset_dim=60, preset_chunk=100, seeds=4)
@@ -123,7 +125,7 @@ def test_the_fabric_leg_holds_parity_and_the_two_participant_paths_to_one_accumu
     assert "'chacha_backends': ['jnp', 'interpret']" in out
     for name in ("chacha_jnp", "chacha_interpret", "limb", "wide61"):
         assert f"'{name}': 'ok'" in out
-    assert out.count("fabric leg ok: ") == 3
+    assert out.count("fabric leg ok: ") == 4
 
     fold = chip_smoke.fold_engine
     monkeypatch.setattr(
@@ -140,6 +142,7 @@ def test_the_fabric_leg_holds_parity_and_the_two_participant_paths_to_one_accumu
     ("sumfirst", "sumfirst", "clerk_sums_from_limb_acc", 0),
     # (W, B, n): a share sum of clerk 7, one of the seven that reveal
     ("participant", "limbmatmul", "limb_recombine_host", -1),
+    ("participant+xla", "limbmatmul", "limb_recombine_host", -1),
     ("participant+pallas", "limbmatmul", "limb_recombine_host", -1),
 ])
 def test_a_corrupted_accumulator_fails_the_fabric_leg(
@@ -389,8 +392,9 @@ def test_no_64_bit_types_inside_kernel_bodies():
     p = (1 << 31) - 1
     stacks = fold_const_limbs(np.arange(56).reshape(7, 8) % p, p)
     limb = _pallas_body_dtypes(
-        lambda v: participant_limb_sums_pallas(v, stacks, interpret=True),
-        jnp.zeros((6, 7, 300), jnp.int32),
+        lambda c, r: participant_limb_sums_pallas(c, r, stacks, interpret=True),
+        jnp.zeros((300 * 5, 6), jnp.int32),
+        jnp.zeros((2, 300, 6), jnp.int32),
     )
     assert chacha and limb
     for found in (chacha, limb):

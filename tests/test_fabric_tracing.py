@@ -308,7 +308,87 @@ def test_kernels_off_the_cells_paths_have_stable_names():
     ]
     plan = plan_for(31)[1]
     stacks = limbmatmul.fold_const_limbs(plan.share_matrix.T, plan.modulus)
-    values = jnp.zeros((ROWS, 7, 7), jnp.int32)
+    by_dim, draws = jnp.zeros((7 * 5, ROWS), jnp.int32), jnp.zeros((2, 7, ROWS), jnp.int32)
     assert kernel_names(
-        lambda v: limb_pallas.participant_limb_sums_pallas(v, stacks, interpret=True), values
+        lambda c, r: limb_pallas.participant_limb_sums_pallas(c, r, stacks, interpret=True),
+        by_dim, draws,
     ) == ["limb_share_combine"]
+
+
+# -- the per-participant engine's fused layout (PR 38) ---------------------------
+
+
+def lowered_for_a_tpu(plan, rows=ROWS):
+    """The participant entry's StableHLO as lowered for a TPU, with its
+    locations: no chip and no compiler, the kernel is a ``tpu_custom_call``."""
+    import jax
+    import jax.numpy as jnp
+
+    secrets = jax.ShapeDtypeStruct(
+        (rows, plan.dim), jnp.int64 if plan.modulus > 1 << 31 else jnp.int32
+    )
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    traced = jax.jit(participant_entry(plan)).trace(secrets, key)
+    return traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+def test_the_fused_step_holds_the_scopes_the_cells_metrics_read():
+    """``engine.layout_s``, ``engine.share_matmul_s`` and ``engine.rand_s``
+    read ``fabric.values``, ``fabric.share_matmul`` and ``fabric.rand``: the
+    step a TPU gets names its transpose, its kernel and its draw so, and the
+    draw's re-layout stays the draw's."""
+    text = lowered_for_a_tpu(plan_for(30)[1])
+    assert "tpu_custom_call" in text and "limb_share_combine" in text
+    for scope in (
+        "fabric.values/optimization_barrier", "fabric.values/transpose",
+        "fabric.share_matmul/dot", "fabric.rand/draw", "fabric.rand/layout", "fabric.combine",
+    ):
+        assert f"/{scope}" in text, scope
+    # the 20-million axis of XLA's formulation is in no operation of it
+    assert "fabric.share_matmul/limbs" not in text and "dot_general" not in text
+
+
+def share_combines():
+    """Per-participant share + combine programs traced since the last reset, by path."""
+    return {
+        dict(labels)["path"]: value
+        for (name, labels), value in telemetry.get_registry().snapshot()["counters"].items()
+        if name == "sda_fabric_share_combine_total"
+    }
+
+
+@pytest.mark.parametrize("backend,bits,rows,path", [
+    ("cpu", 30, ROWS, "xla"),  # what a CPU run counts, whatever the width
+    ("cpu", 61, ROWS, "xla"),
+    ("tpu", 30, ROWS, "fused"),  # c4-w31-d50k's field on a chip
+    ("tpu", 31, ROWS, "xla"),  # a 32-bit prime: too wide for int32 limbs
+    ("tpu", 61, ROWS, "xla"),
+    ("tpu", 30, 3_900, "xla"),  # 3 900 x 35 x 127^2 >= 2^31: past the int32 accumulation
+])
+def test_a_trace_counts_one_share_combine_by_the_path_it_takes(
+    backend, bits, rows, path, monkeypatch, fresh_telemetry
+):
+    """The path is chosen from the platform, the field's width and the
+    chunk's shape, once a trace, and counted where it is chosen, as the
+    process's backend makes it (which is what runs the program)."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    plan = plan_for(bits)[1]
+    jax.make_jaxpr(participant_entry(plan))(
+        jax.ShapeDtypeStruct((rows, DIM), secrets_for(plan).dtype),
+        jax.eval_shape(lambda: jax.random.key(0)),
+    )
+    assert share_combines() == {path: 1}
+
+
+def test_the_kernels_explicit_entry_counts_its_own_path(fresh_telemetry):
+    import jax
+
+    from sda_tpu.parallel import limb_pallas
+
+    plan = plan_for(30)[1]
+    limb_pallas.share_combine_limb_pallas(
+        secrets_for(plan), jax.random.key(1), plan, interpret=True
+    )
+    assert share_combines() == {"interpret": 1}

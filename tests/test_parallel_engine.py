@@ -331,32 +331,83 @@ def test_basic_shamir_engine_end_to_end():
     )
 
 
-def test_pallas_participant_path_bit_identical(jax_mods):
+def _narrow_plan(dim, k=5, t=2, bits=30):
+    from sda_tpu.ops import find_packed_parameters
+    from sda_tpu.parallel.engine import make_plan
+    from sda_tpu.protocol import PackedShamirSharing
+
+    p, w2, w3 = find_packed_parameters(k, t, 8, min_modulus_bits=bits, seed=0)
+    return make_plan(PackedShamirSharing(k, 8, t, p, w2, w3), dim)
+
+
+@pytest.mark.parametrize("dim,P,k,t", [
+    # nb = 5: the dim's pad and one short batch tile; 600 participants = 4
+    # lane blocks of 128 and a ragged fifth
+    pytest.param(23, 600, 5, 2, id="dim23-P600-ragged-lane-block"),
+    # an odd count under one lane block
+    pytest.param(23, 37, 5, 2, id="dim23-P37-one-short-block"),
+    # nb = 2100: four batch tiles of 512 and a ragged fifth, not a multiple of 128
+    pytest.param(10_500, 70, 5, 2, id="dim10500-P70-ragged-batch-tile"),
+    # nb = 257, 300 participants: both axes ragged at once
+    pytest.param(1_283, 300, 5, 2, id="dim1283-P300-both-ragged"),
+    # whole blocks on both axes: no mask, no edge
+    pytest.param(2_560, 256, 5, 2, id="dim2560-P256-whole-blocks"),
+    # K = 3 value rows: k = 2, t = 1, dim odd
+    pytest.param(301, 130, 2, 1, id="K3-dim301-P130"),
+])
+def test_pallas_participant_path_bit_identical(jax_mods, dim, P, k, t):
     """The fused Pallas participant kernel (interpret mode, passed
-    explicitly) produces bit-identical limb accumulators to the jnp
-    share_combine_limb for the same key — across the pad path, several
-    participant blocks, an odd participant count, and more than one
-    batch tile."""
+    explicitly) produces bit-identical limb accumulators to XLA's named
+    formulation (``share_combine_limb_xla``) for the same key, across the pad
+    of every axis: a dim that is no multiple of k, batches that fill no batch
+    tile and no 128 rows, participants that fill no lane block, K = 7 and
+    K < 7. Off the TPU the engine's own entry takes XLA's formulation."""
     import jax.numpy as jnp
     from jax import random
 
-    from sda_tpu.ops import find_packed_parameters
-    from sda_tpu.parallel.engine import make_plan, share_combine_limb
+    from sda_tpu.parallel.engine import share_combine_limb, share_combine_limb_xla
     from sda_tpu.parallel.limb_pallas import share_combine_limb_pallas
 
-    p, w2, w3 = find_packed_parameters(5, 2, 8, min_modulus_bits=30, seed=0)
-    from sda_tpu.protocol import PackedShamirSharing
+    plan = _narrow_plan(dim, k, t)
+    secrets = jnp.asarray(
+        np.random.default_rng(17).integers(0, plan.modulus, size=(P, dim)).astype(np.int32)
+    )
+    key = random.key(P)
+    want = np.asarray(share_combine_limb_xla(secrets, key, plan))
+    got = np.asarray(share_combine_limb_pallas(secrets, key, plan, interpret=True))
+    np.testing.assert_array_equal(got, want)
+    assert want.shape == (5, plan.n_batches, 8) and want.dtype == np.int64 and want.any()
+    np.testing.assert_array_equal(np.asarray(share_combine_limb(secrets, key, plan)), want)
 
-    scheme = PackedShamirSharing(5, 8, 2, p, w2, w3)
-    rng = np.random.default_rng(17)
-    # (dim, participants): nb=5 pad path with 600 > 512 rows = 2 blocks;
-    # an odd count; nb=2100 > 2048 lanes = 2 batch tiles x 3 blocks of 32
-    for dim, P in ((23, 600), (23, 37), (10_500, 70)):
-        plan = make_plan(scheme, dim)
-        secrets = rng.integers(0, p, size=(P, dim)).astype(np.int64)
-        key = random.key(P)
-        want = np.asarray(share_combine_limb(jnp.asarray(secrets), key, plan))
-        got = np.asarray(
-            share_combine_limb_pallas(jnp.asarray(secrets), key, plan, interpret=True)
-        )
-        np.testing.assert_array_equal(got, want)
+
+@pytest.mark.parametrize("bits,P,dim", [
+    # a 32-bit prime: limb extraction needs more than int32
+    pytest.param(31, 9, 23, id="p-over-2^31"),
+    # 3 900 participants x 35 x 127^2 is past 2^31: the int32 accumulation would wrap
+    pytest.param(30, 3_900, 5, id="chunk-past-int32"),
+])
+def test_what_the_fused_kernel_cannot_hold_takes_the_xla_formulation(jax_mods, bits, P, dim):
+    """Past the kernel's bounds the entry takes XLA's formulation without
+    raising, whatever the platform (here even lowered for a TPU, with no
+    kernel in the text); the kernel's explicit entry refuses by name."""
+    import jax
+    import jax.numpy as jnp
+
+    from sda_tpu.parallel.engine import share_combine_limb, share_combine_limb_xla
+    from sda_tpu.parallel.limb_pallas import fused_fits, share_combine_limb_pallas
+
+    plan = _narrow_plan(dim, bits=bits)
+    assert not fused_fits(plan.modulus, P, 7) and fused_fits((1 << 31) - 1, 2_000, 7)
+    dtype = jnp.int32 if plan.modulus <= (1 << 31) else jnp.int64
+    secrets = jnp.asarray(
+        np.random.default_rng(3).integers(0, plan.modulus, size=(P, dim)), dtype=dtype
+    )
+    key = jax.random.key(5)
+    step = jax.jit(lambda s, kk: share_combine_limb(s, kk, plan))
+    np.testing.assert_array_equal(
+        np.asarray(step(secrets, key)), np.asarray(share_combine_limb_xla(secrets, key, plan))
+    )
+    for_tpu = step.trace(secrets, key).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" not in for_tpu and "dot_general" in for_tpu
+    with pytest.raises(ValueError, match="fused participant path"):
+        share_combine_limb_pallas(secrets, key, plan, interpret=True)

@@ -61,6 +61,7 @@ ENTRIES = [
     pytest.param(30, engine.share_combine_limb, "sum_mod_p", id="w31-participant-sum_mod_p"),
     pytest.param(60, engine.share_combine_limb, "sum_mod_p", id="w61-participant-sum_mod_p"),
     pytest.param(30, PALLAS, "sum_mod_p", id="w31-pallas-sum_mod_p"),
+    pytest.param(30, engine.share_combine_limb_xla, "sum_mod_p", id="w31-xla-sum_mod_p"),
 ]
 
 
