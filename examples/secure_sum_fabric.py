@@ -12,7 +12,9 @@ Three stages, each verified against an independent plaintext sum:
    round driver (`sda_tpu.parallel.fold_round`) feeds host blocks to the
    device with a bounded number in flight and pairs the chunk entry with
    its epilogue; a clerk row is corrupted and DROPPED to show t+k-of-n
-   reconstruction never reads it;
+   reconstruction never reads it; then the same feed under the upstream's
+   ChaCha masking (`fold_round(..., masking=)`): the clerks sum masked rows,
+   the feed hands on every row's seed, the recipient unmasks from the seeds;
 3. the sharded fabric — the same sum-first loop over a device Mesh
    (participants sharded over axis ``p``, dims over ``d``), one int64
    ``psum`` carrying the tiny accumulator across the mesh.
@@ -58,7 +60,7 @@ from sda_tpu.parallel.sumfirst import (
     sharded_value_limb_sums,
     value_limb_sums_chunk,
 )
-from sda_tpu.protocol import PackedShamirSharing
+from sda_tpu.protocol import ChaChaMasking, PackedShamirSharing
 
 
 def main():
@@ -98,6 +100,21 @@ def main():
     assert np.array_equal(driver.reveal(clerk_sums, survivors), plain % p)
     print(f"2. sum-first stream OK: 2048 participants, clerk 3 dropped, "
           f"reconstructed from {len(survivors)} of {n} clerk sums")
+
+    # the same feed, masked: every row under a fresh 128-bit seed
+    masked_round = fold_round(
+        scheme, dim, value_limb_sums_chunk, 512, masking=ChaChaMasking(p, dim, 128)
+    )
+    blocks = [rng.integers(0, p, size=(512, dim)).astype(driver.input_dtype) for _ in range(2)]
+    acc, seeds, counts = masked_round.fold_host_rows(blocks, jax.random.key(4), in_flight=2)
+    assert masked_round.short_windows(counts) == 0  # no mask ran out of keystream
+    masked = masked_round.reveal(masked_round.clerk_sums(acc), survivors)
+    want = np.concatenate(blocks).astype(np.int64).sum(axis=0) % p
+    assert not np.array_equal(masked, want)  # the clerks saw masked sums
+    uploads = list(np.concatenate([np.asarray(s) for s in seeds]).astype(np.int64))
+    assert np.array_equal(masked_round.unmask(masked, uploads, chunk=512), want)
+    print("2b. masked stream OK: 1024 participants, a seed each handed on by the feed, "
+          "unmasked from the seeds alone")
 
     # --- 3. the sharded fabric over a device mesh -----------------------
     # fit the mesh to whatever devices exist (8 virtual CPUs by default;

@@ -1,7 +1,9 @@
 """sda_tpu.parallel — the TPU aggregation fabric.
 
 Mesh sharding, the end-to-end ``TpuAggregator`` engine, the int8-limb MXU
-mod-p matmul, and the round driver with its host feed (``round.py``).
+mod-p matmul, and the round driver with its host feed (``round.py``), masked
+under the upstream's ChaCha scheme where the round is given one
+(``fold_round(..., masking=)``; the mask stage is ``masked.py``).
 """
 
 from .engine import AggregationPlan, TpuAggregator, full_training_step, make_plan

@@ -29,6 +29,13 @@ third input; the counts are what :func:`count_short_windows` checks on the
 host, in the round's epilogue, because a jitted step cannot read them (a row
 whose keystream window held fewer than ``dim`` accepted draws has an
 undefined mask tail: about 1e-9 a row).
+
+Nobody has to wrap this by hand: the round driver takes the masking scheme as
+an argument of the round (``round.fold_round(..., masking=)``), puts this
+stage in front of the paired entry in its jitted ``masked_step``, keeps every
+step's seeds and counts through ``fold_chunks`` and ``fold_host_rows``, and
+carries the slack check and the unmasking (``FoldRound.short_windows``,
+``.unmask``).
 """
 
 from __future__ import annotations

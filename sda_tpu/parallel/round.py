@@ -22,6 +22,22 @@ partials stay below p. :func:`fold_round` looks the pair up from the entry
 by hand. The driver adds no arithmetic: its step is the entry on the round's
 key with the step's number folded in, then the accumulate rule.
 
+**A masked round** (``fold_round(..., masking=ChaChaMasking(...))``). The
+deployment's masking scheme is an argument of the round. Under it the step is
+the mask stage in front of the paired entry (``masked.masked_chunk``: a fresh
+seed a row from the step's key, the seed's ChaCha expansion added mod p), then
+the entry's own accumulate rule, and it hands on more than an accumulator:
+``masked_step(acc, chunk, key, i) -> (acc, seeds (chunk, words) uint32, counts
+(chunk,) int32)``. The seeds and the counts do not accumulate by addition, so
+both folds keep every step's, in step order, as the device arrays the steps
+returned (20 bytes a row; nothing is fetched and nothing waits) and return
+``(acc, seeds, counts)``. The rest of the round is the recipient's:
+:meth:`FoldRound.short_windows` over the fetched counts (the slack check a
+jitted step cannot make), :meth:`FoldRound.reveal` as it is (it reveals the
+*masked* aggregate) and :meth:`FoldRound.unmask` from the seeds alone
+(``crypto.masking.ChaChaMasker.combine`` / ``.unmask``). Bounds, spans and
+counters of the feed are the same masked or not.
+
 **The feed** (:meth:`FoldRound.fold_host_rows`). A cohort that does not fit
 the chip's memory sits in host memory and crosses the host link every round.
 The feed takes the round's rows as host blocks ``(block_rows, dim)``,
@@ -53,14 +69,19 @@ keyed on a block's identity or content.
 Spans (``telemetry.span``, also a ``TraceAnnotation`` of the profiler's
 trace): ``fabric.feed``, one a call of the feed (``in_flight``, and ``bytes``,
 what the call put); inside it by time ``fabric.feed.put``, one a
-``device_put`` call (``rows``, ``bytes``), and ``fabric.feed.wait``, the host
-blocked on a bound (``on``: ``in_flight`` or ``link``). The call less its puts
-and waits is the host's own seconds: the slicing and the step dispatches.
-Counters: ``sda_fabric_fed_blocks_total``,
-``sda_fabric_fed_rows_total``, ``sda_fabric_fed_bytes_total``; the gauge
+``device_put`` call (``rows``, ``bytes``), ``fabric.feed.wait``, the host
+blocked on a bound (``on``: ``in_flight`` or ``link``), and
+``fabric.step.dispatch``, the host's call of the jitted step behind a put
+(``step``: its number). The call less its puts, waits and step dispatches is
+the host's own seconds: the slicing and the bookkeeping. ``fabric.feed`` also
+says ``masked`` (whether the round masks). Counters:
+``sda_fabric_fed_blocks_total``, ``sda_fabric_fed_rows_total``,
+``sda_fabric_fed_bytes_total``, and ``sda_fabric_fed_seeds_total``, the seeds
+the feed's steps handed on (the rows of masked steps; none unmasked); the gauge
 ``sda_fabric_feed_in_flight_max``: the most blocks alive at once in the last
-call. The chunk step's device scopes (``fabric.input``, ``fabric.rand``) are
-the entry's own.
+call. The chunk step's device scopes (``fabric.input``, ``fabric.rand``, and
+``fabric.mask`` in front of them in a masked round) are the entry's and the
+mask stage's own.
 """
 
 from __future__ import annotations
@@ -130,32 +151,51 @@ def _input_dtype(modulus: int):
     return np.dtype(np.int32 if modulus <= (1 << 31) else np.int64)
 
 
-def _make_step(entry, plan, accumulate: str):
-    """The jitted ``step(acc, chunk, key, i) -> acc``."""
+def _make_step(entry, plan, accumulate: str, masking=None):
+    """The jitted ``step(acc, chunk, key, i) -> acc``; under ``masking`` the
+    jitted ``masked_step(acc, chunk, key, i) -> (acc, seeds, counts)``: the
+    same step with the mask stage in front of the entry, the rows' seeds and
+    accepted-draw counts leaving it beside the accumulator."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     modulus = plan.modulus
 
-    def step(acc, chunk, key, i):
-        # one chunk step: the round's key with the step's number folded in,
-        # the entry's default share randomness, the accumulate rule
-        out = entry(chunk, jax.random.fold_in(key, i), plan)
+    def accumulated(acc, out):
         acc = acc + out
         if accumulate == "sum_mod_p":
             acc = lax.rem(acc, jnp.int64(modulus))
         return acc
 
-    return jax.jit(step)
+    def step(acc, chunk, key, i):
+        # one chunk step: the round's key with the step's number folded in,
+        # the entry's default share randomness, the accumulate rule
+        return accumulated(acc, entry(chunk, jax.random.fold_in(key, i), plan))
+
+    if masking is None:
+        return jax.jit(step)
+
+    # imported where a masked round is built: an unmasked round's set-up does
+    # not pay for the mask stage's modules
+    from .masked import masked_chunk
+
+    chunk_fn = masked_chunk(entry, plan, masking)  # refuses a scheme that is not the plan's
+
+    def masked_step(acc, chunk, key, i):
+        out, seeds, counts = chunk_fn(chunk, jax.random.fold_in(key, i))
+        return accumulated(acc, out), seeds, counts
+
+    return jax.jit(masked_step)
 
 
 @dataclasses.dataclass(frozen=True)
 class FoldRound:
     """One fold round of a scheme at a dim and a chunk size: the jitted chunk
     step, the zero accumulator, the folds (of resident chunks, of host
-    blocks), the host epilogue and the reveal. Built by :func:`fold_round`;
-    holds no array."""
+    blocks), the host epilogue and the reveal; under a masking scheme also
+    the slack check and the unmasking. Built by :func:`fold_round`; holds no
+    array."""
 
     scheme: object
     plan: engine.AggregationPlan
@@ -163,8 +203,11 @@ class FoldRound:
     entry: object  # entry(secrets, key, plan) -> accumulator, one chunk
     accumulate: str  # "sum" | "sum_mod_p"
     acc_shape: tuple  # of the int64 accumulator a step takes and hands on
-    step: object  # jitted step(acc, chunk, key, i) -> acc
+    #: jitted step(acc, chunk, key, i) -> acc; under a masking scheme
+    #: masked_step(acc, chunk, key, i) -> (acc, seeds, counts)
+    step: object
     epilogue: object  # fn(acc_host, plan) -> (n, B) clerk sums
+    masking: object = None  # the round's protocol.ChaChaMasking, or None
 
     @property
     def modulus(self) -> int:
@@ -179,13 +222,33 @@ class FoldRound:
 
         return jnp.zeros(self.acc_shape, jnp.int64)
 
+    def _stepped(self, acc, chunk, key, number: int, handed: list):
+        """The accumulator after step ``number`` over ``chunk``; what a
+        masked step hands on beside it joins ``handed``."""
+        out = self.step(acc, chunk, key, np.int32(number))
+        if self.masking is None:
+            return out
+        acc, seeds, counts = out
+        handed.append((seeds, counts))
+        return acc
+
+    def _folded(self, acc, handed: list):
+        """What a fold returns: the accumulator, and under a masking scheme
+        the steps' seeds and counts beside it, in step order."""
+        if self.masking is None:
+            return acc
+        return acc, [seeds for seeds, _ in handed], [counts for _, counts in handed]
+
     def fold_chunks(self, chunks, key):
         """The accumulator of ``chunks`` (``(chunk, dim)`` arrays, resident
-        or not), step ``i`` over the ``i``-th of them."""
-        acc = self.zero_acc()
+        or not), step ``i`` over the ``i``-th of them. Under a masking scheme
+        ``(acc, seeds, counts)``: every step's ``(chunk, words)`` uint32 seeds
+        and ``(chunk,)`` int32 counts, in step order, as the device arrays the
+        steps returned."""
+        acc, handed = self.zero_acc(), []
         for i, chunk in enumerate(chunks):
-            acc = self.step(acc, chunk, key, np.int32(i))
-        return acc
+            acc = self._stepped(acc, chunk, key, i, handed)
+        return self._folded(acc, handed)
 
     def fold_host_rows(self, blocks, key, *, in_flight: int):
         """The accumulator of the rows of ``blocks``, host arrays ``(rows,
@@ -194,20 +257,22 @@ class FoldRound:
         once and at most :data:`LINK_BYTES` crossing the link (module doc).
         Returns with the last transfers and steps still running: the caller's
         ``block_until_ready`` on the accumulator is the wait for them. The
-        same accumulator as :meth:`fold_chunks` over the same rows in the same
-        order."""
+        same as :meth:`fold_chunks` over the same rows in the same order,
+        masked or not: under a masking scheme ``(acc, seeds, counts)``."""
         if in_flight < 1:
             raise ValueError("in_flight counts blocks: at least 1")
-        # the program's own `dispatch`: the puts and the waits nest inside it
-        # by time, and what is left of it is the host's own seconds
-        with telemetry.span("fabric.feed", in_flight=in_flight) as call:
-            acc, nbytes = self._feed(blocks, key, in_flight)
+        # the program's own `dispatch`: the puts, the waits and the step
+        # dispatches nest inside it by time, and what is left of it is the
+        # host's own seconds
+        masked = self.masking is not None
+        with telemetry.span("fabric.feed", in_flight=in_flight, masked=masked) as call:
+            folded, nbytes = self._feed(blocks, key, in_flight)
             if call is not None:  # telemetry is on
                 call["attrs"]["bytes"] = nbytes
-        return acc
+        return folded
 
     def _feed(self, blocks, key, in_flight: int):
-        """:meth:`fold_host_rows`' accumulator, and the bytes it put."""
+        """What :meth:`fold_host_rows` returns, and the bytes it put."""
         import jax
 
         fed_blocks = telemetry.counter(
@@ -217,7 +282,10 @@ class FoldRound:
         fed_bytes = telemetry.counter(
             "sda_fabric_fed_bytes_total", "bytes the feed put on the device"
         )
-        acc = self.zero_acc()
+        fed_seeds = telemetry.counter(
+            "sda_fabric_fed_seeds_total", "seeds the feed's masked steps handed on"
+        )
+        acc, handed = self.zero_acc(), []
         # the accumulator after each alive block's last step, oldest first:
         # when it is ready, that block's chunks have been folded
         alive = collections.deque()
@@ -235,18 +303,20 @@ class FoldRound:
                 with telemetry.span("fabric.feed.put", rows=self.chunk, bytes=rows.nbytes):
                     chunk = jax.device_put(rows)
                 crossing.add(chunk)
-                acc = self.step(acc, chunk, key, np.int32(steps))
+                with telemetry.span("fabric.step.dispatch", step=steps):
+                    acc = self._stepped(acc, chunk, key, steps, handed)
                 del chunk  # the feed's reference is the crossing's alone
                 steps += 1
             fed_blocks.inc()
             fed_rows.inc(block.shape[0])
             fed_bytes.inc(block.nbytes)
+            fed_seeds.inc(block.shape[0] if self.masking is not None else 0)
             nbytes += block.nbytes
             alive.append(acc)
         telemetry.gauge(
             "sda_fabric_feed_in_flight_max", "most blocks alive at once in the feed's last call"
         ).set(most)
-        return acc, nbytes
+        return self._folded(acc, handed), nbytes
 
     def _checked(self, block):
         block = np.asarray(block)
@@ -268,15 +338,47 @@ class FoldRound:
 
     def reveal(self, clerk_sums, clerks):
         """The ``(dim,)`` canonical int64 aggregate from the sums of the
-        0-based ``clerks`` (at least ``reconstruction_threshold`` of them)."""
+        0-based ``clerks`` (at least ``reconstruction_threshold`` of them);
+        in a masked round the *masked* aggregate, which :meth:`unmask` takes
+        the masks off."""
         out = shamir.reconstruct_clerk_sums_host(clerk_sums, clerks, self.scheme, self.plan.dim)
         return np.mod(np.asarray(out).astype(np.int64), self.modulus)
 
+    def short_windows(self, counts) -> int:
+        """The slack check of a masked round, over the steps' fetched
+        ``counts`` (one array a step): how many rows' keystream
+        windows held fewer than ``dim`` accepted draws. A round with any must
+        not be revealed (``masked.count_short_windows`` counts them)."""
+        from .masked import count_short_windows
 
-def fold_round(scheme, dim: int, entry, chunk: int) -> FoldRound:
+        self._masked_only()
+        return count_short_windows(np.concatenate([np.ravel(c) for c in counts]), self.plan.dim)
+
+    def unmask(self, masked_aggregate, seeds, *, chunk: int | None = None):
+        """The ``(dim,)`` canonical int64 aggregate of a masked round: what
+        :meth:`reveal` gave less the sum of the masks, which the recipient
+        re-expands from ``seeds`` alone (as it receives them: one vector of
+        the seed's uint32 words a participant, every row's, any order);
+        ``chunk`` seeds a device fold (``ChaChaMasker.combine``)."""
+        from ..crypto.masking import new_mask_combiner
+
+        self._masked_only()
+        masker = new_mask_combiner(self.masking)  # the recipient's ChaChaMasker
+        mask = masker.combine(seeds, chunk=chunk)
+        return np.mod(masker.unmask(mask, masked_aggregate), self.modulus)
+
+    def _masked_only(self) -> None:
+        if self.masking is None:
+            raise ValueError("the round has no masking scheme: nothing to check or take off")
+
+
+def fold_round(scheme, dim: int, entry, chunk: int, masking=None) -> FoldRound:
     """The round of ``scheme`` at ``dim`` through the chunk entry ``entry``
     (``entry(secrets, key, plan) -> accumulator``, one of the module doc's
-    table, or a ``functools.partial`` of one), ``chunk`` rows a step."""
+    table, or a ``functools.partial`` of one), ``chunk`` rows a step; under
+    ``masking`` (a ``protocol.ChaChaMasking`` of the plan's modulus and
+    dimension) the masked round of the module doc, whatever the entry's
+    accumulate rule."""
     ensure_x64()
     paired = _PAIRED.get(getattr(entry, "func", entry))
     if paired is None:
@@ -301,6 +403,7 @@ def fold_round(scheme, dim: int, entry, chunk: int) -> FoldRound:
         entry=entry,
         accumulate=accumulate,
         acc_shape=tuple(acc.shape),
-        step=_make_step(entry, plan, accumulate),
+        step=_make_step(entry, plan, accumulate, masking),
         epilogue=epilogue,
+        masking=masking,
     )

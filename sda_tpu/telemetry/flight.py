@@ -54,6 +54,7 @@ _TRACKS = (
     ("store", 7),
     ("crypto", 8),
     ("fabric.feed", 10),
+    ("fabric.step", 10),  # the feed's step dispatches, beside its puts and waits
     ("fabric.epilogue", 11),
     ("fabric.reconstruct", 11),
     ("fabric.unmask", 12),

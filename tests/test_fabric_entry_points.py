@@ -5,7 +5,10 @@ names the program's entries by dotted path: the chunk ``engine``, and either
 the host ``epilogue`` and the ``reconstruct`` or the round ``driver`` that
 pairs them itself (PR 34). Each must resolve, and none of its parameters may
 carry a default: a default is a second behaviour that no cell measures (the
-``draw=`` and ``exact=`` that a second benchmark used to select).
+``draw=`` and ``exact=`` that a second benchmark used to select). One
+parameter is a deployment's and not an option: the driver's ``masking``
+(PR 39), which a traffic file sets by naming a ``masking_scheme`` and leaves
+at ``None`` by naming none; cells measure it both ways.
 """
 
 import importlib
@@ -20,6 +23,9 @@ TRAFFIC = sorted((REPO / "benchmark" / "traffic").glob("*.json"))
 BOUND = ("engine", "epilogue", "reconstruct")
 #: what a traffic file that names the program's round driver binds instead
 BOUND_BY_DRIVER = ("engine", "driver")
+#: a driver's parameter that cells set through their traffic file, by the key
+#: that sets it: at its default in the cells whose file has no such key
+SET_BY_TRAFFIC = {"masking": "masking_scheme"}
 
 
 def resolve(dotted: str):
@@ -41,5 +47,20 @@ def test_every_bound_entry_resolves_and_has_no_defaulted_parameter(path):
             name
             for name, parameter in inspect.signature(entry).parameters.items()
             if parameter.default is not inspect.Parameter.empty
+            and not (role == "driver" and name in SET_BY_TRAFFIC)
         ]
         assert not defaulted, (path.name, role, traffic[role], "has options", defaulted)
+
+
+@pytest.mark.parametrize("parameter,key", sorted(SET_BY_TRAFFIC.items()))
+def test_a_drivers_parameter_set_by_traffic_is_measured_both_ways(parameter, key):
+    """Some cell's traffic file names the key and some cell's does not, both
+    through a driver that has the parameter, defaulted to ``None``."""
+    by_driver = [json.loads(path.read_text()) for path in TRAFFIC]
+    by_driver = [traffic for traffic in by_driver if "driver" in traffic]
+    assert {key in traffic for traffic in by_driver} == {True, False}
+    for traffic in by_driver:
+        default = inspect.signature(resolve(traffic["driver"])).parameters[parameter].default
+        assert default is None, (traffic["name"], parameter)
+        if key in traffic:
+            assert callable(resolve(traffic[key]))

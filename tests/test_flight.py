@@ -185,6 +185,7 @@ def test_mixed_records_order_and_export_deterministically():
 def test_the_fabric_and_jax_spans_have_tracks_of_their_own():
     spans = [
         _mono("fabric.feed", 0.0, 1.0), _mono("fabric.feed.put", 0.1, 0.1),
+        _mono("fabric.step.dispatch", 0.2, 0.1, step=0),
         _mono("fabric.epilogue.recombine", 1.0, 0.1), _mono("fabric.reconstruct", 1.1, 0.1),
         _mono("fabric.unmask.combine", 1.2, 0.1), _mono("jax.lower", 1.3, 0.1, program="jit_step"),
         _mono("fabric.something_else", 1.4, 0.1),
@@ -194,6 +195,7 @@ def test_the_fabric_and_jax_spans_have_tracks_of_their_own():
     by_name = {e["name"]: tracks[e["tid"]] for e in events if e["ph"] == "X"}
     assert by_name == {
         "fabric.feed": "fabric.feed", "fabric.feed.put": "fabric.feed",
+        "fabric.step.dispatch": "fabric.feed",
         "fabric.epilogue.recombine": "fabric.epilogue", "fabric.reconstruct": "fabric.epilogue",
         "fabric.unmask.combine": "fabric.unmask", "jax.lower": "jax",
         "fabric.something_else": "other",
