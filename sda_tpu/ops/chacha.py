@@ -107,9 +107,10 @@ def chacha_blocks(key_words: np.ndarray, first_counter: int, n_blocks: int) -> n
 def chacha_state_jnp(key_words, first_counter: int, n_blocks: int):
     """Initial ChaCha20 states: (n_blocks, 16) uint32 (pre-round input).
 
-    Shared by the jnp round loop and the Pallas kernel (chacha_pallas.py) so
-    every backend starts from identical bits. ``key_words`` may be a traced
-    (8,) uint32 array.
+    What the jnp round loop starts from. The Pallas kernel
+    (chacha_pallas.py) makes the same sixteen words in registers from the key
+    and a block index and reads no state; the tests hold both to the numpy
+    blocks above. ``key_words`` may be a traced (8,) uint32 array.
     """
     from .jaxcfg import ensure_x64
 

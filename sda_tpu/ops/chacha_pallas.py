@@ -9,10 +9,14 @@ client/src/receive.rs:102-118 + chacha.rs:56-77). Two stages of that
 expansion bounce whole tensors through HBM when written in jax.numpy, and
 each has a kernel here that keeps them on the chip:
 
-* ``chacha_rounds``. The jnp twin (ops/chacha.py) materializes 16 full word
-  tensors between every one of the 80 quarter rounds; the kernel keeps the
-  whole 16-word state in VMEM/registers for all 20 rounds and touches HBM
-  exactly twice per block (load initial state, store keystream).
+* ``chacha_rounds``. The jnp twin (ops/chacha.py) builds a ``(P, blocks, 16)``
+  tensor of initial states and materializes 16 full word tensors between
+  every one of the 80 quarter rounds; the kernel takes the seeds alone, makes
+  each block's state in registers (the constants, the row's key words, the
+  block's index from an iota), keeps the 16 words there for all 20 rounds and
+  touches HBM once a block: to store the keystream. (Built in XLA, the
+  states of 500 seeds of 13 400 blocks are 4.3e8 B written, transposed and
+  read a call, and cost more than the rounds: PERF.md section 6, PR 40.)
 * ``chacha_compact``. Picking a row's first ``dim`` accepted draws is a
   stable compaction, a shift-and-select stage for every bit of the largest
   shift (``_first_accepted``); in XLA each stage is a pass over three
@@ -20,21 +24,23 @@ each has a kernel here that keeps them on the chip:
   from the first stage to the last and HBM sees the draws once, going in,
   and the first ``dim`` of them once, coming out.
 
-Layout of the rounds: states are carried as ``(16, n_blocks)`` uint32 — one
-word per sublane row, blocks along the 128-wide lane axis — so every
-quarter-round op is a full-width VPU op on ``(tile,)`` lanes. The grid tiles
-the block axis; each kernel instance processes ``tile`` blocks independently
-(ChaCha blocks share no state). Multi-seed batches flatten (seeds x blocks)
-onto the same lane axis — one kernel launch expands every participant's
-stream. Layout of the compaction: rows on the sublanes, eight a tile, a
-row's draws along the lanes (``_compact_plan``).
+Layout of the rounds: a tile is eight seeds on the sublanes by a row's blocks
+along the 128-wide lane axis, so each of the 16 words is whole ``(8, lanes)``
+vregs and every quarter-round op a full-width VPU op; a row's key words
+broadcast along the lanes. The grid tiles rows and blocks (``_rounds_plan``);
+inside a grid step a loop walks the lanes, ``_ROUNDS_CHUNK`` at a time (ChaCha
+blocks share no state). The kernel writes ``(16, P, blocks)``, word-major, and
+nothing is padded in HBM; the transposition to stream order is XLA's. One
+launch expands every participant's stream. Layout of the compaction: rows on
+the sublanes, eight a tile, a row's draws along the lanes (``_compact_plan``).
 
-Bit parity: every path (numpy host, jnp, Pallas) runs the same djb quarter
-round over states from the one state builder (``chacha_state_jnp``) and the
-same compaction stages, so outputs are bit-identical — asserted in
-tests/test_ops_field.py on the interpreter and by ``chip_smoke.py`` on the
-TPU. ``ChaChaMasker.combine`` (crypto/masking.py) dispatches here for large
-reveal batches.
+Bit parity: every traced path runs the same djb quarter round
+(``apply_rounds_jnp``) and the same compaction stages. The jnp twin starts
+from the state builder ``chacha_state_jnp``; the kernel makes its states
+itself and is held to the numpy host path's bits, which share no code with
+either, in tests/test_chacha_rounds.py and tests/test_ops_field.py on the
+interpreter and by ``chip_smoke.py`` on the TPU. ``ChaChaMasker.combine``
+(crypto/masking.py) dispatches here for large reveal batches.
 """
 
 from __future__ import annotations
@@ -45,47 +51,118 @@ import logging
 from .. import telemetry
 from .chacha import apply_rounds_jnp, chacha_rounds_jnp, chacha_state_jnp, rand03_zone
 
-# lane-axis tile: 512 blocks x 16 words x 4 B x 2 (in+out) = 64 KiB of VMEM
-_TILE = 512
+#: lanes (blocks of a row) one grid step of the rounds kernel writes at most,
+#: and lanes one step of its inner loop holds in registers, sixteen words of
+#: ``_ROUNDS_CHUNK // 128`` vregs each. The kernel is the VPU's: 500 rows of
+#: 13 400 blocks took 3.02, 2.03, 1.93 ms at 128, 256, 512 lanes a loop step
+#: (4096 a grid step), 2.14, 2.03, 1.93 ms at 512, 4096, 16 384 a grid step
+#: (256 a loop step) and 1.85 ms at these (a v5e; 6.47 ms from states read in
+#: HBM, 1-D word rows, 13 086 grid steps; PERF.md section 6, PR 40)
+_ROUNDS_LANES = 16384
+_ROUNDS_CHUNK = 512
 
 
-def _rounds_kernel(state_ref, out_ref):
-    init = [state_ref[i, :] for i in range(16)]
-    # fully unrolled inside the kernel; round body shared with the jnp twin
-    x = apply_rounds_jnp(list(init))
-    for i in range(16):
-        out_ref[i, :] = x[i] + init[i]
+def _rounds_plan(n_blocks: int):
+    """``(grid steps along a row, lanes a step)``: the row's blocks split
+    evenly over the fewest steps of at most ``_ROUNDS_LANES``, a step's lanes
+    rounded up to whole loop steps, so the last step's overhang past the row
+    (computed, never written) stays under one loop step a grid step."""
+    steps = -(-n_blocks // _ROUNDS_LANES)
+    lanes = -(-n_blocks // (steps * _ROUNDS_CHUNK)) * _ROUNDS_CHUNK
+    return -(-n_blocks // lanes), lanes
 
 
-def _rounds_pallas(states, *, interpret: bool = False):
-    """(N, 16) uint32 initial states -> (N, 16) keystream via the kernel."""
+def _rounds_kernel(seeds_ref, out_ref, *, first_counter: int):
+    """Eight rows' keystream, ``out_ref`` ``(16, 8, lanes)``: word-major, a
+    row a sublane, its blocks along the lanes. The initial states are made
+    here, in registers, from the rows' eight key words (``seeds_ref``, ``(8,
+    8)``, broadcast along the lanes) and the blocks' indices (an iota plus
+    this step's place in the row): the constants, the key, the counter's low
+    word, and zeros for its high word (the entry refuses a counter that would
+    reach it) and the nonce. No state is read from HBM."""
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+    from jax.experimental import pallas as pl
+
+    from .chacha import _CONSTANTS
+
+    lanes, chunk = out_ref.shape[2], _ROUNDS_CHUNK
+    # typed constants throughout: under x64 a python number traces 64 bits wide
+    shape = (8, chunk)
+    constants = [jnp.full(shape, c, jnp.uint32) for c in _CONSTANTS]
+    key = [jnp.broadcast_to(seeds_ref[:, w : w + 1], shape) for w in range(8)]
+    zeros = jnp.zeros(shape, jnp.uint32)
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    # int32 wraps as the counter's uint32 does: the same bits
+    first = jnp.int32(np.uint32(first_counter).astype(np.int32))
+    here = pl.program_id(1) * jnp.int32(lanes) + first
+
+    def step(c, carry):
+        at = pl.multiple_of(c * jnp.int32(chunk), chunk)
+        counter = lax.bitcast_convert_type(lane + (here + at), jnp.uint32)
+        init = [*constants, *key, counter, zeros, zeros, zeros]
+        # fully unrolled inside the kernel; round body shared with the jnp twin
+        x = apply_rounds_jnp(list(init))
+        for i in range(16):
+            out_ref[i, :, pl.ds(at, chunk)] = x[i] + init[i]
+        return carry
+
+    lax.fori_loop(jnp.int32(0), jnp.int32(lanes // chunk), step, jnp.int32(0))
+
+
+def _rounds_pallas(seed_words, n_blocks: int, first_counter: int = 0, *, interpret: bool = False):
+    """``(P, w <= 8)`` uint32 seeds -> ``(P, n_blocks, 16)`` keystream, each
+    row's blocks ``first_counter`` onwards, via the kernel ``chacha_rounds``.
+    The seeds are the kernel's only operand. It writes ``(16, P, n_blocks)``
+    (nothing padded in HBM: the blocks reach past the array's rows and lanes,
+    and the pipeline drops what lies there); the transposition to stream
+    order stays XLA's."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     from .jaxcfg import I32_ZERO as zero  # literal 0 would trace as i64
 
-    n = states.shape[0]
-    padded = max(-(-n // _TILE), 1) * _TILE
-    st = jnp.zeros((16, padded), dtype=jnp.uint32).at[:, :n].set(states.T)
+    rows, width = seed_words.shape
+    keys = jnp.pad(seed_words.astype(jnp.uint32), ((0, 0), (0, 8 - width)))
+    steps, lanes = _rounds_plan(n_blocks)
     out = pl.pallas_call(
-        _rounds_kernel,
-        grid=(padded // _TILE,),
-        in_specs=[pl.BlockSpec((16, _TILE), lambda i: (zero, i))],
-        out_specs=pl.BlockSpec((16, _TILE), lambda i: (zero, i)),
-        out_shape=jax.ShapeDtypeStruct((16, padded), jnp.uint32),
+        functools.partial(_rounds_kernel, first_counter=first_counter),
+        grid=(-(-rows // 8), steps),
+        in_specs=[pl.BlockSpec((8, 8), lambda i, j: (i, zero))],
+        out_specs=pl.BlockSpec((16, 8, lanes), lambda i, j: (zero, i, j)),
+        out_shape=jax.ShapeDtypeStruct((16, rows, n_blocks), jnp.uint32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            # the output's two buffers, and room for the loop's spills
+            vmem_limit_bytes=2 * 16 * 8 * lanes * 4 + (8 << 20),
+        ),
         interpret=interpret,
         name="chacha_rounds",
-    )(st)
-    return out[:, :n].T
+    )(keys)
+    return jnp.transpose(out, (1, 2, 0))
+
+
+def _rounds_jnp(seed_words, n_blocks: int, first_counter: int):
+    """The jnp twin of :func:`_rounds_pallas`, what every platform that is not
+    a TPU runs: the states from ``chacha_state_jnp``, a row at a time under
+    ``vmap``, then ``chacha_rounds_jnp``. The kernel is held to its bits."""
+    import jax
+
+    states = jax.vmap(lambda s: chacha_state_jnp(s, first_counter, n_blocks))(seed_words)
+    return chacha_rounds_jnp(states)
 
 
 def chacha_blocks_pallas(
     key_words, first_counter: int, n_blocks: int, *, interpret: bool = False
 ):
     """Pallas twin of ``chacha_blocks``: (n_blocks, 16) uint32 keystream."""
-    state = chacha_state_jnp(key_words, first_counter, n_blocks)
-    return _rounds_pallas(state, interpret=interpret)
+    import jax.numpy as jnp
+
+    key = jnp.asarray(key_words, dtype=jnp.uint32)[None, :]
+    return _rounds(key, n_blocks, first_counter, "interpret" if interpret else "pallas")[0]
 
 
 def default_backend() -> str:
@@ -100,26 +177,52 @@ def default_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
-def _rounds(states, backend: str):
-    """Dispatch ``(N, 16) -> (N, 16)`` rounds by backend name.
+def count_blocks(path: str, blocks: int) -> None:
+    """``blocks`` more ChaCha blocks whose rounds a traced program runs by
+    ``path``: counted where the path is chosen, once a trace, as
+    :func:`count_compaction` counts rows."""
+    telemetry.counter(
+        "sda_crypto_chacha_blocks_total",
+        "ChaCha blocks whose rounds a traced program runs, by path "
+        "(pallas | interpret | jnp)",
+        path=path,
+    ).inc(blocks)
+
+
+def _rounds(seed_words, n_blocks: int, first_counter: int, backend: str):
+    """``(P, w <= 8)`` uint32 seeds -> ``(P, n_blocks, 16)`` keystream, every
+    row's blocks ``first_counter`` to ``first_counter + n_blocks``, by backend
+    name.
 
     ``auto`` is decided where the program is lowered, from the devices it is
-    compiled for (``lax.platform_dependent``): the compiled kernel for a TPU,
-    attached or only described, the jnp twin for anything else. ``pallas`` /
+    compiled for (``lax.platform_dependent``; counted as this process's
+    backend, which is what runs it): the compiled kernel for a TPU, attached
+    or only described, the jnp twin for anything else. ``pallas`` /
     ``interpret`` / ``jnp`` force a specific path (interpret = Pallas
-    interpreter, for CPU tests of the kernel source).
+    interpreter, for CPU tests of the kernel source). The kernel makes the
+    counter's low word only, so a counter that reaches 2^32 is refused here,
+    under every name.
     """
+    if backend not in ("auto", "pallas", "interpret", "jnp"):
+        raise ValueError(f"unknown backend {backend!r}")
+    if first_counter < 0 or first_counter + n_blocks >= 1 << 32:
+        raise ValueError(
+            f"blocks {first_counter} to {first_counter + n_blocks} of a row: "
+            "the block counter is held to 32 bits"
+        )
+    count_blocks(default_backend() if backend == "auto" else backend, seed_words.shape[0] * n_blocks)
+    blocks = dict(n_blocks=n_blocks, first_counter=first_counter)
     if backend == "auto":
         from jax import lax
 
-        return lax.platform_dependent(states, tpu=_rounds_pallas, default=chacha_rounds_jnp)
-    if backend == "pallas":
-        return _rounds_pallas(states)
-    if backend == "interpret":
-        return _rounds_pallas(states, interpret=True)
+        return lax.platform_dependent(
+            seed_words,
+            tpu=functools.partial(_rounds_pallas, **blocks),
+            default=functools.partial(_rounds_jnp, **blocks),
+        )
     if backend == "jnp":
-        return chacha_rounds_jnp(states)
-    raise ValueError(f"unknown backend {backend!r}")
+        return _rounds_jnp(seed_words, **blocks)
+    return _rounds_pallas(seed_words, **blocks, interpret=backend == "interpret")
 
 
 class SlackExhausted(RuntimeError):
@@ -402,7 +505,6 @@ def expand_seeds_counts(seed_words, dim: int, modulus: int, backend: str = "jnp"
     from .jaxcfg import ensure_x64
 
     ensure_x64()
-    import jax
     import jax.numpy as jnp
 
     from .modular import mod_u64_const
@@ -414,9 +516,7 @@ def expand_seeds_counts(seed_words, dim: int, modulus: int, backend: str = "jnp"
     zone = rand03_zone(modulus)  # rand-0.3 exact: rejection always applies
     need_pairs = _window_pairs(dim, modulus)
     n_blocks = (need_pairs * 2 + 15) // 16
-    states = jax.vmap(lambda s: chacha_state_jnp(s, 0, n_blocks))(seed_words)
-    words = _rounds(states.reshape(P * n_blocks, 16), backend)
-    words = words.reshape(P, n_blocks * 16)
+    words = _rounds(seed_words, n_blocks, 0, backend).reshape(P, n_blocks * 16)
     # a draw is (high word, low word); the two stay apart, in the chip's own
     # 32-bit lanes, until the first ``dim`` accepted ones are picked
     hi, lo = words[:, 0::2], words[:, 1::2]
@@ -505,8 +605,8 @@ def count_slack_exhausted(side: str, rows: int) -> None:
 #: transient device-memory budget per fold of combine_masks_device; the
 #: expansion materializes ~5 chunk x dim x 8 B tensors at peak (the
 #: keystream, its word pairs and their shifts, the compacted pairs, the final
-#: masks): as many with the compaction in its kernel as with its stages in XLA
-#: (the compiler reserves 3.86e9 B for a fold of 500 x 100 000 either way)
+#: masks) where the states are built in XLA too; with both kernels the
+#: compiler reserves 1.05e9 B for a fold of 500 x 100 000 (3.86e9 before PR 40)
 _COMBINE_BYTES_BUDGET = 2 << 30
 
 

@@ -386,8 +386,8 @@ def test_no_64_bit_types_inside_kernel_bodies():
 
     ensure_x64()
     chacha = _pallas_body_dtypes(
-        lambda st: chacha_pallas._rounds_pallas(st, interpret=True),
-        jnp.zeros((700, 16), jnp.uint32),
+        lambda seeds: chacha_pallas._rounds_pallas(seeds, 700, 5, interpret=True),
+        jnp.zeros((9, 4), jnp.uint32),
     )
     p = (1 << 31) - 1
     stacks = fold_const_limbs(np.arange(56).reshape(7, 8) % p, p)

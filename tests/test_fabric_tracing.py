@@ -302,8 +302,8 @@ def test_kernels_off_the_cells_paths_have_stable_names():
             if eqn.primitive.name == "pallas_call"
         ]
 
-    states = jnp.zeros((4, 16), jnp.uint32)
-    assert kernel_names(lambda s: chacha_pallas._rounds_pallas(s, interpret=True), states) == [
+    seeds = jnp.zeros((4, 4), jnp.uint32)
+    assert kernel_names(lambda s: chacha_pallas._rounds_pallas(s, 3, interpret=True), seeds) == [
         "chacha_rounds"
     ]
     plan = plan_for(31)[1]
