@@ -24,7 +24,10 @@ field, dim 100 000), through the entry points a user calls:
   all be the same bits;
 - sharded leg, with more than one chip: every fabric
   ``__graft_entry__.dryrun_multichip`` walks, plus the sum-first limb psum
-  at dim 100 000, revealed and compared the same way.
+  at dim 100 000, revealed and compared the same way, and the masked round
+  over all the chips through ``fold_round(..., masking=, mesh=)``: every
+  chip's own seeds, both kernels inside the ``shard_map`` body, the
+  recipient's fold sharded too.
 
 Rows are cut (never width); weights are seeded random. Any leg that raises
 ends the run non-zero. Wall times are printed as information only. The
@@ -495,11 +498,55 @@ def sharded_leg(*, dim: int = 100_000, rows_per_shard: int = 256) -> None:
     )
     clerk_sums, _ = clerk_sums_from_limb_acc(acc, plan)
     _check_reveal("sharded leg", clerk_sums, scheme, secrets)
+    masked_round_over(n_devices, scheme, secrets[: rows_per_shard // 2 * n_devices])
     say(
         f"sharded leg ok: {n_devices} devices, six dry-run fabrics + sum-first "
         f"limb psum over p={mesh.shape['p']} d={d_size} at dim {dim}, {rows} "
-        f"rows, exact ({time.perf_counter() - t0:.1f} s)"
+        f"rows, exact + the masked round over p={n_devices} "
+        f"({time.perf_counter() - t0:.1f} s)"
     )
+
+
+def masked_round_over(n_devices: int, scheme, secrets) -> None:
+    """The masked round on however many chips there are, through the round
+    driver with the deployment's mesh (``fold_round(..., masking=, mesh=)``):
+    rows over ``p``, every chip masking its own under seeds of its own (both
+    kernels inside the ``shard_map`` body on a TPU), the recipient's sharded
+    fold over the seeds where they lie. The unmasked aggregate must be the
+    exact column sums, the clerks' sums must carry masks, and no two rows may
+    share a seed."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from sda_tpu.parallel import fold_round, make_mesh, shard_participants, sumfirst
+    from sda_tpu.protocol import ChaChaMasking
+
+    p, (rows, dim) = scheme.prime_modulus, secrets.shape
+    mesh = make_mesh(p_size=n_devices, d_size=1)
+    driver = fold_round(
+        scheme, dim, sumfirst.value_limb_sums_chunk, rows,
+        masking=ChaChaMasking(p, dim, 128), mesh=mesh,
+    )
+    acc, seeds, counts = driver.fold_chunks(
+        [shard_participants(jnp.asarray(secrets), mesh)], jax.random.key(6)
+    )
+    if driver.short_windows(counts):
+        raise SmokeFailure("sharded leg, masked: a rejection window came short (~1e-9 a row)")
+    clerk_sums = driver.clerk_sums(acc)
+    masked = driver.reveal(clerk_sums, range(1, 1 + scheme.reconstruction_threshold))
+    seed_rows = np.concatenate([np.asarray(s) for s in seeds])
+    if len({tuple(row) for row in seed_rows}) != rows:
+        raise SmokeFailure("sharded leg, masked: two rows share a seed")
+    lo = (secrets & 0xFFFFFFFF).astype(np.uint64).sum(axis=0)
+    hi = (secrets >> 32).astype(np.uint64).sum(axis=0)
+    want = [((int(h) << 32) + int(l)) % p for h, l in zip(hi, lo)]
+    if [int(v) for v in masked] == want:
+        raise SmokeFailure("sharded leg, masked: the clerks' sums carry no mask")
+    if [int(v) for v in driver.unmask(masked, seeds)] != want:
+        raise SmokeFailure(
+            "sharded leg, masked: the unmasked aggregate != the exact column sums"
+        )
 
 
 def main() -> int:

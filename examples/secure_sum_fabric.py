@@ -17,7 +17,10 @@ Three stages, each verified against an independent plaintext sum:
    the feed hands on every row's seed, the recipient unmasks from the seeds;
 3. the sharded fabric — the same sum-first loop over a device Mesh
    (participants sharded over axis ``p``, dims over ``d``), one int64
-   ``psum`` carrying the tiny accumulator across the mesh.
+   ``psum`` carrying the tiny accumulator across the mesh; then the masked
+   round over a mesh through the round driver (`fold_round(..., masking=,
+   mesh=)`): every chip masks its own rows, the recipient's re-expansion is
+   sharded too.
 
 Run:  python examples/secure_sum_fabric.py
 (forces an 8-device virtual CPU mesh so it runs anywhere — an ambient
@@ -136,6 +139,22 @@ def main():
     assert np.array_equal(positive(np.asarray(out), p), shard.sum(axis=0) % p)
     print(f"3. sharded fabric OK: mesh p={mesh.shape['p']} x d={mesh.shape['d']}, "
           "limb accumulator psum'd across the mesh, aggregate verified")
+
+    # the masked round over a mesh: the layout is an argument of the round
+    # as the masking scheme is; every chip masks its own rows under seeds of
+    # its own, and the recipient's re-expansion runs where the seeds lie
+    flat = Mesh(np.array(devs[: p_size * d_size]).reshape(-1, 1), axis_names=("p", "d"))
+    mesh_round = fold_round(
+        scheme, dim, value_limb_sums_chunk, 1_024, masking=ChaChaMasking(p, dim, 128), mesh=flat
+    )
+    rows = jax.device_put(jnp.asarray(shard), NamedSharding(flat, P("p", "d")))
+    acc, seeds, counts = mesh_round.fold_chunks([rows], jax.random.key(5))
+    assert mesh_round.short_windows(counts) == 0
+    masked = mesh_round.reveal(mesh_round.clerk_sums(acc), survivors)
+    assert not np.array_equal(masked, shard.sum(axis=0) % p)
+    assert np.array_equal(mesh_round.unmask(masked, seeds), shard.sum(axis=0) % p)
+    print(f"3b. masked round over the mesh OK: p={flat.shape['p']}, every chip's own seeds, "
+          "unmasked from the seeds where they lie")
 
 
 if __name__ == "__main__":

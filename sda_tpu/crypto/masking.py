@@ -141,15 +141,28 @@ class ChaChaMasker(SecretMasker, MaskCombiner, SecretUnmasker):
     #: below this many expanded elements the host loop beats device dispatch
     DEVICE_COMBINE_THRESHOLD = 1 << 22
 
-    def combine(self, seeds, *, chunk: int | None = None):
+    def combine(self, seeds, *, chunk: int | None = None, mesh=None):
         """Sum of the seeds' masks mod m. ``chunk`` is how many seeds one
         device fold expands (``combine_masks_device``'s default fits its own
-        memory budget; a recipient that shares the chip says less)."""
-        seed_rows = [np.asarray(s, dtype=np.int64).astype(np.uint32) for s in seeds]
-        on_device = len(seed_rows) * self.dimension >= self.DEVICE_COMBINE_THRESHOLD
+        memory budget; a recipient that shares the chip says less). Given a
+        ``mesh`` (a device mesh: the masked round's, ``FoldRound.unmask``) the
+        device fold runs on every chip of it, ``chunk`` seeds a chip a fold;
+        ``seeds`` are then one vector of words a participant, as ever, or
+        ``(rows, words)`` uint32 device arrays sharded over that mesh, which
+        are folded where they lie."""
+        placed = mesh is not None and len(seeds) > 0 and all(np.ndim(s) == 2 for s in seeds)
+        if placed:
+            seed_rows, count = list(seeds), sum(int(s.shape[0]) for s in seeds)
+        else:
+            seed_rows = [np.asarray(s, dtype=np.int64).astype(np.uint32) for s in seeds]
+            count = len(seed_rows)
+        on_device = count * self.dimension >= self.DEVICE_COMBINE_THRESHOLD
+        if placed and not on_device:  # too few to be worth the chips: as uploaded
+            seed_rows = list(np.concatenate([np.asarray(s) for s in seed_rows]))
         with telemetry.span(
-            "fabric.unmask.combine", seeds=len(seed_rows),
+            "fabric.unmask.combine", seeds=count,
             path="device" if on_device else "host",
+            chips=mesh.size if on_device and mesh is not None else 1,
         ):
             if on_device:
                 # reveal hot loop (receive.rs:102-118): expand + sum on device
@@ -159,7 +172,8 @@ class ChaChaMasker(SecretMasker, MaskCombiner, SecretUnmasker):
 
                 return np.asarray(
                     combine_masks_device(
-                        np.stack(seed_rows), self.dimension, self.modulus, chunk=chunk
+                        seed_rows if placed else np.stack(seed_rows),
+                        self.dimension, self.modulus, chunk=chunk, mesh=mesh,
                     )
                 )
             if not seed_rows:

@@ -41,6 +41,12 @@ itself and is held to the numpy host path's bits, which share no code with
 either, in tests/test_chacha_rounds.py and tests/test_ops_field.py on the
 interpreter and by ``chip_smoke.py`` on the TPU. ``ChaChaMasker.combine``
 (crypto/masking.py) dispatches here for large reveal batches.
+
+Over a device mesh both kernels run inside a ``shard_map`` body, on a chip's
+local seeds (no partitioning rule is needed there): the masked round's step
+(``parallel/round.py``) and the recipient's fold, which
+``combine_masks_device(..., mesh=)`` spreads over the round's chips
+(``fold_chunk_mesh_jit``).
 """
 
 from __future__ import annotations
@@ -591,6 +597,45 @@ def fold_chunk_jit():
     return _FOLD_CHUNK_JIT
 
 
+@functools.lru_cache(maxsize=None)
+def fold_chunk_mesh_jit(mesh):
+    """The recipient's jitted fold over ``mesh``: ``fn(seeds (chips * P, w)
+    uint32, dim, modulus, backend) -> ((dim,) partial mask sum mod m of all of
+    them, (chips, dim) every chip's own partial, (chips * P,) accepted
+    counts)``, the last three arguments static. The seeds are sharded over all
+    of the mesh's axes, ``P`` a chip in the mesh's device order; every chip
+    runs :func:`_fold_chunk`, both kernels, on the seeds of its own shard, and
+    the chips' partials meet under ``fabric.unmask/meet``: gathered, then
+    summed by ``mod_sum_wide_jnp``, whose pair sums stay under 2m (a plain
+    int64 ``psum`` of eight canonical 61-bit partials is 2^63). The met sum is
+    on every chip; a chip's own partial and its counts stay where they were
+    made. One program a mesh, public as :func:`fold_chunk_jit` is."""
+    import jax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from .modular import mod_sum_wide_jnp
+
+    axes = mesh.axis_names
+
+    def _fold_chunk_mesh(batch, dim: int, modulus: int, backend: str):
+        def local_fold(seeds):
+            part, counts = _fold_chunk(seeds, dim, modulus, backend)
+            with jax.named_scope("fabric.unmask/meet"):
+                met = mod_sum_wide_jnp(lax.all_gather(part, axes), modulus, axis=0)
+            return met, part[None], counts
+
+        return jax.shard_map(
+            local_fold,
+            mesh=mesh,
+            in_specs=P(axes),
+            out_specs=(P(), P(axes), P(axes)),
+            check_vma=False,
+        )(batch)
+
+    return jax.jit(_fold_chunk_mesh, static_argnums=(1, 2, 3))
+
+
 def count_slack_exhausted(side: str, rows: int) -> None:
     """``rows`` more seeds whose window held fewer than ``dim`` accepted
     draws, on the ``participant``'s side (a chunk step's counts, checked by
@@ -610,8 +655,128 @@ def count_slack_exhausted(side: str, rows: int) -> None:
 _COMBINE_BYTES_BUDGET = 2 << 30
 
 
+def count_fold_chips(chips: int) -> None:
+    """One more device fold of the recipient's combine, on ``chips`` chips."""
+    telemetry.counter(
+        "sda_crypto_chacha_folds_total", "device folds of the recipient's combine"
+    ).inc()
+    telemetry.counter(
+        "sda_crypto_chacha_fold_chips_total",
+        "chips the recipient's device folds ran on, added a fold",
+    ).inc(chips)
+
+
+def _host_fold(batch, dim: int, modulus: int):
+    """The partial mask sum of ``batch`` by the host path, which extends a
+    seed's stream on demand: what recovers a fold whose window came short
+    (~1e-9 a row), and folds the few seeds that do not divide over a mesh."""
+    import numpy as np
+
+    from .chacha import expand_seed
+    from .modular import mod_sum_wide_np
+
+    masks = np.stack([expand_seed(s, dim, modulus) for s in batch])
+    return mod_sum_wide_np(masks, modulus, axis=0)
+
+
+@functools.lru_cache(maxsize=None)
+def _own_rows_jit(mesh, rows: int):
+    """``fn(seeds, start) -> seeds``: of ``(n, w)`` seeds sharded over all of
+    ``mesh``'s axes, every chip's own rows ``start`` to ``start + rows``, as
+    sharded; nothing crosses. One program a mesh and a slice length."""
+    import jax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    by_rows = P(mesh.axis_names)
+    return jax.jit(
+        jax.shard_map(
+            lambda seeds, start: lax.dynamic_slice_in_dim(seeds, start, rows),
+            mesh=mesh, in_specs=(by_rows, P()), out_specs=by_rows, check_vma=False,
+        )
+    )
+
+
+def _mesh_batches(seed_words, chunk: int, mesh):
+    """``(batches, rest)``: what :func:`combine_masks_device` folds over
+    ``mesh``, a call a batch (``(rows, w)`` uint32 device arrays sharded over
+    all the mesh's axes, at most ``chunk`` rows a chip), and the host rows that
+    are left: the fewer than ``mesh.size`` seeds that do not divide over the
+    chips. Host rows are put sharded, ``chunk`` a chip a call. Device arrays (a
+    list of them, each sharded over the mesh by rows) are folded where they
+    lie: one of at most ``chunk`` rows a chip as it is, a longer one as slices
+    of every chip's own rows."""
+    import jax
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    chips = mesh.size
+    if not isinstance(seed_words, (list, tuple)):
+        seed_words = np.asarray(seed_words, dtype=np.uint32)
+        sharded = NamedSharding(mesh, P(mesh.axis_names))
+        whole = seed_words.shape[0] - seed_words.shape[0] % chips
+        batches = [
+            jax.device_put(seed_words[start : min(start + chunk * chips, whole)], sharded)
+            for start in range(0, whole, chunk * chips)
+        ]
+        return batches, seed_words[whole:]
+    batches = []
+    for seeds in seed_words:
+        local, over = divmod(seeds.shape[0], chips)
+        if over:
+            raise ValueError(
+                f"{seeds.shape[0]} seeds on the device do not divide over {chips} chips"
+            )
+        if local <= chunk:
+            batches.append(seeds)
+            continue
+        for start in range(0, local, chunk):
+            own_rows = _own_rows_jit(mesh, min(chunk, local - start))
+            batches.append(own_rows(seeds, np.int32(start)))
+    return batches, np.zeros((0, 0), np.uint32)
+
+
+def _combine_over_mesh(seed_words, dim: int, modulus: int, chunk: int, backend: str, mesh):
+    """:func:`combine_masks_device` over ``mesh``: ``(the (dim,) combined mask,
+    on every chip of the mesh; the seeds folded)``."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from .modular import mod_sum_wide_np
+
+    chips = mesh.size
+    fold = fold_chunk_mesh_jit(mesh)
+    batches, rest = _mesh_batches(seed_words, chunk, mesh)
+    total = jnp.zeros((dim,), dtype=jnp.int64)
+    for batch in batches:
+        met, parts, counts = fold(batch, dim, modulus, backend)
+        count_fold_chips(chips)
+        short = np.asarray(counts).reshape(chips, -1) < dim
+        if short.any():
+            count_slack_exhausted("recipient", int(short.sum()))
+            logging.getLogger(__name__).info(
+                "rejection slack exhausted on %d chip(s); host-expanding their batches",
+                int(short.any(axis=1).sum()),
+            )
+            parts = np.array(parts)
+            own = np.asarray(batch).reshape(chips, -1, batch.shape[1])
+            for chip in np.flatnonzero(short.any(axis=1)):
+                parts[chip] = _host_fold(own[chip], dim, modulus)
+            met = jnp.asarray(mod_sum_wide_np(parts, modulus, axis=0))
+        total = (total + met) % jnp.int64(modulus)
+    if rest.shape[0]:
+        total = (total + _host_fold(rest, dim, modulus)) % jnp.int64(modulus)
+    return total, sum(int(batch.shape[0]) for batch in batches) + int(rest.shape[0])
+
+
 def combine_masks_device(
-    seed_words, dim: int, modulus: int, *, chunk: int | None = None, backend: str = "auto"
+    seed_words,
+    dim: int,
+    modulus: int,
+    *,
+    chunk: int | None = None,
+    backend: str = "auto",
+    mesh=None,
 ):
     """Recipient reveal hot loop on device: Σ_p expand(seed_p) mod m.
 
@@ -622,6 +787,14 @@ def combine_masks_device(
     ``_COMBINE_BYTES_BUDGET`` (e.g. dim=100K -> chunk ~ 1K folds of ~2 GB),
     so the headline 1M x 100K reveal streams instead of OOMing.
     ``backend`` as in ``_rounds``.
+
+    Given a ``mesh`` (a masked round's: ``parallel.round.FoldRound.unmask``),
+    a fold takes ``chunk`` seeds *a chip* and runs on every chip of it
+    (:func:`fold_chunk_mesh_jit`): host rows are put sharded, and
+    ``seed_words`` may instead be a list of ``(rows, w)`` device arrays that
+    are sharded over the mesh already, which are folded where they lie
+    (:func:`_mesh_batches`). A short window on any chip is counted as on one
+    chip, and that chip's batch alone recovered on the host.
     """
     from .jaxcfg import ensure_x64
 
@@ -629,42 +802,37 @@ def combine_masks_device(
     import jax.numpy as jnp
     import numpy as np
 
-    from .modular import mod_sum_wide_jnp
-
     if chunk is None:
         chunk = max(16, _COMBINE_BYTES_BUDGET // (5 * 8 * dim))
     if backend == "auto":  # resolved here: it is a static jit argument
         backend = default_backend()
-    seed_words = np.asarray(seed_words, dtype=np.uint32)
     # same series the host paths count into (native/__init__.py), so a
     # scrape shows which implementation expanded a reveal's seeds
-    telemetry.counter(
+    expands = telemetry.counter(
         "sda_crypto_chacha_expands_total",
         "ChaCha mask seeds expanded/combined by path",
         path=backend,
-    ).inc(int(seed_words.shape[0]))
+    )
+    if mesh is not None:
+        total, seeds = _combine_over_mesh(seed_words, dim, modulus, chunk, backend, mesh)
+        expands.inc(seeds)
+        return total
+    seed_words = np.asarray(seed_words, dtype=np.uint32)
+    expands.inc(int(seed_words.shape[0]))
 
     fold = fold_chunk_jit()
-
-    def host_fold(batch):
-        # ~1e-9-per-row event: host-expand just this chunk (the host path
-        # extends the stream on demand) and keep the device fold going
-        from .chacha import expand_seed
-
-        masks = jnp.asarray(np.stack([expand_seed(s, dim, modulus) for s in batch]))
-        if modulus <= (1 << 31):
-            return jnp.sum(masks, axis=0) % jnp.int64(modulus)
-        return mod_sum_wide_jnp(masks, modulus, axis=0)
-
     total = jnp.zeros((dim,), dtype=jnp.int64)
     for start in range(0, seed_words.shape[0], chunk):
         batch = seed_words[start : start + chunk]
         part, counts = fold(jnp.asarray(batch), dim, modulus, backend)
+        count_fold_chips(1)
         if counts.shape[0] and int(jnp.min(counts)) < dim:
             count_slack_exhausted("recipient", int(jnp.sum(counts < dim)))
             logging.getLogger(__name__).info(
                 "rejection slack exhausted in chunk at %d; host-expanding it", start
             )
-            part = host_fold(batch)
+            # ~1e-9-per-row event: host-expand just this chunk and keep the
+            # device fold going
+            part = jnp.asarray(_host_fold(batch, dim, modulus))
         total = (total + part) % jnp.int64(modulus)
     return total

@@ -3,7 +3,8 @@
 Mesh sharding, the end-to-end ``TpuAggregator`` engine, the int8-limb MXU
 mod-p matmul, and the round driver with its host feed (``round.py``), masked
 under the upstream's ChaCha scheme where the round is given one
-(``fold_round(..., masking=)``; the mask stage is ``masked.py``).
+(``fold_round(..., masking=)``; the mask stage is ``masked.py``) and over the
+deployment's mesh where it is given that (``fold_round(..., mesh=)``).
 """
 
 from .engine import AggregationPlan, TpuAggregator, full_training_step, make_plan

@@ -30,8 +30,17 @@ host, in the round's epilogue, because a jitted step cannot read them (a row
 whose keystream window held fewer than ``dim`` accepted draws has an
 undefined mask tail: about 1e-9 a row).
 
+Over a mesh the stage is the same function inside the round driver's
+``shard_map`` body, on a chip's own rows: the key it is handed there has the
+chip's mesh position folded in (``engine.fold_mesh_axes``, by the driver's
+step), so a chip's seeds are its own. All chips masking under the same seeds
+is what no aggregate can show: equal masks cancel as well as distinct ones.
+Both kernels lower inside that body as they do under plain ``jit`` (there a
+kernel needs no partitioning rule: it sees the chip's local seeds).
+
 Nobody has to wrap this by hand: the round driver takes the masking scheme as
-an argument of the round (``round.fold_round(..., masking=)``), puts this
+an argument of the round (``round.fold_round(..., masking=)``, and the mesh
+as ``mesh=``), puts this
 stage in front of the paired entry in its jitted ``masked_step``, keeps every
 step's seeds and counts through ``fold_chunks`` and ``fold_host_rows``, and
 carries the slack check and the unmasking (``FoldRound.short_windows``,

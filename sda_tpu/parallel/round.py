@@ -1,6 +1,7 @@
 """The round driver: one object that carries a fold round's chunk step, its
-accumulate rule, its host epilogue and its reveal — and the host feed, which
-brings a round's rows from host memory while the chip folds them.
+accumulate rule, its host epilogue and its reveal, on one chip or over the
+deployment's mesh — and the host feed, which brings a round's rows from host
+memory while the chip folds them.
 
 A fabric round is ``accumulator -> chunk step over every chunk -> host
 epilogue to clerk sums -> reveal from a subset of clerks``. Which accumulate
@@ -37,6 +38,30 @@ jitted step cannot make), :meth:`FoldRound.reveal` as it is (it reveals the
 *masked* aggregate) and :meth:`FoldRound.unmask` from the seeds alone
 (``crypto.masking.ChaChaMasker.combine`` / ``.unmask``). Bounds, spans and
 counters of the feed are the same masked or not.
+
+**A round over a mesh** (``fold_round(..., mesh=make_mesh(...))``). The
+deployment's layout is an argument of the round as its masking scheme is: a
+device mesh of axes ``("p", "d")``. ``chunk`` is then the rows a step folds on
+all chips together, a multiple of the mesh's ``p``, and the step is the same
+entry (the mask stage in front under ``masking=``) under ``shard_map``
+(:func:`_over_mesh`): every chip takes its own ``(chunk / p, dim / d)`` rows,
+the key with the step's number and then the chip's mesh position folded in
+(``engine.fold_mesh_axes``), so no two chips draw the same share randomness
+or the same seeds; the entry's accumulator is summed over ``p`` under
+``fabric.psum``, then the accumulate rule. It hands the accumulator on
+replicated over ``p``, and a masked step its ``(chunk, words)`` seeds and
+``(chunk,)`` counts sharded over ``p``: they stay on the chips that drew them.
+:meth:`FoldRound.fold_chunks` takes chunks that are sharded over the mesh
+(``parallel.shard_participants``). A masked round's mesh keeps ``d = 1`` (a row
+has one seed and its expansion runs the whole dim: ``ValueError``). The
+recipient's side is spread over the same chips: :meth:`FoldRound.unmask` folds
+``chunk`` seeds *a chip* a call, every chip expanding the seeds of its own
+shard (``ops.chacha_pallas.fold_chunk_mesh_jit``: the one-chip fold under
+``shard_map``), and the chips' partial sums meet mod p under
+``fabric.unmask/meet`` without leaving int64's range. Seeds handed over as host
+rows (what a recipient receives) are put sharded; the device arrays the steps
+returned are folded where they lie. The feed is one chip's:
+:meth:`FoldRound.fold_host_rows` over a mesh raises ``ValueError``.
 
 **The feed** (:meth:`FoldRound.fold_host_rows`). A cohort that does not fit
 the chip's memory sits in host memory and crosses the host link every round.
@@ -151,11 +176,13 @@ def _input_dtype(modulus: int):
     return np.dtype(np.int32 if modulus <= (1 << 31) else np.int64)
 
 
-def _make_step(entry, plan, accumulate: str, masking=None):
+def _make_step(entry, plan, accumulate: str, masking=None, mesh=None):
     """The jitted ``step(acc, chunk, key, i) -> acc``; under ``masking`` the
     jitted ``masked_step(acc, chunk, key, i) -> (acc, seeds, counts)``: the
     same step with the mask stage in front of the entry, the rows' seeds and
-    accepted-draw counts leaving it beside the accumulator."""
+    accepted-draw counts leaving it beside the accumulator. Over a ``mesh``
+    the entry (and the mask stage) run on every chip's own rows
+    (:func:`_over_mesh`); the accumulate rule is the same."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -168,25 +195,69 @@ def _make_step(entry, plan, accumulate: str, masking=None):
             acc = lax.rem(acc, jnp.int64(modulus))
         return acc
 
+    chunk_fn = lambda chunk, key: entry(chunk, key, plan)
+    if masking is not None:
+        # imported where a masked round is built: an unmasked round's set-up
+        # does not pay for the mask stage's modules
+        from .masked import masked_chunk
+
+        chunk_fn = masked_chunk(entry, plan, masking)  # refuses a scheme that is not the plan's
+    if mesh is not None:
+        chunk_fn = _over_mesh(chunk_fn, plan, mesh, masked=masking is not None)
+
     def step(acc, chunk, key, i):
         # one chunk step: the round's key with the step's number folded in,
         # the entry's default share randomness, the accumulate rule
-        return accumulated(acc, entry(chunk, jax.random.fold_in(key, i), plan))
-
-    if masking is None:
-        return jax.jit(step)
-
-    # imported where a masked round is built: an unmasked round's set-up does
-    # not pay for the mask stage's modules
-    from .masked import masked_chunk
-
-    chunk_fn = masked_chunk(entry, plan, masking)  # refuses a scheme that is not the plan's
+        return accumulated(acc, chunk_fn(chunk, jax.random.fold_in(key, i)))
 
     def masked_step(acc, chunk, key, i):
         out, seeds, counts = chunk_fn(chunk, jax.random.fold_in(key, i))
         return accumulated(acc, out), seeds, counts
 
-    return jax.jit(masked_step)
+    return jax.jit(step if masking is None else masked_step)
+
+
+def _acc_spec(mesh):
+    """How a round over ``mesh`` keeps its accumulator: replicated over ``p``,
+    its batches (axis 1 of every paired entry's) over ``d``."""
+    from jax.sharding import PartitionSpec as P
+
+    return P(None, "d" if "d" in mesh.axis_names else None, None)
+
+
+def _over_mesh(chunk_fn, plan, mesh, masked: bool):
+    """``chunk_fn(rows, key)`` on every chip of ``mesh`` over the chip's own
+    rows (a chunk's rows over ``p``; unmasked, its dim over ``d``), under
+    ``shard_map``: the key with the chip's mesh position folded in
+    (``engine.fold_mesh_axes``: no two chips draw the same share randomness,
+    or the same seeds), the accumulator summed over ``p`` under
+    ``fabric.psum`` and handed back replicated over ``p``, a masked step's
+    seeds and counts sharded over ``p``: they stay on the chip that drew
+    them. The sum-first entry's unmasked step is the program that
+    ``sumfirst.sharded_value_limb_sums`` builds."""
+    import jax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    engine.validate_d_sharding(mesh, plan.dim, plan.input_size)
+    d_spec = "d" if "d" in mesh.axis_names else None
+    acc_spec = _acc_spec(mesh)
+
+    def local_step(secrets, key):
+        out = chunk_fn(secrets, engine.fold_mesh_axes(key, mesh))
+        acc, *handed = out if masked else (out,)
+        with jax.named_scope("fabric.psum"):
+            acc = lax.psum(acc, axis_name="p")
+        return (acc, *handed) if masked else acc
+
+    mapped = jax.shard_map(
+        local_step,
+        mesh=mesh,
+        in_specs=(P("p", d_spec), P()),
+        out_specs=(acc_spec, P("p", None), P("p")) if masked else acc_spec,
+        check_vma=False,
+    )
+    return jax.jit(mapped)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,6 +279,7 @@ class FoldRound:
     step: object
     epilogue: object  # fn(acc_host, plan) -> (n, B) clerk sums
     masking: object = None  # the round's protocol.ChaChaMasking, or None
+    mesh: object = None  # the deployment's device mesh, axes ("p", "d"), or None
 
     @property
     def modulus(self) -> int:
@@ -218,9 +290,16 @@ class FoldRound:
         return _input_dtype(self.modulus)
 
     def zero_acc(self):
+        import jax
         import jax.numpy as jnp
 
-        return jnp.zeros(self.acc_shape, jnp.int64)
+        acc = jnp.zeros(self.acc_shape, jnp.int64)
+        if self.mesh is None:
+            return acc
+        from jax.sharding import NamedSharding
+
+        # on every chip before the first step, as every step hands it on
+        return jax.device_put(acc, NamedSharding(self.mesh, _acc_spec(self.mesh)))
 
     def _stepped(self, acc, chunk, key, number: int, handed: list):
         """The accumulator after step ``number`` over ``chunk``; what a
@@ -241,10 +320,11 @@ class FoldRound:
 
     def fold_chunks(self, chunks, key):
         """The accumulator of ``chunks`` (``(chunk, dim)`` arrays, resident
-        or not), step ``i`` over the ``i``-th of them. Under a masking scheme
-        ``(acc, seeds, counts)``: every step's ``(chunk, words)`` uint32 seeds
-        and ``(chunk,)`` int32 counts, in step order, as the device arrays the
-        steps returned."""
+        or not; over a mesh sharded over it, rows over ``p``), step ``i`` over
+        the ``i``-th of them. Under a masking scheme ``(acc, seeds, counts)``:
+        every step's ``(chunk, words)`` uint32 seeds and ``(chunk,)`` int32
+        counts, in step order, as the device arrays the steps returned (over
+        a mesh sharded over ``p``: on the chips that drew them)."""
         acc, handed = self.zero_acc(), []
         for i, chunk in enumerate(chunks):
             acc = self._stepped(acc, chunk, key, i, handed)
@@ -259,6 +339,11 @@ class FoldRound:
         ``block_until_ready`` on the accumulator is the wait for them. The
         same as :meth:`fold_chunks` over the same rows in the same order,
         masked or not: under a masking scheme ``(acc, seeds, counts)``."""
+        if self.mesh is not None:
+            raise ValueError(
+                "the feed is one chip's: over a mesh, fold chunks that are sharded over it "
+                "(fold_chunks)"
+            )
         if in_flight < 1:
             raise ValueError("in_flight counts blocks: at least 1")
         # the program's own `dispatch`: the puts, the waits and the step
@@ -359,12 +444,17 @@ class FoldRound:
         :meth:`reveal` gave less the sum of the masks, which the recipient
         re-expands from ``seeds`` alone (as it receives them: one vector of
         the seed's uint32 words a participant, every row's, any order);
-        ``chunk`` seeds a device fold (``ChaChaMasker.combine``)."""
+        ``chunk`` seeds a device fold (``ChaChaMasker.combine``). Over a mesh
+        the fold runs on every chip of it, ``chunk`` seeds *a chip* a fold,
+        and the chips' partial sums meet mod p (``fabric.unmask/meet``):
+        seeds handed over as host rows are put sharded; the ``(rows, words)``
+        device arrays the steps returned, sharded over the mesh, are folded
+        where they lie, every chip expanding the seeds it drew."""
         from ..crypto.masking import new_mask_combiner
 
         self._masked_only()
         masker = new_mask_combiner(self.masking)  # the recipient's ChaChaMasker
-        mask = masker.combine(seeds, chunk=chunk)
+        mask = masker.combine(seeds, chunk=chunk, mesh=self.mesh)
         return np.mod(masker.unmask(mask, masked_aggregate), self.modulus)
 
     def _masked_only(self) -> None:
@@ -372,13 +462,15 @@ class FoldRound:
             raise ValueError("the round has no masking scheme: nothing to check or take off")
 
 
-def fold_round(scheme, dim: int, entry, chunk: int, masking=None) -> FoldRound:
+def fold_round(scheme, dim: int, entry, chunk: int, masking=None, mesh=None) -> FoldRound:
     """The round of ``scheme`` at ``dim`` through the chunk entry ``entry``
     (``entry(secrets, key, plan) -> accumulator``, one of the module doc's
     table, or a ``functools.partial`` of one), ``chunk`` rows a step; under
     ``masking`` (a ``protocol.ChaChaMasking`` of the plan's modulus and
     dimension) the masked round of the module doc, whatever the entry's
-    accumulate rule."""
+    accumulate rule; over ``mesh`` (the deployment's layout: a device mesh of
+    axes ``("p", "d")``, ``parallel.make_mesh``) the round over the mesh of
+    the module doc, ``chunk`` the rows a step folds on all chips together."""
     ensure_x64()
     paired = _PAIRED.get(getattr(entry, "func", entry))
     if paired is None:
@@ -387,10 +479,21 @@ def fold_round(scheme, dim: int, entry, chunk: int, masking=None) -> FoldRound:
         )
     if chunk < 1:
         raise ValueError("a chunk holds at least one row")
+    if mesh is not None and chunk % mesh.shape["p"]:
+        raise ValueError(
+            f"a chunk's rows spread evenly over the mesh's p = {mesh.shape['p']}: not {chunk}"
+        )
+    if mesh is not None and masking is not None and mesh.shape.get("d", 1) > 1:
+        raise ValueError(
+            "a masked round's mesh keeps d = 1: a row has one seed and its expansion runs "
+            "the whole dim, so the mask stage has no dim shard of its own"
+        )
     import jax
 
     accumulate, epilogue = paired
     plan = engine.make_plan(scheme, dim)
+    # the accumulator of all chips' rows is as wide as one chip's: the entry's
+    # at the whole dim (over a mesh a chip holds its d-shard's batches of it)
     acc = jax.eval_shape(
         lambda rows, key: entry(rows, key, plan),
         jax.ShapeDtypeStruct((chunk, dim), _input_dtype(plan.modulus)),
@@ -403,7 +506,8 @@ def fold_round(scheme, dim: int, entry, chunk: int, masking=None) -> FoldRound:
         entry=entry,
         accumulate=accumulate,
         acc_shape=tuple(acc.shape),
-        step=_make_step(entry, plan, accumulate, masking),
+        step=_make_step(entry, plan, accumulate, masking, mesh),
         epilogue=epilogue,
         masking=masking,
+        mesh=mesh,
     )

@@ -5,10 +5,11 @@ names the program's entries by dotted path: the chunk ``engine``, and either
 the host ``epilogue`` and the ``reconstruct`` or the round ``driver`` that
 pairs them itself (PR 34). Each must resolve, and none of its parameters may
 carry a default: a default is a second behaviour that no cell measures (the
-``draw=`` and ``exact=`` that a second benchmark used to select). One
-parameter is a deployment's and not an option: the driver's ``masking``
+``draw=`` and ``exact=`` that a second benchmark used to select). Two
+parameters are a deployment's and not options: the driver's ``masking``
 (PR 39), which a traffic file sets by naming a ``masking_scheme`` and leaves
-at ``None`` by naming none; cells measure it both ways.
+at ``None`` by naming none, and its ``mesh`` (PR 41), the traffic file's
+``mesh`` (``null`` on one chip); cells measure each both ways.
 """
 
 import importlib
@@ -25,7 +26,7 @@ BOUND = ("engine", "epilogue", "reconstruct")
 BOUND_BY_DRIVER = ("engine", "driver")
 #: a driver's parameter that cells set through their traffic file, by the key
 #: that sets it: at its default in the cells whose file has no such key
-SET_BY_TRAFFIC = {"masking": "masking_scheme"}
+SET_BY_TRAFFIC = {"masking": "masking_scheme", "mesh": "mesh"}
 
 
 def resolve(dotted: str):
@@ -54,13 +55,14 @@ def test_every_bound_entry_resolves_and_has_no_defaulted_parameter(path):
 
 @pytest.mark.parametrize("parameter,key", sorted(SET_BY_TRAFFIC.items()))
 def test_a_drivers_parameter_set_by_traffic_is_measured_both_ways(parameter, key):
-    """Some cell's traffic file names the key and some cell's does not, both
-    through a driver that has the parameter, defaulted to ``None``."""
+    """Some cell's traffic file sets the key and some cell's does not (it
+    names none, or ``null``: a one-chip file's ``mesh``), both through a driver
+    that has the parameter, defaulted to ``None``."""
     by_driver = [json.loads(path.read_text()) for path in TRAFFIC]
     by_driver = [traffic for traffic in by_driver if "driver" in traffic]
-    assert {key in traffic for traffic in by_driver} == {True, False}
+    assert {traffic.get(key) is not None for traffic in by_driver} == {True, False}
     for traffic in by_driver:
         default = inspect.signature(resolve(traffic["driver"])).parameters[parameter].default
         assert default is None, (traffic["name"], parameter)
-        if key in traffic:
+        if isinstance(traffic.get(key), str):  # a dotted path; a mesh is its shape
             assert callable(resolve(traffic[key]))
