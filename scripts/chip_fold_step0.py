@@ -3,14 +3,16 @@
 jitted fold (``ops.chacha_pallas.fold_chunk_jit()``) at a cell's shapes
 (seconds a call, compile seconds, temporaries, its largest operations by the
 profiler's trace); the keystream's way to word pairs alone (``draws``: the
-kernel ``chacha_rounds`` from the seeds, the transposition to stream order,
-the split and the zone test), at each ``--rounds-plans`` pair of the kernel's
-lanes a grid step and lanes a loop step, with the kernel held to the host's
-bits at small shapes; and the compaction alone on the fold's own ``(rows,
-window)`` word pairs: in XLA (``_first_accepted``) and in the kernel
-``chacha_compact``, held to XLA's bits.
+kernel ``chacha_rounds`` from the seeds and the one transposition of its
+pair-major words into ``(2, rows, window)`` planes, since PR 42), at each
+``--rounds-plans`` pair of the kernel's blocks a grid step and blocks a loop
+step, with the kernel held to the host's bits at small shapes, and the way
+before PR 42 beside it (``slices``: stream order, two strided slices, the zone
+test); and the compaction alone on the fold's own planes: in XLA (the zone
+test, the counts, ``_first_accepted``) and in the kernel ``chacha_compact``
+(all three), held to XLA's bits.
 
-    chiprun -- python scripts/chip_fold_step0.py [--tree <checkout>] [--rounds-plans 4096,256 ...]
+    chiprun -- python scripts/chip_fold_step0.py [--tree <checkout>] [--rounds-plans 2048,32 ...]
 
 ``--tree`` imports ``sda_tpu`` from another checkout. A tree from before PR 40
 (its rounds took a states array) is timed by its own copy of this script
@@ -77,7 +79,7 @@ def main() -> int:
     ap.add_argument("--dim", type=int, default=100_000)
     ap.add_argument("--calls", type=int, default=5)
     ap.add_argument(
-        "--rounds-plans", nargs="*", default=[], metavar="LANES,CHUNK",
+        "--rounds-plans", nargs="*", default=[], metavar="BLOCKS,CHUNK",
         help="time ``draws`` under these values of the rounds kernel's two constants too",
     )
     ap.add_argument("--rehearse", action="store_true", help="run off the chip: no trace, times mean nothing")
@@ -131,38 +133,48 @@ def main() -> int:
         for p_rows, blocks, width, first in small
         for keys in [rng.integers(0, 1 << 32, size=(p_rows, width), dtype=np.uint64).astype(np.uint32)]
     )
-    plans = [(cp._ROUNDS_LANES, cp._ROUNDS_CHUNK)]
+    plans = [(cp._ROUNDS_BLOCKS, cp._ROUNDS_CHUNK)]
     plans += [tuple(int(v) for v in plan.split(",")) for plan in args.rounds_plans]
     out["draws"] = {}
-    for lanes, chunk in plans[::-1]:  # the module's own plan last: it stays set
-        cp._ROUNDS_LANES, cp._ROUNDS_CHUNK = lanes, chunk
+    for blocks, chunk in plans[::-1]:  # the module's own plan last: it stays set
+        cp._ROUNDS_BLOCKS, cp._ROUNDS_CHUNK = blocks, chunk
 
         def draws(seed_words):  # a function a plan: jit caches by the function
-            words = cp._rounds(seed_words, n_blocks, 0, backend).reshape(rows, -1)
-            hi, lo = words[:, 0::2], words[:, 1::2]
-            zone_hi, zone_lo = jnp.uint32(zone >> 32), jnp.uint32(zone & 0xFFFFFFFF)
-            return hi, lo, (hi < zone_hi) | ((hi == zone_hi) & (lo < zone_lo))
+            return cp._pair_planes(seed_words, n_blocks, interpret=not on_chip)
 
         fn = jax.jit(draws)
         first, seconds = timed(fn, seeds, calls=args.calls)
-        out["draws"][f"{lanes},{chunk}"] = {
-            "grid": [-(-rows // 8), cp._rounds_plan(n_blocks)[0]],
+        out["draws"][f"{blocks},{chunk}"] = {
+            "grid": [-(-rows // 128), cp._rounds_plan(n_blocks)[0]],
             "first_call_s": first, "s": seconds, "trace": trace(fn, seeds, calls=2, top=6),
         }
-    hi, lo, ok = jax.block_until_ready(fn(seeds))
-    out["window"] = int(ok.shape[1])
-    xla = jax.jit(cp._first_accepted, static_argnums=3)
-    out["xla_compaction_s"] = timed(xla, hi, lo, ok, dim, calls=args.calls)[1]
+    planes = jax.block_until_ready(fn(seeds))
+
+    def slices(seed_words):  # the way before PR 42, on the same chip
+        words = cp._rounds(seed_words, n_blocks, 0, backend).reshape(rows, -1)
+        hi, lo = words[:, 0::2], words[:, 1::2]
+        return hi, lo, cp._accepted(hi, lo, zone)
+
+    slices = jax.jit(slices)
+    first, seconds = timed(slices, seeds, calls=args.calls)
+    out["slices"] = {"s": seconds, "trace": trace(slices, seeds, calls=2, top=6)}
+    out["window"] = int(planes.shape[2])
+
+    def xla(planes, dim):
+        hi, lo = planes[0], planes[1]
+        ok = cp._accepted(hi, lo, zone)
+        return (*cp._first_accepted(hi, lo, ok, dim), jnp.sum(ok, axis=1).astype(jnp.int32))
+
+    xla = jax.jit(xla, static_argnums=1)
+    out["xla_compaction_s"] = timed(xla, planes, dim, calls=args.calls)[1]
     kernel = jax.jit(
-        functools.partial(cp._compact_pallas, interpret=not on_chip), static_argnums=3
+        functools.partial(cp._compact_pallas, zone=zone, interpret=not on_chip), static_argnums=1
     )
-    first, seconds = timed(kernel, hi, lo, ok, dim, calls=args.calls)
+    first, seconds = timed(kernel, planes, dim, calls=args.calls)
     out["kernel_first_call_s"], out["kernel_s"] = first, seconds
-    out["kernel_trace"] = trace(kernel, hi, lo, ok, dim, calls=2, top=4)
-    want, got = xla(hi, lo, ok, dim), kernel(hi, lo, ok, dim)
-    out["kernel_equals_xla"] = bool(
-        jnp.array_equal(want[0], got[0]) & jnp.array_equal(want[1], got[1])
-    )
+    out["kernel_trace"] = trace(kernel, planes, dim, calls=2, top=4)
+    want, got = xla(planes, dim), kernel(planes, dim)
+    out["kernel_equals_xla"] = all(bool(jnp.array_equal(w, g)) for w, g in zip(want, got))
     line = json.dumps(out)
     tag = args.tag or pathlib.Path(args.tree).name
     os.makedirs(REPO / "chiprun_out", exist_ok=True)

@@ -22,17 +22,25 @@ each has a kernel here that keeps them on the chip:
   shift (``_first_accepted``); in XLA each stage is a pass over three
   ``(P, window)`` tensors, in the kernel a tile of eight rows stays in VMEM
   from the first stage to the last and HBM sees the draws once, going in,
-  and the first ``dim`` of them once, coming out.
+  and the first ``dim`` of them once, coming out. The kernel tests the draws
+  against the rand-0.3 zone and counts a row's accepted ones itself: no flag
+  tensor is written for it.
 
-Layout of the rounds: a tile is eight seeds on the sublanes by a row's blocks
-along the 128-wide lane axis, so each of the 16 words is whole ``(8, lanes)``
-vregs and every quarter-round op a full-width VPU op; a row's key words
-broadcast along the lanes. The grid tiles rows and blocks (``_rounds_plan``);
-inside a grid step a loop walks the lanes, ``_ROUNDS_CHUNK`` at a time (ChaCha
-blocks share no state). The kernel writes ``(16, P, blocks)``, word-major, and
-nothing is padded in HBM; the transposition to stream order is XLA's. One
-launch expands every participant's stream. Layout of the compaction: rows on
-the sublanes, eight a tile, a row's draws along the lanes (``_compact_plan``).
+Layout of the rounds: a tile is 128 seeds on the lanes by a run of a row's
+blocks down the sublanes, so each of the 16 words is whole ``(8, 128)`` vregs
+and every quarter-round op a full-width VPU op; a row's key words broadcast
+down the sublanes. The grid tiles rows and blocks (``_rounds_plan``); inside a
+grid step a loop walks the blocks, ``_ROUNDS_CHUNK`` at a time (ChaCha blocks
+share no state). One launch expands every participant's stream, and nothing
+is padded in HBM. The kernel writes ``(2, 8 * blocks, P)``: draw ``j`` of a
+block is its words ``2j`` (high) and ``2j + 1`` (low), and a row's draws lie
+in stream order down the second axis, the high words in plane 0, the low in
+plane 1, each word a sublane-strided store. One transposition, XLA's, at
+memory speed, gives the ``(2, P, window)`` planes (``_pair_planes``), the
+compaction kernel's one operand; callers that want whole blocks in stream
+order get them by another transposition of the same words
+(``_rounds_pallas``). Layout of the compaction: rows on the sublanes, eight a
+tile, a row's draws along the lanes (``_compact_plan``).
 
 Bit parity: every traced path runs the same djb quarter round
 (``apply_rounds_jnp``) and the same compaction stages. The jnp twin starts
@@ -57,35 +65,42 @@ import logging
 from .. import telemetry
 from .chacha import apply_rounds_jnp, chacha_rounds_jnp, chacha_state_jnp, rand03_zone
 
-#: lanes (blocks of a row) one grid step of the rounds kernel writes at most,
-#: and lanes one step of its inner loop holds in registers, sixteen words of
-#: ``_ROUNDS_CHUNK // 128`` vregs each. The kernel is the VPU's: 500 rows of
-#: 13 400 blocks took 3.02, 2.03, 1.93 ms at 128, 256, 512 lanes a loop step
-#: (4096 a grid step), 2.14, 2.03, 1.93 ms at 512, 4096, 16 384 a grid step
-#: (256 a loop step) and 1.85 ms at these (a v5e; 6.47 ms from states read in
-#: HBM, 1-D word rows, 13 086 grid steps; PERF.md section 6, PR 40)
-_ROUNDS_LANES = 16384
-_ROUNDS_CHUNK = 512
+#: blocks of a row one grid step of the rounds kernel writes at most, and
+#: blocks one step of its inner loop holds in registers, sixteen words of
+#: ``_ROUNDS_CHUNK // 8`` vregs each (128 rows on the lanes). The kernel is the
+#: VPU's: 500 rows of 13 400 blocks took 1.860, 1.870, 1.905 ms at 1024, 2048,
+#: 512 blocks a grid step (32 a loop step) and 1.965, 2.037 ms at 16, 64 a
+#: loop step (1024 a grid step); stored as ``(2, blocks, 8, P)`` by plain
+#: stores, 2.31 ms (a v5e; PERF.md section 6, PR 42). With a row's blocks on
+#: the lanes (PRs 40-41) it took 1.85 ms at 16 384 lanes a grid step and 512 a
+#: loop step, and its words needed two transpositions to reach the compaction
+_ROUNDS_BLOCKS = 1024
+_ROUNDS_CHUNK = 32
 
 
 def _rounds_plan(n_blocks: int):
-    """``(grid steps along a row, lanes a step)``: the row's blocks split
-    evenly over the fewest steps of at most ``_ROUNDS_LANES``, a step's lanes
-    rounded up to whole loop steps, so the last step's overhang past the row
-    (computed, never written) stays under one loop step a grid step."""
-    steps = -(-n_blocks // _ROUNDS_LANES)
-    lanes = -(-n_blocks // (steps * _ROUNDS_CHUNK)) * _ROUNDS_CHUNK
-    return -(-n_blocks // lanes), lanes
+    """``(grid steps along a row, blocks a step)``: the row's blocks split
+    evenly over the fewest steps of at most ``_ROUNDS_BLOCKS``, a step's
+    blocks rounded up to whole loop steps, so the last step's overhang past
+    the row (computed, never written) stays under one loop step a grid
+    step."""
+    steps = -(-n_blocks // _ROUNDS_BLOCKS)
+    blocks = -(-n_blocks // (steps * _ROUNDS_CHUNK)) * _ROUNDS_CHUNK
+    return -(-n_blocks // blocks), blocks
 
 
-def _rounds_kernel(seeds_ref, out_ref, *, first_counter: int):
-    """Eight rows' keystream, ``out_ref`` ``(16, 8, lanes)``: word-major, a
-    row a sublane, its blocks along the lanes. The initial states are made
-    here, in registers, from the rows' eight key words (``seeds_ref``, ``(8,
-    8)``, broadcast along the lanes) and the blocks' indices (an iota plus
-    this step's place in the row): the constants, the key, the counter's low
-    word, and zeros for its high word (the entry refuses a counter that would
-    reach it) and the nonce. No state is read from HBM."""
+def _rounds_kernel(keys_ref, out_ref, *, first_counter: int):
+    """128 rows' keystream, ``out_ref`` ``(2, 8 * blocks, 128)``: a row a
+    lane; down the sublanes its draws in stream order, draw ``j`` of block
+    ``b`` at ``8b + j``, its high word (the block's word ``2j``) in plane 0
+    and its low word (``2j + 1``) in plane 1. A word of ``_ROUNDS_CHUNK``
+    blocks is one sublane-strided store, eight sublanes apart. The initial
+    states are made here, in registers, from the rows' eight key words
+    (``keys_ref``, ``(8, 128)``, broadcast down the sublanes) and the blocks'
+    indices (an iota plus this step's place in the row): the constants, the
+    key, the counter's low word, and zeros for its high word (the entry
+    refuses a counter that would reach it) and the nonce. No state is read
+    from HBM."""
     import jax.numpy as jnp
     import numpy as np
     from jax import lax
@@ -93,37 +108,40 @@ def _rounds_kernel(seeds_ref, out_ref, *, first_counter: int):
 
     from .chacha import _CONSTANTS
 
-    lanes, chunk = out_ref.shape[2], _ROUNDS_CHUNK
+    blocks, chunk = out_ref.shape[1] // 8, _ROUNDS_CHUNK
     # typed constants throughout: under x64 a python number traces 64 bits wide
-    shape = (8, chunk)
+    shape = (chunk, 128)
     constants = [jnp.full(shape, c, jnp.uint32) for c in _CONSTANTS]
-    key = [jnp.broadcast_to(seeds_ref[:, w : w + 1], shape) for w in range(8)]
+    key = [jnp.broadcast_to(keys_ref[w : w + 1, :], shape) for w in range(8)]
     zeros = jnp.zeros(shape, jnp.uint32)
-    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    block = lax.broadcasted_iota(jnp.int32, shape, 0)
     # int32 wraps as the counter's uint32 does: the same bits
     first = jnp.int32(np.uint32(first_counter).astype(np.int32))
-    here = pl.program_id(1) * jnp.int32(lanes) + first
+    here = pl.program_id(1) * jnp.int32(blocks) + first
+    planes = [out_ref.at[jnp.int32(h)] for h in range(2)]
 
     def step(c, carry):
         at = pl.multiple_of(c * jnp.int32(chunk), chunk)
-        counter = lax.bitcast_convert_type(lane + (here + at), jnp.uint32)
+        counter = lax.bitcast_convert_type(block + (here + at), jnp.uint32)
         init = [*constants, *key, counter, zeros, zeros, zeros]
         # fully unrolled inside the kernel; round body shared with the jnp twin
         x = apply_rounds_jnp(list(init))
+        draws = [plane.at[pl.ds(at * jnp.int32(8), 8 * chunk)] for plane in planes]
         for i in range(16):
-            out_ref[i, :, pl.ds(at, chunk)] = x[i] + init[i]
+            draws[i % 2][pl.ds(i // 2, chunk, stride=8), :] = x[i] + init[i]
         return carry
 
-    lax.fori_loop(jnp.int32(0), jnp.int32(lanes // chunk), step, jnp.int32(0))
+    lax.fori_loop(jnp.int32(0), jnp.int32(blocks // chunk), step, jnp.int32(0))
 
 
-def _rounds_pallas(seed_words, n_blocks: int, first_counter: int = 0, *, interpret: bool = False):
-    """``(P, w <= 8)`` uint32 seeds -> ``(P, n_blocks, 16)`` keystream, each
-    row's blocks ``first_counter`` onwards, via the kernel ``chacha_rounds``.
-    The seeds are the kernel's only operand. It writes ``(16, P, n_blocks)``
-    (nothing padded in HBM: the blocks reach past the array's rows and lanes,
-    and the pipeline drops what lies there); the transposition to stream
-    order stays XLA's."""
+def _rounds_words(seed_words, n_blocks: int, first_counter: int, *, interpret: bool):
+    """``(P, w <= 8)`` uint32 seeds -> ``(2, 8 * n_blocks, P)`` keystream, each
+    row's blocks ``first_counter`` onwards as the kernel ``chacha_rounds``
+    writes it: the high and the low word of every draw, a row's draws in
+    stream order down the second axis, the rows along the last. The seeds,
+    transposed, are the kernel's only operand. Nothing is padded in HBM: the
+    blocks reach past the array's rows and draws, and the pipeline drops what
+    lies there."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -132,23 +150,43 @@ def _rounds_pallas(seed_words, n_blocks: int, first_counter: int = 0, *, interpr
     from .jaxcfg import I32_ZERO as zero  # literal 0 would trace as i64
 
     rows, width = seed_words.shape
-    keys = jnp.pad(seed_words.astype(jnp.uint32), ((0, 0), (0, 8 - width)))
-    steps, lanes = _rounds_plan(n_blocks)
-    out = pl.pallas_call(
+    keys = jnp.pad(seed_words.astype(jnp.uint32), ((0, 0), (0, 8 - width))).T
+    steps, blocks = _rounds_plan(n_blocks)
+    return pl.pallas_call(
         functools.partial(_rounds_kernel, first_counter=first_counter),
-        grid=(-(-rows // 8), steps),
-        in_specs=[pl.BlockSpec((8, 8), lambda i, j: (i, zero))],
-        out_specs=pl.BlockSpec((16, 8, lanes), lambda i, j: (zero, i, j)),
-        out_shape=jax.ShapeDtypeStruct((16, rows, n_blocks), jnp.uint32),
+        grid=(-(-rows // 128), steps),
+        in_specs=[pl.BlockSpec((8, 128), lambda i, j: (zero, i))],
+        out_specs=pl.BlockSpec((2, 8 * blocks, 128), lambda i, j: (zero, j, i)),
+        out_shape=jax.ShapeDtypeStruct((2, 8 * n_blocks, rows), jnp.uint32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             # the output's two buffers, and room for the loop's spills
-            vmem_limit_bytes=2 * 16 * 8 * lanes * 4 + (8 << 20),
+            vmem_limit_bytes=2 * 2 * 8 * blocks * 128 * 4 + (8 << 20),
         ),
         interpret=interpret,
         name="chacha_rounds",
     )(keys)
-    return jnp.transpose(out, (1, 2, 0))
+
+
+def _pair_planes(seed_words, n_blocks: int, *, interpret: bool = False):
+    """``(P, w <= 8)`` uint32 seeds -> ``(2, P, 8 * n_blocks)`` uint32: a
+    row's draws from block 0 on, in stream order, the high words at index 0
+    and the low words at 1. One transposition of the kernel's words, XLA's,
+    at memory speed; what the compaction kernel reads."""
+    import jax.numpy as jnp
+
+    return jnp.transpose(_rounds_words(seed_words, n_blocks, 0, interpret=interpret), (0, 2, 1))
+
+
+def _rounds_pallas(seed_words, n_blocks: int, first_counter: int = 0, *, interpret: bool = False):
+    """``(P, w <= 8)`` uint32 seeds -> ``(P, n_blocks, 16)`` keystream in
+    stream order, by the kernel ``chacha_rounds`` and a transposition of its
+    words: what every caller that wants whole blocks gets."""
+    import jax.numpy as jnp
+
+    rows = seed_words.shape[0]
+    words = _rounds_words(seed_words, n_blocks, first_counter, interpret=interpret)
+    return jnp.transpose(words.reshape(2, n_blocks, 8, rows), (3, 1, 2, 0)).reshape(rows, n_blocks, 16)
 
 
 def _rounds_jnp(seed_words, n_blocks: int, first_counter: int):
@@ -195,6 +233,22 @@ def count_blocks(path: str, blocks: int) -> None:
     ).inc(blocks)
 
 
+def _count_rounds(rows: int, n_blocks: int, first_counter: int, backend: str) -> str:
+    """The rounds' path under ``backend`` (``auto``: this process's backend),
+    counted once a trace; an unknown name, or a counter the kernel cannot
+    make, is refused under every name."""
+    if backend not in ("auto", "pallas", "interpret", "jnp"):
+        raise ValueError(f"unknown backend {backend!r}")
+    if first_counter < 0 or first_counter + n_blocks >= 1 << 32:
+        raise ValueError(
+            f"blocks {first_counter} to {first_counter + n_blocks} of a row: "
+            "the block counter is held to 32 bits"
+        )
+    path = default_backend() if backend == "auto" else backend
+    count_blocks(path, rows * n_blocks)
+    return path
+
+
 def _rounds(seed_words, n_blocks: int, first_counter: int, backend: str):
     """``(P, w <= 8)`` uint32 seeds -> ``(P, n_blocks, 16)`` keystream, every
     row's blocks ``first_counter`` to ``first_counter + n_blocks``, by backend
@@ -209,14 +263,7 @@ def _rounds(seed_words, n_blocks: int, first_counter: int, backend: str):
     counter's low word only, so a counter that reaches 2^32 is refused here,
     under every name.
     """
-    if backend not in ("auto", "pallas", "interpret", "jnp"):
-        raise ValueError(f"unknown backend {backend!r}")
-    if first_counter < 0 or first_counter + n_blocks >= 1 << 32:
-        raise ValueError(
-            f"blocks {first_counter} to {first_counter + n_blocks} of a row: "
-            "the block counter is held to 32 bits"
-        )
-    count_blocks(default_backend() if backend == "auto" else backend, seed_words.shape[0] * n_blocks)
+    _count_rounds(seed_words.shape[0], n_blocks, first_counter, backend)
     blocks = dict(n_blocks=n_blocks, first_counter=first_counter)
     if backend == "auto":
         from jax import lax
@@ -284,7 +331,7 @@ def _first_accepted(hi, lo, ok, dim: int):
     platform that is not a TPU. On a TPU the same prefix sum and stages run
     in the kernel ``chacha_compact`` (:func:`_compact_pallas`), a tile of
     eight rows held in VMEM from the first stage to the last (PERF.md
-    section 6, PR 33; :func:`_compact` chooses)."""
+    section 6, PR 33; :func:`_draws` chooses)."""
     import jax.numpy as jnp
 
     rows, window = ok.shape
@@ -334,8 +381,9 @@ def _compact_plan(window: int, dim: int):
     lanes_in = body + 128
     scratch = body + max(1 << max(stages - 1, 0), 128) + 128
     lanes_out = -(-dim // _COMPACT_LANES) * _COMPACT_LANES
-    # inputs and outputs are double-buffered by the pipeline
-    vmem = 8 * 4 * (2 * 3 * lanes_in + 3 * scratch + 2 * 2 * lanes_out)
+    # the two planes in and the two words and the counts out are
+    # double-buffered by the pipeline
+    vmem = 8 * 4 * (2 * 2 * lanes_in + 3 * scratch + 2 * (2 * lanes_out + 128))
     return stages, lanes_in, body, scratch, lanes_out, vmem
 
 
@@ -347,18 +395,30 @@ def _compact_fits(window: int, dim: int) -> bool:
     return window >= 128 and stages > 0 and vmem <= _COMPACT_VMEM_BUDGET
 
 
+def _accepted(hi, lo, zone: int):
+    """Which draws lie below the rand-0.3 zone, a draw being its high and low
+    uint32 words: the one zone test of every path."""
+    import jax.numpy as jnp
+
+    zone_hi, zone_lo = jnp.uint32(zone >> 32), jnp.uint32(zone & 0xFFFFFFFF)
+    return (hi < zone_hi) | ((hi == zone_hi) & (lo < zone_lo))
+
+
 def _compact_kernel(
-    hi_ref, lo_ref, ok_ref, out_hi_ref, out_lo_ref, hi_s, lo_s, shift_s, *, stages, window, dim, body
+    planes_ref, out_hi_ref, out_lo_ref, counts_ref, hi_s, lo_s, shift_s,
+    *, stages, window, dim, body, zone,
 ):
-    """``_first_accepted`` on one tile of eight rows, in VMEM. First the
-    shifts, a prefix sum of the rejected draws down the row, a loop step's
-    lanes at a time with the count so far carried on (the blocks reach past
-    the window: what lies there is anything, and reads "no draw"). Then the
-    stages in place: a stage walks the row left to right in steps of
-    ``_COMPACT_LANES`` lanes, reads a step's lanes and those ``step`` to their
-    right, and writes the step's lanes back; it never reads what it has
-    written (a draw only moves left). The first stage reads the input words,
-    the last writes the outputs."""
+    """``_first_accepted`` on one tile of eight rows, in VMEM, from the rows'
+    draws alone: ``planes_ref`` ``(2, 8, lanes)``, high words then low words
+    in stream order. First the zone test and the shifts, a prefix sum of the
+    rejected draws down the row, a loop step's lanes at a time with the count
+    so far carried on (the blocks reach past the window: what lies there is
+    anything, and reads "no draw", rejected); the row's count is what that sum
+    leaves of the lanes. Then the stages in place: a stage walks the row left
+    to right in steps of ``_COMPACT_LANES`` lanes, reads a step's lanes and
+    those ``step`` to their right, and writes the step's lanes back; it never
+    reads what it has written (a draw only moves left). The first stage reads
+    the input words, the last writes the outputs."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -367,12 +427,14 @@ def _compact_kernel(
     lanes = _COMPACT_LANES
     # typed constants throughout: under x64 a python number traces 64 bits wide
     none, zero, one = jnp.int32(-1), jnp.int32(0), jnp.int32(1)
+    hi_ref, lo_ref = planes_ref.at[zero], planes_ref.at[one]
     shift_s[:, body:] = jnp.full((8, shift_s.shape[1] - body), none)
     lane = lax.broadcasted_iota(jnp.int32, (8, lanes), 1)
 
     def shifts(c, so_far):
         at = pl.multiple_of(c * jnp.int32(lanes), lanes)
-        ok = (ok_ref[:, pl.ds(at, lanes)] != zero) & (lane < jnp.int32(window) - at)
+        ok = _accepted(hi_ref[:, pl.ds(at, lanes)], lo_ref[:, pl.ds(at, lanes)], zone)
+        ok = ok & (lane < jnp.int32(window) - at)
         rejected = jnp.where(ok, zero, one)
         upto, reach = rejected, 1  # rejected draws in the ``reach`` lanes ending here
         while reach < lanes:
@@ -385,7 +447,8 @@ def _compact_kernel(
         )
         return so_far + jnp.sum(rejected, axis=1, keepdims=True, dtype=jnp.int32)
 
-    lax.fori_loop(zero, jnp.int32(body // lanes), shifts, jnp.zeros((8, 1), jnp.int32))
+    rejected = lax.fori_loop(zero, jnp.int32(body // lanes), shifts, jnp.zeros((8, 1), jnp.int32))
+    counts_ref[...] = jnp.int32(body) - rejected
     for bit in range(stages):
         step = 1 << bit
         last = bit == stages - 1
@@ -422,12 +485,16 @@ def _compact_kernel(
         lax.fori_loop(zero, jnp.int32(steps), chunk, zero)
 
 
-def _compact_pallas(hi, lo, ok, dim: int, *, interpret: bool = False):
-    """``_first_accepted`` as the kernel ``chacha_compact``: ``(P, window)``
-    word pairs and accepted flags -> ``(P, dim)`` pairs, the same bits.
-    Nothing is padded or cut in HBM: a block is eight rows by
-    ``_compact_plan``'s lanes, wider than the arrays, so the pipeline copies
-    what there is of a tile and the kernel takes the rest for "no draw"."""
+def _compact_pallas(planes, dim: int, zone: int, *, interpret: bool = False):
+    """The zone test, the counts and ``_first_accepted`` as the kernel
+    ``chacha_compact``: ``(2, P, window)`` uint32 draws (high words, low
+    words, in stream order; :func:`_pair_planes`) and the zone -> ``(P, dim)``
+    high words, ``(P, dim)`` low words of each row's first ``dim`` accepted
+    draws and ``(P,)`` int32 accepted draws a row: the bits of the XLA path
+    (:func:`_draws_by_slices`). The planes are the one operand. Nothing is
+    padded or cut in HBM: a block is eight rows by ``_compact_plan``'s lanes,
+    wider than the arrays, so the pipeline copies what there is of a tile and
+    the kernel takes the rest for "no draw"."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -435,16 +502,19 @@ def _compact_pallas(hi, lo, ok, dim: int, *, interpret: bool = False):
 
     from .jaxcfg import I32_ZERO as zero  # literal 0 would trace as i64
 
-    rows, window = ok.shape
+    _two, rows, window = planes.shape
     stages, lanes_in, body, scratch, lanes_out, vmem = _compact_plan(window, dim)
     row_tile = lambda lanes: pl.BlockSpec((8, lanes), lambda i: (i, zero))
     out = jax.ShapeDtypeStruct((rows, dim), jnp.uint32)
-    return pl.pallas_call(
-        functools.partial(_compact_kernel, stages=stages, window=window, dim=dim, body=body),
+    kernel = functools.partial(
+        _compact_kernel, stages=stages, window=window, dim=dim, body=body, zone=zone
+    )
+    hi, lo, counts = pl.pallas_call(
+        kernel,
         grid=(-(-rows // 8),),
-        in_specs=[row_tile(lanes_in)] * 3,
-        out_specs=[row_tile(lanes_out)] * 2,
-        out_shape=[out, out],
+        in_specs=[pl.BlockSpec((2, 8, lanes_in), lambda i: (zero, i, zero))],
+        out_specs=[row_tile(lanes_out), row_tile(lanes_out), row_tile(1)],
+        out_shape=[out, out, jax.ShapeDtypeStruct((rows, 1), jnp.int32)],
         scratch_shapes=[
             pltpu.VMEM((8, scratch), jnp.uint32),
             pltpu.VMEM((8, scratch), jnp.uint32),
@@ -455,7 +525,8 @@ def _compact_pallas(hi, lo, ok, dim: int, *, interpret: bool = False):
         ),
         interpret=interpret,
         name="chacha_compact",
-    )(hi, lo, ok.astype(jnp.int32))
+    )(planes)
+    return hi, lo, counts.reshape(rows)
 
 
 def count_compaction(path: str, rows: int) -> None:
@@ -471,29 +542,74 @@ def count_compaction(path: str, rows: int) -> None:
     ).inc(rows)
 
 
-def _compact(hi, lo, ok, dim: int, backend: str):
-    """:func:`_first_accepted` by backend name, as ``_rounds`` dispatches the
-    rounds: the kernel where the program is compiled for a TPU (``auto``
+def count_pair_planes(path: str, rows: int) -> None:
+    """``rows`` more keystream rows whose draws reached the compaction by
+    ``path``: ``kernel`` (written by ``chacha_rounds`` in stream order down
+    the sublanes, transposed once into high and low planes, the zone test
+    and the counts in ``chacha_compact``) or ``slices`` (stream order, strided slices, the zone
+    test and the counts in XLA). Counted where the path is chosen, once a
+    trace, as :func:`count_compaction` counts."""
+    telemetry.counter(
+        "sda_crypto_chacha_pair_planes_total",
+        "keystream rows whose draws reached the compaction as word-pair planes, "
+        "by path (kernel | slices)",
+        path=path,
+    ).inc(rows)
+
+
+def _draws_by_slices(words, dim: int, zone: int):
+    """``(P, blocks, 16)`` keystream in stream order -> the high and low words
+    of each row's first ``dim`` accepted draws and its count of accepted
+    draws, all in XLA: the word pairs by two strided slices, the zone test,
+    the sum, :func:`_first_accepted`. The path of every platform that is not
+    a TPU, and of a shape the compaction kernel does not take."""
+    import jax.numpy as jnp
+
+    words = words.reshape(words.shape[0], -1)
+    hi, lo = words[:, 0::2], words[:, 1::2]
+    ok = _accepted(hi, lo, zone)
+    return (*_first_accepted(hi, lo, ok, dim), jnp.sum(ok, axis=1).astype(jnp.int32))
+
+
+def _draws_by_kernel(seed_words, n_blocks: int, dim: int, zone: int, *, interpret: bool = False):
+    """:func:`_draws_by_slices`'s bits by both kernels: ``chacha_rounds``'s
+    ``(2, window, P)`` words, one transposition into ``(2, P, window)``
+    planes, and
+    ``chacha_compact``, which tests the zone, counts and compacts. No
+    ``(P, window)`` flag tensor exists, and nothing outside the kernels reads
+    the planes."""
+    planes = _pair_planes(seed_words, n_blocks, interpret=interpret)
+    return _compact_pallas(planes, dim, zone, interpret=interpret)
+
+
+def _draws(seed_words, n_blocks: int, dim: int, zone: int, backend: str):
+    """``(P, w <= 8)`` seeds -> ``((P, dim) high words, (P, dim) low words,
+    (P,) int32 counts)`` of every row's first ``dim`` accepted draws of
+    ``n_blocks`` blocks, by backend name, as ``_rounds`` dispatches the
+    rounds: both kernels where the program is compiled for a TPU (``auto``
     decides where it is lowered; counted as this process's backend, which is
-    what runs it), its source on the interpreter, or the compaction in XLA. A
-    shape the kernel is not worth or cannot hold (``_compact_fits``) takes
-    XLA's under every name."""
+    what runs it), their source on the interpreter, or the jnp twin's rounds
+    and XLA's compaction. A shape the compaction kernel is not worth or cannot
+    hold (``_compact_fits``) takes XLA's slices and compaction under every
+    name, behind the rounds that name picks."""
     from jax import lax
 
-    rows, window = ok.shape
+    rows, window = seed_words.shape[0], 8 * n_blocks
     if backend == "jnp" or not _compact_fits(window, dim):
+        words = _rounds(seed_words, n_blocks, 0, backend)
         count_compaction("jnp", rows)
-        return _first_accepted(hi, lo, ok, dim)
-    count_compaction(default_backend() if backend == "auto" else backend, rows)
+        count_pair_planes("slices", rows)
+        return _draws_by_slices(words, dim, zone)
+    path = _count_rounds(rows, n_blocks, 0, backend)
+    count_compaction(path, rows)
+    count_pair_planes("slices" if path == "jnp" else "kernel", rows)
     if backend == "auto":
         return lax.platform_dependent(
-            hi,
-            lo,
-            ok,
-            tpu=functools.partial(_compact_pallas, dim=dim),
-            default=functools.partial(_first_accepted, dim=dim),
+            seed_words,
+            tpu=functools.partial(_draws_by_kernel, n_blocks=n_blocks, dim=dim, zone=zone),
+            default=lambda seeds: _draws_by_slices(_rounds_jnp(seeds, n_blocks, 0), dim, zone),
         )
-    return _compact_pallas(hi, lo, ok, dim, interpret=backend == "interpret")
+    return _draws_by_kernel(seed_words, n_blocks, dim, zone, interpret=backend == "interpret")
 
 
 def expand_seeds_counts(seed_words, dim: int, modulus: int, backend: str = "jnp"):
@@ -520,16 +636,10 @@ def expand_seeds_counts(seed_words, dim: int, modulus: int, backend: str = "jnp"
     if P == 0:
         return jnp.zeros((0, dim), dtype=jnp.int64), jnp.zeros((0,), dtype=jnp.int32)
     zone = rand03_zone(modulus)  # rand-0.3 exact: rejection always applies
-    need_pairs = _window_pairs(dim, modulus)
-    n_blocks = (need_pairs * 2 + 15) // 16
-    words = _rounds(seed_words, n_blocks, 0, backend).reshape(P, n_blocks * 16)
+    n_blocks = (_window_pairs(dim, modulus) * 2 + 15) // 16
     # a draw is (high word, low word); the two stay apart, in the chip's own
     # 32-bit lanes, until the first ``dim`` accepted ones are picked
-    hi, lo = words[:, 0::2], words[:, 1::2]
-    zone_hi, zone_lo = jnp.uint32(zone >> 32), jnp.uint32(zone & 0xFFFFFFFF)
-    ok = (hi < zone_hi) | ((hi == zone_hi) & (lo < zone_lo))
-    counts = jnp.sum(ok, axis=1).astype(jnp.int32)
-    hi, lo = _compact(hi, lo, ok, dim, backend)
+    hi, lo, counts = _draws(seed_words, n_blocks, dim, zone, backend)
     # the accepted draws mod p, still as word pairs: no division on the device
     masks = mod_u64_const(hi, lo, modulus).astype(jnp.int64)
     return masks, counts

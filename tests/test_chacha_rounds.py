@@ -27,10 +27,11 @@ P31, P46, P61 = (
     int(find_packed_parameters(5, 2, 8, min_modulus_bits=bits, seed=0)[0]) for bits in (30, 45, 60)
 )
 
-#: rows below, at and over a tile of eight, and over two; blocks a row below,
-#: just under, at and over a lane tile (700: two loop steps of one grid
-#: step); every seed width and both counters ride along, each four times
-ROWS, BLOCKS, WIDTHS = (1, 5, 9, 17), (1, 127, 128, 700), (1, 2, 4, 8)
+#: rows on the lanes (PR 42): well below a lane tile, one row under it, and a
+#: ragged second tile; blocks a row below and just under a loop step of 32, many loop
+#: steps of one grid step, and over a grid step of 1 024; every seed width and
+#: both counters ride along, each four times
+ROWS, BLOCKS, WIDTHS = (1, 9, 127, 130), (1, 31, 700, 1030), (1, 2, 4, 8)
 CASES = [
     (rows, blocks, WIDTHS[(i + j) % 4], (0, 5)[(i + j // 2) % 2])
     for i, rows in enumerate(ROWS)
@@ -129,12 +130,82 @@ def test_the_kernels_only_operand_is_the_seeds_and_no_state_tensor_is_traced():
     traced = jax.make_jaxpr(lambda s: chacha_pallas.expand_seeds_counts(s, dim, P61, "interpret"))(seeds)
     avals, kernels = _all_avals(traced.jaxpr)
     (rounds,) = [k for k in kernels if k.params["name"] == "chacha_rounds"]
-    assert [(tuple(v.aval.shape), str(v.aval.dtype)) for v in rounds.invars] == [((rows, 8), "uint32")]
-    assert [tuple(v.aval.shape) for v in rounds.outvars] == [(16, rows, blocks)]
-    # outside the kernel a sixteen-word minor axis is the keystream in stream
-    # order, once; the parent built, scattered into and transposed states too
+    assert [(tuple(v.aval.shape), str(v.aval.dtype)) for v in rounds.invars] == [((8, rows), "uint32")]
+    assert [tuple(v.aval.shape) for v in rounds.outvars] == [(2, 8 * blocks, rows)]
+    # no sixteen-word minor axis anywhere outside the kernels: PR 40's parent
+    # built, scattered into and transposed states, PR 41's the keystream in
+    # stream order (since PR 42 the words go to the compaction as planes)
     states = {a for a in avals if a[0][-1:] == (16,) and np.prod(a[0]) >= rows * blocks * 16}
-    assert states == {((rows, blocks, 16), "uint32", False)}
+    assert {a for a in states if not a[2]} == set()
+
+
+def _outside_kernels(jaxpr):
+    """Every equation of a jaxpr and its sub-jaxprs, those of a
+    ``pallas_call``'s body left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _outside_kernels(inner)
+
+
+def _strided(jaxpr) -> list:
+    """The lane-strided reads outside the kernels: a ``slice`` with a stride
+    other than 1, or a ``gather`` (what ``words[:, 0::2]`` traces to)."""
+    return [
+        eqn.primitive.name
+        for eqn in _outside_kernels(jaxpr)
+        if eqn.primitive.name == "gather"
+        or (eqn.primitive.name == "slice" and any(s != 1 for s in eqn.params["strides"] or ()))
+    ]
+
+
+def pair_planes_by_path() -> dict:
+    return {
+        dict(labels)["path"]: value
+        for (name, labels), value in telemetry.get_registry().snapshot()["counters"].items()
+        if name == "sda_crypto_chacha_pair_planes_total"
+    }
+
+
+def test_the_compaction_takes_the_pair_planes_and_no_flag_tensor_is_traced(fresh_telemetry):
+    """PR 42: ``chacha_rounds`` writes its words pair-major, one transposition
+    makes the ``(2, P, window)`` high and low planes, and ``chacha_compact``
+    takes them as its one operand and runs the zone test and the counts
+    itself: no strided read and no ``(P, window)`` flag tensor outside the
+    kernels. The XLA path keeps both, which shows the test can see them; the
+    counter names each path once a trace."""
+    import jax
+    import jax.numpy as jnp
+
+    rows, dim = 10, 300  # shapes no other test traces: jit's cache outlives a test
+    window = (chacha_pallas._window_pairs(dim, P61) * 2 + 15) // 16 * 8
+    fold = jax.jit(chacha_pallas.expand_seeds_counts, static_argnums=(1, 2, 3))
+    seeds = jnp.zeros((rows, 4), jnp.uint32)
+
+    def traced(backend):
+        return jax.make_jaxpr(lambda s: fold(s, dim, P61, backend))(seeds).jaxpr
+
+    kernel_path, twin = traced("interpret"), traced("jnp")
+    avals, kernels = _all_avals(kernel_path)
+    (compact,) = [k for k in kernels if k.params["name"] == "chacha_compact"]
+    assert [(tuple(v.aval.shape), str(v.aval.dtype)) for v in compact.invars] == [
+        ((2, rows, window), "uint32")
+    ]
+    flags = {(shape, dtype, inside) for shape, dtype, inside in avals if shape == (rows, window)}
+    assert {a for a in flags if not a[2] and a[1] in ("bool", "int32")} == set()
+    assert _strided(kernel_path) == []
+    twin_avals, _ = _all_avals(twin)
+    assert ((rows, window), "bool", False) in twin_avals and "gather" in _strided(twin)
+    assert pair_planes_by_path() == {"kernel": rows, "slices": rows}
+    fold.lower(seeds, dim, P61, "interpret")  # the same trace: counted once
+    # ``auto`` is what this process's backend runs: the slices off a TPU
+    fold.lower(jnp.zeros((3, 4), jnp.uint32), dim, P61, "auto")
+    assert pair_planes_by_path() == {"kernel": rows, "slices": rows + 3}
 
 
 def test_blocks_are_counted_by_path_once_a_trace(fresh_telemetry):

@@ -307,23 +307,26 @@ COMPACTION_CASES = {
 }
 
 
-def compaction_ticks(path):
+def compaction_ticks(path, name="sda_crypto_chacha_compactions_total"):
     from sda_tpu import telemetry
 
     return sum(
         c["value"] for c in telemetry.snapshot()["counters"]
-        if c["name"] == "sda_crypto_chacha_compactions_total" and c["labels"].get("path") == path
+        if c["name"] == name and c["labels"].get("path") == path
     )
 
 
 @pytest.mark.parametrize("case", COMPACTION_CASES)
 def test_compaction_kernel_is_the_stages_bit_for_bit(case, monkeypatch):
-    """The kernel ``chacha_compact`` (its source, on the interpreter) against
-    ``_first_accepted`` and the plain definition, on a real keystream with a
-    row that rejects nothing and one that accepts nothing put in by hand; then
-    the whole expansion by ``interpret`` against ``jnp`` and the host's
-    ``expand_seed``, masks and counts, rows that come short among them (their
-    kept draws intact, zeros after); and the counter says which path ran."""
+    """The kernel ``chacha_compact`` (its source, on the interpreter), which
+    takes the ``(2, P, window)`` word planes and the zone and makes the zone
+    test and the counts itself, against ``_first_accepted`` and the plain
+    definition, on a real keystream with a row of zero words (every draw
+    accepted) and one of ``0xFFFFFFFF`` words (every draw rejected at these
+    moduli) put in by hand; then the whole expansion by ``interpret`` against
+    ``jnp`` and the host's ``expand_seed``, masks and counts, rows that come
+    short among them (their kept draws intact, zeros after); and the counter
+    says which path ran."""
     import jax.numpy as jnp
 
     from sda_tpu.ops import chacha_pallas as cp
@@ -337,35 +340,44 @@ def test_compaction_kernel_is_the_stages_bit_for_bit(case, monkeypatch):
     window, zone = n_blocks * 8, chacha.rand03_zone(modulus)
     rng = np.random.default_rng(sorted(COMPACTION_CASES).index(case))
     seeds = rng.integers(0, 1 << 32, size=(rows, 4), dtype=np.uint64).astype(np.uint32)
-    words = np.stack([chacha.chacha_blocks(s, 0, n_blocks).reshape(-1) for s in seeds])
-    hi, lo = words[:, 0::2], words[:, 1::2]
-    ok = ((hi.astype(np.uint64) << np.uint64(32)) | lo) < np.uint64(zone)
-    counts = ok.sum(axis=1)
+    stream = np.stack([chacha.chacha_blocks(s, 0, n_blocks).reshape(-1) for s in seeds])
+
+    def draws(words):
+        hi, lo = words[:, 0::2], words[:, 1::2]
+        return hi, lo, ((hi.astype(np.uint64) << np.uint64(32)) | lo) < np.uint64(zone)
+
+    words = stream.copy()
+    words[0] = 0  # a row with no rejected draw
+    if rows > 2:
+        words[2] = 0xFFFFFFFF  # and one with no draw at all
+    hi, lo, ok = draws(words)
+    assert ok[0].all() and (rows <= 2 or not ok[2].any())
 
     # the kernel alone: at any shape, whatever the dispatcher would choose
-    forced = ok.copy()
-    forced[0] = True  # a row with no rejected draw
-    if rows > 2:
-        forced[2] = False  # and one with no draw at all
     want_hi, want_lo = np.zeros((2, rows, dim), np.uint32)
     for r in range(rows):
-        kept = np.flatnonzero(forced[r])
+        kept = np.flatnonzero(ok[r])
         before = kept - np.arange(len(kept))  # rejected draws before each
         kept = kept[before <= window - dim][:dim]
         want_hi[r, : len(kept)], want_lo[r, : len(kept)] = hi[r, kept], lo[r, kept]
-    args = jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(forced)
-    twin = cp._first_accepted(*args, dim)
-    kernel = cp._compact_pallas(*args, dim, interpret=True)
+    twin = cp._first_accepted(jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(ok), dim)
+    kernel = cp._compact_pallas(jnp.asarray(np.stack([hi, lo])), dim, zone, interpret=True)
     for got in (twin, kernel):
         assert got[0].shape == (rows, dim) and got[0].dtype == jnp.uint32
         assert np.array_equal(got[0], want_hi) and np.array_equal(got[1], want_lo)
+    assert kernel[2].shape == (rows,) and kernel[2].dtype == jnp.int32
+    assert np.array_equal(kernel[2], ok.sum(axis=1))
+    _hi, _lo, ok = draws(stream)
+    counts = ok.sum(axis=1)
 
     # the whole expansion, by the path the shape takes
     path = "interpret" if cp._compact_fits(window, dim) else "jnp"
     assert (path == "jnp") == ("takes-the-twin" in case)
-    before = compaction_ticks(path)
+    planes = "slices" if path == "jnp" else "kernel"
+    before = compaction_ticks(path), compaction_ticks(planes, "sda_crypto_chacha_pair_planes_total")
     masks, got_counts = cp.expand_seeds_counts(jnp.asarray(seeds), dim, modulus, "interpret")
-    assert compaction_ticks(path) == before + rows
+    assert compaction_ticks(path) == before[0] + rows
+    assert compaction_ticks(planes, "sda_crypto_chacha_pair_planes_total") == before[1] + rows
     twin_masks, twin_counts = cp.expand_seeds_counts(jnp.asarray(seeds), dim, modulus, "jnp")
     assert np.array_equal(masks, twin_masks) and masks.dtype == jnp.int64
     assert np.array_equal(got_counts, counts) and np.array_equal(twin_counts, counts)
