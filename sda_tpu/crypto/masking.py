@@ -154,11 +154,13 @@ class ChaChaMasker(SecretMasker, MaskCombiner, SecretUnmasker):
         if placed:
             seed_rows, count = list(seeds), sum(int(s.shape[0]) for s in seeds)
         else:
-            seed_rows = [np.asarray(s, dtype=np.int64).astype(np.uint32) for s in seeds]
+            # every upload's int64 words to uint32 in one call (a negative
+            # word wraps as each row's own cast would): (rows, words)
+            seed_rows = np.asarray(seeds, dtype=np.int64).astype(np.uint32)
             count = len(seed_rows)
         on_device = count * self.dimension >= self.DEVICE_COMBINE_THRESHOLD
         if placed and not on_device:  # too few to be worth the chips: as uploaded
-            seed_rows = list(np.concatenate([np.asarray(s) for s in seed_rows]))
+            seed_rows = np.concatenate([np.asarray(s) for s in seed_rows])
         with telemetry.span(
             "fabric.unmask.combine", seeds=count,
             path="device" if on_device else "host",
@@ -172,15 +174,14 @@ class ChaChaMasker(SecretMasker, MaskCombiner, SecretUnmasker):
 
                 return np.asarray(
                     combine_masks_device(
-                        seed_rows if placed else np.stack(seed_rows),
-                        self.dimension, self.modulus, chunk=chunk, mesh=mesh,
+                        seed_rows, self.dimension, self.modulus, chunk=chunk, mesh=mesh
                     )
                 )
-            if not seed_rows:
+            if not count:
                 return np.zeros(self.dimension, dtype=np.int64)
             # one C call expands + folds the whole cohort (19x the numpy loop;
             # falls back to it when the extension isn't built)
-            return chacha_combine(np.stack(seed_rows), self.dimension, self.modulus)
+            return chacha_combine(seed_rows, self.dimension, self.modulus)
 
     def unmask(self, mask, masked):
         with telemetry.span("fabric.unmask.subtract", shape=np.shape(masked)):

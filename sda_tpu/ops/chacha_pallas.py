@@ -776,6 +776,57 @@ def count_fold_chips(chips: int) -> None:
     ).inc(chips)
 
 
+def count_combine_sync() -> None:
+    """One more wait of the host on the device inside a device combine: the
+    one read of every fold's counts, or a short fold's parts and seeds
+    fetched over a mesh for its recovery."""
+    telemetry.counter(
+        "sda_crypto_chacha_combine_syncs_total",
+        "host waits on the device inside the recipient's device combine",
+    ).inc()
+
+
+@functools.lru_cache(maxsize=None)
+def _add_parts_jit():
+    """``fn(total, part, modulus) -> (total + part) mod modulus`` of two
+    canonical ``(dim,)`` partial mask sums, the last argument static: one
+    level of ``mod_sum_wide_jnp``, a conditional less m (no division). One
+    program a dim, whatever the number of folds."""
+    import jax
+    import jax.numpy as jnp
+
+    from .modular import mod_sum_wide_jnp
+
+    return jax.jit(
+        lambda total, part, modulus: mod_sum_wide_jnp(jnp.stack((total, part)), modulus),
+        static_argnums=2,
+    )
+
+
+def _sum_parts(parts, dim: int, modulus: int):
+    """The ``(dim,)`` sum mod m of the folds' partials, a device add a part
+    behind the folds: dispatched, never waited for."""
+    import jax.numpy as jnp
+
+    if not parts:
+        return jnp.zeros((dim,), dtype=jnp.int64)
+    add = _add_parts_jit()
+    total = parts[0]
+    for part in parts[1:]:
+        total = add(total, part, modulus)
+    return total
+
+
+def _read_counts(counts):
+    """Every fold's accepted counts on the host, in one fetch after the last
+    fold is dispatched: the combine's one wait on the device."""
+    import jax
+
+    host = jax.device_get(list(counts))
+    count_combine_sync()
+    return host
+
+
 def _host_fold(batch, dim: int, modulus: int):
     """The partial mask sum of ``batch`` by the host path, which extends a
     seed's stream on demand: what recovers a fold whose window came short
@@ -848,7 +899,9 @@ def _mesh_batches(seed_words, chunk: int, mesh):
 
 def _combine_over_mesh(seed_words, dim: int, modulus: int, chunk: int, backend: str, mesh):
     """:func:`combine_masks_device` over ``mesh``: ``(the (dim,) combined mask,
-    on every chip of the mesh; the seeds folded)``."""
+    on every chip of the mesh; the seeds folded)``. Every fold is dispatched
+    before the host reads anything; then all folds' counts in one fetch."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -857,25 +910,35 @@ def _combine_over_mesh(seed_words, dim: int, modulus: int, chunk: int, backend: 
     chips = mesh.size
     fold = fold_chunk_mesh_jit(mesh)
     batches, rest = _mesh_batches(seed_words, chunk, mesh)
-    total = jnp.zeros((dim,), dtype=jnp.int64)
+    mets, chip_parts, counts = [], [], []
     for batch in batches:
-        met, parts, counts = fold(batch, dim, modulus, backend)
+        met, parts, batch_counts = fold(batch, dim, modulus, backend)
         count_fold_chips(chips)
-        short = np.asarray(counts).reshape(chips, -1) < dim
-        if short.any():
-            count_slack_exhausted("recipient", int(short.sum()))
-            logging.getLogger(__name__).info(
-                "rejection slack exhausted on %d chip(s); host-expanding their batches",
-                int(short.any(axis=1).sum()),
-            )
-            parts = np.array(parts)
-            own = np.asarray(batch).reshape(chips, -1, batch.shape[1])
-            for chip in np.flatnonzero(short.any(axis=1)):
-                parts[chip] = _host_fold(own[chip], dim, modulus)
-            met = jnp.asarray(mod_sum_wide_np(parts, modulus, axis=0))
-        total = (total + met) % jnp.int64(modulus)
-    if rest.shape[0]:
-        total = (total + _host_fold(rest, dim, modulus)) % jnp.int64(modulus)
+        mets.append(met)
+        chip_parts.append(parts)
+        counts.append(batch_counts)
+    if rest.shape[0]:  # folded on the host while the chips fold
+        mets.append(jnp.asarray(_host_fold(rest, dim, modulus)))
+    total = _sum_parts(mets, dim, modulus)
+    recovered = False
+    for i, batch_counts in enumerate(_read_counts(counts)):
+        short = batch_counts.reshape(chips, -1) < dim
+        if not short.any():
+            continue
+        count_slack_exhausted("recipient", int(short.sum()))
+        logging.getLogger(__name__).info(
+            "rejection slack exhausted on %d chip(s); host-expanding their batches",
+            int(short.any(axis=1).sum()),
+        )
+        parts, own = jax.device_get((chip_parts[i], batches[i]))
+        count_combine_sync()
+        parts, own = np.array(parts), own.reshape(chips, -1, own.shape[1])
+        for chip in np.flatnonzero(short.any(axis=1)):
+            parts[chip] = _host_fold(own[chip], dim, modulus)
+        mets[i] = jnp.asarray(mod_sum_wide_np(parts, modulus, axis=0))
+        recovered = True
+    if recovered:
+        total = _sum_parts(mets, dim, modulus)
     return total, sum(int(batch.shape[0]) for batch in batches) + int(rest.shape[0])
 
 
@@ -905,6 +968,13 @@ def combine_masks_device(
     are sharded over the mesh already, which are folded where they lie
     (:func:`_mesh_batches`). A short window on any chip is counted as on one
     chip, and that chip's batch alone recovered on the host.
+
+    The host waits on the device once a call: every fold is dispatched back
+    to back, the partials added mod m on the device behind them
+    (:func:`_sum_parts`), and all folds' counts read in one fetch after the
+    last dispatch (``sda_crypto_chacha_combine_syncs_total``). Each fold's
+    ``(dim,)`` partial stays on the device until then, so that a fold whose
+    window came short is replaced in the total by its host fold.
     """
     from .jaxcfg import ensure_x64
 
@@ -931,18 +1001,27 @@ def combine_masks_device(
     expands.inc(int(seed_words.shape[0]))
 
     fold = fold_chunk_jit()
-    total = jnp.zeros((dim,), dtype=jnp.int64)
-    for start in range(0, seed_words.shape[0], chunk):
-        batch = seed_words[start : start + chunk]
-        part, counts = fold(jnp.asarray(batch), dim, modulus, backend)
+    starts = range(0, seed_words.shape[0], chunk)
+    parts, counts = [], []
+    for start in starts:  # back to back: no read between two folds
+        batch = jnp.asarray(seed_words[start : start + chunk])
+        part, batch_counts = fold(batch, dim, modulus, backend)
         count_fold_chips(1)
-        if counts.shape[0] and int(jnp.min(counts)) < dim:
-            count_slack_exhausted("recipient", int(jnp.sum(counts < dim)))
-            logging.getLogger(__name__).info(
-                "rejection slack exhausted in chunk at %d; host-expanding it", start
-            )
-            # ~1e-9-per-row event: host-expand just this chunk and keep the
-            # device fold going
-            part = jnp.asarray(_host_fold(batch, dim, modulus))
-        total = (total + part) % jnp.int64(modulus)
+        parts.append(part)
+        counts.append(batch_counts)
+    total = _sum_parts(parts, dim, modulus)
+    recovered = False
+    for i, (start, batch_counts) in enumerate(zip(starts, _read_counts(counts))):
+        short = int(np.count_nonzero(batch_counts < dim))
+        if not short:
+            continue
+        count_slack_exhausted("recipient", short)
+        logging.getLogger(__name__).info(
+            "rejection slack exhausted in chunk at %d; host-expanding it", start
+        )
+        # ~1e-9-per-row event: host-expand just this chunk, in its fold's place
+        parts[i] = jnp.asarray(_host_fold(seed_words[start : start + chunk], dim, modulus))
+        recovered = True
+    if recovered:
+        total = _sum_parts(parts, dim, modulus)
     return total

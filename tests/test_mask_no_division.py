@@ -146,15 +146,34 @@ def test_halving_sum_on_the_edges_of_a_correction(m):
     np.testing.assert_array_equal(got, [0, 0, m - 1, 1 - m, m - 2, 2 - m, 0, 0])
 
 
+@pytest.mark.parametrize("modulus", [P46, P61, P62], ids=["p46", "p61", "p62"])
+def test_the_sharded_fold_and_the_partials_add_lower_to_no_division(modulus):
+    """The recipient's two other programs: the fold over a mesh, its partials
+    meeting, and the add of two folds' partials that ``combine_masks_device``
+    runs behind the folds."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("p",))
+    seeds = jnp.zeros((2 * 5, 4), jnp.uint32)
+    part = jnp.zeros((150,), jnp.int64)
+    for lowered in (
+        chacha_pallas.fold_chunk_mesh_jit(mesh).lower(seeds, 150, modulus, "jnp"),
+        chacha_pallas._add_parts_jit().lower(part, part, modulus),
+    ):
+        assert _names_a_division(lowered.as_text()) == []
+        assert _names_a_division(lowered.compile().as_text()) == []
+
+
 #: what a recipient's first ``combine`` of two folds compiles on the CPU after
-#: ``jax.clear_caches()``: ``jit(_fold_chunk)`` and the eager programs around
-#: it in ``combine_masks_device``: ``convert_element_type`` and
-#: ``broadcast_in_dim`` (the zero total), ``_reduce_min`` (the counts' check),
-#: ``add`` and ``remainder`` (``(total + part) % m``, over ``dim`` values a
-#: fold). A change that leaves an operation outside the fold, or splits the
-#: fold, moves this number: then read what a warm-up on the chip loads
+#: ``jax.clear_caches()``: ``jit(_fold_chunk)`` and ``_add_parts_jit()``'s add
+#: of the two folds' partials mod m. Nothing else: the batches are put, the
+#: counts fetched in one read, no eager operation runs between folds. A change
+#: that leaves an operation outside the fold, or splits the fold, moves this
+#: number: then read what a warm-up on the chip loads
 #: (``scripts/setup_events.py``).
-COMBINE_PROGRAMS = 6
+COMBINE_PROGRAMS = 2
 
 
 def test_a_combine_compiles_a_fixed_number_of_programs_and_then_none(monkeypatch):

@@ -243,6 +243,100 @@ def test_slack_check_fires_on_a_short_window(field, monkeypatch):
     assert np.array_equal(total, reference_chacha.mask_sum(np.asarray(seeds), DIM, p))
 
 
+def _seeds(rows, seed=43):
+    rng = np.random.default_rng(seed)
+    return rng.integers(0, 1 << 32, size=(rows, 4), dtype=np.uint64).astype(np.uint32)
+
+
+@pytest.mark.parametrize("chunk", [4, 3, 1], ids=["2-folds", "3-folds", "12-folds"])
+def test_the_folds_go_back_to_back_and_the_counts_are_read_once(field, chunk, monkeypatch):
+    """Every fold is dispatched before the host waits on the device once, for
+    all folds' counts; the total is the plain reference's bit for bit."""
+    p = field[0].prime_modulus
+    seeds = _seeds(12)
+    fold = chacha_pallas.fold_chunk_jit()
+    syncs_at_dispatch = []
+
+    def spy(batch, dim, modulus, backend):
+        syncs_at_dispatch.append(ticks("sda_crypto_chacha_combine_syncs_total"))
+        return fold(batch, dim, modulus, backend)
+
+    monkeypatch.setattr(chacha_pallas, "fold_chunk_jit", lambda: spy)
+    before = ticks("sda_crypto_chacha_combine_syncs_total")
+    total = chacha_pallas.combine_masks_device(seeds, DIM, p, chunk=chunk)
+    assert syncs_at_dispatch == [before] * (12 // chunk)
+    assert ticks("sda_crypto_chacha_combine_syncs_total") == before + 1
+    assert np.array_equal(total, reference_chacha.mask_sum(seeds, DIM, p))
+
+
+def test_a_short_window_in_a_middle_fold_is_recovered_alone(field, monkeypatch):
+    """The second of three folds says two of its rows came short, and its
+    device partial is garbage: that fold alone is host-expanded and takes its
+    place in the total, which is the host path's; the counter ticks by the
+    short rows, and the one read stays one."""
+    import jax.numpy as jnp
+
+    p = field[0].prime_modulus
+    seeds = _seeds(9)
+    fold = chacha_pallas.fold_chunk_jit()
+    calls = []
+
+    def short_in_the_middle(batch, dim, modulus, backend):
+        part, counts = fold(batch, dim, modulus, backend)
+        calls.append(batch)
+        if len(calls) == 2:
+            return jnp.zeros_like(part), counts.at[:2].set(dim - 1)
+        return part, counts
+
+    host_fold, recovered = chacha_pallas._host_fold, []
+
+    def spy_host_fold(batch, dim, modulus):
+        recovered.append(np.array(batch))
+        return host_fold(batch, dim, modulus)
+
+    monkeypatch.setattr(chacha_pallas, "fold_chunk_jit", lambda: short_in_the_middle)
+    monkeypatch.setattr(chacha_pallas, "_host_fold", spy_host_fold)
+    short_before = ticks("sda_mask_slack_exhausted_total", side="recipient")
+    syncs_before = ticks("sda_crypto_chacha_combine_syncs_total")
+    total = chacha_pallas.combine_masks_device(seeds, DIM, p, chunk=3)
+    assert len(calls) == 3 and [r.tolist() for r in recovered] == [seeds[3:6].tolist()]
+    assert ticks("sda_mask_slack_exhausted_total", side="recipient") == short_before + 2
+    assert ticks("sda_crypto_chacha_combine_syncs_total") == syncs_before + 1
+    assert np.array_equal(total, host_fold(seeds, DIM, p))
+    assert np.array_equal(total, reference_chacha.mask_sum(seeds, DIM, p))
+
+
+def _cast_row_by_row(uploads):
+    """The recipient's conversion as it was: each upload alone."""
+    return np.stack([np.asarray(s, dtype=np.int64).astype(np.uint32) for s in uploads])
+
+
+@pytest.mark.parametrize("path", ["host", "device"])
+@pytest.mark.parametrize("form", ["int64-vectors", "2d-int64-array", "negative-word"])
+def test_the_uploads_become_one_seed_array_with_each_rows_bits(field, form, path, monkeypatch):
+    """``ChaChaMasker.combine`` converts the uploads in one call: a list of
+    int64 vectors, one 2-D int64 array, and a word uploaded negative (it wraps
+    mod 2^32, as each row's own cast did) give the mask of the row-by-row
+    conversion, on the host path and on the device path."""
+    from sda_tpu.crypto.masking import ChaChaMasker
+
+    p = field[0].prime_modulus
+    rows = _seeds(5, seed=7).astype(np.int64)
+    uploads = {
+        "int64-vectors": list(rows),
+        "2d-int64-array": rows,
+        "negative-word": [np.array([-1, -(1 << 31), 5, 1 << 32 | 9], np.int64)] + list(rows[1:]),
+    }[form]
+    if path == "device":
+        monkeypatch.setattr(ChaChaMasker, "DEVICE_COMBINE_THRESHOLD", 1)
+    masker = ChaChaMasker(p, DIM, 128)
+    got = masker.combine(uploads, chunk=2)
+    (span,) = telemetry.spans("fabric.unmask.combine")[-1:]
+    assert span["attrs"]["path"] == path and span["attrs"]["seeds"] == 5
+    want = reference_chacha.mask_sum(_cast_row_by_row(uploads), DIM, p)
+    assert got.dtype == np.int64 and np.array_equal(got, want)
+
+
 @pytest.mark.parametrize("dim,window", [(5, 9), (40, 64), (100, 107), (64, 200)])
 def test_first_accepted_is_the_stable_compaction(dim, window):
     """Against the plain definition, on random acceptance patterns, rows that

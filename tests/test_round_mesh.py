@@ -192,6 +192,81 @@ def test_the_sharded_combine_is_the_one_chip_combine_bit_for_bit(chips):
     ) == [len(seeds), 2, 2 * chips]
 
 
+def test_the_sharded_folds_go_back_to_back_and_the_counts_are_read_once(monkeypatch):
+    """Three sharded folds on four chips, every one dispatched before the
+    host waits on the chips once, for all folds' counts: the plain
+    reference's mask sum, bit for bit."""
+    from benchmark import reference_chacha  # conftest puts the checkout on the path
+
+    p = scheme().prime_modulus
+    mesh = make_mesh(p_size=4, d_size=1)
+    seeds = np.random.default_rng(43).integers(0, 1 << 32, size=(24, 4), dtype=np.uint64)
+    seeds = seeds.astype(np.uint32)
+    fold = chacha_pallas.fold_chunk_mesh_jit(mesh)
+    syncs_at_dispatch = []
+
+    def spy(batch, dim, modulus, backend):
+        syncs_at_dispatch.append(counters("sda_crypto_chacha_combine_syncs_total")[0])
+        return fold(batch, dim, modulus, backend)
+
+    monkeypatch.setattr(chacha_pallas, "fold_chunk_mesh_jit", lambda mesh: spy)
+    telemetry.reset()
+    got = chacha_pallas.combine_masks_device(seeds, DIM, p, chunk=2, mesh=mesh)
+    assert syncs_at_dispatch == [0, 0, 0]
+    assert got.sharding.is_fully_replicated
+    assert np.array_equal(np.asarray(got), reference_chacha.mask_sum(seeds, DIM, p))
+    assert counters(
+        "sda_crypto_chacha_combine_syncs_total", "sda_crypto_chacha_folds_total",
+        "sda_crypto_chacha_fold_chips_total",
+    ) == [1, 3, 12]
+
+
+def test_a_short_window_on_one_chip_of_a_middle_fold_recovers_that_chip_alone(monkeypatch):
+    """The second of three sharded folds says chip 2 had a row come short,
+    and that chip's partial (and so the met sum) is garbage: only chip 2's own
+    rows of that fold are host-expanded, the fold's met sum is made again from
+    the chips' partials, and the total is the host path's. The counter ticks
+    by the short row; the host waits twice: the counts, then that fold's
+    partials and seeds."""
+    from benchmark import reference_chacha  # conftest puts the checkout on the path
+
+    p = scheme().prime_modulus
+    mesh = make_mesh(p_size=4, d_size=1)
+    seeds = np.random.default_rng(44).integers(0, 1 << 32, size=(24, 4), dtype=np.uint64)
+    seeds = seeds.astype(np.uint32)
+    fold = chacha_pallas.fold_chunk_mesh_jit(mesh)
+    calls = []
+
+    def short_on_chip_2_of_the_middle_fold(batch, dim, modulus, backend):
+        met, parts, counts = fold(batch, dim, modulus, backend)
+        calls.append(batch)
+        if len(calls) == 2:  # chip 2 holds rows 4 and 5 of the fold's 8
+            return jnp.zeros_like(met), parts.at[2].set(0), counts.at[5].set(dim - 1)
+        return met, parts, counts
+
+    host_fold, recovered = chacha_pallas._host_fold, []
+
+    def spy_host_fold(batch, dim, modulus):
+        recovered.append(np.array(batch))
+        return host_fold(batch, dim, modulus)
+
+    monkeypatch.setattr(
+        chacha_pallas, "fold_chunk_mesh_jit", lambda mesh: short_on_chip_2_of_the_middle_fold
+    )
+    monkeypatch.setattr(chacha_pallas, "_host_fold", spy_host_fold)
+    telemetry.reset()
+    got = chacha_pallas.combine_masks_device(seeds, DIM, p, chunk=2, mesh=mesh)
+    assert len(calls) == 3 and [r.tolist() for r in recovered] == [seeds[12:14].tolist()]
+    assert np.array_equal(np.asarray(got), host_fold(seeds, DIM, p))
+    assert np.array_equal(np.asarray(got), reference_chacha.mask_sum(seeds, DIM, p))
+    short = [
+        c["value"] for c in telemetry.snapshot(0)["counters"]
+        if c["name"] == "sda_mask_slack_exhausted_total" and c["labels"] == {"side": "recipient"}
+    ]
+    assert short == [1]
+    assert counters("sda_crypto_chacha_combine_syncs_total") == [2]
+
+
 def test_the_partials_meet_without_leaving_int64_at_eight_chips(monkeypatch):
     """Eight canonical 61-bit partials of p - 1 each: a plain int64 ``psum``
     of them is past 2^63; gathered and summed by halving they are 8 (p - 1)
