@@ -542,21 +542,6 @@ def count_compaction(path: str, rows: int) -> None:
     ).inc(rows)
 
 
-def count_pair_planes(path: str, rows: int) -> None:
-    """``rows`` more keystream rows whose draws reached the compaction by
-    ``path``: ``kernel`` (written by ``chacha_rounds`` in stream order down
-    the sublanes, transposed once into high and low planes, the zone test
-    and the counts in ``chacha_compact``) or ``slices`` (stream order, strided slices, the zone
-    test and the counts in XLA). Counted where the path is chosen, once a
-    trace, as :func:`count_compaction` counts."""
-    telemetry.counter(
-        "sda_crypto_chacha_pair_planes_total",
-        "keystream rows whose draws reached the compaction as word-pair planes, "
-        "by path (kernel | slices)",
-        path=path,
-    ).inc(rows)
-
-
 def _draws_by_slices(words, dim: int, zone: int):
     """``(P, blocks, 16)`` keystream in stream order -> the high and low words
     of each row's first ``dim`` accepted draws and its count of accepted
@@ -598,11 +583,9 @@ def _draws(seed_words, n_blocks: int, dim: int, zone: int, backend: str):
     if backend == "jnp" or not _compact_fits(window, dim):
         words = _rounds(seed_words, n_blocks, 0, backend)
         count_compaction("jnp", rows)
-        count_pair_planes("slices", rows)
         return _draws_by_slices(words, dim, zone)
     path = _count_rounds(rows, n_blocks, 0, backend)
     count_compaction(path, rows)
-    count_pair_planes("slices" if path == "jnp" else "kernel", rows)
     if backend == "auto":
         return lax.platform_dependent(
             seed_words,
@@ -897,51 +880,6 @@ def _mesh_batches(seed_words, chunk: int, mesh):
     return batches, np.zeros((0, 0), np.uint32)
 
 
-def _combine_over_mesh(seed_words, dim: int, modulus: int, chunk: int, backend: str, mesh):
-    """:func:`combine_masks_device` over ``mesh``: ``(the (dim,) combined mask,
-    on every chip of the mesh; the seeds folded)``. Every fold is dispatched
-    before the host reads anything; then all folds' counts in one fetch."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from .modular import mod_sum_wide_np
-
-    chips = mesh.size
-    fold = fold_chunk_mesh_jit(mesh)
-    batches, rest = _mesh_batches(seed_words, chunk, mesh)
-    mets, chip_parts, counts = [], [], []
-    for batch in batches:
-        met, parts, batch_counts = fold(batch, dim, modulus, backend)
-        count_fold_chips(chips)
-        mets.append(met)
-        chip_parts.append(parts)
-        counts.append(batch_counts)
-    if rest.shape[0]:  # folded on the host while the chips fold
-        mets.append(jnp.asarray(_host_fold(rest, dim, modulus)))
-    total = _sum_parts(mets, dim, modulus)
-    recovered = False
-    for i, batch_counts in enumerate(_read_counts(counts)):
-        short = batch_counts.reshape(chips, -1) < dim
-        if not short.any():
-            continue
-        count_slack_exhausted("recipient", int(short.sum()))
-        logging.getLogger(__name__).info(
-            "rejection slack exhausted on %d chip(s); host-expanding their batches",
-            int(short.any(axis=1).sum()),
-        )
-        parts, own = jax.device_get((chip_parts[i], batches[i]))
-        count_combine_sync()
-        parts, own = np.array(parts), own.reshape(chips, -1, own.shape[1])
-        for chip in np.flatnonzero(short.any(axis=1)):
-            parts[chip] = _host_fold(own[chip], dim, modulus)
-        mets[i] = jnp.asarray(mod_sum_wide_np(parts, modulus, axis=0))
-        recovered = True
-    if recovered:
-        total = _sum_parts(mets, dim, modulus)
-    return total, sum(int(batch.shape[0]) for batch in batches) + int(rest.shape[0])
-
-
 def combine_masks_device(
     seed_words,
     dim: int,
@@ -966,62 +904,87 @@ def combine_masks_device(
     (:func:`fold_chunk_mesh_jit`): host rows are put sharded, and
     ``seed_words`` may instead be a list of ``(rows, w)`` device arrays that
     are sharded over the mesh already, which are folded where they lie
-    (:func:`_mesh_batches`). A short window on any chip is counted as on one
-    chip, and that chip's batch alone recovered on the host.
+    (:func:`_mesh_batches`).
 
-    The host waits on the device once a call: every fold is dispatched back
-    to back, the partials added mod m on the device behind them
-    (:func:`_sum_parts`), and all folds' counts read in one fetch after the
-    last dispatch (``sda_crypto_chacha_combine_syncs_total``). Each fold's
-    ``(dim,)`` partial stays on the device until then, so that a fold whose
-    window came short is replaced in the total by its host fold.
+    One loop serves one chip and a mesh. A fold is a device batch of seeds
+    and the chips it runs on; it hands back the met ``(dim,)`` partial, every
+    chip's own partial and the counts (on one chip :func:`fold_chunk_jit`'s
+    partial is both, and a batch is put beside its own fold). The host waits
+    on the device once a call: every fold is dispatched back to back, the
+    met partials added mod m on the device behind them (:func:`_sum_parts`),
+    and all folds' counts read in one fetch after the last dispatch
+    (``sda_crypto_chacha_combine_syncs_total``). A fold whose window came
+    short on a chip has that chip's partial replaced by the host fold of the
+    chip's own rows, and its met partial summed again (over a mesh the
+    fold's partials and seeds are fetched for it, one wait more).
     """
     from .jaxcfg import ensure_x64
 
     ensure_x64()
+    import jax
     import jax.numpy as jnp
     import numpy as np
+
+    from .modular import mod_sum_wide_np
 
     if chunk is None:
         chunk = max(16, _COMBINE_BYTES_BUDGET // (5 * 8 * dim))
     if backend == "auto":  # resolved here: it is a static jit argument
         backend = default_backend()
+    if mesh is None:
+        chips, fold = 1, fold_chunk_jit()
+        seed_words = np.asarray(seed_words, dtype=np.uint32)
+        starts = range(0, seed_words.shape[0], chunk)
+        # every seed divides over one chip: no rest
+        batches, rest = (seed_words[s : s + chunk] for s in starts), seed_words[:0]
+
+        def run(rows):  # put beside its own fold; the one chip's partial is the met one
+            part, counts = fold(jnp.asarray(rows), dim, modulus, backend)
+            return part, (part,), counts
+    else:
+        chips, fold = mesh.size, fold_chunk_mesh_jit(mesh)
+        batches, rest = _mesh_batches(seed_words, chunk, mesh)
+        run = lambda batch: fold(batch, dim, modulus, backend)  # noqa: E731
+
+    mets, held, counts = [], [], []
+    for batch in batches:  # back to back: no read between two folds
+        met, parts, fold_counts = run(batch)
+        count_fold_chips(chips)
+        mets.append(met)
+        held.append((batch, parts))
+        counts.append(fold_counts)
+    if rest.shape[0]:  # folded on the host while the chips fold
+        mets.append(jnp.asarray(_host_fold(rest, dim, modulus)))
+    total = _sum_parts(mets, dim, modulus)
+    recovered = False
+    for i, fold_counts in enumerate(_read_counts(counts)):
+        short = fold_counts.reshape(chips, -1) < dim
+        if not short.any():
+            continue
+        # a ~1e-9-per-row event: the short chips' rows host-expanded
+        count_slack_exhausted("recipient", int(short.sum()))
+        short_chips = np.flatnonzero(short.any(axis=1))
+        logging.getLogger(__name__).info(
+            "rejection slack exhausted on %d of %d chip(s) in fold %d; host-expanding their rows",
+            len(short_chips), chips, i,
+        )
+        batch, parts = held[i]
+        if mesh is not None:  # the fold's partials and seeds lie on the chips
+            parts, batch = jax.device_get((parts, batch))
+            count_combine_sync()
+        # on one chip its one partial is the short chip's: replaced, never read
+        parts, own = list(parts), batch.reshape(chips, -1, batch.shape[1])
+        for chip in short_chips:
+            parts[chip] = _host_fold(own[chip], dim, modulus)
+        mets[i] = jnp.asarray(mod_sum_wide_np(np.stack(parts), modulus, axis=0))
+        recovered = True
+    if recovered:
+        total = _sum_parts(mets, dim, modulus)
     # same series the host paths count into (native/__init__.py), so a
     # scrape shows which implementation expanded a reveal's seeds
-    expands = telemetry.counter(
+    telemetry.counter(
         "sda_crypto_chacha_expands_total",
         "ChaCha mask seeds expanded/combined by path",
         path=backend,
-    )
-    if mesh is not None:
-        total, seeds = _combine_over_mesh(seed_words, dim, modulus, chunk, backend, mesh)
-        expands.inc(seeds)
-        return total
-    seed_words = np.asarray(seed_words, dtype=np.uint32)
-    expands.inc(int(seed_words.shape[0]))
-
-    fold = fold_chunk_jit()
-    starts = range(0, seed_words.shape[0], chunk)
-    parts, counts = [], []
-    for start in starts:  # back to back: no read between two folds
-        batch = jnp.asarray(seed_words[start : start + chunk])
-        part, batch_counts = fold(batch, dim, modulus, backend)
-        count_fold_chips(1)
-        parts.append(part)
-        counts.append(batch_counts)
-    total = _sum_parts(parts, dim, modulus)
-    recovered = False
-    for i, (start, batch_counts) in enumerate(zip(starts, _read_counts(counts))):
-        short = int(np.count_nonzero(batch_counts < dim))
-        if not short:
-            continue
-        count_slack_exhausted("recipient", short)
-        logging.getLogger(__name__).info(
-            "rejection slack exhausted in chunk at %d; host-expanding it", start
-        )
-        # ~1e-9-per-row event: host-expand just this chunk, in its fold's place
-        parts[i] = jnp.asarray(_host_fold(seed_words[start : start + chunk], dim, modulus))
-        recovered = True
-    if recovered:
-        total = _sum_parts(parts, dim, modulus)
+    ).inc(sum(int(batch.shape[0]) for batch, _ in held) + int(rest.shape[0]))
     return total

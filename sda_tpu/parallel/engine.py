@@ -463,11 +463,11 @@ class TpuAggregator:
         return jax.jit(mapped)
 
     def _limb_accumulator_local_step(self, psum_axes):
-        """Shared per-device body of the wide-modulus fabric: fused limb
-        share+combine, then int64 partial psums over ``psum_axes`` in
-        order (single-slice: ('p',); hybrid: ('p', 'h') — ICI before
-        DCN). One definition so overflow-bound or chunking fixes apply to
-        every fabric at once."""
+        """Per-device body of the hierarchical wide-modulus fabric
+        (``multihost.hierarchical_limb_accumulators``): fused limb
+        share+combine, then int64 partial psums over ``psum_axes`` in order
+        (('p', 'h') — ICI before DCN). The one-slice fabric is
+        :func:`over_mesh`'s (:meth:`sharded_limb_accumulators`)."""
         import jax
         from jax import lax
 
@@ -504,21 +504,15 @@ class TpuAggregator:
         device at all.
 
         Returns fn(secrets_sharded, key) -> (W, B, n) int64 accumulators
-        (replicated over ``p``, sharded over ``d`` on the B axis). Feed
-        ``limb_recombine_host(acc, p).T`` then ``reconstruct``.
+        (replicated over ``p``, sharded over ``d`` on the B axis): the chunk
+        step of :func:`over_mesh`, which refuses a dim that does not divide
+        over ``input_size × d``. Feed ``limb_recombine_host(acc, p).T`` then
+        ``reconstruct``.
         """
-        import jax
-        from jax.sharding import PartitionSpec as P
+        import functools
 
-        mapped = jax.shard_map(
-            self._limb_accumulator_local_step(("p",)),
-            mesh=self.mesh,
-            # in_specs requires a "d" axis, so no d-less fallback here
-            in_specs=(P("p", "d"), P()),
-            out_specs=P(None, "d", None),
-            check_vma=False,
-        )
-        return jax.jit(mapped)
+        entry = functools.partial(share_combine_limb, plan=self.plan)
+        return over_mesh(entry, self.plan, self.mesh)
 
     def sharded_clerk_sums(self):
         """Build the jitted sharded share+combine step over the mesh.
@@ -575,7 +569,7 @@ def validate_d_sharding(mesh, dim: int, input_size: int) -> None:
     """With a sharded dim axis every d-shard zero-pads its own tail batch
     independently — non-divisible dims would misalign batch boundaries and
     silently reconstruct a wrong aggregate. One definition of the rule for
-    every fabric (engine, multihost, sumfirst)."""
+    every fabric (:func:`over_mesh`, multihost)."""
     d_size = mesh.shape.get("d", 1)
     if d_size > 1 and dim % (input_size * d_size) != 0:
         raise ValueError(
@@ -600,6 +594,52 @@ def fold_mesh_axes(key, mesh):
     for axis in mesh.axis_names:
         key = jax.random.fold_in(key, lax.axis_index(axis))
     return key
+
+
+def mesh_acc_spec(mesh):
+    """How a chunk step over ``mesh`` keeps its accumulator: replicated over
+    ``p``, its batches (axis 1 of every chunk entry's) over ``d``."""
+    from jax.sharding import PartitionSpec as P
+
+    return P(None, "d" if "d" in mesh.axis_names else None, None)
+
+
+def over_mesh(chunk_fn, plan: AggregationPlan, mesh, masked: bool = False):
+    """The row-sharded chunk step of every fabric over ``mesh``: the round
+    driver's (``round.fold_round(..., mesh=)``), the sum-first one
+    (``sumfirst.sharded_value_limb_sums``) and the limb one
+    (:meth:`TpuAggregator.sharded_limb_accumulators`). ``chunk_fn(rows,
+    key)`` runs on every chip over the chip's own rows (a chunk's rows over
+    ``p``; unmasked, its dim over ``d``, which :func:`validate_d_sharding`
+    holds to whole batches) under ``shard_map``: the key with the chip's mesh
+    position folded in (:func:`fold_mesh_axes`: no two chips draw the same
+    share randomness, or the same seeds), the accumulator summed over ``p``
+    under ``fabric.psum`` and handed back as :func:`mesh_acc_spec` says, a
+    masked step's seeds and counts sharded over ``p``: they stay on the chip
+    that drew them."""
+    import jax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    validate_d_sharding(mesh, plan.dim, plan.input_size)
+    d_spec = "d" if "d" in mesh.axis_names else None
+    acc_spec = mesh_acc_spec(mesh)
+
+    def local_step(secrets, key):
+        out = chunk_fn(secrets, fold_mesh_axes(key, mesh))
+        acc, *handed = out if masked else (out,)
+        with jax.named_scope("fabric.psum"):
+            acc = lax.psum(acc, axis_name="p")
+        return (acc, *handed) if masked else acc
+
+    mapped = jax.shard_map(
+        local_step,
+        mesh=mesh,
+        in_specs=(P("p", d_spec), P()),
+        out_specs=(acc_spec, P("p", None), P("p")) if masked else acc_spec,
+        check_vma=False,
+    )
+    return jax.jit(mapped)
 
 
 def verified_step(agg, sums_fn):

@@ -43,12 +43,13 @@ counters of the feed are the same masked or not.
 deployment's layout is an argument of the round as its masking scheme is: a
 device mesh of axes ``("p", "d")``. ``chunk`` is then the rows a step folds on
 all chips together, a multiple of the mesh's ``p``, and the step is the same
-entry (the mask stage in front under ``masking=``) under ``shard_map``
-(:func:`_over_mesh`): every chip takes its own ``(chunk / p, dim / d)`` rows,
-the key with the step's number and then the chip's mesh position folded in
-(``engine.fold_mesh_axes``), so no two chips draw the same share randomness
-or the same seeds; the entry's accumulator is summed over ``p`` under
-``fabric.psum``, then the accumulate rule. It hands the accumulator on
+entry (the mask stage in front under ``masking=``) in the row-sharded wrapper
+every fabric over a mesh shares (``engine.over_mesh``; the sum-first and the
+limb fabric are built by it too): every chip takes its own ``(chunk / p, dim /
+d)`` rows, the key with the step's number and then the chip's mesh position
+folded in (``engine.fold_mesh_axes``), so no two chips draw the same share
+randomness or the same seeds; the entry's accumulator is summed over ``p``
+under ``fabric.psum``, then the accumulate rule. It hands the accumulator on
 replicated over ``p``, and a masked step its ``(chunk, words)`` seeds and
 ``(chunk,)`` counts sharded over ``p``: they stay on the chips that drew them.
 :meth:`FoldRound.fold_chunks` takes chunks that are sharded over the mesh
@@ -56,10 +57,11 @@ replicated over ``p``, and a masked step its ``(chunk, words)`` seeds and
 has one seed and its expansion runs the whole dim: ``ValueError``). The
 recipient's side is spread over the same chips: :meth:`FoldRound.unmask` folds
 ``chunk`` seeds *a chip* a call, every chip expanding the seeds of its own
-shard (``ops.chacha_pallas.fold_chunk_mesh_jit``: the one-chip fold under
-``shard_map``), and the chips' partial sums meet mod p under
-``fabric.unmask/meet`` without leaving int64's range. Seeds handed over as host
-rows (what a recipient receives) are put sharded; the device arrays the steps
+shard (``ops.chacha_pallas.fold_chunk_mesh_jit``: the one-chip fold on every
+chip), and the chips' partial sums meet mod p under ``fabric.unmask/meet``
+without leaving int64's range; the one combine loop of one chip and a mesh is
+``ops.chacha_pallas.combine_masks_device``. Seeds handed over as host rows
+(what a recipient receives) are put sharded; the device arrays the steps
 returned are folded where they lie. The feed is one chip's:
 :meth:`FoldRound.fold_host_rows` over a mesh raises ``ValueError``.
 
@@ -182,7 +184,7 @@ def _make_step(entry, plan, accumulate: str, masking=None, mesh=None):
     same step with the mask stage in front of the entry, the rows' seeds and
     accepted-draw counts leaving it beside the accumulator. Over a ``mesh``
     the entry (and the mask stage) run on every chip's own rows
-    (:func:`_over_mesh`); the accumulate rule is the same."""
+    (``engine.over_mesh``); the accumulate rule is the same."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -203,7 +205,7 @@ def _make_step(entry, plan, accumulate: str, masking=None, mesh=None):
 
         chunk_fn = masked_chunk(entry, plan, masking)  # refuses a scheme that is not the plan's
     if mesh is not None:
-        chunk_fn = _over_mesh(chunk_fn, plan, mesh, masked=masking is not None)
+        chunk_fn = engine.over_mesh(chunk_fn, plan, mesh, masked=masking is not None)
 
     def step(acc, chunk, key, i):
         # one chunk step: the round's key with the step's number folded in,
@@ -215,49 +217,6 @@ def _make_step(entry, plan, accumulate: str, masking=None, mesh=None):
         return accumulated(acc, out), seeds, counts
 
     return jax.jit(step if masking is None else masked_step)
-
-
-def _acc_spec(mesh):
-    """How a round over ``mesh`` keeps its accumulator: replicated over ``p``,
-    its batches (axis 1 of every paired entry's) over ``d``."""
-    from jax.sharding import PartitionSpec as P
-
-    return P(None, "d" if "d" in mesh.axis_names else None, None)
-
-
-def _over_mesh(chunk_fn, plan, mesh, masked: bool):
-    """``chunk_fn(rows, key)`` on every chip of ``mesh`` over the chip's own
-    rows (a chunk's rows over ``p``; unmasked, its dim over ``d``), under
-    ``shard_map``: the key with the chip's mesh position folded in
-    (``engine.fold_mesh_axes``: no two chips draw the same share randomness,
-    or the same seeds), the accumulator summed over ``p`` under
-    ``fabric.psum`` and handed back replicated over ``p``, a masked step's
-    seeds and counts sharded over ``p``: they stay on the chip that drew
-    them. The sum-first entry's unmasked step is the program that
-    ``sumfirst.sharded_value_limb_sums`` builds."""
-    import jax
-    from jax import lax
-    from jax.sharding import PartitionSpec as P
-
-    engine.validate_d_sharding(mesh, plan.dim, plan.input_size)
-    d_spec = "d" if "d" in mesh.axis_names else None
-    acc_spec = _acc_spec(mesh)
-
-    def local_step(secrets, key):
-        out = chunk_fn(secrets, engine.fold_mesh_axes(key, mesh))
-        acc, *handed = out if masked else (out,)
-        with jax.named_scope("fabric.psum"):
-            acc = lax.psum(acc, axis_name="p")
-        return (acc, *handed) if masked else acc
-
-    mapped = jax.shard_map(
-        local_step,
-        mesh=mesh,
-        in_specs=(P("p", d_spec), P()),
-        out_specs=(acc_spec, P("p", None), P("p")) if masked else acc_spec,
-        check_vma=False,
-    )
-    return jax.jit(mapped)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -299,7 +258,7 @@ class FoldRound:
         from jax.sharding import NamedSharding
 
         # on every chip before the first step, as every step hands it on
-        return jax.device_put(acc, NamedSharding(self.mesh, _acc_spec(self.mesh)))
+        return jax.device_put(acc, NamedSharding(self.mesh, engine.mesh_acc_spec(self.mesh)))
 
     def _stepped(self, acc, chunk, key, number: int, handed: list):
         """The accumulator after step ``number`` over ``chunk``; what a

@@ -62,7 +62,7 @@ from ..ops.modular import (
     modmatmul_np,
     modmatmul_path,
 )
-from .engine import AggregationPlan, _batch_secrets, _device_randomness
+from .engine import AggregationPlan, _batch_secrets, _device_randomness, over_mesh
 
 #: participant bound for exact int64 limb accumulation (see module doc)
 MAX_PARTICIPANTS = 1 << 31
@@ -263,21 +263,15 @@ def sharded_value_limb_sums(plan: AggregationPlan, mesh):
     pre-bounded per-shard limb sums).
 
     Returns ``fn(secrets_sharded, key) -> (L, B, K)`` int64 limb sums
-    (replicated over ``p``, sharded over ``d`` on the B axis). Feed the
-    gathered result to :func:`clerk_sums_from_limb_acc` on host, exactly
-    like the single-chip chunks.
+    (replicated over ``p``, sharded over ``d`` on the B axis): the chunk
+    step of ``engine.over_mesh``, which the round driver's step over a mesh
+    is too. Feed the gathered result to :func:`clerk_sums_from_limb_acc` on
+    host, exactly like the single-chip chunks.
     """
-    import jax
-    from jax import lax
-    from jax.sharding import PartitionSpec as P
-
-    from .engine import fold_mesh_axes, validate_d_sharding
-
-    validate_d_sharding(mesh, plan.dim, plan.input_size)
     p_size = mesh.shape["p"]
 
-    def local_step(secrets, key):
-        # shapes are static under shard_map, so this enforces the documented
+    def bounded(secrets, key):
+        # a chip's shapes are static, so this enforces the documented
         # *global* exactness bound at trace time (psum adds p_size shards),
         # mirroring clerk_sums_sum_first's guard
         if secrets.shape[0] * p_size > MAX_PARTICIPANTS:
@@ -286,17 +280,6 @@ def sharded_value_limb_sums(plan: AggregationPlan, mesh):
                 f"exceeds the exact limb-sum bound {MAX_PARTICIPANTS}; "
                 "chunk the input"
             )
-        key = fold_mesh_axes(key, mesh)
-        acc = value_limb_sums_chunk(secrets, key, plan)
-        with jax.named_scope("fabric.psum"):
-            return lax.psum(acc, axis_name="p")
+        return value_limb_sums_chunk(secrets, key, plan)
 
-    d_spec = "d" if "d" in mesh.axis_names else None
-    mapped = jax.shard_map(
-        local_step,
-        mesh=mesh,
-        in_specs=(P("p", d_spec), P()),
-        out_specs=P(None, d_spec, None),
-        check_vma=False,
-    )
-    return jax.jit(mapped)
+    return over_mesh(bounded, plan, mesh)

@@ -164,11 +164,11 @@ def _strided(jaxpr) -> list:
     ]
 
 
-def pair_planes_by_path() -> dict:
+def compactions_by_path() -> dict:
     return {
         dict(labels)["path"]: value
         for (name, labels), value in telemetry.get_registry().snapshot()["counters"].items()
-        if name == "sda_crypto_chacha_pair_planes_total"
+        if name == "sda_crypto_chacha_compactions_total"
     }
 
 
@@ -178,7 +178,7 @@ def test_the_compaction_takes_the_pair_planes_and_no_flag_tensor_is_traced(fresh
     takes them as its one operand and runs the zone test and the counts
     itself: no strided read and no ``(P, window)`` flag tensor outside the
     kernels. The XLA path keeps both, which shows the test can see them; the
-    counter names each path once a trace."""
+    compaction counter names each path once a trace."""
     import jax
     import jax.numpy as jnp
 
@@ -201,11 +201,11 @@ def test_the_compaction_takes_the_pair_planes_and_no_flag_tensor_is_traced(fresh
     assert _strided(kernel_path) == []
     twin_avals, _ = _all_avals(twin)
     assert ((rows, window), "bool", False) in twin_avals and "gather" in _strided(twin)
-    assert pair_planes_by_path() == {"kernel": rows, "slices": rows}
+    assert compactions_by_path() == {"interpret": rows, "jnp": rows}
     fold.lower(seeds, dim, P61, "interpret")  # the same trace: counted once
     # ``auto`` is what this process's backend runs: the slices off a TPU
     fold.lower(jnp.zeros((3, 4), jnp.uint32), dim, P61, "auto")
-    assert pair_planes_by_path() == {"kernel": rows, "slices": rows + 3}
+    assert compactions_by_path() == {"interpret": rows, "jnp": rows + 3}
 
 
 def test_blocks_are_counted_by_path_once_a_trace(fresh_telemetry):

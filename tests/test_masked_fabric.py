@@ -248,45 +248,91 @@ def _seeds(rows, seed=43):
     return rng.integers(0, 1 << 32, size=(rows, 4), dtype=np.uint64).astype(np.uint32)
 
 
-@pytest.mark.parametrize("chunk", [4, 3, 1], ids=["2-folds", "3-folds", "12-folds"])
-def test_the_folds_go_back_to_back_and_the_counts_are_read_once(field, chunk, monkeypatch):
-    """Every fold is dispatched before the host waits on the device once, for
-    all folds' counts; the total is the plain reference's bit for bit."""
+def _mesh(chips):
+    """``None`` for one chip, else a mesh of ``chips`` over ``p``."""
+    from sda_tpu.parallel import make_mesh
+
+    return None if chips == 1 else make_mesh(p_size=chips, d_size=1)
+
+
+def _patch_fold(monkeypatch, mesh, wrap):
+    """The combine's fold, on one chip or over ``mesh``, as ``wrap(fold)``."""
+    if mesh is None:
+        fold = chacha_pallas.fold_chunk_jit()
+        monkeypatch.setattr(chacha_pallas, "fold_chunk_jit", lambda: wrap(fold))
+    else:
+        fold = chacha_pallas.fold_chunk_mesh_jit(mesh)
+        monkeypatch.setattr(chacha_pallas, "fold_chunk_mesh_jit", lambda mesh: wrap(fold))
+
+
+@pytest.mark.parametrize(
+    "chips,rows,chunk,folds",
+    [(1, 12, 4, 3), (1, 12, 3, 4), (1, 12, 1, 12), (4, 24, 2, 3)],
+    ids=["2-folds", "3-folds", "12-folds", "3-folds-on-4-chips"],
+)
+def test_the_folds_go_back_to_back_and_the_counts_are_read_once(
+    field, chips, rows, chunk, folds, monkeypatch
+):
+    """Every fold, on one chip or sharded over four, is dispatched before the
+    host waits on the device once, for all folds' counts; the total is the
+    plain reference's bit for bit (over a mesh on every chip)."""
     p = field[0].prime_modulus
-    seeds = _seeds(12)
-    fold = chacha_pallas.fold_chunk_jit()
+    seeds, mesh = _seeds(rows), _mesh(chips)
     syncs_at_dispatch = []
 
-    def spy(batch, dim, modulus, backend):
-        syncs_at_dispatch.append(ticks("sda_crypto_chacha_combine_syncs_total"))
-        return fold(batch, dim, modulus, backend)
+    def spying(fold):
+        def spy(batch, dim, modulus, backend):
+            syncs_at_dispatch.append(ticks("sda_crypto_chacha_combine_syncs_total"))
+            return fold(batch, dim, modulus, backend)
 
-    monkeypatch.setattr(chacha_pallas, "fold_chunk_jit", lambda: spy)
-    before = ticks("sda_crypto_chacha_combine_syncs_total")
-    total = chacha_pallas.combine_masks_device(seeds, DIM, p, chunk=chunk)
-    assert syncs_at_dispatch == [before] * (12 // chunk)
-    assert ticks("sda_crypto_chacha_combine_syncs_total") == before + 1
-    assert np.array_equal(total, reference_chacha.mask_sum(seeds, DIM, p))
+        return spy
+
+    _patch_fold(monkeypatch, mesh, spying)
+    names = (
+        "sda_crypto_chacha_combine_syncs_total", "sda_crypto_chacha_folds_total",
+        "sda_crypto_chacha_fold_chips_total",
+    )
+    before = [ticks(name) for name in names]
+    total = chacha_pallas.combine_masks_device(seeds, DIM, p, chunk=chunk, mesh=mesh)
+    assert syncs_at_dispatch == [before[0]] * folds
+    assert [ticks(name) - then for name, then in zip(names, before)] == [1, folds, folds * chips]
+    assert mesh is None or total.sharding.is_fully_replicated
+    assert np.array_equal(np.asarray(total), reference_chacha.mask_sum(seeds, DIM, p))
 
 
-def test_a_short_window_in_a_middle_fold_is_recovered_alone(field, monkeypatch):
-    """The second of three folds says two of its rows came short, and its
-    device partial is garbage: that fold alone is host-expanded and takes its
-    place in the total, which is the host path's; the counter ticks by the
-    short rows, and the one read stays one."""
+@pytest.mark.parametrize(
+    "chips,rows,seed,chunk,short_rows,recovered_rows,syncs",
+    [(1, 9, 43, 3, [0, 1], slice(3, 6), 1), (4, 24, 44, 2, [5], slice(12, 14), 2)],
+    ids=["one-chip", "chip-2-of-4"],
+)
+def test_a_short_window_in_a_middle_fold_is_recovered_alone(
+    field, chips, rows, seed, chunk, short_rows, recovered_rows, syncs, monkeypatch
+):
+    """The second of three folds says rows of one chip came short (on four
+    chips, chip 2, which holds rows 4 and 5 of the fold's 8), and that chip's
+    partial, and so the fold's met partial, is garbage: only that chip's own
+    rows of that fold are host-expanded, the fold's met partial is made again
+    from the chips' partials, and the total is the host path's. The counter
+    ticks by the short rows; the host waits once for the counts, and over a
+    mesh once more for that fold's partials and seeds."""
     import jax.numpy as jnp
 
     p = field[0].prime_modulus
-    seeds = _seeds(9)
-    fold = chacha_pallas.fold_chunk_jit()
+    seeds, mesh = _seeds(rows, seed=seed), _mesh(chips)
     calls = []
 
-    def short_in_the_middle(batch, dim, modulus, backend):
-        part, counts = fold(batch, dim, modulus, backend)
-        calls.append(batch)
-        if len(calls) == 2:
-            return jnp.zeros_like(part), counts.at[:2].set(dim - 1)
-        return part, counts
+    def short_in_the_middle(fold):
+        def faulty(batch, dim, modulus, backend):
+            # one chip: (part, counts); a mesh: (met, every chip's part, counts)
+            met, *parts, counts = fold(batch, dim, modulus, backend)
+            calls.append(batch)
+            if len(calls) != 2:
+                return (met, *parts, counts)
+            chip = short_rows[0] // (batch.shape[0] // chips)
+            counts = counts.at[jnp.asarray(short_rows)].set(dim - 1)
+            return (jnp.zeros_like(met), *[part.at[chip].set(0) for part in parts], counts)
+
+        return faulty
 
     host_fold, recovered = chacha_pallas._host_fold, []
 
@@ -294,14 +340,14 @@ def test_a_short_window_in_a_middle_fold_is_recovered_alone(field, monkeypatch):
         recovered.append(np.array(batch))
         return host_fold(batch, dim, modulus)
 
-    monkeypatch.setattr(chacha_pallas, "fold_chunk_jit", lambda: short_in_the_middle)
+    _patch_fold(monkeypatch, mesh, short_in_the_middle)
     monkeypatch.setattr(chacha_pallas, "_host_fold", spy_host_fold)
     short_before = ticks("sda_mask_slack_exhausted_total", side="recipient")
     syncs_before = ticks("sda_crypto_chacha_combine_syncs_total")
-    total = chacha_pallas.combine_masks_device(seeds, DIM, p, chunk=3)
-    assert len(calls) == 3 and [r.tolist() for r in recovered] == [seeds[3:6].tolist()]
-    assert ticks("sda_mask_slack_exhausted_total", side="recipient") == short_before + 2
-    assert ticks("sda_crypto_chacha_combine_syncs_total") == syncs_before + 1
+    total = np.asarray(chacha_pallas.combine_masks_device(seeds, DIM, p, chunk=chunk, mesh=mesh))
+    assert len(calls) == 3 and [r.tolist() for r in recovered] == [seeds[recovered_rows].tolist()]
+    assert ticks("sda_mask_slack_exhausted_total", side="recipient") == short_before + len(short_rows)
+    assert ticks("sda_crypto_chacha_combine_syncs_total") == syncs_before + syncs
     assert np.array_equal(total, host_fold(seeds, DIM, p))
     assert np.array_equal(total, reference_chacha.mask_sum(seeds, DIM, p))
 

@@ -307,12 +307,12 @@ COMPACTION_CASES = {
 }
 
 
-def compaction_ticks(path, name="sda_crypto_chacha_compactions_total"):
+def compaction_ticks(path):
     from sda_tpu import telemetry
 
     return sum(
         c["value"] for c in telemetry.snapshot()["counters"]
-        if c["name"] == name and c["labels"].get("path") == path
+        if c["name"] == "sda_crypto_chacha_compactions_total" and c["labels"].get("path") == path
     )
 
 
@@ -373,11 +373,11 @@ def test_compaction_kernel_is_the_stages_bit_for_bit(case, monkeypatch):
     # the whole expansion, by the path the shape takes
     path = "interpret" if cp._compact_fits(window, dim) else "jnp"
     assert (path == "jnp") == ("takes-the-twin" in case)
-    planes = "slices" if path == "jnp" else "kernel"
-    before = compaction_ticks(path), compaction_ticks(planes, "sda_crypto_chacha_pair_planes_total")
+    other = "interpret" if path == "jnp" else "jnp"
+    before = compaction_ticks(path), compaction_ticks(other)
     masks, got_counts = cp.expand_seeds_counts(jnp.asarray(seeds), dim, modulus, "interpret")
     assert compaction_ticks(path) == before[0] + rows
-    assert compaction_ticks(planes, "sda_crypto_chacha_pair_planes_total") == before[1] + rows
+    assert compaction_ticks(other) == before[1]  # the path ran, and no other
     twin_masks, twin_counts = cp.expand_seeds_counts(jnp.asarray(seeds), dim, modulus, "jnp")
     assert np.array_equal(masks, twin_masks) and masks.dtype == jnp.int64
     assert np.array_equal(got_counts, counts) and np.array_equal(twin_counts, counts)

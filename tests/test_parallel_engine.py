@@ -270,6 +270,17 @@ def test_sharded_sum_first_rejects_nondivisible_dim(jax_mods):
         sharded_value_limb_sums(plan, mesh)
 
 
+def test_sharded_limb_accumulators_reject_nondivisible_dim(jax_mods):
+    """The limb fabric holds the rule the sum-first fabric and the round
+    driver hold: its step is the same row-sharded wrapper."""
+    from sda_tpu.parallel import TpuAggregator, make_mesh
+
+    mesh = make_mesh(p_size=4, d_size=2)
+    with pytest.raises(ValueError, match="divide over input_size"):
+        TpuAggregator(PACKED, 26, mesh=mesh).sharded_limb_accumulators()  # 26 % (3*2) != 0
+    TpuAggregator(PACKED, 24, mesh=mesh).sharded_limb_accumulators()
+
+
 def test_sharded_sum_first_wide_modulus(jax_mods):
     """Sum-first on the mesh at 61-bit width: the two-limb exact path
     (no int64 overflow, no mod on device) through the same psum fabric."""

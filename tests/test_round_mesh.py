@@ -2,6 +2,7 @@
 mesh=)``) on the CPU's virtual devices: the sharded masked round reveals what
 the one-chip masked round reveals, every chip draws seeds of its own (and a
 step body that leaves the mesh position out of its key is caught), the
+driver's unmasked step is the sharded fabrics' program text for text, the
 recipient's sharded combine is ``combine_masks_device`` bit for bit, its
 partials meet without leaving int64's range at eight chips, both kernels run
 inside the ``shard_map`` body (on the interpreter), and what the mesh round
@@ -164,6 +165,40 @@ def test_the_unmasked_round_over_a_mesh_reveals_the_plain_sum(p_size, d_size):
     assert np.array_equal(got, column_sums(rows))
 
 
+def step_around(sharded, accumulate, modulus):
+    """The chunk step a caller writes around a sharded fabric, as the
+    benchmark's sum-first cell over a mesh does."""
+
+    def step(acc, chunk, key, i):
+        acc = acc + sharded(chunk, jax.random.fold_in(key, i))
+        if accumulate == "sum_mod_p":
+            acc = jax.lax.rem(acc, jnp.int64(modulus))
+        return acc
+
+    return jax.jit(step)
+
+
+@pytest.mark.parametrize("p_size,d_size", [(2, 2), (4, 1)])
+@pytest.mark.parametrize("fabric", ["sum-first", "limb"])
+def test_the_drivers_unmasked_mesh_step_is_the_sharded_fabrics_program(fabric, p_size, d_size):
+    """One row-sharded wrapper builds the three: the driver's unmasked step
+    over a mesh lowers to the text of the same step around
+    ``sumfirst.sharded_value_limb_sums``, and for the limb entry around
+    ``TpuAggregator.sharded_limb_accumulators``."""
+    mesh = make_mesh(p_size=p_size, d_size=d_size)
+    modulus = scheme().prime_modulus
+    if fabric == "sum-first":
+        plan = engine.make_plan(scheme(), DIM)
+        entry, sharded = ENTRY, sumfirst.sharded_value_limb_sums(plan, mesh)
+    else:
+        aggregator = engine.TpuAggregator(scheme(), DIM, mesh=mesh)
+        entry, sharded = engine.share_combine_limb, aggregator.sharded_limb_accumulators()
+    driver = fold_round(scheme(), DIM, entry, CHUNK, mesh=mesh)
+    args = (driver.zero_acc(), chunks_over(rows_of(), mesh)[0], jax.random.key(0), np.int32(0))
+    want = step_around(sharded, driver.accumulate, modulus).lower(*args).as_text()
+    assert driver.step.lower(*args).as_text() == want
+
+
 def seventeen_seeds():
     seeds = np.random.default_rng(17).integers(0, 1 << 32, size=(17, 4), dtype=np.uint64)
     return seeds.astype(np.uint32)
@@ -190,81 +225,6 @@ def test_the_sharded_combine_is_the_one_chip_combine_bit_for_bit(chips):
         "sda_crypto_chacha_expands_total", "sda_crypto_chacha_folds_total",
         "sda_crypto_chacha_fold_chips_total",
     ) == [len(seeds), 2, 2 * chips]
-
-
-def test_the_sharded_folds_go_back_to_back_and_the_counts_are_read_once(monkeypatch):
-    """Three sharded folds on four chips, every one dispatched before the
-    host waits on the chips once, for all folds' counts: the plain
-    reference's mask sum, bit for bit."""
-    from benchmark import reference_chacha  # conftest puts the checkout on the path
-
-    p = scheme().prime_modulus
-    mesh = make_mesh(p_size=4, d_size=1)
-    seeds = np.random.default_rng(43).integers(0, 1 << 32, size=(24, 4), dtype=np.uint64)
-    seeds = seeds.astype(np.uint32)
-    fold = chacha_pallas.fold_chunk_mesh_jit(mesh)
-    syncs_at_dispatch = []
-
-    def spy(batch, dim, modulus, backend):
-        syncs_at_dispatch.append(counters("sda_crypto_chacha_combine_syncs_total")[0])
-        return fold(batch, dim, modulus, backend)
-
-    monkeypatch.setattr(chacha_pallas, "fold_chunk_mesh_jit", lambda mesh: spy)
-    telemetry.reset()
-    got = chacha_pallas.combine_masks_device(seeds, DIM, p, chunk=2, mesh=mesh)
-    assert syncs_at_dispatch == [0, 0, 0]
-    assert got.sharding.is_fully_replicated
-    assert np.array_equal(np.asarray(got), reference_chacha.mask_sum(seeds, DIM, p))
-    assert counters(
-        "sda_crypto_chacha_combine_syncs_total", "sda_crypto_chacha_folds_total",
-        "sda_crypto_chacha_fold_chips_total",
-    ) == [1, 3, 12]
-
-
-def test_a_short_window_on_one_chip_of_a_middle_fold_recovers_that_chip_alone(monkeypatch):
-    """The second of three sharded folds says chip 2 had a row come short,
-    and that chip's partial (and so the met sum) is garbage: only chip 2's own
-    rows of that fold are host-expanded, the fold's met sum is made again from
-    the chips' partials, and the total is the host path's. The counter ticks
-    by the short row; the host waits twice: the counts, then that fold's
-    partials and seeds."""
-    from benchmark import reference_chacha  # conftest puts the checkout on the path
-
-    p = scheme().prime_modulus
-    mesh = make_mesh(p_size=4, d_size=1)
-    seeds = np.random.default_rng(44).integers(0, 1 << 32, size=(24, 4), dtype=np.uint64)
-    seeds = seeds.astype(np.uint32)
-    fold = chacha_pallas.fold_chunk_mesh_jit(mesh)
-    calls = []
-
-    def short_on_chip_2_of_the_middle_fold(batch, dim, modulus, backend):
-        met, parts, counts = fold(batch, dim, modulus, backend)
-        calls.append(batch)
-        if len(calls) == 2:  # chip 2 holds rows 4 and 5 of the fold's 8
-            return jnp.zeros_like(met), parts.at[2].set(0), counts.at[5].set(dim - 1)
-        return met, parts, counts
-
-    host_fold, recovered = chacha_pallas._host_fold, []
-
-    def spy_host_fold(batch, dim, modulus):
-        recovered.append(np.array(batch))
-        return host_fold(batch, dim, modulus)
-
-    monkeypatch.setattr(
-        chacha_pallas, "fold_chunk_mesh_jit", lambda mesh: short_on_chip_2_of_the_middle_fold
-    )
-    monkeypatch.setattr(chacha_pallas, "_host_fold", spy_host_fold)
-    telemetry.reset()
-    got = chacha_pallas.combine_masks_device(seeds, DIM, p, chunk=2, mesh=mesh)
-    assert len(calls) == 3 and [r.tolist() for r in recovered] == [seeds[12:14].tolist()]
-    assert np.array_equal(np.asarray(got), host_fold(seeds, DIM, p))
-    assert np.array_equal(np.asarray(got), reference_chacha.mask_sum(seeds, DIM, p))
-    short = [
-        c["value"] for c in telemetry.snapshot(0)["counters"]
-        if c["name"] == "sda_mask_slack_exhausted_total" and c["labels"] == {"side": "recipient"}
-    ]
-    assert short == [1]
-    assert counters("sda_crypto_chacha_combine_syncs_total") == [2]
 
 
 def test_the_partials_meet_without_leaving_int64_at_eight_chips(monkeypatch):
