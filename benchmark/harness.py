@@ -16,7 +16,8 @@ name, and imports nothing of the program:
   describes: it builds the scheme and the step, makes the input and the
   reference, and runs one round from key to comparison under its own spans;
 * a per-layer metric is one module in ``benchmark/layers/``, found by listing
-  the directory.
+  the directory; it says what it reads (``reads_spans``, ``reads``), so that
+  where a run lacks that the metric is known to be silent without a chip.
 
 What is the harness's own, common to every round: the window with its
 failure rules, the spans' clock, the count of compiles inside the window,
@@ -56,6 +57,15 @@ TRACE_WINDOW_SECONDS = 12.0
 
 #: a window that fails this many rounds in a row stops
 MAX_CONSECUTIVE_FAILURES = 3
+
+#: what a layer file may say it reads beyond the round's spans (``reads``: a
+#: tuple of ``"<kind>:<name>"``), by kind: ``scope`` the seconds under one of the
+#: program's device scopes or paths, or ``unscoped`` (``LayerContext.scopes``);
+#: ``host_span`` one of the program's own host spans (``LayerContext.host_spans``);
+#: ``kernel`` the device operations of that name, numbered (``chacha_rounds.1``),
+#: in the trace; ``ring`` the program's span records of that prefix, which the
+#: file takes itself before the window (``before_window``)
+READS = ("scope", "host_span", "kernel", "ring")
 
 
 class HarnessError(RuntimeError):
@@ -159,7 +169,13 @@ def load_layers(root) -> dict:
     metric's name. Found by listing the directory: a new metric is a new
     file. Which cells report a metric the manifest alone says (``workloads``);
     a layer file says which of the round's spans it reads (``reads_spans``),
-    so that a cell whose round opens no such span is found without a chip."""
+    so that a cell whose round opens no such span is found without a chip, and
+    what else it reads (``reads``, of the kinds in :data:`READS`; none if it
+    does not say), so that a recorded run that lacks it is known to leave the
+    metric silent. A file may have ``before_window(since)``: called in a
+    traced run once set-up is over, before the window opens, with
+    ``time.perf_counter()`` at the process's start; what it returns reaches
+    ``reduce`` as ``LayerContext.before_window[name]``."""
     layers = {}
     directory = pathlib.Path(root) / "benchmark" / "layers"
     for path in sorted(directory.glob("*.py")):
@@ -171,6 +187,10 @@ def load_layers(root) -> dict:
         for attr in ("name", "unit", "layer", "moves", "reads_spans", "reduce"):
             if not hasattr(module, attr):
                 raise HarnessError(f"{path}: a layer metric needs `{attr}`")
+        for read in getattr(module, "reads", ()):
+            kind, _, what = read.partition(":")
+            if kind not in READS or not what:
+                raise HarnessError(f"{path}: reads {read!r}, not a kind of {READS} with a name")
         if module.name in layers:
             raise HarnessError(f"{path}: metric {module.name!r} defined twice")
         layers[module.name] = module
@@ -305,9 +325,18 @@ def run_cell(
 
     spans = Spans()
     window = min(seconds, TRACE_WINDOW_SECONDS) if trace else seconds
+    # a round whose traced rounds leave more to the profiler than the host
+    # holds caps them (``TRACE_WINDOW_ROUNDS``: ``benchmark.rounds``)
+    most_rounds = getattr(round_module, "TRACE_WINDOW_ROUNDS", None) if trace else None
     trace_dir = out_dir / f"trace-{tag}"
+    before_window = {}
     if trace:
         import jax
+
+        for metric in cell.per_layer:
+            hook = getattr(layers[metric["name"]], "before_window", None)
+            if hook is not None:
+                before_window[metric["name"]] = hook(process_start)
 
         shutil.rmtree(trace_dir, ignore_errors=True)
         options = jax.profiler.ProfileOptions()
@@ -339,7 +368,8 @@ def run_cell(
                 failed += 1
                 consecutive += 1
             now = time.perf_counter()
-            if now - first_start >= window or consecutive >= MAX_CONSECUTIVE_FAILURES:
+            if (now - first_start >= window or consecutive >= MAX_CONSECUTIVE_FAILURES
+                    or attempted == most_rounds):
                 break
         last_end = now
     finally:
@@ -446,6 +476,7 @@ def run_cell(
             chunk_step_modules=(
                 frozenset(name.split("/", 1)[0] for name in chunk_step) if chunk_step else None
             ),
+            before_window=before_window,
             **told,
         )
         for metric in cell.per_layer:
@@ -517,6 +548,9 @@ class LayerContext:
     #: the module(s) of the first of the round's ``steps``, from its compiled
     #: text; ``None`` with no device plane
     chunk_step_modules: frozenset | None = None
+    #: what each listed metric's ``before_window`` returned, by the metric's
+    #: name (``load_layers``); a metric with no such hook is not in it
+    before_window: dict = dataclasses.field(default_factory=dict)
 
 
 def _device_line(session) -> dict:

@@ -11,6 +11,7 @@ unit = "s"
 layer = "fabric engines"
 moves = "round_s"
 reads_spans = ()
+reads = ("scope:fabric.input",)
 
 
 def reduce(spans, trace, cell):

@@ -12,6 +12,7 @@ unit = "s"
 layer = "fabric engines"
 moves = "round_s"
 reads_spans = ()
+reads = ("scope:fabric.rand",)
 
 
 def reduce(spans, trace, cell):

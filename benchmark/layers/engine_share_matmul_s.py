@@ -10,6 +10,7 @@ unit = "s"
 layer = "fabric engines"
 moves = "round_s"
 reads_spans = ()
+reads = ("scope:fabric.share_matmul",)
 
 
 def reduce(spans, trace, cell):

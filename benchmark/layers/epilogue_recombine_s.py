@@ -8,6 +8,7 @@ unit = "s"
 layer = "host epilogue and reconstruct"
 moves = "round_s"
 reads_spans = ()
+reads = ("host_span:fabric.epilogue.recombine",)
 
 
 def reduce(spans, trace, cell):

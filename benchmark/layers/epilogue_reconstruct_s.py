@@ -7,6 +7,7 @@ unit = "s"
 layer = "host epilogue and reconstruct"
 moves = "round_s"
 reads_spans = ()
+reads = ("host_span:fabric.reconstruct",)
 
 
 def reduce(spans, trace, cell):

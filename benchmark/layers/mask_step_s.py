@@ -11,6 +11,7 @@ unit = "s"
 layer = "mask stage"
 moves = "round_s"
 reads_spans = ()
+reads = ("scope:fabric.mask",)
 
 
 def reduce(spans, trace, cell):

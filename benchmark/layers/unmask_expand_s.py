@@ -9,6 +9,7 @@ unit = "s"
 layer = "recipient unmask"
 moves = "round_s"
 reads_spans = ()
+reads = ("scope:fabric.unmask/expand",)
 
 
 def reduce(spans, trace, cell):

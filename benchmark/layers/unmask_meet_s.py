@@ -13,6 +13,7 @@ moves = "round_s"
 reads_spans = ()
 
 SCOPE = "fabric.unmask/meet"
+reads = (f"scope:{SCOPE}",)
 
 
 def reduce(spans, trace, cell):

@@ -73,6 +73,13 @@ What a round module gives, and what :mod:`benchmark.harness` takes from it:
     ``(jitted, example arguments)`` of the program that makes one chunk of
     the resident input in set-up, for the compile rehearsal: set-up has to
     fit the chip beside the input it has made so far.
+
+``TRACE_WINDOW_ROUNDS`` (optional)
+    The most rounds a traced window runs, where fewer than the traced window's
+    seconds hold would leave the profiler more events than the host's memory
+    holds (a round whose rows cross the host link: every chunk's host relayout
+    is an event). A round module without it runs the traced window for its
+    seconds alone (``harness.TRACE_WINDOW_SECONDS``).
 """
 
 #: the spans every round opens, under these names: the metrics that every

@@ -64,6 +64,12 @@ TRAFFIC_KEYS = (
     "block_rows", "in_flight", "fresh_rows_per_block",
 )
 
+#: a traced round leaves the profiler about 2.75 GB of host memory (8.8e6
+#: events of the chunks' host relayout) and reading the trace 1.6 times that
+#: again: on a one-chip v5e host of 40 GiB three traced rounds peaked at
+#: 45.1 GB of RSS and four were killed, so two
+TRACE_WINDOW_ROUNDS = 2
+
 FED_BYTES = "sda_fabric_fed_bytes_total"
 IN_FLIGHT_MAX = "sda_fabric_feed_in_flight_max"
 

@@ -59,6 +59,9 @@ span_names = ("dispatch", "fold", "fetch", "epilogue", "unmask", "check")
 #: what the round reads of its traffic file beyond the generator's fields
 TRAFFIC_KEYS = hostfed_fold.TRAFFIC_KEYS + ("masking_scheme", "recipient_fold", "recipient_chunk")
 
+#: its rows cross the host link as ``hostfed_fold``'s do
+TRACE_WINDOW_ROUNDS = hostfed_fold.TRACE_WINDOW_ROUNDS
+
 FED_SEEDS = "sda_fabric_fed_seeds_total"
 
 

@@ -160,6 +160,15 @@ class Reduced:
     def max_busy_seconds(self, modules=None) -> float:
         return max(self.busy_seconds(c, modules) for c in self.chips)
 
+    def max_kernel_seconds(self, kernel: str) -> float:
+        """Device seconds of the operations named ``kernel`` in any program (a
+        Pallas kernel's ``name``, which its operations carry numbered:
+        ``chacha_rounds.1``), on the chip with most."""
+        return max(
+            sum(e - s for n, s, e in self.ops[chip] if n.rpartition("/")[2].startswith(kernel))
+            for chip in self.chips
+        ) * NS
+
     def idlest_chip(self):
         return min(self.chips, key=self.busy_seconds)
 

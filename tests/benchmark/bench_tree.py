@@ -85,6 +85,22 @@ def tiny_tree(dest: pathlib.Path) -> pathlib.Path:
     return root
 
 
+#: the set-up metrics (``benchmark/layers/setup_*.py``): they read the
+#: program's records of what JAX traced, lowered, compiled or loaded since the
+#: run began, and in a test's process a run may find every program made by an
+#: earlier test, and have none to read
+SETUP_METRICS = frozenset({"setup.trace_s", "setup.lower_s", "setup.load_s", "setup.compile_s"})
+
+
+def reported(line) -> set:
+    """The metrics a traced CPU run's line reports, the set-up metrics left
+    out: those are all there or none, and none is under 0."""
+    names = set(line["metrics"])
+    assert names & SETUP_METRICS in (set(), SETUP_METRICS), names
+    assert all(line["metrics"][name]["value"] >= 0 for name in names & SETUP_METRICS)
+    return names - SETUP_METRICS
+
+
 #: a metric with a list of cells whose code the toy cell shares with them
 TOY_SHARED_METRIC = "epilogue.recombine_s"
 

@@ -10,6 +10,7 @@ constants of the one round the first cells have.
 from __future__ import annotations
 
 import json
+import numbers
 import pathlib
 import re
 
@@ -114,6 +115,8 @@ def check_cell(root, name: str) -> None:
             )
     for span in rounds_interface.CELL_WIDE_SPANS:
         assert span in opened, f"round {module.__name__} opens no span {span!r}"
+    cap = getattr(module, "TRACE_WINDOW_ROUNDS", None)
+    assert cap is None or (isinstance(cap, int) and cap >= 1), cap
     # what the round reads of the traffic file is there, and what names the
     # program or an adapter by dotted path resolves
     for key in getattr(module, "TRAFFIC_KEYS", ()):
@@ -128,6 +131,68 @@ def check_cell(root, name: str) -> None:
             if key != "accumulate":
                 assert callable(traffic_mod.resolve(params[key])), key
         assert loaded.traffic.chunk == loaded.config["chunk"]
+
+
+# ---------------------------------------------------------------------------
+# Layer files against a recorded run: silent exactly where their input is not
+# ---------------------------------------------------------------------------
+
+
+def recorded_inputs(trace, report=None, ring=()):
+    """``present(kind, name)`` for one recorded run: whether it holds what a
+    layer file names in ``reads`` (``harness.READS``). ``trace`` is the reduced
+    trace (or ``None``), ``report`` the scope report ``scopes.split`` makes of
+    it (or ``None``: the context a layer file is handed then has no scopes and
+    no host spans), ``ring`` the program's records a ``before_window`` took."""
+
+    def present(kind, name):
+        if kind == "scope":
+            if report is None or report["absent"]:
+                return False
+            return any(
+                name in chip["by_scope"] or name in chip["by_path"]
+                or (name == scopes.UNSCOPED and chip[scopes.UNSCOPED]["s"] > 0)
+                for chip in report["chips"].values()
+            )
+        if kind == "host_span":
+            return report is not None and name in report["host_spans_s"]
+        if kind == "kernel":
+            return trace is not None and trace.max_kernel_seconds(name) > 0
+        if kind == "ring":
+            return any(r["name"].startswith(name) for r in ring)
+        raise AssertionError(f"a layer file reads {kind!r}, which the harness hands out nowhere")
+
+    return present
+
+
+def finds_what_it_reads(module, spans, present) -> bool:
+    """Whether a layer file finds, in a run, all it says it reads: every span
+    it names (``reads_spans``) among ``spans``, the names of the spans the run
+    opened, and every input it names (``reads``) by ``present``."""
+    return set(module.reads_spans) <= set(spans) and all(
+        present(*read.split(":", 1)) for read in getattr(module, "reads", ())
+    )
+
+
+def check_layers_on_recording(root, spans, trace, context, present) -> dict:
+    """Every layer file under ``root`` on one recorded run (the harness's
+    ``spans``, the reduced ``trace``, the ``context`` a layer file is handed,
+    ``present`` as :func:`recorded_inputs` makes it): silent exactly where it
+    does not find what it says it reads, and a positive number everywhere
+    else. What the silent ones are is worked out from the files, so a new file
+    that reads what a recording lacks passes here with no test edited.
+    Returns the numbers, by metric."""
+    opened = {s.name for s in spans}
+    values = {}
+    for name, module in harness.load_layers(root).items():
+        value = module.reduce(spans, trace, context)
+        if finds_what_it_reads(module, opened, present):
+            assert value is not None, f"{name} finds what it reads and gives nothing"
+            assert isinstance(value, numbers.Real) and value > 0, f"{name} gives {value!r}"
+            values[name] = value
+        else:
+            assert value is None, f"{name} gives {value!r} where what it reads is not"
+    return values
 
 
 # ---------------------------------------------------------------------------

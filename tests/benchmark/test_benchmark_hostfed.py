@@ -201,10 +201,28 @@ def test_the_cohort_changes_between_rounds_and_the_reference_follows(tree):
 def test_a_traced_hostfed_run_reports_the_span_metrics_and_invents_no_device_number(tree):
     line = run(tree, TINY, trace=True)
     assert line["correct"] is True
-    assert set(line["metrics"]) == {"engine.fold_s", "epilogue.s", "elems_per_s"}
+    assert bench_tree.reported(line) == {"engine.fold_s", "epilogue.s", "elems_per_s"}
     assert SHARED_METRICS <= {m["name"] for m in harness.load_cell(tree, TINY).per_layer}
     listed = {m["name"] for m in harness.load_cell(REPO, CELL).per_layer}
     assert SHARED_METRICS <= listed and "engine.layout_s" not in listed
+
+
+@pytest.mark.parametrize("round_name", ["hostfed_fold", "hostfed_masked_fold"])
+def test_a_traced_window_of_a_fed_round_stops_after_the_rounds_its_round_allows(tree, round_name):
+    """A traced fed round leaves the profiler gigabytes of host events: its
+    round caps the traced window by rounds, whatever its seconds; an untraced
+    window runs on, and a round without the cap runs its traced seconds."""
+    import importlib
+
+    from benchmark.rounds import packed_fold
+
+    module = importlib.import_module(f"benchmark.rounds.{round_name}")
+    assert module.TRACE_WINDOW_ROUNDS == 2
+    assert not hasattr(packed_fold, "TRACE_WINDOW_ROUNDS")
+    if round_name == "hostfed_fold":
+        traced = run(tree, TINY, trace=True, seconds=5.0)
+        assert traced["correct"] is True and traced["attempted"] == 2
+        assert run(tree, TINY, seconds=1.0)["attempted"] > 2
 
 
 def test_a_feed_that_keeps_last_rounds_blocks_is_caught_by_the_aggregate(tree):
@@ -296,7 +314,7 @@ def test_a_traced_cpu_run_reports_the_feeds_rate_from_the_rounds_spans(tree_with
     report in a trace with no device plane, and say nothing."""
     line = run(tree_with_layers, TINY, trace=True)
     assert line["correct"] is True
-    assert set(line["metrics"]) == {"engine.fold_s", "epilogue.s", "elems_per_s", "feed.gb_per_s"}
+    assert bench_tree.reported(line) == {"engine.fold_s", "epilogue.s", "elems_per_s", "feed.gb_per_s"}
     rate, fold = line["metrics"]["feed.gb_per_s"], line["metrics"]["engine.fold_s"]
     assert rate["unit"] == "GB/s"
     assert rate["value"] == pytest.approx(ROWS * DIM * 8 / fold["value"] / 1e9)

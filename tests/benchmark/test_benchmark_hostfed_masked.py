@@ -2,12 +2,11 @@
 tiny size on the CPU, through the harness as the chip runs it: the cell's
 comparisons as on the chip, the program's masked driver bound by dotted path,
 feeds and steps that break a guarantee caught each by its own comparison, the
-same clerk sums as the resident masked round, and the cell's three layer
+same clerk sums as the resident masked round, and the cell's two feed
 metrics, which wait in ``hostfed_masked_layers/`` for the ``benchmark`` PR that
-may edit the one test that lists which metrics a trace without the program's
-names leaves silent (``test_benchmark_trace_reduce.py``; PERF.md section 7).
-The manifest's parametrised checks and the compile rehearsal hold
-``c5-hostfed-masked`` itself, by its name."""
+evaluates a metric that reads no trace in an untraced run (a traced run's link
+is the profiler's; PERF.md section 7). The manifest's parametrised checks and
+the compile rehearsal hold ``c5-hostfed-masked`` itself, by its name."""
 
 import json
 import pathlib
@@ -36,6 +35,11 @@ COMPARED = [
 SHARED_METRICS = {
     "engine.input_s", "engine.rand_s", "epilogue.recombine_s", "epilogue.share_matmul_s",
     "epilogue.reconstruct_s",
+}
+#: what the cell shares with the other masked cells, not with ``c5-hostfed``
+MASKED_METRICS = {
+    "mask.step_s", "unmask.stage_s", "unmask.expand_s", "chacha_rounds_roofline",
+    "chacha_compact_roofline",
 }
 SPANS = ["dispatch", "fold", "fetch", "epilogue", "unmask", "check"]
 
@@ -251,13 +255,17 @@ def test_the_cohort_changes_between_rounds_and_every_round_hands_on_its_own_seed
 def test_a_traced_run_reports_the_span_metrics_and_invents_no_device_number(tree):
     line = run(tree, TINY, trace=True)
     assert line["correct"] is True
-    assert set(line["metrics"]) == {"engine.fold_s", "epilogue.s", "elems_per_s"}
-    assert SHARED_METRICS <= {m["name"] for m in harness.load_cell(tree, TINY).per_layer}
-    # exactly the lists that held both parents' cells gained the new one
+    assert bench_tree.reported(line) == {"engine.fold_s", "epilogue.s", "elems_per_s", "unmask.stage_s"}
+    assert SHARED_METRICS | MASKED_METRICS <= {
+        m["name"] for m in harness.load_cell(tree, TINY).per_layer
+    }
+    # exactly the lists that held both parents' cells gained the new one, and
+    # those of the masked cells' own stages
     for metric in cell_checks.manifest_of(REPO)["per_layer"]:
-        cells = metric.get("workloads", ())
-        assert (CELL in cells) == ("c5-masked" in cells and "c5-hostfed" in cells), metric["name"]
-        assert (CELL in cells) == (metric["name"] in SHARED_METRICS)
+        cells, name = metric.get("workloads", ()), metric["name"]
+        both = "c5-masked" in cells and "c5-hostfed" in cells
+        assert (CELL in cells) == (both or name in MASKED_METRICS), name
+        assert (CELL in cells) == (name in SHARED_METRICS | MASKED_METRICS | bench_tree.SETUP_METRICS)
 
 
 def faulty(tree, name, driver):
@@ -338,15 +346,15 @@ def test_the_round_refuses_a_configuration_that_masks_nothing(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# The cell's own layer metrics, dropped into a copy as the files a
-# ``benchmark`` PR would add under ``benchmark/layers/``
+# The cell's two feed metrics, dropped into a copy as the files a
+# ``benchmark`` PR would add under ``benchmark/layers/``; its unmask stage is
+# there already
 # ---------------------------------------------------------------------------
 
 LAYER_FILES = pathlib.Path(__file__).resolve().parent / "hostfed_masked_layers"
 STAGED = {
     "feed.exposed_s": ("s", "host feed", "device_trace"),
     "feed.seeds_per_round": ("seeds", "host feed", "program_counter"),
-    "unmask.stage_s": ("s", "recipient unmask", "program_span"),
 }
 
 
@@ -371,7 +379,7 @@ def tree_with_layers(tmp_path_factory):
 def test_the_staged_layer_files_are_layer_files_and_pass_the_cell_checks(tree_with_layers):
     layers = harness.load_layers(tree_with_layers)
     assert sorted(p.stem for p in LAYER_FILES.glob("*.py")) == [
-        "feed_exposed_s", "feed_seeds_per_round", "unmask_stage_s"
+        "feed_exposed_s", "feed_seeds_per_round"
     ]
     for name, (unit, layer, _source) in STAGED.items():
         module = layers[name]
@@ -392,7 +400,7 @@ def test_a_traced_cpu_run_reports_the_seeds_a_round_and_the_unmask_stage(tree_wi
     telemetry.reset()
     line = run(tree_with_layers, TINY, trace=True)
     assert line["correct"] is True
-    assert set(line["metrics"]) == {
+    assert bench_tree.reported(line) == {
         "engine.fold_s", "epilogue.s", "elems_per_s", "feed.seeds_per_round", "unmask.stage_s",
     }
     assert line["metrics"]["feed.seeds_per_round"] == {"value": float(ROWS), "unit": "seeds"}

@@ -2,15 +2,11 @@
 the CPU, through the harness as the chip runs it: the cell's comparisons as
 on the chip, a round that skips the mask and a wrong fold caught, and what the
 rounds kernel has to move at the least (``benchmark/models_chacha.py``), and
-the round's four layer metrics, which wait in ``masked_layers/`` for the
-``benchmark`` PR that may edit the one test that lists which metrics a trace
-without the program's names leaves silent (``test_benchmark_trace_reduce.py``;
-PERF.md section 7). The manifest's parametrised checks and the compile
-rehearsal hold ``c5-masked`` itself, by its name."""
+the layer metrics of the mask stage, the recipient's unmask and the two ChaCha
+kernels, which the cell lists. The manifest's parametrised checks and the
+compile rehearsal hold ``c5-masked`` itself, by its name."""
 
 import json
-import pathlib
-import shutil
 import time
 import types
 
@@ -18,7 +14,7 @@ import pytest
 
 import bench_tree
 import cell_checks
-from benchmark import harness, models_chacha
+from benchmark import harness, models_chacha, trace_reduce
 
 REPO = bench_tree.REPO
 CELL, CONFIG, TRAFFIC = "c5-masked", "c5-w61-d100k-chacha", "masked-wide"
@@ -111,15 +107,17 @@ def cell_modulus(cell):
 
 
 def test_a_traced_masked_run_reports_the_span_metrics_and_invents_no_device_number(tree):
-    """The metrics every cell reports read the three spans every round opens;
-    the five whose lists the cell joined read the program's names, which a
-    trace with no device plane does not hold."""
+    """The metrics every cell reports read the three spans every round opens,
+    and ``unmask.stage_s`` the round's own; the others whose lists the cell
+    joined read the program's names or the kernels' operations, which a trace
+    with no device plane does not hold."""
     line = run(tree, TINY, trace=True)
     assert line["correct"] is True
-    assert set(line["metrics"]) == {"engine.fold_s", "epilogue.s", "elems_per_s"}
+    assert bench_tree.reported(line) == {"engine.fold_s", "epilogue.s", "elems_per_s", "unmask.stage_s"}
     listed = {m["name"] for m in harness.load_cell(tree, TINY).per_layer}
     assert {"engine.input_s", "engine.rand_s", "epilogue.recombine_s",
             "epilogue.share_matmul_s", "epilogue.reconstruct_s"} <= listed
+    assert set(MASKED_METRICS) | bench_tree.SETUP_METRICS <= listed
 
 
 def test_a_round_that_skips_the_mask_is_caught_by_unmasked_reveals(tree):
@@ -175,74 +173,58 @@ def test_models_chacha_counts_what_the_expansion_needs():
     assert models_chacha.rejected_share(1 << 63) == 0.5
     # 100 000 accepted of 106 667 draws, eight draws a block
     assert models_chacha.blocks_per_seed(100_000, p61) == 13_334
-    assert models_chacha.rounds_kernel_bytes(500, 100_000, p61) == 500 * 13_334 * 128
+    assert models_chacha.rounds_kernel_bytes(500, 100_000, p61) == 500 * (13_334 * 64 + 16)
     assert models_chacha.seeds_expanded_per_round(10_000) == 20_000
 
 
 # ---------------------------------------------------------------------------
-# The round's own layer metrics, dropped into a copy as the files a
-# ``benchmark`` PR would add under ``benchmark/layers/``
+# The round's own layer metrics, under ``benchmark/layers/``
 # ---------------------------------------------------------------------------
 
-LAYER_FILES = pathlib.Path(__file__).resolve().parent / "masked_layers"
-MASKED_METRICS = ["mask.step_s", "unmask.stage_s", "unmask.expand_s", "chacha_rounds_roofline"]
+MASKED_METRICS = [
+    "mask.step_s", "unmask.stage_s", "unmask.expand_s", "chacha_rounds_roofline",
+    "chacha_compact_roofline",
+]
 
 
-@pytest.fixture(scope="module")
-def tree_with_layers(tmp_path_factory):
-    root = bench_tree.copy_benchmark(tmp_path_factory.mktemp("masked_layers") / "copy")
-    for file in sorted(LAYER_FILES.glob("*.py")):
-        shutil.copy(file, root / "benchmark/layers" / file.name)
-    add_tiny_masked(root)
-    manifest = json.loads((root / "BENCHMARK.json").read_text())
-    layers = harness.load_layers(root)
-    for name in MASKED_METRICS:
-        module = layers[name]
-        source = "program_span" if module.reads_spans else "device_trace"
-        manifest["per_layer"].append({
-            "name": name, "unit": module.unit,
-            "better": "higher" if module.unit == "%" else "lower", "source": source,
-            "layer": module.layer, "moves": module.moves, "workloads": [TINY],
-        })
-    (root / "BENCHMARK.json").write_text(json.dumps(manifest))
-    return root
-
-
-def test_the_masked_layer_files_are_layer_files_and_pass_the_cell_checks(tree_with_layers):
-    layers = harness.load_layers(tree_with_layers)
+def test_the_masked_layer_files_are_layer_files_and_pass_the_cell_checks(tree):
+    layers = harness.load_layers(REPO)
     assert set(MASKED_METRICS) <= set(layers)
     assert [layers[n].layer for n in MASKED_METRICS] == [
-        "mask stage", "recipient unmask", "recipient unmask", "kernels"
+        "mask stage", "recipient unmask", "recipient unmask", "kernels", "kernels",
     ]
-    cell_checks.check_cell(tree_with_layers, TINY)
+    for name in MASKED_METRICS:
+        assert CELL in next(m for m in cell_checks.manifest_of(REPO)["per_layer"]
+                            if m["name"] == name)["workloads"]
+    cell_checks.check_cell(tree, TINY)
 
 
-def test_a_traced_cpu_run_reports_the_unmask_stage_and_no_device_number(tree_with_layers):
-    line = run(tree_with_layers, TINY, trace=True)
+def test_a_traced_cpu_run_reports_the_unmask_stage_and_no_device_number(tree):
+    line = run(tree, TINY, trace=True)
     assert line["correct"] is True
-    assert set(line["metrics"]) == {"engine.fold_s", "epilogue.s", "elems_per_s", "unmask.stage_s"}
-    record = json.loads((tree_with_layers / "out" / f"rounds-{TINY}-seed5-trace1.json").read_text())
+    assert bench_tree.reported(line) == {"engine.fold_s", "epilogue.s", "elems_per_s", "unmask.stage_s"}
+    record = json.loads((tree / "out" / f"rounds-{TINY}-seed5-trace1.json").read_text())
     stage = line["metrics"]["unmask.stage_s"]
     assert stage["unit"] == "s" and min(record["spans"]["unmask"]) <= stage["value"] <= max(
         record["spans"]["unmask"]
     )
 
 
-def test_the_device_metrics_of_the_masked_round_read_scopes_and_the_kernels_operations(
-    tree_with_layers,
-):
+def test_the_device_metrics_of_the_masked_round_read_scopes_and_the_kernels_operations():
     """Their arithmetic on a made-up trace and scope report, which a CPU run
     never reaches: two chips, the busier one decides."""
-    layers = harness.load_layers(tree_with_layers)
+    layers = harness.load_layers(REPO)
     ms = 1_000_000  # ns
-    trace = types.SimpleNamespace(
-        chips=[0, 1],
+    trace = trace_reduce.Reduced(
+        window=(0, 20 * ms),
         ops={
             0: [("jit_masked_step/chacha_rounds.1", 0, 4 * ms),
                 ("jit_masked_step/fusion.2", 4 * ms, 9 * ms),
-                ("jit__fold_chunk/chacha_rounds.3", 9 * ms, 15 * ms)],
+                ("jit__fold_chunk/chacha_rounds.3", 9 * ms, 15 * ms),
+                ("jit__fold_chunk/chacha_compact.1", 15 * ms, 20 * ms)],
             1: [("jit_masked_step/chacha_rounds.1", 0, 2 * ms)],
         },
+        async_ops={}, host_spans=[],
     )
     report = {
         "busiest_chip": "0",
@@ -254,20 +236,24 @@ def test_the_device_metrics_of_the_masked_round_read_scopes_and_the_kernels_oper
     }
     p61, lines = (1 << 60) + 225, []
     context = types.SimpleNamespace(
-        rounds=2, scopes=report, config={"dim": 100_000}, plan=types.SimpleNamespace(modulus=p61),
-        traffic=types.SimpleNamespace(rows=10, passes=1), peaks={"hbm_bytes_per_s": 819e9},
-        log=lines.append,
+        rounds=2, chips=1, scopes=report, config={"dim": 100_000},
+        plan=types.SimpleNamespace(modulus=p61), traffic=types.SimpleNamespace(rows=10, passes=1),
+        peaks={"hbm_bytes_per_s": 819e9}, log=lines.append,
     )
     assert layers["mask.step_s"].reduce([], trace, context) == 0.25
     assert layers["unmask.expand_s"].reduce([], trace, context) == 0.375
     share = layers["chacha_rounds_roofline"].reduce([], trace, context)
     least = 2 * models_chacha.rounds_kernel_bytes(20, 100_000, p61) / 819e9
     assert share == pytest.approx(100 * least / 0.010) and 0 < share < 100
-    assert "13334 blocks" in lines[0]
+    assert "20 seeds a chip x 13334 blocks" in lines[0]
+    share = layers["chacha_compact_roofline"].reduce([], trace, context)
+    least = 2 * models_chacha.compact_kernel_bytes(20, 100_000, p61) / 819e9
+    assert share == pytest.approx(100 * least / 0.005) and 0 < share < 100
     # nothing to read: no trace, no report, no kernel in the trace
     context.scopes = None
-    empty = types.SimpleNamespace(chips=[0], ops={0: [("jit_step/fusion.1", 0, ms)]})
-    for name in ("mask.step_s", "unmask.expand_s", "chacha_rounds_roofline"):
+    empty = trace_reduce.Reduced(
+        window=(0, ms), ops={0: [("jit_step/fusion.1", 0, ms)]}, async_ops={}, host_spans=[]
+    )
+    for name in MASKED_METRICS:
         assert layers[name].reduce([], None, context) is None
         assert layers[name].reduce([], empty, context) is None
-    assert layers["unmask.stage_s"].reduce([], None, context) is None

@@ -4,18 +4,14 @@ chips run it: the cell's comparisons as on the chips, the program's driver
 bound by dotted path with its masking scheme and its mesh, drivers that break
 one of the configuration's own guarantees caught each by its own comparison,
 the cell's programs compiled for a described v5e 2x2 with both kernels and a
-collective in each, and the cell's three layer metrics, which wait in
-``masked_mesh_layers/`` for the ``benchmark`` PR that may edit the one test
-that lists which metrics a trace without the program's names leaves silent
-(``test_benchmark_trace_reduce.py``; PERF.md section 7). The manifest's
-parametrised checks and the compile rehearsal hold ``c5-masked-x4`` itself,
-by its name."""
+collective in each, and the cell's own layer metrics (the recipient's
+meeting, the mask stage's skew over the chips, the unmask stage). The
+manifest's parametrised checks and the compile rehearsal hold
+``c5-masked-x4`` itself, by its name."""
 
 import json
 import os
-import pathlib
 import re
-import shutil
 import time
 import types
 
@@ -41,6 +37,12 @@ SHARED_METRICS = {
     "collective.s", "collective.exposed_share", "engine.input_s", "engine.rand_s",
     "epilogue.recombine_s", "epilogue.share_matmul_s", "epilogue.reconstruct_s",
 }
+#: what the cell shares with both masked one-chip cells, and its own
+MASKED_METRICS = {
+    "mask.step_s", "unmask.stage_s", "unmask.expand_s", "chacha_rounds_roofline",
+    "chacha_compact_roofline",
+}
+OWN_METRICS = {"mask.chip_skew", "unmask.meet_s"}
 SPANS = ["dispatch", "fold", "fetch", "epilogue", "unmask", "check"]
 
 
@@ -146,10 +148,16 @@ def test_the_configuration_states_the_deployment_its_two_cuts_and_its_own_guaran
     assert traffic["recipient_fold"] == "sda_tpu.ops.chacha_pallas.fold_chunk_mesh_jit"
     entry = next(w for w in cell_checks.manifest_of(REPO)["workloads"] if w["name"] == CELL)
     assert (entry["config"], entry["traffic"], entry["chips"]) == (CONFIG, TRAFFIC, 4)
-    # exactly the lists that hold the other mesh cell, or both masked one-chip cells
+    # exactly the lists that hold the other mesh cell, or both masked one-chip
+    # cells, or every cell, and its own
     for metric in cell_checks.manifest_of(REPO)["per_layer"]:
         cells = metric.get("workloads", ())
-        assert (CELL in cells) == (metric["name"] in SHARED_METRICS), metric["name"]
+        listed = SHARED_METRICS | MASKED_METRICS | OWN_METRICS | bench_tree.SETUP_METRICS
+        assert (CELL in cells) == (metric["name"] in listed), metric["name"]
+        if metric["name"] in MASKED_METRICS:
+            assert {"c5-masked", "c5-hostfed-masked"} <= set(cells), metric["name"]
+        if metric["name"] in OWN_METRICS:
+            assert cells == [CELL], metric["name"]
 
 
 def test_the_round_binds_the_programs_driver_with_its_mesh_and_imports_nothing_of_the_program():
@@ -391,75 +399,52 @@ def test_the_drivers_unmasked_mesh_step_is_the_sharded_cells_program(topo):
 
 
 # ---------------------------------------------------------------------------
-# The cell's own layer metrics, dropped into a copy as the files a
-# ``benchmark`` PR would add under ``benchmark/layers/``
+# The cell's own layer metrics, under ``benchmark/layers/``
 # ---------------------------------------------------------------------------
 
-LAYER_FILES = pathlib.Path(__file__).resolve().parent / "masked_mesh_layers"
-STAGED = {
+HOOKED = {
     "unmask.stage_s": ("s", "recipient unmask", "program_span"),
     "unmask.meet_s": ("s", "recipient unmask", "device_trace"),
     "mask.chip_skew": ("ratio", "mask stage", "device_trace"),
 }
 
 
-@pytest.fixture(scope="module")
-def tree_with_layers(tmp_path_factory):
-    root = bench_tree.copy_benchmark(tmp_path_factory.mktemp("masked_mesh_layers") / "copy")
-    for file in sorted(LAYER_FILES.glob("*.py")):
-        shutil.copy(file, root / "benchmark/layers" / file.name)
-    add_tiny_masked_mesh(root)
-    manifest = json.loads((root / "BENCHMARK.json").read_text())
-    layers = harness.load_layers(root)
-    for name, (unit, _layer, source) in STAGED.items():
-        module = layers[name]
-        manifest["per_layer"].append({
-            "name": name, "unit": unit, "better": "lower", "source": source,
-            "layer": module.layer, "moves": module.moves, "workloads": [TINY],
-        })
-    (root / "BENCHMARK.json").write_text(json.dumps(manifest))
-    return root
-
-
-def test_the_staged_layer_files_are_layer_files_and_pass_the_cell_checks(tree_with_layers):
-    layers = harness.load_layers(tree_with_layers)
-    assert sorted(p.stem for p in LAYER_FILES.glob("*.py")) == [
-        "mask_chip_skew", "unmask_meet_s", "unmask_stage_s"
-    ]
-    for name, (unit, layer, _source) in STAGED.items():
+def test_the_staged_layer_files_are_layer_files_and_pass_the_cell_checks(tree):
+    layers = harness.load_layers(REPO)
+    listed = {m["name"]: m for m in cell_checks.manifest_of(REPO)["per_layer"]}
+    for name, (unit, layer, source) in HOOKED.items():
         module = layers[name]
         assert (module.unit, module.layer, module.moves) == (unit, layer, "round_s")
+        assert listed[name]["source"] == source and CELL in listed[name]["workloads"]
         assert isinstance(module.reads_spans, tuple)
-        source = (LAYER_FILES / f"{name.replace('.', '_')}.py").read_text()
-        assert "import sda_tpu" not in source and "from sda_tpu" not in source
+        text = (REPO / "benchmark/layers" / f"{name.replace('.', '_')}.py").read_text()
+        assert "import sda_tpu" not in text and "from sda_tpu" not in text
     assert layers["unmask.stage_s"].reads_spans == ("unmask",)
-    cell_checks.check_cell(tree_with_layers, TINY)
+    assert layers["unmask.meet_s"].reads == ("scope:fabric.unmask/meet",)
+    assert layers["mask.chip_skew"].reads == ("scope:fabric.mask",)
+    cell_checks.check_cell(tree, TINY)
 
 
-def test_a_traced_cpu_run_reports_the_unmask_stage_and_invents_no_device_number(
-    tree_with_layers,
-):
-    """The span metrics and the staged one that reads a span; of the seven
-    that the cell shares with its neighbours and of the two staged ones that
-    read the device, nothing: a CPU trace has no device plane."""
-    line = run(tree_with_layers, TINY, trace=True)
+def test_a_traced_cpu_run_reports_the_unmask_stage_and_invents_no_device_number(tree):
+    """The span metrics and the one of its own that reads a span; of those
+    that the cell shares with its neighbours and of its own that read the
+    device, nothing: a CPU trace has no device plane."""
+    line = run(tree, TINY, trace=True)
     assert line["correct"] is True
-    assert set(line["metrics"]) == {
+    assert bench_tree.reported(line) == {
         "engine.fold_s", "epilogue.s", "elems_per_s", "unmask.stage_s",
     }
-    reported = {m["name"] for m in harness.load_cell(tree_with_layers, TINY).per_layer}
-    assert SHARED_METRICS | set(STAGED) <= reported
-    record = json.loads(
-        (tree_with_layers / "out" / f"rounds-{TINY}-seed5-trace1.json").read_text()
-    )
+    reported = {m["name"] for m in harness.load_cell(tree, TINY).per_layer}
+    assert SHARED_METRICS | MASKED_METRICS | OWN_METRICS <= reported
+    record = json.loads((tree / "out" / f"rounds-{TINY}-seed5-trace1.json").read_text())
     stage = line["metrics"]["unmask.stage_s"]["value"]
     assert min(record["spans"]["unmask"]) <= stage <= max(record["spans"]["unmask"])
 
 
-def test_the_meeting_and_the_skew_read_every_chip_of_a_scope_report(tree_with_layers):
+def test_the_meeting_and_the_skew_read_every_chip_of_a_scope_report():
     """Their arithmetic on a made-up scope report, which a CPU run never
     reaches."""
-    layers = harness.load_layers(tree_with_layers)
+    layers = harness.load_layers(REPO)
     meet, skew = layers["unmask.meet_s"], layers["mask.chip_skew"]
 
     def chip(mask, met):

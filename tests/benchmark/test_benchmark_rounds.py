@@ -395,9 +395,14 @@ def test_a_traced_run_with_a_device_plane_reports_all_layers_and_the_breakdown(t
     line = run(tree, name, trace=True)
     listed = {m["name"] for m in manifest["per_layer"]}
     # the CPU reports no peak; the recorded trace holds none of the program's
-    # names, and its operations are absent from the text compiled here
-    assert set(NEW_LAYERS) <= listed
-    assert set(line["metrics"]) == listed - {"device.peak_gib"} - set(NEW_LAYERS)
+    # names, and its operations are absent from the text compiled here: what
+    # the metrics that read those find is worked out from what each file says
+    # it reads, beside the spans of the cell's round
+    layers = harness.load_layers(tree)
+    present = cell_checks.recorded_inputs(reduced)
+    finding = {n for n in listed if cell_checks.finds_what_it_reads(layers[n], SPANS, present)}
+    assert set(NEW_LAYERS) <= listed - finding
+    assert bench_tree.reported(line) == finding - {"device.peak_gib"}
     assert line["device"]["busy_s"] == pytest.approx(reduced.mean_busy_seconds())
     assert line["device"]["window_s"] == pytest.approx(reduced.window_seconds)
     assert 0 < line["device"]["busy_s"] < line["device"]["window_s"]

@@ -1,13 +1,13 @@
-"""The seven layer metrics that read the program's timeline, dropped into a
-copy as the files a ``benchmark`` PR would add under ``benchmark/layers/``:
-four of set-up (JAX's trace / lower / compile / load events, kept by the
-program in its span ring by program name) and three of the host feed (its wait
-on each bound and its own seconds, cut by the harness's ``round`` spans: one
-clock, no profiler). They wait in ``timeline_layers/`` for the ``benchmark`` PR
-that may edit the one test that lists which metrics a trace without the
-program's names leaves silent (``test_benchmark_trace_reduce.py``; PERF.md
-section 7). A traced CPU run through the harness reports all of them that
-apply: the ring needs no device plane."""
+"""The seven layer metrics that read the program's timeline: the four of
+set-up under ``benchmark/layers/`` (JAX's trace / lower / compile / load events,
+kept by the program in its span ring by program name, taken before the window)
+and the three of the host feed, dropped into a copy as the files a ``benchmark``
+PR would add (its wait on each bound and its own seconds, cut by the harness's
+``round`` spans: one clock, no profiler). The feed's wait in ``timeline_layers/``
+for the ``benchmark`` PR that evaluates a metric that reads no trace in an
+untraced run (a traced run's link is the profiler's; PERF.md section 7). A
+traced CPU run through the harness reports all of them that apply: the ring
+needs no device plane."""
 
 import json
 import os
@@ -36,6 +36,9 @@ ALREADY = {"engine.fold_s", "epilogue.s", "elems_per_s"}  # what a traced CPU ru
 
 @pytest.fixture(scope="module")
 def tree(tmp_path_factory):
+    """The feed's three files and their entries for the host-fed twin; the
+    twin joins the set-up metrics' lists as every twin of a listed cell does,
+    and the sum-first twin is added to them by hand."""
     root = bench_tree.copy_benchmark(tmp_path_factory.mktemp("timeline") / "copy")
     for file in sorted(LAYER_FILES.glob("*.py")):
         shutil.copy(file, root / "benchmark/layers" / file.name)
@@ -43,11 +46,13 @@ def tree(tmp_path_factory):
     bench_tree.add_cell(root, *SUMFIRST)
     manifest = json.loads((root / "BENCHMARK.json").read_text())
     layers = harness.load_layers(root)
-    for name in SETUP_METRICS + FEED_METRICS:
-        cells = [HOSTFED] if name in FEED_METRICS else [HOSTFED, SUMFIRST[0]]
+    for metric in manifest["per_layer"]:
+        if metric["name"] in SETUP_METRICS:
+            metric["workloads"].append(SUMFIRST[0])
+    for name in FEED_METRICS:
         manifest["per_layer"].append({
             "name": name, "unit": "s", "better": "lower", "source": "program_span",
-            "layer": layers[name].layer, "moves": layers[name].moves, "workloads": cells,
+            "layer": layers[name].layer, "moves": layers[name].moves, "workloads": [HOSTFED],
         })
     (root / "BENCHMARK.json").write_text(json.dumps(manifest))
     return root
@@ -69,16 +74,20 @@ def test_the_timeline_layer_files_are_layer_files_and_pass_the_cell_checks(tree)
     layers = harness.load_layers(tree)
     assert sorted(p.stem for p in LAYER_FILES.glob("*.py")) == [
         "feed_in_flight_wait_s", "feed_link_wait_s", "feed_own_s",
-        "setup_compile_s", "setup_load_s", "setup_lower_s", "setup_trace_s",
     ]
+    listed = {m["name"]: m for m in cell_checks.manifest_of(bench_tree.REPO)["per_layer"]}
+    every_cell = [w["name"] for w in cell_checks.manifest_of(bench_tree.REPO)["workloads"]]
     for name in SETUP_METRICS:
         module = layers[name]
         assert (module.unit, module.layer, module.moves) == ("s", "set-up", "setup_s")
+        assert module.reads_spans == () and module.reads == ("ring:jax.",)
+        assert listed[name]["workloads"] == every_cell
+        source = (bench_tree.REPO / "benchmark/layers" / f"{name.replace('.', '_')}.py").read_text()
+        assert "import sda_tpu" not in source and "from sda_tpu" not in source
     for name in FEED_METRICS:
         module = layers[name]
         assert (module.unit, module.layer, module.moves) == ("s", "host feed", "round_s")
-    for name in SETUP_METRICS + FEED_METRICS:
-        assert layers[name].reads_spans == ("round",)
+        assert module.reads_spans == ("round",)
         source = (LAYER_FILES / f"{name.replace('.', '_')}.py").read_text()
         assert "import sda_tpu" not in source and "from sda_tpu" not in source
     cell_checks.check_cell(tree, HOSTFED)
@@ -156,11 +165,15 @@ def test_each_timeline_metric_says_nothing_of_an_empty_ring(tree):
 
     layers = harness.load_layers(tree)
     spans = [harness.Span("round", 1, 10.0, 11.0), harness.Span("round", 2, 11.0, 12.0)]
-    context = types.SimpleNamespace(host_spans=None)
     telemetry.reset()
+
+    def context():
+        taken = {name: layers[name].before_window(0.0) for name in SETUP_METRICS}
+        return types.SimpleNamespace(host_spans=None, before_window=taken)
+
     for name in SETUP_METRICS + FEED_METRICS:
-        assert layers[name].reduce(spans, None, context) is None, name
-        assert layers[name].reduce([], None, context) is None, name
+        assert layers[name].reduce(spans, None, context()) is None, name
+        assert layers[name].reduce([], None, context()) is None, name
     # records from before the field existed, and records that are not set-up's
     try:
         with telemetry.span("fabric.feed.put", rows=1, bytes=8) as put:
@@ -170,6 +183,6 @@ def test_each_timeline_metric_says_nothing_of_an_empty_ring(tree):
         del put["start_mono"], wait["start_mono"]
         spans = [harness.Span("round", 1, 0.0, 1e12)]
         for name in SETUP_METRICS + FEED_METRICS:
-            assert layers[name].reduce(spans, None, context) is None, name
+            assert layers[name].reduce(spans, None, context()) is None, name
     finally:
         telemetry.reset()

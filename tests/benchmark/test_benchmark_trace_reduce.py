@@ -14,6 +14,7 @@ from benchmark.rounds import packed_fold
 SPAN_NAMES = ("round", *packed_fold.span_names)
 
 HERE = pathlib.Path(__file__).resolve().parent
+REPO = HERE.parents[1]
 US = 1000.0  # nanoseconds
 
 
@@ -186,44 +187,63 @@ def test_recorded_trace_reduces_to_the_numbers_of_the_day(recorded):
     assert sum(gaps.values()) == pytest.approx(want["idle_share"] * want["window_s"])
 
 
-def test_every_layer_metric_reduces_the_recorded_trace_to_a_number(recorded):
-    """The layer files' own arithmetic, which a CPU run never reaches (its
-    trace holds no device plane)."""
+def recorded_run(reduced, report=None):
+    """``(spans, context)`` of a recorded four-chip run as the harness would
+    hand them to a layer file: its ``round`` spans and those of its round,
+    and what it tells of the cell (``c5-sumfirst-x4``'s files, a tiny twin's
+    sizes), of the scope report (none where there is none, or where an
+    operation was ``absent``) and of the program's host spans."""
     import types
 
-    from benchmark import harness, models, traffic
+    from benchmark import harness
 
-    repo = HERE.parents[1]
-    _want, reduced = recorded
-    cell = harness.load_cell(repo, "c5-sumfirst-x4")
+    cell = harness.load_cell(REPO, "c5-sumfirst-x4")
     spans = [
         harness.Span(name, 1, start * 1e-9, end * 1e-9)
         for name, start, end in reduced.host_spans
     ]
-    rounds = sum(s.name == "round" for s in spans)
     plan = types.SimpleNamespace(
         modulus=(1 << 60) + 33, input_size=5, rand_size=2, share_count=8, n_batches=13
     )
     lines = []
     context = harness.LayerContext(
-        name=cell.name, chips=4, config=cell.config, traffic=cell.traffic, rounds=rounds,
-        elements_per_round=8 * 62, chunk_bytes=8 * 62 * 8, acc_bytes=2 * 13 * 7 * 8, steps_per_round=4, plan=plan,
-        peaks=harness.load_peaks(repo, "TPU v5 lite"), memory_peak_bytes=5 << 30,
+        name=cell.name, chips=4, config=cell.config, traffic=cell.traffic,
+        rounds=sum(s.name == "round" for s in spans), elements_per_round=8 * 62,
+        chunk_bytes=8 * 62 * 8, acc_bytes=2 * 13 * 7 * 8, steps_per_round=4, plan=plan,
+        peaks=harness.load_peaks(REPO, "TPU v5 lite"), memory_peak_bytes=5 << 30,
         log=lines.append, chunk_step_modules=frozenset({"jit_step"}),
+        scopes=report if report and not report["absent"] else None,
+        host_spans=report["host_spans_s"] if report else None,
     )
-    values = {
-        name: module.reduce(spans, reduced, context)
-        for name, module in harness.load_layers(repo).items()
-    }
+    return spans, context, lines
+
+
+#: the metrics that read the program's own names, which the recording without
+#: them leaves silent
+PROGRAM_NAMES = {
+    "engine.input_s", "engine.rand_s", "engine.layout_s", "engine.share_matmul_s",
+    "engine.unscoped_s", "epilogue.recombine_s", "epilogue.share_matmul_s",
+    "epilogue.reconstruct_s",
+}
+
+
+def test_every_layer_metric_reduces_the_recorded_trace_to_a_number(recorded):
+    """The layer files' own arithmetic, which a CPU run never reaches (its
+    trace holds no device plane). Which files the recording leaves silent is
+    worked out from what each says it reads (``cell_checks``)."""
+    import cell_checks
+    from benchmark import harness, models, traffic
+
+    _want, reduced = recorded
+    spans, context, lines = recorded_run(reduced)
+    rounds = context.rounds
+    values = cell_checks.check_layers_on_recording(
+        REPO, spans, reduced, context, cell_checks.recorded_inputs(reduced)
+    )
     # this trace holds none of the program's names and the context no report
     # of them: the metrics that read them find nothing, and say nothing
-    silent = {name for name, value in values.items() if value is None}
-    assert silent == {
-        "engine.input_s", "engine.rand_s", "engine.layout_s", "engine.share_matmul_s",
-        "engine.unscoped_s", "epilogue.recombine_s", "epilogue.share_matmul_s",
-        "epilogue.reconstruct_s",
-    }
-    values = {name: value for name, value in values.items() if name not in silent}
+    silent = set(harness.load_layers(REPO)) - set(values)
+    assert PROGRAM_NAMES <= silent
     assert all(isinstance(v, float) and v > 0 for v in values.values()), values
     busy = reduced.max_busy_seconds()
     assert values["kernel.busy_s"] == pytest.approx(busy / rounds)
@@ -244,11 +264,145 @@ def test_every_layer_metric_reduces_the_recorded_trace_to_a_number(recorded):
     assert step_busy < busy
     assert values["chunk_step_roofline"] == pytest.approx(100 * least / (step_busy / (rounds * 4)))
     silent_context = dataclasses.replace(context, chunk_step_modules=frozenset({"jit_not_there"}))
-    layers = harness.load_layers(repo)
+    layers = harness.load_layers(REPO)
     assert layers["chunk_step_roofline"].reduce(spans, reduced, silent_context) is None
     assert layers["kernel.busy_s"].reduce(spans, reduced, silent_context) == pytest.approx(busy / rounds)
     assert values["engine.fold_s"] > 0 and values["epilogue.s"] > 0
     whole = [s for s in spans if s.name == "round"]
     elapsed = max(s.end for s in whole) - min(s.start for s in whole)
     assert values["elems_per_s"] == pytest.approx(rounds * 8 * 62 / elapsed)
-    assert traffic.load(repo / "benchmark/traffic/participant-narrow.json").share_matmul_in_step
+    assert traffic.load(REPO / "benchmark/traffic/participant-narrow.json").share_matmul_in_step
+
+
+SCOPED = HERE / "recorded-trace-scopes.json"
+
+
+@pytest.fixture(scope="module")
+def scoped():
+    """The recording of a run with the program's names (``record_scopes_trace.py``):
+    the raw trace, reduced, and the harness's report of its scopes."""
+    from benchmark import scopes
+
+    raw = json.loads(SCOPED.read_text())
+    return trace_reduce.reduce(raw, SPAN_NAMES), scopes.split(raw, raw["paths"], SPAN_NAMES)
+
+
+def test_every_layer_metric_reduces_the_scoped_recording_to_a_number(scoped):
+    """The same check on the recording that holds the program's scopes and host
+    spans: the files that read them give the report's numbers; those that read
+    a scope, a kernel, a span or records it lacks say nothing."""
+    import cell_checks
+    from benchmark import harness, scopes
+
+    reduced, report = scoped
+    assert not report["absent"]
+    spans, context, _lines = recorded_run(reduced, report)
+    values = cell_checks.check_layers_on_recording(
+        REPO, spans, reduced, context, cell_checks.recorded_inputs(reduced, report)
+    )
+    busiest = report["chips"][report["busiest_chip"]]
+    assert values["engine.input_s"] == busiest["by_scope"]["fabric.input"]
+    assert values["engine.rand_s"] == busiest["by_scope"]["fabric.rand"]
+    assert values["engine.unscoped_s"] == busiest[scopes.UNSCOPED]["s"]
+    for name, span in (
+        ("epilogue.recombine_s", "fabric.epilogue.recombine"),
+        ("epilogue.share_matmul_s", "fabric.epilogue.share_matmul"),
+        ("epilogue.reconstruct_s", "fabric.reconstruct"),
+    ):
+        assert values[name] == report["host_spans_s"][span]
+    # the sharded sum-first step has no layout or share matmul of its own, no
+    # mask stage, no recipient, no ChaCha kernel; the recording keeps no ring
+    silent = set(harness.load_layers(REPO)) - set(values)
+    assert {
+        "engine.layout_s", "engine.share_matmul_s", "mask.step_s", "mask.chip_skew",
+        "unmask.stage_s", "unmask.expand_s", "unmask.meet_s", "chacha_rounds_roofline",
+        "chacha_compact_roofline", "setup.trace_s", "setup.lower_s", "setup.load_s",
+        "setup.compile_s",
+    } <= silent
+
+
+TOY_LAYER = """
+from benchmark import scopes
+name, unit, layer, moves = {name!r}, 's', 'toy layer', 'round_s'
+reads_spans = {spans!r}
+reads = {reads!r}
+def reduce(spans, trace, cell):
+    return {value}
+"""
+
+
+def drop_toy_layer(root, name, value, reads=(), spans=()):
+    """A layer file of a later PR, dropped into a copy of the benchmark."""
+    (root / "benchmark/layers" / f"{name.replace('.', '_')}.py").write_text(
+        TOY_LAYER.format(name=name, reads=tuple(reads), spans=tuple(spans), value=value)
+    )
+
+
+def check_both_recordings(root, recorded, scoped):
+    import cell_checks
+
+    _want, reduced = recorded
+    spans, context, _lines = recorded_run(reduced)
+    cell_checks.check_layers_on_recording(
+        root, spans, reduced, context, cell_checks.recorded_inputs(reduced)
+    )
+    reduced, report = scoped
+    spans, context, _lines = recorded_run(reduced, report)
+    return cell_checks.check_layers_on_recording(
+        root, spans, reduced, context, cell_checks.recorded_inputs(reduced, report)
+    )
+
+
+def test_a_new_layer_file_that_reads_what_the_recordings_lack_passes_with_no_test_edited(
+    tmp_path, recorded, scoped
+):
+    """What a later PR does when it brings a metric of a new device scope, a
+    new host span of the program, a new kernel, a new span of its round or a
+    new record of the ring: a file under ``benchmark/layers/`` that says so,
+    and no edit to this test or to any other file."""
+    import bench_tree
+
+    root = bench_tree.copy_benchmark(tmp_path / "copy")
+    drop_toy_layer(root, "toy.scope_s", "scopes.scope_seconds(cell.scopes, 'fabric.toy')",
+                   reads=["scope:fabric.toy"])
+    drop_toy_layer(root, "toy.host_s", "(cell.host_spans or {}).get('fabric.toy.host')",
+                   reads=["host_span:fabric.toy.host"])
+    drop_toy_layer(root, "toy.kernel_s", "trace.max_kernel_seconds('toy_kernel') or None",
+                   reads=["kernel:toy_kernel"])
+    drop_toy_layer(root, "toy.span_s", "next((s.seconds for s in spans if s.name == 'toy'), None)",
+                   spans=["toy"])
+    drop_toy_layer(root, "toy.ring_s", "cell.before_window.get('toy.ring_s')",
+                   reads=["ring:toy."])
+    # and one that reads the scope the recording has, beside them
+    drop_toy_layer(root, "toy.rand_s", "scopes.scope_seconds(cell.scopes, 'fabric.rand')",
+                   reads=["scope:fabric.rand"])
+    values = check_both_recordings(root, recorded, scoped)
+    assert not {"toy.scope_s", "toy.host_s", "toy.kernel_s", "toy.span_s", "toy.ring_s"} & set(values)
+    assert values["toy.rand_s"] == values["engine.rand_s"] > 0
+
+
+@pytest.mark.parametrize("value,reads,fault", [
+    ("None", ["scope:fabric.rand"], "finds what it reads and gives nothing"),
+    ("0.0", ["scope:fabric.rand"], "gives 0.0"),
+    ("-1.0", [], "gives -1.0"),
+    ("1.0", ["kernel:toy_kernel"], "where what it reads is not"),
+])
+def test_a_layer_file_silent_where_its_input_is_or_not_positive_fails_the_check(
+    tmp_path, recorded, scoped, value, reads, fault
+):
+    import bench_tree
+
+    root = bench_tree.copy_benchmark(tmp_path / "copy")
+    drop_toy_layer(root, "toy.faulty_s", value, reads=reads)
+    with pytest.raises(AssertionError, match=fault):
+        check_both_recordings(root, recorded, scoped)
+
+
+def test_a_layer_file_that_reads_a_kind_the_harness_hands_out_nowhere_is_refused(tmp_path):
+    import bench_tree
+    from benchmark import harness
+
+    root = bench_tree.copy_benchmark(tmp_path / "copy")
+    drop_toy_layer(root, "toy.bad_s", "None", reads=["counter:sda_toy_total"])
+    with pytest.raises(harness.HarnessError, match="counter:sda_toy_total"):
+        harness.load_layers(root)
