@@ -520,3 +520,95 @@ def mod_u64_const(hi, lo, m: int):
         s_hi, s_lo = sub64(r_hi, r_lo, u32(m_hi), u32(m_lo))
         r_hi, r_lo = jnp.where(ge, s_hi, r_hi), jnp.where(ge, s_lo, r_lo)
     return join(r_hi, r_lo)
+
+
+# ---------------------------------------------------------------------------
+# a·c mod p at up to 62 bits on uint32 words: Shoup's precomputed quotient
+# ---------------------------------------------------------------------------
+
+
+def _mul32(x, y):
+    """``(hi, lo)`` uint32 words of the 64-bit product of uint32 ``x`` and
+    ``y``. The chip has no multiply-high: schoolbook on 16-bit halves, every
+    partial product and sum below 2**32."""
+    import jax.numpy as jnp
+
+    u32 = jnp.uint32
+    x1, x0 = x >> u32(16), x & u32(0xFFFF)
+    y1, y0 = y >> u32(16), y & u32(0xFFFF)
+    p00, p01, p10 = x0 * y0, x0 * y1, x1 * y0
+    mid = (p00 >> u32(16)) + (p01 & u32(0xFFFF)) + (p10 & u32(0xFFFF))
+    hi = x1 * y1 + (p01 >> u32(16)) + (p10 >> u32(16)) + (mid >> u32(16))
+    return hi, x * y
+
+
+def _add32(a, b):
+    """``(carry, a + b mod 2**32)`` of uint32 words, the carry 0 or 1."""
+    import jax.numpy as jnp
+
+    s = a + b
+    return (s < a).astype(jnp.uint32), s
+
+
+def add64(a_hi, a_lo, b_hi, b_lo):
+    """``a + b mod 2**64`` on uint32 word pairs."""
+    carry, lo = _add32(a_lo, b_lo)
+    return a_hi + b_hi + carry, lo
+
+
+def shoup_words(c: int, p: int) -> tuple:
+    """The four uint32 words that :func:`mulmod_shoup` takes for the
+    multiplier ``c`` (``0 <= c < p``): ``(c_hi, c_lo, w_hi, w_lo)``, ``w =
+    floor(c·2**64 / p)``, the quotient worked out here in python integers."""
+    if not 1 < p < 1 << 62:
+        raise ValueError(f"modulus out of range: {p}")
+    if not 0 <= c < p:
+        raise ValueError(f"the multiplier is canonical, in [0, {p}): not {c}")
+    w = (c << 64) // p
+    return c >> 32, c & 0xFFFFFFFF, w >> 32, w & 0xFFFFFFFF
+
+
+def mulmod_shoup(a_hi, a_lo, c_words, p: int):
+    """``a·c mod p`` as its uint32 words ``(hi, lo)``, exact, with no
+    division and no 64-bit type: ``a`` any value below 2**64 as uint32 word
+    arrays, ``c`` canonical and fixed for the call, given as
+    :func:`shoup_words` makes them (python ints, folded into the program, or
+    uint32 arrays that broadcast against ``a``: a matrix handed in as an
+    operand), ``1 < p < 2**62`` a python int.
+
+    Shoup's product (Shoup, NTL; Harvey 2014, "Faster arithmetic for
+    number-theoretic transforms", algorithm 2): with ``w = floor(c·2**64/p)``
+    the quotient ``q = floor(a·w / 2**64)`` is the true ``floor(a·c/p)`` or
+    one short, for every ``a < 2**64``, so ``r = a·c − q·p`` lies in ``[0,
+    2p)`` and is taken mod 2**64 from the low words alone; one compare and
+    subtract makes it canonical. ``q`` is the exact high half of the 128-bit
+    ``a·w``: four 32-bit products and their carries."""
+    import jax.numpy as jnp
+
+    u32 = jnp.uint32
+    if not 1 < p < 1 << 62:
+        raise ValueError(f"modulus out of range: {p}")
+    c_hi, c_lo, w_hi, w_lo = (jnp.asarray(x, dtype=u32) for x in c_words)
+    # q = high 64 bits of a·w
+    h00, l00 = _mul32(a_lo, w_lo)
+    h01, l01 = _mul32(a_lo, w_hi)
+    h10, l10 = _mul32(a_hi, w_lo)
+    h11, l11 = _mul32(a_hi, w_hi)
+    c1, mid = _add32(h00, l01)
+    c2, _ = _add32(mid, l10)
+    c3, q_lo = _add32(l11, h01)
+    c4, q_lo = _add32(q_lo, h10)
+    c5, q_lo = _add32(q_lo, c1 + c2)
+    q_hi = h11 + c3 + c4 + c5
+    # a·c and q·p mod 2**64
+    ac_hi, ac_lo = _mul32(a_lo, c_lo)
+    ac_hi = ac_hi + a_lo * c_hi + a_hi * c_lo
+    p_hi, p_lo = p >> 32, p & 0xFFFFFFFF
+    qp_hi, qp_lo = _mul32(q_lo, u32(p_lo))
+    qp_hi = qp_hi + q_lo * u32(p_hi) + q_hi * u32(p_lo)
+    r_hi = ac_hi - qp_hi - (ac_lo < qp_lo).astype(u32)
+    r_lo = ac_lo - qp_lo
+    ge = (r_hi > u32(p_hi)) | ((r_hi == u32(p_hi)) & (r_lo >= u32(p_lo)))
+    s_hi = r_hi - u32(p_hi) - (r_lo < u32(p_lo)).astype(u32)
+    s_lo = r_lo - u32(p_lo)
+    return jnp.where(ge, s_hi, r_hi), jnp.where(ge, s_lo, r_lo)

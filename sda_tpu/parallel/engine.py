@@ -363,7 +363,10 @@ def reconstruct(clerk_sums, indices, scheme, dim: int):
         ]
     p = scheme.prime_modulus
     if p >= (1 << 31):
-        # wide modulus: tiny matrices, exact host interpolation
+        # wide modulus: exact host interpolation, the road for the clerk sums
+        # of the 10⁵-wide cohorts (B ≤ 2e4 batches). Clerk sums of 10⁷
+        # batches and more are revealed where they lie, on 32-bit words:
+        # parallel/epilogue.reveal, which round.FoldRound.reveal takes
         return jnp.asarray(
             shamir.reconstruct_clerk_sums_host(clerk_sums, indices, scheme, dim)
         )

@@ -1,27 +1,41 @@
 """The round driver: one object that carries a fold round's chunk step, its
-accumulate rule, its host epilogue and its reveal, on one chip or over the
+accumulate rule, its epilogue and its reveal, on one chip or over the
 deployment's mesh — and the host feed, which brings a round's rows from host
 memory while the chip folds them.
 
-A fabric round is ``accumulator -> chunk step over every chunk -> host
-epilogue to clerk sums -> reveal from a subset of clerks``. Which accumulate
-rule and which epilogue belong to a chunk entry is a property of the entry:
+A fabric round is ``accumulator -> chunk step over every chunk -> epilogue to
+clerk sums -> reveal from a subset of clerks``. Which accumulate rule and
+which host epilogue belong to a chunk entry is a property of the entry:
 
-==========================================  ============  ==================================
-chunk entry ``entry(secrets, key, plan)``   accumulate    host epilogue to ``(n, B)``
-==========================================  ============  ==================================
-``sumfirst.value_limb_sums_chunk``          ``sum``       ``sumfirst.clerk_sums_from_limb_acc``
-``engine.share_combine_limb``               ``sum_mod_p`` ``limbmatmul.limb_recombine_host``
-``engine.share_combine_limb_xla``           ``sum_mod_p`` ``limbmatmul.limb_recombine_host``
-``limb_pallas.share_combine_limb_pallas``   ``sum_mod_p`` ``limbmatmul.limb_recombine_host``
-==========================================  ============  ==================================
+==========================================  =============  ==================================
+chunk entry ``entry(secrets, key, plan)``   accumulate     host epilogue to ``(n, B)``
+==========================================  =============  ==================================
+``sumfirst.value_limb_sums_chunk``          ``sum``        ``sumfirst.clerk_sums_from_limb_acc``
+``sumfirst.value_limb_sums_flat``           ``sum_words``  the same, the words joined first
+``engine.share_combine_limb``               ``sum_mod_p``  ``limbmatmul.limb_recombine_host``
+``engine.share_combine_limb_xla``           ``sum_mod_p``  ``limbmatmul.limb_recombine_host``
+``limb_pallas.share_combine_limb_pallas``   ``sum_mod_p``  ``limbmatmul.limb_recombine_host``
+==========================================  =============  ==================================
 
 ``sum``: exact integer limb sums, added with ``+`` (``sumfirst``'s bound of
-2³¹ participants a round); ``sum_mod_p``: ``+`` then ``rem p``, so that the
-partials stay below p. :func:`fold_round` looks the pair up from the entry
-(a ``functools.partial`` of an entry is that entry), so no caller pairs them
-by hand. The driver adds no arithmetic: its step is the entry on the round's
-key with the step's number folded in, then the accumulate rule.
+2³¹ participants a round); ``sum_words``: the same sums held as uint32 words,
+added with their carries (``sumfirst.add_limb_words``); ``sum_mod_p``: ``+``
+then ``rem p``, so that the partials stay below p. :func:`fold_round` looks
+the pair up from the entry (a ``functools.partial`` of an entry is that
+entry), so no caller pairs them by hand. The driver adds no arithmetic: its
+step is the entry on the round's key with the step's number folded in, then
+the accumulate rule.
+
+**The road of the epilogue and the reveal** is chosen by the accumulator's
+size, not by a flag (:meth:`FoldRound.clerk_sums`). A sum-first accumulator
+under ``epilogue.DEVICE_ROAD_BYTES`` (every 10⁵-wide cohort's, ≤ 3.2e6 B)
+takes the host epilogue of the table and ``shamir.reconstruct_clerk_sums_host``;
+one at or over it (a few fat rows of 10⁸ coordinates: 2.45e9 B) stays on the
+chip: the device epilogue to ``(n, B)`` clerk sums that stay there, and the
+device reveal, whose aggregate alone crosses to the host, as uint32 words
+(``parallel/epilogue.py``). Both roads hand :meth:`FoldRound.reveal`'s caller
+the same ``(dim,)`` int64 aggregate; ``sda_fabric_epilogue_roads_total{road}``
+counts them.
 
 **A masked round** (``fold_round(..., masking=ChaChaMasking(...))``). The
 deployment's masking scheme is an argument of the round. Under it the step is
@@ -115,13 +129,14 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 
 import numpy as np
 
 from .. import telemetry
 from ..ops import shamir
 from ..ops.jaxcfg import ensure_x64
-from . import engine, limb_pallas, limbmatmul, sumfirst
+from . import engine, epilogue, limb_pallas, limbmatmul, sumfirst
 
 #: bytes the feed lets cross the host link at once (module doc): three quarters
 #: of the runtime's staging pool. Measured on a v5e (chip runs, PR 34; a round
@@ -159,6 +174,10 @@ def _limb_acc_epilogue(acc, plan):
     return sumfirst.clerk_sums_from_limb_acc(acc, plan)[0]
 
 
+def _flat_limb_acc_epilogue(acc, plan):
+    return _limb_acc_epilogue(sumfirst.batched_limb_acc(acc, plan), plan)
+
+
 def _limb_recombine_epilogue(acc, plan):
     return limbmatmul.limb_recombine_host(acc, plan.modulus).T
 
@@ -166,10 +185,15 @@ def _limb_recombine_epilogue(acc, plan):
 #: a chunk entry's accumulate rule and its host epilogue (module doc)
 _PAIRED = {
     sumfirst.value_limb_sums_chunk: ("sum", _limb_acc_epilogue),
+    sumfirst.value_limb_sums_flat: ("sum_words", _flat_limb_acc_epilogue),
     engine.share_combine_limb: ("sum_mod_p", _limb_recombine_epilogue),
     engine.share_combine_limb_xla: ("sum_mod_p", _limb_recombine_epilogue),
     limb_pallas.share_combine_limb_pallas: ("sum_mod_p", _limb_recombine_epilogue),
 }
+
+
+#: the entries whose accumulator the device epilogue takes (module doc)
+_SUM_FIRST = (sumfirst.value_limb_sums_chunk, sumfirst.value_limb_sums_flat)
 
 
 def _input_dtype(modulus: int):
@@ -192,6 +216,8 @@ def _make_step(entry, plan, accumulate: str, masking=None, mesh=None):
     modulus = plan.modulus
 
     def accumulated(acc, out):
+        if accumulate == "sum_words":
+            return sumfirst.add_limb_words(acc, out)
         acc = acc + out
         if accumulate == "sum_mod_p":
             acc = lax.rem(acc, jnp.int64(modulus))
@@ -223,22 +249,23 @@ def _make_step(entry, plan, accumulate: str, masking=None, mesh=None):
 class FoldRound:
     """One fold round of a scheme at a dim and a chunk size: the jitted chunk
     step, the zero accumulator, the folds (of resident chunks, of host
-    blocks), the host epilogue and the reveal; under a masking scheme also
-    the slack check and the unmasking. Built by :func:`fold_round`; holds no
-    array."""
+    blocks), the epilogue and the reveal on either road; under a masking
+    scheme also the slack check and the unmasking. Built by
+    :func:`fold_round`; holds no array."""
 
     scheme: object
     plan: engine.AggregationPlan
     chunk: int  # rows a chunk step folds
     entry: object  # entry(secrets, key, plan) -> accumulator, one chunk
-    accumulate: str  # "sum" | "sum_mod_p"
-    acc_shape: tuple  # of the int64 accumulator a step takes and hands on
+    accumulate: str  # "sum" | "sum_words" | "sum_mod_p"
+    acc_shape: tuple  # of the accumulator a step takes and hands on
     #: jitted step(acc, chunk, key, i) -> acc; under a masking scheme
     #: masked_step(acc, chunk, key, i) -> (acc, seeds, counts)
     step: object
     epilogue: object  # fn(acc_host, plan) -> (n, B) clerk sums
     masking: object = None  # the round's protocol.ChaChaMasking, or None
     mesh: object = None  # the deployment's device mesh, axes ("p", "d"), or None
+    acc_dtype: str = "int64"  # uint32 for the flat entry's words
 
     @property
     def modulus(self) -> int:
@@ -252,7 +279,7 @@ class FoldRound:
         import jax
         import jax.numpy as jnp
 
-        acc = jnp.zeros(self.acc_shape, jnp.int64)
+        acc = jnp.zeros(self.acc_shape, self.acc_dtype)
         if self.mesh is None:
             return acc
         from jax.sharding import NamedSharding
@@ -303,6 +330,7 @@ class FoldRound:
                 "the feed is one chip's: over a mesh, fold chunks that are sharded over it "
                 "(fold_chunks)"
             )
+
         if in_flight < 1:
             raise ValueError("in_flight counts blocks: at least 1")
         # the program's own `dispatch`: the puts, the waits and the step
@@ -375,16 +403,45 @@ class FoldRound:
             raise ValueError(f"a block is {self.input_dtype}, not {block.dtype}")
         return block
 
+    @functools.cached_property
+    def epilogue_program(self):
+        """The device road's jitted ``device_clerk_sums(word_acc) -> (n,
+        B)`` (``epilogue.clerk_sums_program``)."""
+        return epilogue.clerk_sums_program(self.plan)
+
+    @functools.cached_property
+    def reveal_program(self):
+        """The device road's jitted ``device_reveal(clerk_sums, rows,
+        lagrange) -> (lo, hi)`` (``epilogue.reveal_program``)."""
+        return epilogue.reveal_program(self.plan)
+
     def clerk_sums(self, acc):
-        """Host epilogue: the (fetched) accumulator -> ``(n, B)`` int64
-        canonical clerk sums."""
+        """The accumulator (on the device or fetched) -> ``(n, B)`` int64
+        canonical clerk sums, by the road its size picks (module doc): a
+        host array from the host epilogue, or, for a sum-first accumulator
+        of ``epilogue.DEVICE_ROAD_BYTES`` or more, a device array from the
+        device epilogue. Counted in ``sda_fabric_epilogue_roads_total``."""
+        sum_first = getattr(self.entry, "func", self.entry) in _SUM_FIRST
+        if sum_first and epilogue.device_road(acc.nbytes):
+            epilogue.count_road("device")
+            return self.epilogue_program(epilogue.word_limb_acc(acc, self.plan))
+        epilogue.count_road("host")
         return np.asarray(self.epilogue(np.asarray(acc), self.plan))
 
     def reveal(self, clerk_sums, clerks):
-        """The ``(dim,)`` canonical int64 aggregate from the sums of the
-        0-based ``clerks`` (at least ``reconstruction_threshold`` of them);
-        in a masked round the *masked* aggregate, which :meth:`unmask` takes
+        """The ``(dim,)`` canonical int64 aggregate on the host from the sums
+        of the 0-based ``clerks`` (at least ``reconstruction_threshold`` of
+        them), on the road the clerk sums came by: host clerk sums by
+        ``shamir.reconstruct_clerk_sums_host``, device ones by the device
+        reveal (``epilogue.reveal``, the aggregate fetched as uint32 words).
+        In a masked round the *masked* aggregate, which :meth:`unmask` takes
         the masks off."""
+        import jax
+
+        if isinstance(clerk_sums, jax.Array):
+            lagrange = epilogue.lagrange_operand(self.scheme, clerks)
+            rows = np.asarray(clerks, dtype=np.int32)
+            return epilogue.fetch_aggregate(*self.reveal_program(clerk_sums, rows, lagrange))
         out = shamir.reconstruct_clerk_sums_host(clerk_sums, clerks, self.scheme, self.plan.dim)
         return np.mod(np.asarray(out).astype(np.int64), self.modulus)
 
@@ -442,6 +499,8 @@ def fold_round(scheme, dim: int, entry, chunk: int, masking=None, mesh=None) -> 
         raise ValueError(
             f"a chunk's rows spread evenly over the mesh's p = {mesh.shape['p']}: not {chunk}"
         )
+    if mesh is not None and getattr(entry, "func", entry) is sumfirst.value_limb_sums_flat:
+        raise ValueError("the flat accumulator is one chip's: over a mesh, the batched entry")
     if mesh is not None and masking is not None and mesh.shape.get("d", 1) > 1:
         raise ValueError(
             "a masked round's mesh keeps d = 1: a row has one seed and its expansion runs "
@@ -465,6 +524,7 @@ def fold_round(scheme, dim: int, entry, chunk: int, masking=None, mesh=None) -> 
         entry=entry,
         accumulate=accumulate,
         acc_shape=tuple(acc.shape),
+        acc_dtype=str(acc.dtype),
         step=_make_step(entry, plan, accumulate, masking, mesh),
         epilogue=epilogue,
         masking=masking,

@@ -40,11 +40,24 @@ bits wide, and a 64-bit tensor costs it a re-laying and carries besides:
 Only the reduced ``(B, cols)`` rows widen to int64; all three hand on the
 same ``(L, B, K)`` accumulator, element for element.
 
-The epilogue (recombine mod p + share matmul) runs host-side on the tiny
-accumulator, exact and in machine integers: each limb reduced by an int64
-``%``, the two of a wide modulus joined by ``ops.modular.mod_limbs_np``, the
-share matmul by ``modmatmul_np``. Python integers remain only where a caller
-asks for the unreduced sums (``exact_value_sums``).
+- ``rows`` — the flat entry's (``value_limb_sums_flat``), for a chunk of a
+  few fat rows: the rows added one by one into each limb's sum held as its
+  high and low uint32 word, ``(L, 2, B·k + B·t)``, the same integers laid
+  flat with the columns on the lanes. The batched accumulator's ``(B, k)``
+  and ``(B, t)`` tensors keep 5 and 2 columns in a tile's 128 lanes, which
+  at 10⁸ coordinates no chip holds.
+
+The epilogue (recombine mod p + share matmul) and the reveal take one of two
+roads, by the accumulator's size (``round.FoldRound`` chooses,
+``epilogue.DEVICE_ROAD_BYTES``). Below it, as for the 10⁵-wide cohorts'
+accumulators of 2.2e5 to 3.2e6 B, the host's: exact and in machine integers,
+each limb reduced by an int64 ``%``, the two of a wide modulus joined by
+``ops.modular.mod_limbs_np``, the share matmul by ``modmatmul_np``. At or
+above it, as for a few fat rows' 2.45e9 B, the device's
+(``parallel/epilogue.py``): the same integers on uint32 words, the 61-bit
+products by ``ops.modular.mulmod_shoup``, the clerk sums left on the chip
+for the device reveal. Python integers remain only where a caller asks for
+the unreduced sums (``exact_value_sums``).
 """
 
 from __future__ import annotations
@@ -57,6 +70,7 @@ from ..ops.jaxcfg import ensure_x64
 from ..ops.modular import (
     MAX_SAFE_MODULUS,
     WIDE_MAX_MODULUS,
+    add64,
     count_wide_product,
     mod_limbs_np,
     modmatmul_np,
@@ -69,6 +83,10 @@ MAX_PARTICIPANTS = 1 << 31
 
 #: chunk bound for the int32 narrow reduction: C * (2^16 - 1) < 2^31
 MAX_NARROW_CHUNK = 1 << 15
+
+#: rows a chunk of :func:`value_limb_sums_flat` adds one by one: its rows are
+#: fat (at the dims it is for, 32 int64 rows are more than a chip holds)
+MAX_FLAT_CHUNK = 32
 
 
 def limb_count_sum(p: int) -> int:
@@ -168,6 +186,95 @@ def value_limb_sums_chunk(secrets, key, plan: AggregationPlan):
     with jax.named_scope("fabric.rand/limb_sum"):
         rand_sums = limb_sums(randomness).reshape(-1, nb, plan.rand_size)
     return jnp.concatenate([input_sums, rand_sums], axis=-1)
+
+
+def value_limb_sums_flat(secrets, key, plan: AggregationPlan):
+    """:func:`value_limb_sums_chunk` for a chunk of a few fat rows, its
+    accumulator laid flat and kept as uint32 words.
+
+    ``(C, dim)`` canonical secrets -> ``(L, 2, B·k + B·t)`` uint32: for each
+    limb the high and the low word of its sum down the chunk's rows, column
+    by column: the secrets in the input's own order (the tail padded to
+    whole batches), then the randomness in the draw's order. The same
+    integers as :func:`value_limb_sums_chunk`'s ``(L, B, K)``
+    (:func:`batched_limb_acc`), from the same draw (a flat ``(C, B·t)`` draw:
+    threefry's bits depend on the linear index alone). Accumulate with
+    :func:`add_limb_words`: the round driver's ``sum_words``.
+
+    Why a second layout: the batched one reduces to ``(B, k)`` and ``(B,
+    t)`` tensors, and the chip keeps a minor dimension of 5 or 2 in a tile
+    of 128 lanes. At c5-sumfirst's 20 000 batches that is 40 MB; at 2.2e7
+    batches the compiler asks for 2.2e10 B of the chip's 1.7e10 and refuses
+    the step. Here every tensor keeps the columns on the lanes, and no int64
+    array is handed on: the chip keeps one as two uint32 planes and splits
+    it into a temporary of its own size at every program's edge, which at
+    an accumulator of 2.45e9 B the chip cannot spare beside the rows.
+
+    The rows are added one by one, every word with its carry (the road
+    ``rows`` of ``sda_limb_sum_roads_total``): no partial sums of the rows
+    are made, so the sum, the draw and the accumulate are one pass over the
+    columns. At most :data:`MAX_FLAT_CHUNK` rows; one chip only.
+    """
+    ensure_x64()
+    import jax
+    import jax.numpy as jnp
+
+    p = plan.modulus
+    C, d = secrets.shape
+    if C > MAX_FLAT_CHUNK:
+        raise ValueError(f"the flat entry adds at most {MAX_FLAT_CHUNK} rows a chunk, not {C}")
+    nb = -(-d // plan.input_size)
+    randomness = _device_randomness(key, (C, nb * plan.rand_size), p)
+    with jax.named_scope("fabric.input/batch"):
+        padded = jnp.pad(secrets, ((0, 0), (0, nb * plan.input_size - d)))
+    with jax.named_scope("fabric.input/limb_sum"):
+        input_sums = _limb_sum_words(padded, p)
+    with jax.named_scope("fabric.rand/limb_sum"):
+        rand_sums = _limb_sum_words(randomness, p)
+    return jnp.concatenate([input_sums, rand_sums], axis=-1)
+
+
+def _limb_sum_words(x, p: int):
+    """``(C, N)`` canonical -> ``(L, 2, N)`` uint32: each limb's sum down
+    the rows as its high and low word, the rows added one by one."""
+    import jax.numpy as jnp
+
+    count_limb_sum_road("rows")
+    u32 = jnp.uint32
+    if limb_count_sum(p) == 1:
+        limbs = [x.astype(u32)]
+    else:
+        x = x.astype(jnp.int64)
+        limbs = [(x & jnp.int64(0xFFFFFFFF)).astype(u32), (x >> jnp.int64(32)).astype(u32)]
+    out = []
+    for limb in limbs:
+        hi, lo = jnp.zeros(limb.shape[1:], u32), limb[0]
+        for row in range(1, limb.shape[0]):
+            hi, lo = add64(hi, lo, u32(0), limb[row])
+        out.append(jnp.stack([hi, lo]))
+    return jnp.stack(out)
+
+
+def add_limb_words(acc, out):
+    """The ``sum_words`` accumulate rule: two ``(L, 2, N)`` word
+    accumulators of :func:`value_limb_sums_flat` added, every limb in 64
+    bits (exact while the participants stay below ``MAX_PARTICIPANTS``)."""
+    import jax.numpy as jnp
+
+    hi, lo = add64(acc[:, 0], acc[:, 1], out[:, 0], out[:, 1])
+    return jnp.stack([hi, lo], axis=1)
+
+
+def batched_limb_acc(word_acc, plan: AggregationPlan):
+    """The word accumulator of :func:`value_limb_sums_flat` as the batched
+    ``(L, B, K)`` int64 one of :func:`value_limb_sums_chunk`: the same
+    integers, on the host."""
+    words = np.asarray(word_acc).astype(np.int64)
+    acc = (words[:, 0] << 32) | words[:, 1]
+    B, k = plan.n_batches, plan.input_size
+    secrets = acc[:, : B * k].reshape(acc.shape[0], B, k)
+    randomness = acc[:, B * k :].reshape(acc.shape[0], B, plan.rand_size)
+    return np.concatenate([secrets, randomness], axis=-1)
 
 
 def exact_value_sums(limb_acc):
