@@ -16,6 +16,18 @@ multiply ``ops.modular.mulmod_shoup`` and the reduction
 ``ops.modular.mod_u64_const``; no 64-bit multiply or division is asked of the
 chip. Both hand back the same integers as the host road, bit for bit.
 
+That arithmetic runs on ``(rows, 128)`` tiles: every operand of a product is
+a block of batches as rows of 128, so a vector register's eight sublanes of
+128 lanes are all filled. A product is about 130 lane operations (six 16-bit
+schoolbook multiplies, their carries, a conditional subtract) and reads and
+writes a few words, so the vector unit, not the memory, bounds both programs;
+an operand laid out as one row of a block's batches, ``(1, B)``, is tiled one
+row of 128 to a register and leaves seven sublanes in eight idle (on a TPU
+v5e the two programs took 0.92 s a round that way at the silo cohort's 2.2e7
+batches, 0.17 s on whole tiles). So the batches are padded to whole rows of
+128 (:func:`_blocking`), every block starts on a whole row, and each clerk's
+sums and each secret are ``(rows, 128)``.
+
 * :func:`clerk_sums` takes the flat accumulator of
   ``sumfirst.value_limb_sums_flat`` (or the batched one of
   ``value_limb_sums_chunk``, laid flat first) and returns the ``(n, B)`` int64
@@ -49,13 +61,16 @@ from ..ops.modular import add64, mod_u64_const, mulmod_shoup, shoup_words
 #: host road costs a round ≈ 10.6 ns an accumulator byte: the fetch at 0.21
 #: GB/s (4.8 ns, measured on a TPU v5e) and the numpy epilogue and
 #: reconstruct, 0.0132 s for c5-sumfirst's 2.24e6 B on the v5e's host
-#: (5.9 ns). The device road moves the accumulator a few times at the
-#: memory's speed (5.96e9 B at 2.45e9 B of accumulator: 7.3 ms at its peak)
-#: but compiles two programs more in set-up, a few seconds; at 2**28 B the
-#: host road would take 2.8 s every round, so the device road pays that back
-#: in its first round. The eight cells of the 10⁵-wide cohorts (≤ 3.2e6 B)
-#: are eighty times under it and keep the road their numbers were measured
-#: on; the silo cohort's 2.45e9 B is nine times over it.
+#: (5.9 ns). The device road's two programs are bound by the vector unit's
+#: field arithmetic, not by moving the accumulator (5.96e9 B at 2.45e9 B of
+#: accumulator, 7.3 ms at the memory's peak): on a TPU v5e they take 0.17 s a
+#: round at 2.45e9 B (≈ 0.07 ns an accumulator byte; 0.92 s while their
+#: operands were one row of a block), and they compile in set-up, a few
+#: seconds. At 2**28 B the host road would take 2.8 s every round, so the
+#: device road pays that back in its first round. The eight cells of the
+#: 10⁵-wide cohorts (≤ 3.2e6 B) are eighty times under it and keep the road
+#: their numbers were measured on; the silo cohort's 2.45e9 B is nine times
+#: over it.
 DEVICE_ROAD_BYTES = 1 << 28
 
 #: a tile's lanes: the planes are laid out 128 batches at a time
@@ -98,34 +113,22 @@ def _split(u):
 
 
 def _planes(x, w: int):
-    """``(B·w,)`` -> ``(w, B)``: element ``b·w + i`` to plane ``i``, column
-    ``b``. A reshape to ``(B, w)`` would keep ``w`` in a tile's 128 lanes (25
-    times the bytes at w = 5); read as rows of 128 batches, ``(R, 128·w)``, the
-    same move is two transpositions of large tiles."""
-    import jax.numpy as jnp
-
-    B = x.shape[0] // w
-    padded = -(-B // _LANES) * _LANES
-    if padded != B:
-        x = jnp.pad(x, (0, (padded - B) * w))
-    rows = padded // _LANES
-    t = x.reshape(rows, _LANES * w).T  # (128·w, R): row l·w + i
-    t = t.reshape(_LANES, w, rows).transpose(1, 2, 0)  # (w, R, 128)
-    return t.reshape(w, padded)[:, :B]
+    """``(R, 128·w)`` rows of 128 batches, element ``l·w + i`` of a row batch
+    ``l``'s part ``i``, -> ``(w, R, 128)``: part ``i`` of every batch in
+    whole tiles. A reshape to ``(R·128, w)`` would keep ``w`` in a tile's 128
+    lanes (25 times the bytes at w = 5); two transpositions of large tiles
+    make the same move."""
+    rows = x.shape[0]
+    t = x.T  # (128·w, R): row l·w + i
+    return t.reshape(_LANES, w, rows).transpose(1, 2, 0)
 
 
 def _interleave(planes):
-    """``(w, B)`` -> ``(B·w,)``, :func:`_planes` undone."""
-    import jax.numpy as jnp
-
-    w, B = planes.shape
-    padded = -(-B // _LANES) * _LANES
-    if padded != B:
-        planes = jnp.pad(planes, ((0, 0), (0, padded - B)))
-    rows = padded // _LANES
-    t = planes.reshape(w, rows, _LANES).transpose(2, 0, 1)  # (128, w, R)
-    t = t.reshape(_LANES * w, rows).T  # (R, 128·w)
-    return t.reshape(padded * w)[: B * w]
+    """``(w, R, 128)`` -> ``(R·128·w,)``, :func:`_planes` undone and laid
+    flat."""
+    w, rows, _ = planes.shape
+    t = planes.transpose(2, 0, 1).reshape(_LANES * w, rows).T  # (R, 128·w)
+    return t.reshape(rows * _LANES * w)
 
 
 def _sum_mod(products, p: int):
@@ -159,18 +162,20 @@ def _check_fits(plan) -> None:
 
 
 def _blocking(B: int) -> tuple:
-    """``(size, count)`` of the blocks of batches the programs loop over;
-    block ``j`` starts at ``min(j·size, B − size)`` (the last one overlaps
-    the one before where ``size`` does not divide ``B``: its batches are
-    computed twice, to the same values)."""
-    size = min(B, BLOCK_BATCHES)
-    return size, -(-B // size)
+    """``(rows, size, count)``: the ``B`` batches padded to ``rows`` rows of
+    128, and the blocks of ``size`` rows the programs loop over, ``count`` of
+    them; block ``j`` starts at row ``min(j·size, rows − size)`` (the last one
+    overlaps the one before where ``size`` does not divide ``rows``: its
+    batches are computed twice, to the same values)."""
+    rows = -(-B // _LANES)
+    size = min(rows, max(1, BLOCK_BATCHES // _LANES))
+    return rows, size, -(-rows // size)
 
 
-def _block_start(j, size: int, B: int):
+def _block_start(j, size: int, rows: int):
     import jax.numpy as jnp
 
-    return jnp.minimum(j * size, B - size).astype(jnp.int32)
+    return jnp.minimum(j * size, rows - size).astype(jnp.int32)
 
 
 def clerk_sums(word_acc, plan):
@@ -187,24 +192,30 @@ def clerk_sums(word_acc, plan):
     _check_fits(plan)
     p, k, t, B, n = plan.modulus, plan.input_size, plan.rand_size, plan.n_batches, plan.share_count
     S = plan.share_matrix
-    size, count = _blocking(B)
+    L = word_acc.shape[0]
+    rows, size, count = _blocking(B)
+    # the last block's random part reads whole rows past the B batches
+    word_acc = jnp.pad(word_acc, ((0, 0), (0, 0), (0, (rows * _LANES - B) * t)))
 
-    def columns(start, offset, w):
-        """The block's batches of one part as ``w`` planes of values mod p."""
-        part = lax.dynamic_slice_in_dim(word_acc, offset + start * w, size * w, axis=2)
+    def columns(row, offset, w):
+        """The block's batches of one part as ``w`` planes of values mod p,
+        ``(size, 128)`` each."""
+        start = offset + row * (_LANES * w)
+        part = lax.dynamic_slice_in_dim(word_acc, start, size * _LANES * w, axis=2)
         with jax.named_scope("fabric.epilogue/recombine"):
+            part = part.reshape(L, 2, size, _LANES * w)  # rows of 128 batches
             v = _split(mod_u64_const(part[0, 0], part[0, 1], p))
-            if part.shape[0] == 2:
+            if L == 2:
                 # the high limb counts 2³² times: its sum times 2³² mod p
                 high = mulmod_shoup(part[1, 0], part[1, 1], shoup_words((1 << 32) % p, p), p)
                 v = _sum_mod([v, high], p)
         with jax.named_scope("fabric.epilogue/share_matmul"):
-            planes = [_planes(word, w) for word in v]  # (w, size) each
+            planes = [_planes(word, w) for word in v]  # (w, size, 128) each
             return [(planes[0][i], planes[1][i]) for i in range(w)]
 
     def block(j, out):
-        start = _block_start(j, size, B)
-        values = columns(start, 0, k) + columns(start, B * k, t)
+        row = _block_start(j, size, rows)
+        values = columns(row, 0, k) + columns(row, B * k, t)
         with jax.named_scope("fabric.epilogue/share_matmul"):
             sums = []
             for j_clerk in range(n):
@@ -214,13 +225,14 @@ def clerk_sums(word_acc, plan):
                 ]
                 sums.append(_sum_mod(products, p))
             return tuple(
-                lax.dynamic_update_slice_in_dim(o, jnp.stack([s[w] for s in sums]), start, axis=1)
+                lax.dynamic_update_slice_in_dim(o, jnp.stack([s[w] for s in sums]), row, axis=1)
                 for w, o in enumerate(out)
             )
 
-    zeros = jnp.zeros((n, B), jnp.uint32)
+    zeros = jnp.zeros((n, rows, _LANES), jnp.uint32)
     hi, lo = lax.fori_loop(0, count, block, (zeros, zeros))
     with jax.named_scope("fabric.epilogue/share_matmul"):
+        hi, lo = (word.reshape(n, rows * _LANES)[:, :B] for word in (hi, lo))
         return (hi.astype(jnp.int64) << jnp.int64(32)) | lo.astype(jnp.int64)
 
 
@@ -246,32 +258,36 @@ def reveal(clerk_sums_, rows, lagrange, plan):
     from jax import lax
 
     _check_fits(plan)
-    p, k, B = plan.modulus, plan.input_size, plan.n_batches
+    p, k, B, n = plan.modulus, plan.input_size, plan.n_batches, plan.share_count
     R = rows.shape[0]
     if R * p >= 1 << 64:
         raise ValueError(f"a sum of {R} products mod {p} leaves 64 bits")
-    size, count = _blocking(B)
+    tile_rows, size, count = _blocking(B)
+    with jax.named_scope("fabric.reveal/reconstruct"):
+        # every clerk's sums as rows of 128 batches, once
+        tiled = jnp.pad(clerk_sums_, ((0, 0), (0, tile_rows * _LANES - B)))
+        tiled = _words(tiled.reshape(n, tile_rows, _LANES))
 
     def block(j, out):
-        start = _block_start(j, size, B)
+        row = _block_start(j, size, tile_rows)
         with jax.named_scope("fabric.reveal/reconstruct"):
-            picked = [
-                lax.dynamic_slice(clerk_sums_, (rows[r].astype(jnp.int32), start), (1, size))[0]
-                for r in range(R)
+            at = [(r.astype(jnp.int32), row, jnp.int32(0)) for r in rows]
+            shares = [
+                tuple(lax.dynamic_slice(word, start, (1, size, _LANES))[0] for word in tiled)
+                for start in at
             ]
-            shares = [_words(row) for row in picked]
             secrets = []
             for i in range(k):
                 products = [mulmod_shoup(*shares[r], tuple(lagrange[:, i, r]), p) for r in range(R)]
                 secrets.append(_sum_mod(products, p))
             return tuple(
                 lax.dynamic_update_slice_in_dim(
-                    o, _interleave(jnp.stack([s[w] for s in secrets])), start * k, axis=0
+                    o, _interleave(jnp.stack([s[w] for s in secrets])), row * (_LANES * k), axis=0
                 )
                 for w, o in enumerate(out)
             )
 
-    zeros = jnp.zeros((B * k,), jnp.uint32)
+    zeros = jnp.zeros((tile_rows * _LANES * k,), jnp.uint32)
     hi, lo = lax.fori_loop(0, count, block, (zeros, zeros))
     return lo[: plan.dim], hi[: plan.dim]
 

@@ -142,17 +142,26 @@ def test_the_flat_entry_refuses_many_rows_and_a_mesh():
 # ---------------------------------------------------------------------------
 
 
+#: ``(batches a block, dim)``: at k = 5, 1923 is 385 batches, not whole rows
+#: of 128 (four blocks of a row, or one of four rows); 1280 is 256, two whole
+#: rows; 7041 is 1409 batches, 12 rows, so that the second block of 8 rows
+#: overlaps the first
+BLOCKS = [(128, 1923), (1024, 1923), (128, 1280), (1024, 7041)]
+
+
+@pytest.mark.parametrize("block,dim", BLOCKS)
 @pytest.mark.parametrize("width", sorted(BITS))
 def test_device_clerk_sums_and_every_reveal_of_7_of_8_are_the_host_roads_and_the_exact_sum(
-    width, small_blocks
+    width, block, dim, monkeypatch
 ):
     import jax
 
     from sda_tpu.ops import shamir
     from sda_tpu.parallel import epilogue, fold_round, sumfirst
 
+    monkeypatch.setattr(epilogue, "BLOCK_BATCHES", block)
     scheme = scheme_of(BITS[width])
-    p, dim = scheme.prime_modulus, 1923
+    p = scheme.prime_modulus
     driver = fold_round(scheme, dim, sumfirst.value_limb_sums_flat, 4)
     x = rows_of(scheme, dim, 8, 7)
     acc = driver.fold_chunks([x[:4], x[4:]], jax.random.key(2))
@@ -169,7 +178,7 @@ def test_device_clerk_sums_and_every_reveal_of_7_of_8_are_the_host_roads_and_the
         assert np.array_equal(np.mod(np.asarray(by_host).astype(np.int64), p), got)
     # the reveal's program is one for every subset: the Lagrange matrix is an operand
     assert driver.reveal_program._cache_size() == 1
-    assert epilogue.BLOCK_BATCHES == 128
+    assert epilogue.BLOCK_BATCHES == block
 
 
 @pytest.mark.parametrize("width", sorted(BITS))
