@@ -40,15 +40,19 @@ sums and each secret are ``(rows, 128)``.
   (under 7p < 2⁶⁴) and reduced once.
 * :func:`reveal` takes those clerk sums, the rows of the clerks revealed
   from and their Lagrange matrix as operands (one program for every subset),
-  and returns the ``(dim,)`` aggregate as its low and high uint32 words: a
-  32-bit array crosses to the host at 3.3 GB/s where an int64 one crosses at
-  0.21 (measured on a TPU v5e). Device scope ``fabric.reveal/reconstruct``; the
-  crossing and the join of the words are the host's (:func:`fetch_aggregate`:
-  span ``fabric.reveal.fetch``, counter
-  ``sda_fabric_reveal_fetch_bytes_total``).
+  and returns the ``(dim,)`` aggregate as one ``(2·dim,)`` uint32 array, each
+  coordinate's low word then its high word: a 32-bit array crosses to the
+  host at 3.3 GB/s where an int64 one crosses at 0.21 (measured on a TPU
+  v5e), and these words are the aggregate's little-endian int64 bytes. Device
+  scope ``fabric.reveal/reconstruct``; the crossing is the host's
+  (:func:`fetch_aggregate`: span ``fabric.reveal.fetch``, counter
+  ``sda_fabric_reveal_fetch_bytes_total``), and the aggregate is a view of
+  the fetched words.
 """
 
 from __future__ import annotations
+
+import sys
 
 import numpy as np
 
@@ -249,9 +253,10 @@ def lagrange_operand(scheme, clerks) -> np.ndarray:
 def reveal(clerk_sums_, rows, lagrange, plan):
     """The ``(dim,)`` aggregate from the ``(n, B)`` clerk sums of
     :func:`clerk_sums`: ``rows`` the ``(R,)`` int32 clerks revealed from,
-    ``lagrange`` their :func:`lagrange_operand`. Returns its low and high
-    uint32 words, ``(dim,)`` each, block by block of :data:`BLOCK_BATCHES`
-    batches. Traceable; :func:`reveal_program` is its jitted program."""
+    ``lagrange`` their :func:`lagrange_operand`. Returns its ``(2·dim,)``
+    uint32 words, coordinate ``c``'s low word at ``2c`` and its high word at
+    ``2c + 1``, block by block of :data:`BLOCK_BATCHES` batches. Traceable;
+    :func:`reveal_program` is its jitted program."""
     ensure_x64()
     import jax
     import jax.numpy as jnp
@@ -276,20 +281,19 @@ def reveal(clerk_sums_, rows, lagrange, plan):
                 tuple(lax.dynamic_slice(word, start, (1, size, _LANES))[0] for word in tiled)
                 for start in at
             ]
-            secrets = []
+            words = []
             for i in range(k):
                 products = [mulmod_shoup(*shares[r], tuple(lagrange[:, i, r]), p) for r in range(R)]
-                secrets.append(_sum_mod(products, p))
-            return tuple(
-                lax.dynamic_update_slice_in_dim(
-                    o, _interleave(jnp.stack([s[w] for s in secrets])), row * (_LANES * k), axis=0
-                )
-                for w, o in enumerate(out)
+                hi, lo = _sum_mod(products, p)
+                words += [lo, hi]
+            # 2k planes, secret-major and low word first: interleaved, a
+            # coordinate's two words lie side by side, as its int64 bytes
+            return lax.dynamic_update_slice_in_dim(
+                out, _interleave(jnp.stack(words)), row * (_LANES * 2 * k), axis=0
             )
 
-    zeros = jnp.zeros((tile_rows * _LANES * k,), jnp.uint32)
-    hi, lo = lax.fori_loop(0, count, block, (zeros, zeros))
-    return lo[: plan.dim], hi[: plan.dim]
+    out = jnp.zeros((tile_rows * _LANES * 2 * k,), jnp.uint32)
+    return lax.fori_loop(0, count, block, out)[: 2 * plan.dim]
 
 
 def clerk_sums_program(plan):
@@ -303,8 +307,8 @@ def clerk_sums_program(plan):
 
 
 def reveal_program(plan):
-    """The jitted ``device_reveal(clerk_sums, rows, lagrange) -> (lo, hi)``
-    of ``plan``: one program for every subset of clerks."""
+    """The jitted ``device_reveal(clerk_sums, rows, lagrange) -> words`` of
+    ``plan``: one program for every subset of clerks."""
     import jax
 
     def device_reveal(clerk_sums_, rows, lagrange):
@@ -313,20 +317,38 @@ def reveal_program(plan):
     return jax.jit(device_reveal)
 
 
-def fetch_aggregate(lo, hi) -> np.ndarray:
-    """The ``(dim,)`` int64 aggregate on the host from its device words: the
-    crossing under the span ``fabric.reveal.fetch`` (``bytes``), counted in
-    ``sda_fabric_reveal_fetch_bytes_total``, then the words joined."""
+class _Taken:
+    """A fetched host array handed on writable, over the same memory: JAX
+    marks the host copy of a device array read-only, and the aggregate is its
+    caller's to keep and change, as the host road's is. It holds the fetched
+    array, which holds the memory."""
+
+    def __init__(self, host: np.ndarray):
+        self.host = host
+        self.__array_interface__ = {**host.__array_interface__, "data": (host.ctypes.data, False)}
+
+
+def fetch_aggregate(words) -> np.ndarray:
+    """The ``(dim,)`` int64 aggregate on the host from the ``(2·dim,)``
+    uint32 device words of :func:`reveal`, which it takes: the crossing under
+    the span ``fabric.reveal.fetch`` (``bytes``), counted in
+    ``sda_fabric_reveal_fetch_bytes_total``, then the device words deleted,
+    so that the host copy has no other reader. On a little-endian host the
+    words are the aggregate's own int64 bytes, and it is their view: no pass
+    over the values, no new array; elsewhere the words are joined."""
     import jax
 
-    nbytes = int(lo.nbytes + hi.nbytes)
+    nbytes = int(words.nbytes)
     with telemetry.span("fabric.reveal.fetch", bytes=nbytes):
-        lo, hi = jax.device_get((lo, hi))
+        host = jax.device_get(words)
+    words.delete()
     telemetry.counter(
         "sda_fabric_reveal_fetch_bytes_total",
         "bytes of revealed aggregates fetched from the device",
     ).inc(nbytes)
-    out = hi.astype(np.int64)
+    if sys.byteorder == "little":
+        return np.asarray(_Taken(host)).view(np.int64)
+    out = host[1::2].astype(np.int64)
     out <<= 32
-    out |= lo
+    out |= host[0::2]
     return out

@@ -412,7 +412,7 @@ class FoldRound:
     @functools.cached_property
     def reveal_program(self):
         """The device road's jitted ``device_reveal(clerk_sums, rows,
-        lagrange) -> (lo, hi)`` (``epilogue.reveal_program``)."""
+        lagrange) -> words`` (``epilogue.reveal_program``)."""
         return epilogue.reveal_program(self.plan)
 
     def clerk_sums(self, acc):
@@ -433,7 +433,8 @@ class FoldRound:
         of the 0-based ``clerks`` (at least ``reconstruction_threshold`` of
         them), on the road the clerk sums came by: host clerk sums by
         ``shamir.reconstruct_clerk_sums_host``, device ones by the device
-        reveal (``epilogue.reveal``, the aggregate fetched as uint32 words).
+        reveal (``epilogue.reveal``: the aggregate fetched as its uint32
+        words, low then high, and viewed as int64).
         In a masked round the *masked* aggregate, which :meth:`unmask` takes
         the masks off."""
         import jax
@@ -441,7 +442,7 @@ class FoldRound:
         if isinstance(clerk_sums, jax.Array):
             lagrange = epilogue.lagrange_operand(self.scheme, clerks)
             rows = np.asarray(clerks, dtype=np.int32)
-            return epilogue.fetch_aggregate(*self.reveal_program(clerk_sums, rows, lagrange))
+            return epilogue.fetch_aggregate(self.reveal_program(clerk_sums, rows, lagrange))
         out = shamir.reconstruct_clerk_sums_host(clerk_sums, clerks, self.scheme, self.plan.dim)
         return np.mod(np.asarray(out).astype(np.int64), self.modulus)
 

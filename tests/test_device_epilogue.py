@@ -172,6 +172,15 @@ def test_device_clerk_sums_and_every_reveal_of_7_of_8_are_the_host_roads_and_the
     assert np.array_equal(np.asarray(device), host)
     exact = np.array([int(v) % p for v in x.astype(object).sum(axis=0)], dtype=np.int64)
     for clerks in itertools.combinations(range(8), 7):
+        # the reveal's words: one (2·dim,) array, low words at even places
+        words = np.asarray(
+            driver.reveal_program(
+                device, np.asarray(clerks, np.int32), epilogue.lagrange_operand(scheme, clerks)
+            )
+        )
+        assert words.dtype == np.uint32 and words.shape == (2 * dim,), clerks
+        assert np.array_equal(words[0::2], exact.astype(np.uint32)), clerks
+        assert np.array_equal(words[1::2], (exact >> 32).astype(np.uint32)), clerks
         got = driver.reveal(device, list(clerks))
         assert got.dtype == np.int64 and np.array_equal(got, exact), clerks
         by_host = shamir.reconstruct_clerk_sums_host(host, list(clerks), scheme, dim)
@@ -179,6 +188,53 @@ def test_device_clerk_sums_and_every_reveal_of_7_of_8_are_the_host_roads_and_the
     # the reveal's program is one for every subset: the Lagrange matrix is an operand
     assert driver.reveal_program._cache_size() == 1
     assert epilogue.BLOCK_BATCHES == block
+
+
+@pytest.mark.parametrize("byteorder", ["little", "big"])
+def test_fetch_aggregate_views_its_words_on_a_little_endian_host_and_joins_them_elsewhere(
+    byteorder, monkeypatch
+):
+    """The words cross under their span and counter and are deleted on the
+    device; on a little-endian host the int64 aggregate shares their memory
+    (no pass, no new array), on a big-endian one they are joined; either way
+    the caller may change it, as the host road's."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    from sda_tpu import telemetry
+    from sda_tpu.parallel import epilogue
+
+    def fetched_bytes():
+        snap = telemetry.snapshot(0)
+        return sum(
+            c["value"]
+            for c in snap["counters"]
+            if c["name"] == "sda_fabric_reveal_fetch_bytes_total"
+        )
+
+    host = []
+    device_get = jax.device_get
+
+    def kept(x):
+        host.append(device_get(x))
+        return host[-1]
+
+    monkeypatch.setattr(jax, "device_get", kept)
+    monkeypatch.setattr(sys, "byteorder", byteorder)
+    values = np.array([0, 1, (1 << 32) - 1, 1 << 32, PRIME_61 - 1, 123456789012345], np.int64)
+    low_high = np.stack([values.astype(np.uint32), (values >> 32).astype(np.uint32)], axis=1)
+    words = jnp.asarray(low_high.reshape(-1))
+    before = fetched_bytes()
+    got = epilogue.fetch_aggregate(words)
+    assert got.dtype == np.int64 and got.shape == values.shape and got.flags.c_contiguous
+    assert np.array_equal(got, values)
+    assert np.shares_memory(got, host[0]) == (byteorder == "little")
+    assert fetched_bytes() == before + 8 * values.size
+    assert words.is_deleted() and got.flags.writeable
+    got[1] = 7
+    assert got[1] == 7 and np.array_equal(got[2:], values[2:])
 
 
 @pytest.mark.parametrize("width", sorted(BITS))

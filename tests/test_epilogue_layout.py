@@ -73,6 +73,8 @@ def compiled(one_chip):
             reveal = epilogue.reveal_program(plan).lower(
                 shape((n, B), jnp.int64), shape((7,), jnp.int32), shape((4, 5, 7), jnp.uint32)
             )
+            # the aggregate as one array of its words, low and high interleaved
+            assert (reveal.out_info.shape, reveal.out_info.dtype) == ((2 * DIM,), jnp.uint32)
             return {
                 "device_clerk_sums": sums.compile().as_text(),
                 "device_reveal": reveal.compile().as_text(),
